@@ -31,35 +31,32 @@ from delta_tpu.storage.logstore import logstore_for_path
 
 _CACHE_CONFIGURED = False
 
+# fixed path inside the checkout: the directory is part of the cache key,
+# so a cache under $HOME or a temp name never hits in a relocated run
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def _configure_compilation_cache() -> None:
+
+def configure_compilation_cache() -> None:
     """Point JAX at a persistent compilation cache so a fresh process
     pays ~0.2s for a snapshot load instead of a multi-second XLA compile
-    of the replay kernel's shape bucket. Opt out with
-    DELTA_TPU_JAX_CACHE=0 (or point it at a different directory)."""
+    of the replay kernel's shape bucket. `JAX_COMPILATION_CACHE_DIR`
+    places it (JAX reads that variable itself, so nothing is set here);
+    otherwise it lives at `<checkout>/.jax_cache`."""
     global _CACHE_CONFIGURED
     if _CACHE_CONFIGURED:
         return
     _CACHE_CONFIGURED = True
-    setting = os.environ.get("DELTA_TPU_JAX_CACHE", "")
-    if setting == "0":
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax
 
-    cache_dir = setting or os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "delta_tpu_jax")
-    try:
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return  # user already configured a cache
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    # delta-lint: disable=except-swallow (audited: the jax config surface
-    # varies across versions; the compile cache is an optimization and
-    # must never fail engine construction)
-    except Exception:
-        pass  # cache is an optimization; never fail engine construction
+    if jax.config.jax_compilation_cache_dir:
+        return  # caller already configured a cache through jax.config
+    os.makedirs(_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 class TpuEngine(HostEngine):
@@ -92,7 +89,7 @@ class TpuEngine(HostEngine):
         replay_shards: Optional[int] = None,
     ):
         super().__init__(store_resolver, metrics_reporters)
-        _configure_compilation_cache()
+        configure_compilation_cache()
         from delta_tpu.expressions.device_eval import DeviceExpressionHandler
 
         self.expressions = DeviceExpressionHandler()
@@ -135,15 +132,9 @@ def _default_mesh(replay_shards: Optional[int]):
         replay_shards = int(env)
     if replay_shards is not None and replay_shards <= 1:
         return None
-    try:
-        import jax
+    import jax
 
-        n = len(jax.devices())
-    # delta-lint: disable=except-swallow (audited: device discovery can
-    # fail on misconfigured hosts; engine construction must survive and
-    # fall back to the single-chip path)
-    except Exception:
-        return None
+    n = len(jax.devices())
     if replay_shards is not None:
         n = min(n, replay_shards)
     if n <= 1:

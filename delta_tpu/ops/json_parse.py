@@ -120,6 +120,8 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
     import jax
     import jax.numpy as jnp
 
+    from delta_tpu.ops.scans import cummax_1d, cumsum_1d
+
     n = n_pad
     big = jnp.int32(n)
 
@@ -149,7 +151,7 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             rb = b == 125
 
         nli = nl.astype(jnp.int32)
-        nl_rank = jnp.cumsum(nli)        # inclusive newline rank
+        nl_rank = cumsum_1d(nli)         # inclusive newline rank
         line_id = nl_rank - nli          # line containing each byte
         drop = jnp.int32(l_pad)          # OOB segment sentinel
         line_start = (jnp.zeros(l_pad, jnp.int32)
@@ -161,17 +163,16 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
 
         # escape initiators: a backslash at even offset within its run
         run_start = bs & ~shift_in(bs)
-        last_rs = jax.lax.associative_scan(
-            jnp.maximum, jnp.where(run_start, pos, jnp.int32(-1)))
+        last_rs = cummax_1d(jnp.where(run_start, pos, jnp.int32(-1)))
         initiator = bs & (((pos - last_rs) & 1) == 0)
         uq = quote & ~shift_in(initiator)  # structurally active quote
         uqi = uq.astype(jnp.int32)
-        q_cum = jnp.cumsum(uqi)
+        q_cum = cumsum_1d(uqi)
         outside = ((q_cum - uqi) & 1) == 0  # even quote parity before
 
         s_colon = colon & outside
-        depth = jnp.cumsum((lb & outside).astype(jnp.int32)
-                           - (rb & outside).astype(jnp.int32))
+        depth = cumsum_1d((lb & outside).astype(jnp.int32)
+                          - (rb & outside).astype(jnp.int32))
         c1 = s_colon & (depth == 1)
         c2 = s_colon & (depth == 2)
         c3 = s_colon & (depth >= 3)
@@ -192,7 +193,7 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
         pos_by_rank = (jnp.full(n + 1, n, jnp.int32)
                        .at[jnp.where(uq, q_cum - 1, big)]
                        .set(pos, mode="drop"))
-        bs_cum = jnp.cumsum(bs.astype(jnp.int32))
+        bs_cum = cumsum_1d(bs.astype(jnp.int32))
 
         at_ls = shift_in(nl).at[0].set(True)
 
@@ -337,14 +338,16 @@ def parse_window_fields(window: np.ndarray, n_lines: int, device=None):
     n = int(window.shape[0])
     if not window_eligible(n):
         return None
-    n_pad = pad_bucket(n)
+    from delta_tpu.ops.pallas_kernels import _BYTE_TILE
+
+    # every bucket is a whole number of byte-class tiles, so the class
+    # stage has one implementation per backend
+    n_pad = pad_bucket(n, min_bucket=_BYTE_TILE)
     l_pad = pad_bucket(n_lines + 1)
     # 0x20 padding: joins the (discarded) tail line, matches no pattern
     lane_bytes = np.full(n_pad + _TAIL_PAD, 0x20, np.uint8)
     lane_bytes[:n] = window
-    from delta_tpu.ops.pallas_kernels import _BYTE_TILE
-
-    pallas_ok = _use_device_classes() and n_pad % _BYTE_TILE == 0
+    pallas_ok = _use_device_classes()
     fn = _parse_fn_cached(n_pad, l_pad, pallas_ok)
     with obs.device_dispatch("json_parse.window",
                              key=(n_pad, l_pad, pallas_ok),

@@ -24,13 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 _LANES = 128
 _SUBLANES = 8
@@ -38,12 +32,8 @@ _TILE = _SUBLANES * _LANES
 
 
 def _x32():
-    """Scoped x32 context: `jax.enable_x64(False)` was removed from the
-    jax namespace; `jax.experimental.enable_x64` is the supported
-    scoped switch and takes the desired state as an argument."""
-    from jax.experimental import enable_x64
-
-    return enable_x64(False)
+    """Scoped x32 context (`jax.enable_x64` takes the desired state)."""
+    return jax.enable_x64(False)
 
 
 def _use_interpret() -> bool:
@@ -98,7 +88,7 @@ def interleave_bits_auto(cols, n_bits: int = 32):
     with _x32():
         stacked = jnp.stack(list(cols))
         k, n = stacked.shape
-        if not HAVE_PALLAS or n % _TILE != 0:
+        if n % _TILE != 0:
             return interleave_bits(list(cols), n_bits=n_bits)
         return interleave_bits_tiled(stacked, n_bits=n_bits)
 
@@ -191,12 +181,12 @@ def _byte_class_kernel(in_ref, out_ref):
     class bits per byte — the first stage of the device JSON parse
     (quote/escape/colon masks feed the parity scans in
     ops/json_parse.py)."""
-    b = in_ref[:]
+    b = in_ref[:].astype(jnp.int32)
     cls = jnp.zeros_like(b)
     for byte, bit in _BYTE_CLASS_VALUES:
-        cls = cls | jnp.where(b == jnp.uint8(byte), jnp.uint8(bit),
-                              jnp.uint8(0))
-    out_ref[:] = cls
+        cls = cls | jnp.where(b == jnp.int32(byte), jnp.int32(bit),
+                              jnp.int32(0))
+    out_ref[:] = cls.astype(jnp.uint8)
 
 
 @jax.jit
@@ -206,15 +196,19 @@ def byte_class_tiled(b: jnp.ndarray) -> jnp.ndarray:
     assert n % _BYTE_TILE == 0, n
     tiles = n // _BYTE_TILE
     shaped = b.reshape(tiles * _BYTE_SUBLANES, _LANES)
-    out = pl.pallas_call(
-        _byte_class_kernel,
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec((_BYTE_SUBLANES, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((_BYTE_SUBLANES, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles * _BYTE_SUBLANES, _LANES),
-                                       jnp.uint8),
-        interpret=_use_interpret(),
-    )(shaped)
+    # the parse jit traces under x64; Mosaic index maps must stay i32
+    with _x32():
+        out = pl.pallas_call(
+            _byte_class_kernel,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec((_BYTE_SUBLANES, _LANES),
+                                   lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((_BYTE_SUBLANES, _LANES),
+                                   lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct(
+                (tiles * _BYTE_SUBLANES, _LANES), jnp.uint8),
+            interpret=_use_interpret(),
+        )(shaped)
     return out.reshape(n)
 
 
@@ -300,25 +294,7 @@ def unpack_bitpacked(packed_words: np.ndarray, w: int,
     # so pin x32 semantics for the call
     with _x32():
         arr = jax.device_put(shaped, device)
-        if not HAVE_PALLAS:
-            return _unpack_jnp(arr, w)[:n_groups * 32]
         return unpack_bitpacked_tiled(arr, w)[:n_groups * 32]
-
-
-@functools.partial(jax.jit, static_argnames=("w",))
-def _unpack_jnp(packed: jnp.ndarray, w: int) -> jnp.ndarray:
-    """packed: [w, G] word-major; same output layout as the kernel."""
-    _check_unpack_width(w)
-    g = packed.shape[1]
-    mask = jnp.uint32((1 << w) - 1) if w < 32 else jnp.uint32(0xFFFFFFFF)
-    outs = []
-    for j in range(32):
-        lo, sh = divmod(j * w, 32)
-        v = packed[lo] >> jnp.uint32(sh)
-        if sh + w > 32:
-            v = v | (packed[lo + 1] << jnp.uint32(32 - sh))
-        outs.append(v & mask)
-    return jnp.stack(outs, axis=-1).reshape(g * 32)
 
 
 # ---------------------------------------------------------------------------
@@ -380,6 +356,6 @@ def shift_extract(lo: jnp.ndarray, hi: jnp.ndarray, sh: jnp.ndarray,
     """Trace-time dispatcher used INSIDE the page-decode jit: the Pallas
     tile on TPU, the identical fused-jnp body elsewhere (interpret-mode
     Pallas inside a large jit would serialize the whole dispatch)."""
-    if use_pallas and HAVE_PALLAS and lo.shape[0] % _TILE == 0:
+    if use_pallas and lo.shape[0] % _TILE == 0:
         return shift_extract_tiled(lo, hi, sh, w)
     return _shift_extract_body(lo, hi, sh, w)

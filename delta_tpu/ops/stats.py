@@ -55,9 +55,9 @@ def _x64():
     int64 device math without flipping the process-global
     `jax_enable_x64`, which would silently change default dtypes for
     every other kernel sharing the process."""
-    from jax.experimental import enable_x64
+    import jax
 
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def device_stats_enabled(engine=None) -> bool:
@@ -81,15 +81,9 @@ def device_dv_decode_enabled() -> bool:
 def accel_backend_default() -> bool:
     """Construction-time autodetect for TpuEngine: aggregate on device
     when a real accelerator backend is present."""
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    # delta-lint: disable=except-swallow (audited: backend discovery can
-    # fail on misconfigured hosts; engine construction must survive and
-    # the stats stage falls back to the host path)
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 # ---------------------------------------------------------- aggregation

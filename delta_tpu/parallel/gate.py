@@ -1,12 +1,10 @@
-"""DEVICE_MERIT-derived profitability gate for the replay product path.
+"""Link-model profitability gate for the replay product path.
 
 The replay driver has three routes — host-vectorized, single-chip
 kernel, and mesh-sharded (`parallel/sharded_replay.py`) — and the right
-one depends on the *link*, not the compute: DEVICE_MERIT.json measured
-the bench host's host<->device path at ~1.05 GB/s for <=8 MB transfers
-but only ~29 MB/s beyond, with a 78 ms round trip. This module turns
-those measurements into the routing decision instead of hardcoded row
-counts:
+one depends on the *link*, not the compute: the host<->device path's
+bandwidth per transfer size and its round trip. This module turns a
+`LinkModel` into the routing decision instead of hardcoded row counts:
 
 - tiny segments are RTT-dominated -> host replay beats any device
   dispatch;
@@ -16,14 +14,15 @@ counts:
   state residency (parallel/resident.py) amortizes the link cost across
   `Snapshot.update()` calls.
 
-The model is loaded from DEVICE_MERIT.json at the repo root when the
-default JAX backend is an accelerator; on CPU backends (tests, dev
-boxes) transfers are memcpys and the model collapses to "device always
-profitable" so behavior is deterministic. Env overrides:
+On accelerator backends the model is the `_FALLBACK_*` placeholders
+below unless `DELTA_TPU_LINK_MODEL` names a measured capture; on CPU
+backends (tests, dev boxes) transfers are memcpys and the model
+collapses to "device always profitable" so behavior is deterministic.
+Env overrides:
 
   DELTA_TPU_REPLAY_ROUTE       force "host" | "single" | "sharded"
   DELTA_TPU_SHARDED_MIN_ROWS   row floor for the sharded route
-  DELTA_TPU_LINK_MODEL         path to an alternative DEVICE_MERIT json
+  DELTA_TPU_LINK_MODEL         path to a link-model json (device_merit shape)
   DELTA_TPU_LINK_H2D_BPS       flat H2D bandwidth override (bytes/s)
   DELTA_TPU_LINK_RTT_S         round-trip override (seconds)
   DELTA_TPU_H2D_CHUNK          transfer chunk size override (bytes)
@@ -48,8 +47,9 @@ from typing import Dict, NamedTuple, Optional
 from delta_tpu.obs.device import record_gate_decision
 from delta_tpu.obs.registry import counter
 
-# Fallbacks when no DEVICE_MERIT.json is available (same shape as the
-# bench host's measurements so the gate degrades to sane behavior).
+# PLACEHOLDERS, not measured on this device: no capture of the link the
+# engine runs on today exists yet (ROADMAP A2), so these shape the
+# routing until one is supplied through DELTA_TPU_LINK_MODEL.
 _FALLBACK_H2D = {8 << 20: 1_050_000_000.0, 64 << 20: 29_000_000.0}
 _FALLBACK_RTT_S = 0.078
 # replay_fa workload calibration fallbacks: host-vectorized replay rate
@@ -97,7 +97,7 @@ _DEVICE_SKIP_CELLS_PS = 5e9
 # segment reductions are memory-bound. As with the other gates only
 # the crossover's order of magnitude matters — the dominant real-world
 # term is the link (`h2d_seconds` over the operand bytes), which is
-# what keeps SQL on host across a slow tunnel and on device locally.
+# what keeps SQL on host across a slow link and on device locally.
 _HOST_SQL_ROWS_PS = {"join": 8e6, "group-agg": 20e6, "sort": 15e6}
 _DEVICE_SQL_ROWS_PS = {"join": 120e6, "group-agg": 300e6, "sort": 150e6}
 
@@ -133,42 +133,24 @@ class LinkModel(NamedTuple):
 _CPU_MODEL = LinkModel({}, 0.0, _FALLBACK_HOST_ROWS_S, float("inf"))
 
 
-def _device_platform() -> str:
-    try:
-        import jax
-
-        return jax.default_backend()
-    # delta-lint: disable=except-swallow (audited: backend discovery can
-    # fail on hosts with no configured platform; the gate must degrade
-    # to the CPU model, never fail routing)
-    except Exception:
-        return "cpu"
-
-
-def _model_path() -> Optional[Path]:
-    override = os.environ.get("DELTA_TPU_LINK_MODEL")
-    if override:
-        return Path(override)
-    p = Path(__file__).resolve().parents[2] / "DEVICE_MERIT.json"
-    return p if p.exists() else None
-
-
 @functools.lru_cache(maxsize=1)
 def link_model() -> LinkModel:
-    """The active link model: measured numbers on accelerator backends,
-    the trivial (free-transfer) model on CPU backends."""
-    if (_device_platform() == "cpu"
-            and not os.environ.get("DELTA_TPU_LINK_MODEL")):
+    """The active link model: the `DELTA_TPU_LINK_MODEL` capture (or the
+    placeholders) on accelerator backends, the trivial (free-transfer)
+    model on CPU backends."""
+    import jax
+
+    override = os.environ.get("DELTA_TPU_LINK_MODEL")
+    if jax.default_backend() == "cpu" and not override:
         return _CPU_MODEL
 
     h2d = dict(_FALLBACK_H2D)
     rtt = _FALLBACK_RTT_S
     host_rate = _FALLBACK_HOST_ROWS_S
     dev_rate = _FALLBACK_DEVICE_ROWS_S
-    path = _model_path()
-    if path is not None:
+    if override:
         try:
-            merit = json.loads(path.read_text())
+            merit = json.loads(Path(override).read_text())
             link = merit.get("link", {})
             raw = link.get("h2d_bytes_per_s") or {}
             if raw:

@@ -1,7 +1,7 @@
 """Device-resident sharded replay state across `Snapshot.update()`.
 
 The sharded replay (`sharded_replay.py`) already rebuilds each shard's
-key lane on device; DEVICE_MERIT.json says the expensive thing is the
+key lane on device; the design assumes the expensive thing is the
 host->device link, not the sort. So after a sharded full replay the
 rebuilt per-shard key lane is simply KEPT on device (zero extra
 transfer — `want_key` in the FA kernel), and every incremental

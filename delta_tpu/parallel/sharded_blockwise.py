@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from delta_tpu import obs
@@ -50,11 +51,6 @@ from delta_tpu.ops.replay import (
 )
 from delta_tpu.ops.replay_blockwise import _block_kernel_impl
 from delta_tpu.parallel.sharded_replay import REPLAY_AXIS
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 DEFAULT_BLOCK_ROWS = 1 << 20  # 1M rows/shard/block
 

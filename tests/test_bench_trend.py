@@ -210,20 +210,3 @@ def test_cli_backfill_and_metric_filter(tmp_path, capsys):
                              "--metric", "a_per_sec"]) == 0
     out = capsys.readouterr().out
     assert "a_per_sec" in out and "b_per_sec" not in out
-
-
-def test_repo_artifacts_produce_verdicts():
-    """Acceptance: the tool runs over the repo's own BENCH_r01..r06 and
-    reaches a banded verdict for the cross-revision headline metric."""
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    paths = bench_trend._find_artifacts(root, "BENCH_r*.json")
-    assert len(paths) >= 6
-    runs = bench_trend.load_bench_runs(paths)
-    assert all(r["fingerprint"] == CONDITIONS_UNKNOWN for r in runs)
-    verdicts = bench_trend.trend_verdicts(
-        runs, metrics=["e2e_snapshot_load_actions_per_sec"])
-    [v] = verdicts
-    assert v["comparable_points"] >= 3
-    assert v["verdict"] in ("stable", "improved", "regressed")

@@ -1,0 +1,168 @@
+"""Ask the v5e compiler, not the chip: the device kernels of the product
+path compile for a *described* TPU v5e at the shapes `chip_smoke.py`
+produces (on-chip-measurement guide, section 2).
+
+Nothing runs — a passing compile says the chip's compiler accepts the
+program (tiling, VMEM, HBM, Mosaic legality), not that its result or its
+speed is right. Everything about the chip happens inside the `topo`
+fixture, after this file's first test has started: only the xdist worker
+that is handed this file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from delta_tpu.ops import json_parse, page_decode, pallas_kernels, skipping
+from delta_tpu.ops import replay, scans, sqlops
+
+V5E_HBM_BYTES = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back: keep these out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+    # traces made with interpret=False must not leak to CPU tests that
+    # share this worker
+    json_parse._parse_fn_cached.cache_clear()
+    page_decode._decode_fn.cache_clear()
+    skipping._skip_fn_cached.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.fixture
+def on_chip(topo, monkeypatch):
+    """Shape builder placed on the described chip, with the kernels
+    steered onto their TPU branch (compiled Mosaic, device byte
+    classes) as `jax.default_backend() == "tpu"` would."""
+    monkeypatch.setattr(pallas_kernels, "_use_interpret", lambda: False)
+    monkeypatch.setattr(json_parse, "_use_device_classes", lambda: True)
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    return shape
+
+
+def _assert_fits(compiled):
+    ma = compiled.memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes)
+    assert total < V5E_HBM_BYTES, ma
+
+
+def _assert_mosaic(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_fits(compiled)
+
+
+def test_interleave_bits_tiled_4m_rows(on_chip):
+    cols = on_chip((3, 1 << 22), jnp.uint32)
+    with jax.enable_x64(False):  # as interleave_bits_auto pins it
+        compiled = (pallas_kernels.interleave_bits_tiled
+                    .lower(cols, n_bits=32).compile())
+    _assert_mosaic(compiled)
+
+
+def test_byte_class_tiled_64mib(on_chip):
+    window = on_chip((64 << 20,), jnp.uint8)
+    with jax.enable_x64(True):  # traced inside the x64 parse jit
+        compiled = pallas_kernels.byte_class_tiled.lower(window).compile()
+    _assert_mosaic(compiled)
+
+
+def test_shift_extract_tiled_16m(on_chip):
+    lane = on_chip((16 << 20,), jnp.uint32)
+    with jax.enable_x64(False):  # as decode_part pins it
+        compiled = (pallas_kernels.shift_extract_tiled
+                    .lower(lane, lane, lane, lane).compile())
+    _assert_mosaic(compiled)
+
+
+def test_winner_kernel_1m_bucket(on_chip):
+    m = replay.pad_bucket(1_000_000)
+    operands = (on_chip((m,), jnp.uint8),) * 3 + (on_chip((), jnp.int32),)
+    _assert_fits(replay._winner_kernel.lower(operands, width=3).compile())
+
+
+def test_winner_kernel_fa_packed_1m_bucket(on_chip):
+    # the smoke's cold-load layout: 3 ref planes over 262144 refs
+    m, r_pad = replay.pad_bucket(1_000_000), 262144
+    layout = (m, 3, r_pad, 0)
+    buf = on_chip((8 + m // 8 + 3 * r_pad,), jnp.uint8)
+    _assert_fits(replay._winner_kernel_fa_packed
+                 .lower(buf, layout=layout).compile())
+
+
+def test_json_parse_window_1mib(on_chip):
+    n_pad, l_pad = 1 << 20, 8192
+    with jax.enable_x64(True):  # as parse_window_fields runs it
+        fn = json_parse._parse_fn_cached(
+            n_pad, l_pad, json_parse._use_device_classes())
+        compiled = fn.lower(
+            on_chip((n_pad + json_parse._TAIL_PAD,), jnp.uint8),
+            on_chip((), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+
+
+def test_chunked_scans_64m(on_chip):
+    """The scans that made the parse jit uncompilable at its product
+    window, at that window's length."""
+    lane = on_chip((64 << 20,), jnp.int32)
+    compiled = jax.jit(
+        lambda x: (scans.cumsum_1d(x), scans.cummax_1d(x))
+    ).lower(lane).compile()
+    _assert_fits(compiled)
+
+
+def test_page_decode_part(on_chip):
+    b_pad, r_pad, p_pad, h_pad, n_pad = 262144, 2048, 256, 1 << 20, 1 << 19
+    with jax.enable_x64(False):  # as decode_part runs it
+        fn = page_decode._decode_fn(b_pad, r_pad, p_pad, h_pad, n_pad,
+                                    n_pad, True, True)
+        compiled = fn.lower(
+            on_chip((b_pad,), jnp.uint8),
+            on_chip((r_pad, page_decode.RUN_F), jnp.int32),
+            on_chip((p_pad, page_decode.PAGE_F), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+
+
+def test_skipping_mask_block_1m_files(on_chip):
+    rows, f_pad, a_pad, g_segs = 4, 1 << 20, 16, 17
+    atoms = on_chip((a_pad,), jnp.int32)
+    with jax.enable_x64(True):
+        compiled = skipping._skip_fn_cached(a_pad, g_segs).lower(
+            on_chip((rows, f_pad), jnp.int64),
+            on_chip((rows, f_pad), jnp.bool_),
+            atoms, atoms, atoms, atoms, on_chip((a_pad,), jnp.int64),
+            atoms, on_chip((), jnp.int32)).compile()
+    _assert_fits(compiled)
+
+
+def test_sql_group_aggregate_4m_rows(on_chip):
+    n = 1 << 22
+    with jax.enable_x64(True):
+        compiled = sqlops._segagg_kernel.lower(
+            on_chip((n,), jnp.int32), on_chip((n,), jnp.int64),
+            on_chip((n,), jnp.bool_), op="sum", n_seg=256).compile()
+    _assert_fits(compiled)

@@ -5,14 +5,11 @@ import numpy as np
 import pytest
 
 from delta_tpu.ops.pallas_kernels import (
-    HAVE_PALLAS,
     batched_file_stats,
     interleave_bits_auto,
     interleave_bits_tiled,
 )
 from delta_tpu.ops.zorder import interleave_bits
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 
 
 def test_interleave_tiled_matches_jnp():
