@@ -798,7 +798,7 @@ def checkpoint_read_metric(workdir: str) -> None:
     to 0 when the routes' reconstructed states diverge or the device
     route was vacuous (no part actually decoded on device, or any part
     fell back); capture conditions ride on the metric line so
-    delta-bench-trend groups comparable runs."""
+    comparable runs can be grouped."""
     from delta_tpu import obs
     from delta_tpu.config import settings
     from delta_tpu.engine.host import HostEngine
@@ -2456,7 +2456,7 @@ def main():
     n_actions = commits * FILES_PER_COMMIT
 
     # capture-conditions stamp: rides into the bench artifact's metric
-    # list so delta-bench-trend groups this run with comparable history
+    # list so this run can be grouped with comparable history
     from delta_tpu import obs as _obs
     print(json.dumps({
         "metric": "capture_conditions",

@@ -44,12 +44,6 @@ FORCE_ENV = {
     "DELTA_TPU_DEVICE_SKIP": "force",
 }
 
-# (jitted function, seconds) of every JAX backend compile (a cache
-# retrieval counts as one), appended by the listener `main` installs
-_COMPILES: list = []
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
 def emit(phase: str, **fields) -> None:
     print(json.dumps({"phase": phase, **fields}, default=str), flush=True)
 
@@ -59,21 +53,21 @@ def check(cond, msg: str) -> None:
         raise SystemExit(f"chip_smoke: check failed: {msg}")
 
 
-def _on_duration(event: str, seconds: float, fun_name="?", **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        _COMPILES.append((str(fun_name), seconds))
-
-
-def compile_seconds(start: int = 0) -> float:
-    return sum(s for _, s in _COMPILES[start:])
+def compiled_programs(records) -> list:
+    """[dispatch, program, backend seconds, "cache" | "built"] of every
+    program the compiler reported inside one of the dispatch `records`
+    (`obs/device.py` joins JAX's compile events to the open dispatch)."""
+    return [[r["kernel"], p["fun_name"], round(p["compile_s"], 3),
+             "cache" if p["cache_hit"] else "built"]
+            for r in records for p in r.get("programs", ())]
 
 
 class Step:
-    """Wall clock, compile seconds, gate decisions and device dispatches
-    of one step, run under the route overrides `env` (the host oracles
-    run outside a step, so they never see an override). Every API
-    called inside returns numpy/Arrow, so the wall time has
-    `block_until_ready` semantics."""
+    """Wall clock, gate decisions and device dispatches of one step,
+    with the compile seconds its dispatch records carry, run under the
+    route overrides `env` (the host oracles run outside a step, so they
+    never see an override). Every API called inside returns
+    numpy/Arrow, so the wall time has `block_until_ready` semantics."""
 
     def __init__(self, env=None):
         self._env = env or {}
@@ -81,14 +75,11 @@ class Step:
     def __enter__(self):
         os.environ.update(self._env)
         self._ts = time.time_ns()
-        self._c0 = len(_COMPILES)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.wall_s = time.perf_counter() - self._t0
-        self.compiles = _COMPILES[self._c0:]
-        self.compile_s = compile_seconds(self._c0)
         self._te = time.time_ns()
         for k in self._env:
             del os.environ[k]
@@ -112,12 +103,12 @@ class Step:
         mine = self._mine(obs.get_dispatch_records())
         dispatches = collections.Counter(r["kernel"] for r in mine)
         shapes = [f"{r['kernel']}{r['key']}" for r in mine if r["compile"]]
+        compile_s = sum(r["compile_s"] for r in mine)
         return {
             "wall_s": round(self.wall_s, 3),
-            "compile_s": round(self.compile_s, 3),
-            "steady_s": round(self.wall_s - self.compile_s, 3),
-            "compiles_over_1s": [[n, round(t, 1)] for n, t in self.compiles
-                                 if t >= 1.0],
+            "compile_s": round(compile_s, 3),
+            "steady_s": round(self.wall_s - compile_s, 3),
+            "programs": compiled_programs(mine),
             "gates": gates,
             "dispatches": dispatches,
             "new_shapes": shapes,
@@ -349,7 +340,6 @@ def phase_log(path: str, staged: str, commits: int) -> None:
     check(not any(k in os.environ for k in FORCE_ENV),
           "a route variable is set; the default leg needs none")
     oracle: dict = {}
-    obs.set_device_obs_mode("on")
     log_leg("default", {}, path, staged, commits, oracle)
 
     watched = ([r.fallback_counter for r in gate.ROUTES.values()]
@@ -401,7 +391,6 @@ def phase_data(workdir: str, seed: int) -> None:
     from delta_tpu.expressions import col, lit
     from delta_tpu.obs import hbm
 
-    obs.set_device_obs_mode("on")
     root = os.path.join(workdir, "data")
     shutil.rmtree(root, ignore_errors=True)
     os.makedirs(root)
@@ -491,12 +480,11 @@ def phase_mesh(path: str, chips: int) -> None:
     """The mesh-sharded replay, asked for the documented way
     (`TpuEngine(mesh=make_mesh())`), against the single-chip kernel and
     the host oracle."""
-    from delta_tpu import Table, obs
+    from delta_tpu import Table
     from delta_tpu.engine.tpu import TpuEngine
     from delta_tpu.parallel.mesh import make_mesh
     from delta_tpu.replay.columnar import clear_parse_cache
 
-    obs.set_device_obs_mode("on")
     # the replay route is this phase's subject: the commit parse stays
     # on the host scanner (the one-chip run covers the device parse)
     host_parse = {"DELTA_TPU_DEVICE_PARSE": "off"}
@@ -556,7 +544,11 @@ def main(argv=None) -> int:
                           os.path.join(args.workdir, "native"))
     import jax
 
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    from delta_tpu import obs
+
+    # on for the whole run: the dispatch records carry what the
+    # compiler reports (compile seconds, program name, cache hit)
+    obs.set_device_obs_mode("on")
 
     t0 = time.perf_counter()
     phase = "device"
@@ -577,9 +569,17 @@ def main(argv=None) -> int:
               file=sys.stderr, flush=True)
         raise
     stats = jax.devices()[0].memory_stats() or {}
+    records = obs.get_dispatch_records()
+    by_dispatch = collections.defaultdict(set)
+    for kernel, program, _, _ in compiled_programs(records):
+        by_dispatch[kernel].add(program)
     emit("total", peak_bytes_in_use=stats.get("peak_bytes_in_use"),
-         compile_s=round(compile_seconds(), 3),
+         compile_s=round(sum(r["compile_s"] for r in records), 3),
+         programs={k: sorted(v) for k, v in sorted(by_dispatch.items())},
          wall_s=round(time.perf_counter() - t0, 3))
+    launched = {p for programs in by_dispatch.values() for p in programs}
+    check(not launched & {"jit(kernel)", "jit(fn)", "jit(<lambda>)"},
+          f"a dispatch launched an unnamed program: {dict(by_dispatch)}")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -34,6 +34,7 @@ from delta_tpu.obs.device import (
     gate_observation,
     get_dispatch_records,
     get_gate_records,
+    program,
     record_gate_decision,
     reset_device_obs,
     set_device_obs_mode,
@@ -94,6 +95,7 @@ from delta_tpu.obs.trace import (
     current_span,
     get_finished_spans,
     process_label,
+    record_span,
     remote_parent,
     remove_exporter,
     reset_trace_buffer,
@@ -165,11 +167,13 @@ __all__ = [
     "metric_catalog",
     "metrics_snapshot",
     "record_gate_decision",
+    "record_span",
     "reset_device_obs",
     "reset_hbm_obs",
     "set_hbm_obs_mode",
     "parse_prometheus",
     "process_label",
+    "program",
     "prom_name",
     "registry",
     "remote_parent",

@@ -12,8 +12,15 @@ the runtime half of both:
   recording per-kernel wall time, whether this launch compiled (first
   sighting of a shape-bucket `key`) or ran steady-state, and actual
   H2D/D2H bytes per named lane (``dd.h2d("lane_bytes", arr, units=n)``).
-  Recompile storms from shape churn become a counted, alarmable event
-  (`device.recompile_storms`) instead of a silent bench mystery.
+  What the compiler itself reports joins the record: while device obs
+  is on, JAX's monitoring listeners put on the dispatch open on the
+  compiling thread each program's `fun_name`, its backend seconds
+  (`compile_s`) and whether the persistent cache answered, and the same
+  interval lands as a finished `device.compile` span. Where a launch is
+  asynchronous, the later blocking read joins through ``dd.wait()``
+  (`wait_ns`). Recompile storms from shape churn become a counted,
+  alarmable event (`device.recompile_storms`) instead of a silent bench
+  mystery.
 - **Runtime transfer-budget audit** — observed lane bytes are
   reconciled against `resources/transfer_budget.json` at dispatch exit:
   each recorded lane must match its manifest declaration byte-exactly
@@ -107,6 +114,7 @@ def set_device_obs_mode(mode: Optional[str]) -> None:
             raise ValueError(
                 f"unknown device obs mode {mode!r}; expected off|on|strict"
             ) from None
+    _sync_compile_listeners()
 
 
 # -- instruments (resolved once; see resources/metric_names.json) ------------
@@ -337,6 +345,71 @@ def gate_observation(gate: str, route: str):
     return _GateObsCtx(gate, route)
 
 
+# -- compile events from the compiler ----------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# fired only when the persistent cache answered, inside the compile
+# event of the same program, on the compiling thread
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+# the dispatch open in the calling context (jit compiles on the thread
+# that calls it, so the compile event finds its dispatch here)
+_OPEN: contextvars.ContextVar[Optional["_DispatchCtx"]] = (
+    contextvars.ContextVar("delta_tpu_open_dispatch", default=None))
+_retrieval = threading.local()
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _retrieval.seconds = duration_secs
+
+
+def _on_time_span(event: str, start_time: float, end_time: float,
+                  fun_name: str = "?", **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    retrieval_s = getattr(_retrieval, "seconds", None)
+    _retrieval.seconds = None
+    compile_s = end_time - start_time
+    program = {"fun_name": str(fun_name), "compile_s": compile_s,
+               "cache_hit": retrieval_s is not None}
+    if retrieval_s is not None:
+        program["cache_retrieval_s"] = retrieval_s
+    dd = _OPEN.get()
+    if dd is not None:
+        dd._programs.append(program)
+    _trace.record_span("device.compile", int(start_time * 1e9),
+                       int(compile_s * 1e9),
+                       kernel=dd._name if dd is not None else None,
+                       fun_name=program["fun_name"],
+                       cache_hit=program["cache_hit"])
+
+
+def _sync_compile_listeners() -> None:
+    """JAX's listeners are registered while device obs is on and
+    unregistered when it goes off. A process that has not imported JAX
+    yet registers at its first live dispatch (the site is about to
+    call a jit, so JAX is there by then)."""
+    global _listening
+    want = _mode != MODE_OFF
+    if want == _listening or (want and "jax" not in sys.modules):
+        return
+    with _listen_lock:
+        if want == _listening:
+            return
+        from jax import monitoring
+
+        if want:
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_time_span_listener(_on_time_span)
+        else:
+            monitoring.unregister_event_duration_listener(_on_duration)
+            monitoring.unregister_event_time_span_listener(_on_time_span)
+        _listening = want
+
+
 # -- the dispatch funnel -----------------------------------------------------
 
 
@@ -362,6 +435,9 @@ class _NoopDispatch:
     def set(self, **attrs) -> None:
         pass
 
+    def wait(self):
+        return self
+
 
 _NOOP_DISPATCH = _NoopDispatch()
 
@@ -385,7 +461,8 @@ class _DispatchCtx:
     """Live-path recorder for one kernel launch."""
 
     __slots__ = ("_name", "_key", "_budget", "_units", "_gate", "_route",
-                 "_attrs", "_lanes", "_h2d_total", "_d2h_total", "_t0")
+                 "_attrs", "_lanes", "_h2d_total", "_d2h_total", "_t0",
+                 "_programs", "_open_token", "_record", "_gate_record")
 
     def __init__(self, name: str, key, budget: Optional[str],
                  units: Optional[int], gate: Optional[str], route: str):
@@ -400,10 +477,22 @@ class _DispatchCtx:
         self._h2d_total = 0
         self._d2h_total = 0
         self._t0 = 0
+        self._programs: List[dict] = []
+        self._open_token = None
+        self._record: Optional[dict] = None
+        self._gate_record: Optional[dict] = None
 
     def __enter__(self):
+        self._open_token = _OPEN.set(self)
         self._t0 = time.perf_counter_ns()
         return self
+
+    def wait(self):
+        """Context manager round the blocking read of an asynchronous
+        launch, after this dispatch has closed: its time joins the
+        record (`wait_ns`) and the gate decision the launch joined, so
+        both see the kernel and not only its launch."""
+        return _DispatchWait(self)
 
     def h2d(self, lane: str, obj, units: Optional[int] = None):
         """Record `obj` (an array about to cross host->device, or an
@@ -458,6 +547,7 @@ class _DispatchCtx:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         wall_ns = time.perf_counter_ns() - self._t0
+        _OPEN.reset(self._open_token)
         compiled = False
         n_keys = 0
         if self._key is not None:
@@ -489,6 +579,7 @@ class _DispatchCtx:
             "key": repr(self._key) if self._key is not None else None,
             "compile": compiled,
             "distinct_keys": n_keys,
+            "compile_s": sum(p["compile_s"] for p in self._programs),
             "wall_ns": wall_ns,
             "h2d_bytes": self._h2d_total,
             "d2h_bytes": self._d2h_total,
@@ -504,8 +595,12 @@ class _DispatchCtx:
         }
         if self._attrs:
             rec["attrs"] = self._attrs
+        if self._programs:
+            rec["programs"] = self._programs
+        self._record = rec
         _dispatch_ring.append(rec)
         if self._gate is not None:
+            self._gate_record = (_PENDING.get() or {}).get(self._gate)
             # failed dispatches feed calibration too: a route that burns
             # wall time and then falls back to host must look *more*
             # expensive to the gate, not invisible
@@ -520,6 +615,32 @@ class _DispatchCtx:
             if _mode >= MODE_STRICT and exc_type is None:
                 raise RuntimeError(
                     "transfer budget exceeded: " + "; ".join(violations))
+        return False
+
+
+class _DispatchWait:
+    """Times the blocking read that follows an asynchronous launch
+    (see `_DispatchCtx.wait`)."""
+
+    __slots__ = ("_dd", "_t0")
+
+    def __init__(self, dd: _DispatchCtx):
+        self._dd = dd
+        self._t0 = 0
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        wait_ns = time.perf_counter_ns() - self._t0
+        rec = self._dd._record
+        if rec is not None:
+            rec["wait_ns"] = rec.get("wait_ns", 0) + wait_ns
+        gate_rec = self._dd._gate_record
+        if gate_rec is not None and not gate_rec.get("_final"):
+            gate_rec["observed_s"] = ((gate_rec["observed_s"] or 0.0)
+                                      + wait_ns / 1e9)
         return False
 
 
@@ -547,7 +668,32 @@ def device_dispatch(name: str, *, key=None, budget: Optional[str] = None,
                                           route=route)
     if _mode == MODE_OFF:
         return _NOOP_DISPATCH
+    if not _listening:
+        _sync_compile_listeners()
     return _DispatchCtx(name, key, budget, units, gate, route)
+
+
+def program(name: str):
+    """Name the function a site hands to `jax.jit` after the dispatch
+    that launches it: ``jax.jit(obs.program("json_parse.window")(fn))``
+    compiles as `jit_json_parse_window`, so a reader joins dispatch
+    records to the device operations of a profile by name (dots become
+    underscores; a dispatch that launches several programs suffixes
+    them). The name is part of the persistent cache's key, the scopes
+    inside a program are not: a program that gains `jax.named_scope`s
+    has to change its name too, or the cache hands back the old
+    executable without them. Wraps, never renames `fn` itself."""
+    program_name = name.replace(".", "_")
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def named(*args, **kwargs):
+            return fn(*args, **kwargs)
+
+        named.__name__ = named.__qualname__ = program_name
+        return named
+
+    return deco
 
 
 def get_dispatch_records() -> List[dict]:
@@ -570,9 +716,9 @@ def reset_device_obs() -> None:
 
 CONDITIONS_SCHEMA = "delta-tpu/capture-conditions/v1"
 
-# sentinel stamped onto pre-schema bench artifacts by the backfill tool
-# (obs/bench_trend.py) so trend analysis can refuse to mix them with
-# conditioned captures instead of silently comparing across platforms
+# sentinel for bench artifacts that predate the schema, so a comparison
+# can refuse to mix them with conditioned captures instead of silently
+# comparing across platforms
 CONDITIONS_UNKNOWN = "unknown-pre-r20"
 
 # Every env knob that can change a routing decision or the shape of

@@ -20,6 +20,12 @@ made once at the root and inherited by every descendant (including
 cross-thread children via `wrap()` and cross-process children via the
 envelope ids, which an unsampled client simply never stamps).
 
+Profiler bridge: in a process that has imported JAX, a live span also
+enters a `jax.profiler.TraceAnnotation` of its name (the span id rides
+as an argument), so a profile taken while tracing holds the program's
+spans of every thread on the trace's own clock, beside the device
+lines. Outside a profiling session the annotation is one flag test.
+
 Finished spans land in a bounded in-process ring buffer
 (`get_finished_spans`) and are fanned out to registered exporters;
 `DELTA_TPU_TRACE_FILE=<path>` auto-installs a JSONL exporter.
@@ -32,6 +38,7 @@ import contextvars
 import logging
 import os
 import random
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -113,6 +120,27 @@ def process_label() -> Optional[str]:
 
 def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
+
+
+# `jax.profiler.TraceAnnotation`, resolved by the first live span of a
+# process that has imported JAX (False: this JAX has none). A process
+# that never imported JAX holds no device to profile, so the bridge
+# never imports it either; with tracing off nothing here is reached.
+_annotation_cls = None
+
+
+def _profiler_annotation(name: str, span_id: str):
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _annotation_cls = cls
+    return cls(name, span_id=span_id) if cls else None
 
 
 class Span:
@@ -244,13 +272,14 @@ class _SpanCtx:
     """Live-path context manager: creates the span on __enter__ (so the
     parent is read from the entering context, not the creating one)."""
 
-    __slots__ = ("_name", "_attrs", "_span", "_token")
+    __slots__ = ("_name", "_attrs", "_span", "_token", "_annotation")
 
     def __init__(self, name: str, attrs: Dict[str, object]):
         self._name = name
         self._attrs = attrs
         self._span: Optional[Span] = None
         self._token = None
+        self._annotation = None
 
     def __enter__(self):
         parent = _CURRENT.get()
@@ -268,6 +297,9 @@ class _SpanCtx:
         s = Span(self._name, trace_id, _new_id(8), parent_id, self._attrs)
         self._span = s
         self._token = _CURRENT.set(s)
+        self._annotation = _profiler_annotation(self._name, s.span_id)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return s
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -280,6 +312,8 @@ class _SpanCtx:
                 self._token = None
             return False
         s.duration_ns = time.perf_counter_ns() - s.monotonic_start_ns
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         if exc_type is not None:
             s.status = "error"
             s.attrs.setdefault("error.type", exc_type.__name__)
@@ -304,6 +338,29 @@ def span(name: str, _verbose: bool = False, **attrs):
     if _CURRENT.get() is _SUPPRESSED:
         return _NOOP_CTX  # unsampled trace: skip the ctx allocation too
     return _SpanCtx(name, attrs)
+
+
+def record_span(name: str, start_unix_ns: int, duration_ns: int,
+                **attrs) -> None:
+    """Record an interval that something else timed (a compiler event,
+    say) as a finished span with that start and length, under the span
+    open in the calling context. No-op when tracing is off or the
+    trace is unsampled; outside any span it roots a trace of its own."""
+    if _mode == MODE_OFF:
+        return
+    parent = _CURRENT.get()
+    if parent is _SUPPRESSED:
+        return
+    if parent is not None:
+        trace_id, parent_id = parent.trace_id, parent.span_id
+    else:
+        if _sample_rate < 1.0 and _sample_rng.random() >= _sample_rate:
+            return
+        trace_id, parent_id = _new_id(16), None
+    s = Span(name, trace_id, _new_id(8), parent_id, attrs)
+    s.start_unix_ns = int(start_unix_ns)
+    s.duration_ns = int(duration_ns)
+    _finish(s)
 
 
 def current_span() -> Optional[Span]:

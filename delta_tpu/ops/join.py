@@ -38,6 +38,7 @@ _PAD_CODE = np.uint32(0xFFFFFFFF)
 
 
 @functools.partial(jax.jit, static_argnames=("nt_pad", "ns_pad"))
+@obs.program("join.merge_match")
 def _join_kernel(codes, nt_pad: int, ns_pad: int):
     """codes u32[nt_pad + ns_pad]: target codes then source codes, pads =
     all-ones sentinel. Returns (match_src i32[nt_pad] source-local row or

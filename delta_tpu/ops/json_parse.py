@@ -129,92 +129,97 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
         """Previous-byte view of a mask (False shifted in at pos 0)."""
         return jnp.concatenate([jnp.zeros(1, m.dtype), m[:-1]])
 
+    @obs.program("json_parse.window")
     def kernel(bx, n_lines):
-        b = bx[:n]
-        pos = jnp.arange(n, dtype=jnp.int32)
-        if pallas_classes:
-            from delta_tpu.ops.pallas_kernels import byte_class_tiled
+        with jax.named_scope("parse.classes"):
+            b = bx[:n]
+            pos = jnp.arange(n, dtype=jnp.int32)
+            if pallas_classes:
+                from delta_tpu.ops.pallas_kernels import byte_class_tiled
 
-            cls = byte_class_tiled(b)
-            nl = (cls & 1) != 0
-            quote = (cls & 2) != 0
-            bs = (cls & 4) != 0
-            colon = (cls & 8) != 0
-            lb = (cls & 16) != 0
-            rb = (cls & 32) != 0
-        else:
-            nl = b == 10
-            quote = b == 34
-            bs = b == 92
-            colon = b == 58
-            lb = b == 123
-            rb = b == 125
+                cls = byte_class_tiled(b)
+                nl = (cls & 1) != 0
+                quote = (cls & 2) != 0
+                bs = (cls & 4) != 0
+                colon = (cls & 8) != 0
+                lb = (cls & 16) != 0
+                rb = (cls & 32) != 0
+            else:
+                nl = b == 10
+                quote = b == 34
+                bs = b == 92
+                colon = b == 58
+                lb = b == 123
+                rb = b == 125
 
-        nli = nl.astype(jnp.int32)
-        nl_rank = cumsum_1d(nli)         # inclusive newline rank
-        line_id = nl_rank - nli          # line containing each byte
-        drop = jnp.int32(l_pad)          # OOB segment sentinel
-        line_start = (jnp.zeros(l_pad, jnp.int32)
-                      .at[jnp.where(nl, nl_rank, drop)]
-                      .set(pos + 1, mode="drop"))
-        line_end = (jnp.full(l_pad, n, jnp.int32)
-                    .at[jnp.where(nl, nl_rank - 1, drop)]
-                    .set(pos, mode="drop"))
+        with jax.named_scope("parse.lines"):
+            nli = nl.astype(jnp.int32)
+            nl_rank = cumsum_1d(nli)         # inclusive newline rank
+            line_id = nl_rank - nli          # line containing each byte
+            drop = jnp.int32(l_pad)          # OOB segment sentinel
+            line_start = (jnp.zeros(l_pad, jnp.int32)
+                          .at[jnp.where(nl, nl_rank, drop)]
+                          .set(pos + 1, mode="drop"))
+            line_end = (jnp.full(l_pad, n, jnp.int32)
+                        .at[jnp.where(nl, nl_rank - 1, drop)]
+                        .set(pos, mode="drop"))
 
-        # escape initiators: a backslash at even offset within its run
-        run_start = bs & ~shift_in(bs)
-        last_rs = cummax_1d(jnp.where(run_start, pos, jnp.int32(-1)))
-        initiator = bs & (((pos - last_rs) & 1) == 0)
-        uq = quote & ~shift_in(initiator)  # structurally active quote
-        uqi = uq.astype(jnp.int32)
-        q_cum = cumsum_1d(uqi)
-        outside = ((q_cum - uqi) & 1) == 0  # even quote parity before
+        with jax.named_scope("parse.quotes"):
+            # escape initiators: a backslash at even offset within its run
+            run_start = bs & ~shift_in(bs)
+            last_rs = cummax_1d(jnp.where(run_start, pos, jnp.int32(-1)))
+            initiator = bs & (((pos - last_rs) & 1) == 0)
+            uq = quote & ~shift_in(initiator)  # structurally active quote
+            uqi = uq.astype(jnp.int32)
+            q_cum = cumsum_1d(uqi)
+            outside = ((q_cum - uqi) & 1) == 0  # even quote parity before
+            # rank -> position of each active quote (closing-quote lookup)
+            pos_by_rank = (jnp.full(n + 1, n, jnp.int32)
+                           .at[jnp.where(uq, q_cum - 1, big)]
+                           .set(pos, mode="drop"))
+            bs_cum = cumsum_1d(bs.astype(jnp.int32))
 
-        s_colon = colon & outside
-        depth = cumsum_1d((lb & outside).astype(jnp.int32)
-                          - (rb & outside).astype(jnp.int32))
-        c1 = s_colon & (depth == 1)
-        c2 = s_colon & (depth == 2)
-        c3 = s_colon & (depth >= 3)
+        with jax.named_scope("parse.depth"):
+            s_colon = colon & outside
+            depth = cumsum_1d((lb & outside).astype(jnp.int32)
+                              - (rb & outside).astype(jnp.int32))
+            c1 = s_colon & (depth == 1)
+            c2 = s_colon & (depth == 2)
+            c3 = s_colon & (depth >= 3)
 
-        def seg_sum(m):
-            return jax.ops.segment_sum(m.astype(jnp.int32), line_id,
-                                       num_segments=l_pad)
+            def seg_sum(m):
+                return jax.ops.segment_sum(m.astype(jnp.int32), line_id,
+                                           num_segments=l_pad)
 
-        n_c1, n_c2, n_c3 = seg_sum(c1), seg_sum(c2), seg_sum(c3)
-        n_quotes = jax.ops.segment_sum(uqi, line_id, num_segments=l_pad)
-        depth_end = (jnp.zeros(l_pad, jnp.int32)
-                     .at[jnp.where(nl, line_id, drop)]
-                     .set(depth, mode="drop"))
-        depth_min = jax.ops.segment_min(depth, line_id,
-                                        num_segments=l_pad)
+            n_c1, n_c2, n_c3 = seg_sum(c1), seg_sum(c2), seg_sum(c3)
+            n_quotes = jax.ops.segment_sum(uqi, line_id, num_segments=l_pad)
+            depth_end = (jnp.zeros(l_pad, jnp.int32)
+                         .at[jnp.where(nl, line_id, drop)]
+                         .set(depth, mode="drop"))
+            depth_min = jax.ops.segment_min(depth, line_id,
+                                            num_segments=l_pad)
 
-        # rank -> position of each active quote (closing-quote lookup)
-        pos_by_rank = (jnp.full(n + 1, n, jnp.int32)
-                       .at[jnp.where(uq, q_cum - 1, big)]
-                       .set(pos, mode="drop"))
-        bs_cum = cumsum_1d(bs.astype(jnp.int32))
+        with jax.named_scope("parse.keys"):
+            at_ls = shift_in(nl).at[0].set(True)
 
-        at_ls = shift_in(nl).at[0].set(True)
+            def match(pat):
+                acc = jnp.ones(n, bool)
+                for k, ch in enumerate(pat):
+                    acc = acc & (bx[k:k + n] == np.uint8(ch))
+                return acc
 
-        def match(pat):
-            acc = jnp.ones(n, bool)
-            for k, ch in enumerate(pat):
-                acc = acc & (bx[k:k + n] == np.uint8(ch))
-            return acc
+            m_add = match(_PAT_ADD) & at_ls
+            m_rem = match(_PAT_REMOVE) & at_ls
+            is_add = seg_sum(m_add) > 0
+            is_rem = seg_sum(m_rem) > 0
+            filerow = is_add | is_rem
 
-        m_add = match(_PAT_ADD) & at_ls
-        m_rem = match(_PAT_REMOVE) & at_ls
-        is_add = seg_sum(m_add) > 0
-        is_rem = seg_sum(m_rem) > 0
-        filerow = is_add | is_rem
-
-        counts, mpos = [], []
-        for _name, pat, _kind in KEY_PATTERNS:
-            m = match(pat) & uq & outside & (depth == 2)
-            counts.append(seg_sum(m))
-            mpos.append(jax.ops.segment_min(
-                jnp.where(m, pos, big), line_id, num_segments=l_pad))
+            counts, mpos = [], []
+            for _name, pat, _kind in KEY_PATTERNS:
+                m = match(pat) & uq & outside & (depth == 2)
+                counts.append(seg_sum(m))
+                mpos.append(jax.ops.segment_min(
+                    jnp.where(m, pos, big), line_id, num_segments=l_pad))
 
         def gather8(idx):
             return bx[jnp.clip(idx, 0, n + _TAIL_PAD - 1)]
@@ -222,101 +227,104 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
         def gather32(arr, idx, limit):
             return arr[jnp.clip(idx, 0, limit)]
 
-        # string spans: [open_quote + 1, closing quote)
-        span_start, span_end, span_esc, span_bad = {}, {}, {}, {}
-        for i in _STR_KEYS:
-            name, pat, _ = KEY_PATTERNS[i]
-            present = counts[i] == 1
-            o = mpos[i] + np.int32(len(pat) - 1)   # value's opening quote
-            rank = gather32(q_cum, o, n - 1)
-            close = gather32(pos_by_rank, rank, n)
-            start = o + 1
-            nbs = (gather32(bs_cum, close - 1, n - 1)
-                   - gather32(bs_cum, start - 1, n - 1))
-            span_start[name] = jnp.where(present, start, 0)
-            span_end[name] = jnp.where(present, close, 0)
-            span_esc[name] = present & (nbs > 0)
-            span_bad[name] = present & ((close >= line_end)
-                                        | (close <= o))
+        with jax.named_scope("parse.strings"):
+            # string spans: [open_quote + 1, closing quote)
+            span_start, span_end, span_esc, span_bad = {}, {}, {}, {}
+            for i in _STR_KEYS:
+                name, pat, _ = KEY_PATTERNS[i]
+                present = counts[i] == 1
+                o = mpos[i] + np.int32(len(pat) - 1)   # value's opening quote
+                rank = gather32(q_cum, o, n - 1)
+                close = gather32(pos_by_rank, rank, n)
+                start = o + 1
+                nbs = (gather32(bs_cum, close - 1, n - 1)
+                       - gather32(bs_cum, start - 1, n - 1))
+                span_start[name] = jnp.where(present, start, 0)
+                span_end[name] = jnp.where(present, close, 0)
+                span_esc[name] = present & (nbs > 0)
+                span_bad[name] = present & ((close >= line_end)
+                                            | (close <= o))
 
-        # numerics: unrolled Horner over at most _MAX_INT_DIGITS digits
-        num_val, num_present, num_bad = {}, {}, {}
-        for i in _INT_KEYS:
-            name, pat, _ = KEY_PATTERNS[i]
-            present = counts[i] == 1
-            vs = mpos[i] + np.int32(len(pat))
-            negm = gather8(vs) == np.uint8(45)
-            base = vs + negm.astype(jnp.int32)
-            val = jnp.zeros(l_pad, jnp.int64)
-            active = jnp.ones(l_pad, bool)
-            term_ok = jnp.zeros(l_pad, bool)
-            ndig = jnp.zeros(l_pad, jnp.int32)
-            for j in range(_MAX_INT_DIGITS + 1):
-                ch = gather8(base + np.int32(j))
-                is_d = (ch >= np.uint8(48)) & (ch <= np.uint8(57))
-                take = active & is_d
-                val = jnp.where(take,
-                                val * 10 + (ch - np.uint8(48))
-                                .astype(jnp.int64), val)
-                ndig = ndig + take.astype(jnp.int32)
-                stop = active & ~is_d
-                term_ok = jnp.where(
-                    stop, (ch == np.uint8(44)) | (ch == np.uint8(125)),
-                    term_ok)
-                active = active & is_d
-            num_val[name] = jnp.where(negm, -val, val)
-            num_present[name] = present
-            # still-active after the unroll = too many digits for int64
-            num_bad[name] = present & (active | (ndig < 1) | ~term_ok)
+        with jax.named_scope("parse.ints"):
+            # numerics: unrolled Horner over at most _MAX_INT_DIGITS digits
+            num_val, num_present, num_bad = {}, {}, {}
+            for i in _INT_KEYS:
+                name, pat, _ = KEY_PATTERNS[i]
+                present = counts[i] == 1
+                vs = mpos[i] + np.int32(len(pat))
+                negm = gather8(vs) == np.uint8(45)
+                base = vs + negm.astype(jnp.int32)
+                val = jnp.zeros(l_pad, jnp.int64)
+                active = jnp.ones(l_pad, bool)
+                term_ok = jnp.zeros(l_pad, bool)
+                ndig = jnp.zeros(l_pad, jnp.int32)
+                for j in range(_MAX_INT_DIGITS + 1):
+                    ch = gather8(base + np.int32(j))
+                    is_d = (ch >= np.uint8(48)) & (ch <= np.uint8(57))
+                    take = active & is_d
+                    val = jnp.where(take,
+                                    val * 10 + (ch - np.uint8(48))
+                                    .astype(jnp.int64), val)
+                    ndig = ndig + take.astype(jnp.int32)
+                    stop = active & ~is_d
+                    term_ok = jnp.where(
+                        stop, (ch == np.uint8(44)) | (ch == np.uint8(125)),
+                        term_ok)
+                    active = active & is_d
+                num_val[name] = jnp.where(negm, -val, val)
+                num_present[name] = present
+                # still-active after the unroll = too many digits for int64
+                num_bad[name] = present & (active | (ndig < 1) | ~term_ok)
 
-        bool_val, bool_present, bool_bad = {}, {}, {}
-        for i in _BOOL_KEYS:
-            name, pat, _ = KEY_PATTERNS[i]
-            present = counts[i] == 1
-            ch = gather8(mpos[i] + np.int32(len(pat)))
-            bool_val[name] = ch == np.uint8(116)   # 't'
-            bool_present[name] = present
-            bool_bad[name] = present & (ch != np.uint8(116)) \
-                & (ch != np.uint8(102))            # nor 'f'
+        with jax.named_scope("parse.flags"):
+            bool_val, bool_present, bool_bad = {}, {}, {}
+            for i in _BOOL_KEYS:
+                name, pat, _ = KEY_PATTERNS[i]
+                present = counts[i] == 1
+                ch = gather8(mpos[i] + np.int32(len(pat)))
+                bool_val[name] = ch == np.uint8(116)   # 't'
+                bool_present[name] = present
+                bool_bad[name] = present & (ch != np.uint8(116)) \
+                    & (ch != np.uint8(102))            # nor 'f'
 
-        matched = counts[0]
-        for c in counts[1:]:
-            matched = matched + c
-        dup = jnp.zeros(l_pad, bool)
-        for c in counts:
-            dup = dup | (c > 1)
-        tail_ch = gather8(line_end - 1)
-        any_bad = (span_bad["path"] | span_bad["stats"]
-                   | num_bad["size"] | num_bad["mod_time"]
-                   | num_bad["del_ts"]
-                   | bool_bad["data_change"] | bool_bad["ext_meta"])
-        complex_line = filerow & (
-            (n_c1 != 1) | (n_c2 != matched) | (n_c3 > 0) | dup
-            | (counts[0] != 1)                 # path is mandatory
-            | (tail_ch != np.uint8(125))       # line must close with '}'
-            | any_bad)
+            matched = counts[0]
+            for c in counts[1:]:
+                matched = matched + c
+            dup = jnp.zeros(l_pad, bool)
+            for c in counts:
+                dup = dup | (c > 1)
+            tail_ch = gather8(line_end - 1)
+            any_bad = (span_bad["path"] | span_bad["stats"]
+                       | num_bad["size"] | num_bad["mod_time"]
+                       | num_bad["del_ts"]
+                       | bool_bad["data_change"] | bool_bad["ext_meta"])
+            complex_line = filerow & (
+                (n_c1 != 1) | (n_c2 != matched) | (n_c3 > 0) | dup
+                | (counts[0] != 1)                 # path is mandatory
+                | (tail_ch != np.uint8(125))       # line must close with '}'
+                | any_bad)
 
-        valid_line = jnp.arange(l_pad, dtype=jnp.int32) < n_lines
-        bal_bad = valid_line & (((n_quotes & 1) != 0)
-                                | (depth_end != 0) | (depth_min < 0))
-        window_ok = ~jnp.any(bal_bad)
+            valid_line = jnp.arange(l_pad, dtype=jnp.int32) < n_lines
+            bal_bad = valid_line & (((n_quotes & 1) != 0)
+                                    | (depth_end != 0) | (depth_min < 0))
+            window_ok = ~jnp.any(bal_bad)
 
-        vals = jnp.stack([num_val["size"], num_val["mod_time"],
-                          num_val["del_ts"]])
-        spans = jnp.stack([line_start, line_end,
-                           span_start["path"], span_end["path"],
-                           span_start["stats"], span_end["stats"]])
-        flags = jnp.stack([
-            is_add, is_rem, complex_line,
-            span_esc["path"], span_esc["stats"],
-            counts[1] == 1,
-            num_present["size"], num_present["mod_time"],
-            num_present["del_ts"],
-            bool_present["data_change"], bool_val["data_change"],
-            bool_present["ext_meta"], bool_val["ext_meta"],
-            counts[7] == 1,
-        ])
-        return vals, spans, flags, window_ok
+            vals = jnp.stack([num_val["size"], num_val["mod_time"],
+                              num_val["del_ts"]])
+            spans = jnp.stack([line_start, line_end,
+                               span_start["path"], span_end["path"],
+                               span_start["stats"], span_end["stats"]])
+            flags = jnp.stack([
+                is_add, is_rem, complex_line,
+                span_esc["path"], span_esc["stats"],
+                counts[1] == 1,
+                num_present["size"], num_present["mod_time"],
+                num_present["del_ts"],
+                bool_present["data_change"], bool_val["data_change"],
+                bool_present["ext_meta"], bool_val["ext_meta"],
+                counts[7] == 1,
+            ])
+            return vals, spans, flags, window_ok
 
     return jax.jit(kernel)
 
@@ -357,12 +365,13 @@ def parse_window_fields(window: np.ndarray, n_lines: int, device=None):
         dd.h2d("lane_bytes", lane_bytes)
         vals, spans, flags, window_ok = fn(
             jax.device_put(lane_bytes, device), np.int32(n_lines))
-        if not bool(window_ok):
-            dd.set(window_ok=False)
-            return None
-        vals = dd.d2h("vals", np.asarray(vals))[:, :n_lines]
-        spans = dd.d2h("spans", np.asarray(spans))[:, :n_lines]
-        flags = dd.d2h("flags", np.asarray(flags))[:, :n_lines]
+        with obs.span("parse.wait", bytes=n, rows=n_lines):
+            if not bool(window_ok):
+                dd.set(window_ok=False)
+                return None
+            vals = dd.d2h("vals", np.asarray(vals))[:, :n_lines]
+            spans = dd.d2h("spans", np.asarray(spans))[:, :n_lines]
+            flags = dd.d2h("flags", np.asarray(flags))[:, :n_lines]
     out = {}
     for i, name in enumerate(VAL_NAMES):
         out[name] = vals[i]

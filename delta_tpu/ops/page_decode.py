@@ -130,6 +130,7 @@ def _decode_fn(b_pad: int, r_pad: int, p_pad: int, h_pad: int,
     import jax
     import jax.numpy as jnp
 
+    @obs.program("page_decode.part")
     def fn(lane, runs, pages):
         hyb = _decode_stage_hybrid(lane, runs, h_pad, use_pallas)
 
@@ -285,6 +286,7 @@ def _handoff_fn(m: int, k_pads: tuple):
 
     from delta_tpu.ops.replay import _sort_winner_pack
 
+    @obs.program("page_decode.handoff")
     def fn(remap, meta, n_real, *code_lanes):
         out = jnp.full((m,), 0xFFFFFFFF, jnp.uint32)
         for i, codes in enumerate(code_lanes):
@@ -380,6 +382,6 @@ def launch_checkpoint_handoff(parts: Sequence[PartKeys], n_shards: int = 1,
                         jax.device_put(part_meta, device),
                         np.int32(n), *[p.codes for p in live])
         _OBS_HANDOFFS.inc()
-        return ReplayPending(winner, add_words, n, None)
+        return ReplayPending(winner, add_words, n, None, dispatch=dd)
     finally:
         release_part_keys(parts)

@@ -75,6 +75,7 @@ def interleave_bits_tiled(cols: jnp.ndarray, n_bits: int = 32) -> jnp.ndarray:
         out_specs=pl.BlockSpec((n_words, _SUBLANES, _LANES), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_words, tiles * _SUBLANES, _LANES), jnp.uint32),
         interpret=_use_interpret(),
+        name="interleave_bits_tiled",
     )(shaped)
     return out.reshape(n_words, n)
 
@@ -132,6 +133,7 @@ def segmented_minmax(values: jnp.ndarray, valid: jnp.ndarray):
             jax.ShapeDtypeStruct((f, _LANES), jnp.float32),
         ),
         interpret=_use_interpret(),
+        name="segmented_minmax",
     )(values.astype(jnp.float32), valid)
     return mn[:, 0], mx[:, 0], cnt[:, 0].astype(jnp.int32)
 
@@ -208,6 +210,7 @@ def byte_class_tiled(b: jnp.ndarray) -> jnp.ndarray:
             out_shape=jax.ShapeDtypeStruct(
                 (tiles * _BYTE_SUBLANES, _LANES), jnp.uint8),
             interpret=_use_interpret(),
+            name="byte_class_tiled",
         )(shaped)
     return out.reshape(n)
 
@@ -266,6 +269,7 @@ def unpack_bitpacked_tiled(packed: jnp.ndarray, w: int) -> jnp.ndarray:
         out_shape=jax.ShapeDtypeStruct((32, tiles * _SUBLANES, _LANES),
                                        jnp.uint32),
         interpret=_use_interpret(),
+        name="unpack_bitpacked_tiled",
     )(shaped)
     # [32, G] -> group-major [G, 32] -> flat
     return out.reshape(32, -1).T.reshape(-1)
@@ -347,6 +351,7 @@ def shift_extract_tiled(lo: jnp.ndarray, hi: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((tiles * _SUBLANES, _LANES),
                                        jnp.uint32),
         interpret=_use_interpret(),
+        name="shift_extract_tiled",
     )(*shaped)
     return out.reshape(n)
 

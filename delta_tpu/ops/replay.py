@@ -193,33 +193,40 @@ def _sort_winner_pack(lanes, n_real) -> jax.Array:
     of a run is its last *valid* row — a real row whose key happens to
     equal the all-ones pad sentinel is never swallowed by padding."""
     m = lanes[0].shape[0]
-    payload = jnp.arange(m, dtype=jnp.uint32)
-    sorted_ = lax.sort((*lanes, payload), num_keys=len(lanes) + 1,
-                       is_stable=False)
+    with jax.named_scope("replay.sort"):
+        payload = jnp.arange(m, dtype=jnp.uint32)
+        sorted_ = lax.sort((*lanes, payload), num_keys=len(lanes) + 1,
+                           is_stable=False)
     s_lanes, s_payload = sorted_[:-1], sorted_[-1]
-    s_idx = s_payload.astype(jnp.int32)
-    s_valid = s_idx < n_real
+    with jax.named_scope("replay.winner"):
+        s_idx = s_payload.astype(jnp.int32)
+        s_valid = s_idx < n_real
 
-    same_as_next = jnp.ones((m - 1,), dtype=bool)
-    for k in s_lanes:
-        same_as_next = same_as_next & (k[:-1] == k[1:])
-    next_valid = jnp.concatenate([s_valid[1:], jnp.zeros((1,), dtype=bool)])
-    is_last = jnp.concatenate([~same_as_next, jnp.ones((1,), dtype=bool)])
-    winner = s_valid & (is_last | ~next_valid)
+        same_as_next = jnp.ones((m - 1,), dtype=bool)
+        for k in s_lanes:
+            same_as_next = same_as_next & (k[:-1] == k[1:])
+        next_valid = jnp.concatenate(
+            [s_valid[1:], jnp.zeros((1,), dtype=bool)])
+        is_last = jnp.concatenate(
+            [~same_as_next, jnp.ones((1,), dtype=bool)])
+        winner = s_valid & (is_last | ~next_valid)
 
-    winner_orig = jnp.zeros((m,), dtype=bool).at[s_idx].set(winner)
-    bit_pos = jnp.arange(32, dtype=jnp.uint32)
-    weights = jnp.uint32(1) << bit_pos
-    return (winner_orig.reshape(-1, 32).astype(jnp.uint32) * weights).sum(
-        axis=1, dtype=jnp.uint32)
+    with jax.named_scope("replay.pack"):
+        winner_orig = jnp.zeros((m,), dtype=bool).at[s_idx].set(winner)
+        bit_pos = jnp.arange(32, dtype=jnp.uint32)
+        weights = jnp.uint32(1) << bit_pos
+        return (winner_orig.reshape(-1, 32).astype(jnp.uint32)
+                * weights).sum(axis=1, dtype=jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("width",))
+@obs.program("replay.single_raw")
 def _winner_kernel(operands, width: int) -> jax.Array:
     """Full-key path. operands = (*key_planes[u8, m] | *key_lanes[u32, m],
     n_real[i32]) -> winner_words[u32, m/32]."""
     *key_ops, n_real = operands
-    lanes = (_decode_planes(key_ops),) if width else tuple(key_ops)
+    with jax.named_scope("replay.decode"):
+        lanes = (_decode_planes(key_ops),) if width else tuple(key_ops)
     return _sort_winner_pack(lanes, n_real)
 
 
@@ -229,6 +236,7 @@ def _bitcast_u32(b: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("layout",))
+@obs.program("replay.single_fa")
 def _winner_kernel_fa_packed(buf, layout) -> jax.Array:
     """Single-transfer variant of `_winner_kernel_fa`: every operand —
     n_real, sub_radix, flag words, ref planes, the sparse DV lane —
@@ -236,6 +244,14 @@ def _winner_kernel_fa_packed(buf, layout) -> jax.Array:
     high-latency host<->device link, one H2D beats six.
 
     layout = (m, ref_width, r_pad, d_pad) — all bucket-padded statics."""
+    with jax.named_scope("replay.decode"):
+        key, n_real = _decode_fa_packed(buf, layout)
+    return _sort_winner_pack((key,), n_real)
+
+
+def _decode_fa_packed(buf, layout):
+    """The packed buffer's operands sliced out and the key lane rebuilt
+    from its first-appearance coding: (key[u32, m], n_real)."""
     m, ref_width, r_pad, d_pad = layout
     off = 0
 
@@ -269,8 +285,7 @@ def _winner_kernel_fa_packed(buf, layout) -> jax.Array:
             sub_val, mode="drop")
         key = key * sub_radix + sub
     iota = jnp.arange(m, dtype=jnp.int32)
-    key = jnp.where(iota < n_real, key, jnp.uint32(0xFFFFFFFF))
-    return _sort_winner_pack((key,), n_real)
+    return jnp.where(iota < n_real, key, jnp.uint32(0xFFFFFFFF)), n_real
 
 
 def _pack_fa_operands(fa: "_FAEncoding", n: int) -> tuple[np.ndarray, tuple]:
@@ -292,7 +307,8 @@ def _pack_fa_operands(fa: "_FAEncoding", n: int) -> tuple[np.ndarray, tuple]:
 
 @functools.lru_cache(maxsize=16)
 def _concat_chunks_jit(k: int):
-    return jax.jit(lambda *chunks: jnp.concatenate(chunks))
+    return jax.jit(obs.program("replay.single_fa.concat")(
+        lambda *chunks: jnp.concatenate(chunks)))
 
 
 def _put_chunked(buf: np.ndarray, device):
@@ -473,30 +489,38 @@ class ReplayPending:
     sort while the host keeps working — call `finish()` to block on the
     winner words and split them into (live, tombstone) masks."""
 
-    __slots__ = ("_winner", "_add_words", "_n", "_perm")
+    __slots__ = ("_winner", "_add_words", "_n", "_perm", "_dispatch")
 
-    def __init__(self, winner, add_words: np.ndarray, n: int, perm):
+    def __init__(self, winner, add_words: np.ndarray, n: int, perm,
+                 dispatch=None):
         self._winner = winner
         self._add_words = add_words
         self._n = n
         self._perm = perm
+        # the launch's (closed) dispatch: the blocking read joins its
+        # record, which otherwise holds the launch and not the kernel
+        # (None only for the empty replay, which never waits)
+        self._dispatch = dispatch
 
     def finish(self) -> tuple[np.ndarray, np.ndarray]:
         n = self._n
         if n == 0:
             z = np.zeros((0,), dtype=bool)
             return z, z
-        winner_words = np.asarray(self._winner)
-        live_words = winner_words & self._add_words
-        tomb_words = winner_words & ~self._add_words
-        live = _unpack_bits(live_words, n)
-        tomb = _unpack_bits(tomb_words, n)
-        if self._perm is not None:
-            inv_live = np.zeros(n, dtype=bool)
-            inv_tomb = np.zeros(n, dtype=bool)
-            inv_live[self._perm] = live
-            inv_tomb[self._perm] = tomb
-            live, tomb = inv_live, inv_tomb
+        with obs.span("replay.wait", rows=n) as sp, self._dispatch.wait():
+            winner_words = np.asarray(self._winner)
+            sp.set_attr("bytes", winner_words.nbytes)
+        with obs.span("replay.unpack", rows=n):
+            live_words = winner_words & self._add_words
+            tomb_words = winner_words & ~self._add_words
+            live = _unpack_bits(live_words, n)
+            tomb = _unpack_bits(tomb_words, n)
+            if self._perm is not None:
+                inv_live = np.zeros(n, dtype=bool)
+                inv_tomb = np.zeros(n, dtype=bool)
+                inv_live[self._perm] = live
+                inv_tomb[self._perm] = tomb
+                live, tomb = inv_live, inv_tomb
         return live, tomb
 
 
@@ -542,66 +566,75 @@ def replay_select_launch(
     if n == 0:
         return ReplayPending(None, np.empty(0, np.uint32), 0, None)
 
-    perm = None
-    if not chrono_ok(np.asarray(version), np.asarray(order)):
-        perm = np.lexsort((order, version))
-        key_lanes = [np.asarray(k)[perm] for k in key_lanes]
-        is_add = np.asarray(is_add)[perm]
-        fa_hint = None  # hint flags are in original row order
+    with obs.span("replay.pack", rows=n) as sp:
+        perm = None
+        if not chrono_ok(np.asarray(version), np.asarray(order)):
+            perm = np.lexsort((order, version))
+            key_lanes = [np.asarray(k)[perm] for k in key_lanes]
+            is_add = np.asarray(is_add)[perm]
+            fa_hint = None  # hint flags are in original row order
 
-    m = pad_bucket(n)
-    pad = m - n
-    is_add = np.asarray(is_add, dtype=np.bool_)
-    add_words_np = _pack_bits(
-        np.concatenate([is_add, np.zeros(pad, np.bool_)]) if pad else is_add)
+        m = pad_bucket(n)
+        pad = m - n
+        is_add = np.asarray(is_add, dtype=np.bool_)
+        add_words_np = _pack_bits(
+            np.concatenate([is_add, np.zeros(pad, np.bool_)])
+            if pad else is_add)
 
-    lanes = [np.asarray(k) for k in key_lanes]
-    fa = None
-    if fa_hint is not None:
-        flags, refs, n_uniq = fa_hint
-        fa = _fa_from_hint(flags, refs, int(n_uniq), lanes, n, m)
-    if fa is None:
-        fa = _try_fa_encode(lanes, n, m)
+        lanes = [np.asarray(k) for k in key_lanes]
+        fa = None
+        if fa_hint is not None:
+            flags, refs, n_uniq = fa_hint
+            fa = _fa_from_hint(flags, refs, int(n_uniq), lanes, n, m)
+        if fa is None:
+            fa = _try_fa_encode(lanes, n, m)
 
-    n_op = np.asarray(n, dtype=np.int32)
+        if fa is not None:
+            parts, layout = _pack_fa_operands(fa, n)
+            buf = np.concatenate(parts)
+            nbytes = buf.nbytes
+        else:
+            combined = combine_key_lanes(lanes)
+            if combined is not None:
+                width = key_byte_width(int(combined.max(initial=0)))
+                key_ops = _pack_key_planes(combined, width, pad)
+            else:
+                width = 0
+                key_ops = tuple(
+                    np.ascontiguousarray(np.concatenate(
+                        [np.asarray(k, np.uint32),
+                         np.full(pad, _PAD_KEY, np.uint32)])
+                        if pad else np.asarray(k, np.uint32))
+                    for k in lanes)
+            nbytes = sum(int(o.nbytes) for o in key_ops)
+        sp.set_attrs(bytes=nbytes, encoding="fa" if fa is not None else "raw")
+
     # these data-dependent lanes are accounted at runtime through
     # replay.h2d_bytes (no static per-unit budget entry — the FA buffer
     # mixes bitplanes and byte-packed refs); the funnel still records
     # per-lane bytes and the compile/steady-state split per shape bucket
-    if fa is not None:
-        parts, layout = _pack_fa_operands(fa, n)
-        buf = np.concatenate(parts)
-        with obs.device_dispatch("replay.single_fa", key=(m, layout),
-                                 gate="replay", route="single") as dd:
-            dd.h2d("fa_buf", buf)
-            _H2D_BYTES.inc(buf.nbytes)
-            buf = _put_chunked(buf, device)
-            winner_words = _winner_kernel_fa_packed(buf, layout)
-    else:
-        combined = combine_key_lanes(lanes)
-        if combined is not None:
-            width = key_byte_width(int(combined.max(initial=0)))
-            key_ops = _pack_key_planes(combined, width, pad)
+    with obs.span("replay.launch", rows=n, bytes=nbytes):
+        if fa is not None:
+            with obs.device_dispatch("replay.single_fa", key=(m, layout),
+                                     gate="replay", route="single") as dd:
+                dd.h2d("fa_buf", buf)
+                _H2D_BYTES.inc(nbytes)
+                buf = _put_chunked(buf, device)
+                winner_words = _winner_kernel_fa_packed(buf, layout)
         else:
-            width = 0
-            key_ops = tuple(
-                np.ascontiguousarray(np.concatenate(
-                    [np.asarray(k, np.uint32),
-                     np.full(pad, _PAD_KEY, np.uint32)])
-                    if pad else np.asarray(k, np.uint32))
-                for k in lanes)
-        operands = (*key_ops, n_op)
-        with obs.device_dispatch("replay.single_raw",
-                                 key=(m, width, len(key_ops)),
-                                 gate="replay", route="single") as dd:
-            for i, o in enumerate(key_ops):
-                dd.h2d(f"key_plane_{i}", o)
-            _H2D_BYTES.inc(sum(int(o.nbytes) for o in key_ops))
-            if device is not None:
-                operands = tuple(jax.device_put(o, device) for o in operands)
-            winner_words = _winner_kernel(operands, width=width)
+            operands = (*key_ops, np.asarray(n, dtype=np.int32))
+            with obs.device_dispatch("replay.single_raw",
+                                     key=(m, width, len(key_ops)),
+                                     gate="replay", route="single") as dd:
+                for i, o in enumerate(key_ops):
+                    dd.h2d(f"key_plane_{i}", o)
+                _H2D_BYTES.inc(nbytes)
+                if device is not None:
+                    operands = tuple(jax.device_put(o, device)
+                                     for o in operands)
+                winner_words = _winner_kernel(operands, width=width)
 
-    return ReplayPending(winner_words, add_words_np, n, perm)
+    return ReplayPending(winner_words, add_words_np, n, perm, dispatch=dd)
 
 
 def python_replay_reference(

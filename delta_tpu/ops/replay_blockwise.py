@@ -95,7 +95,8 @@ def _block_kernel_impl(seen_words, keys, n_real, m: int):
 
 
 _block_kernel = functools.partial(jax.jit, static_argnames=("m",),
-                                  donate_argnums=(0,))(_block_kernel_impl)
+                                  donate_argnums=(0,))(
+    obs.program("replay.blockwise")(_block_kernel_impl))
 
 
 def replay_select_blockwise(

@@ -91,6 +91,7 @@ def _skip_fn_cached(a_pad: int, g_segs: int):
     import jax
     import jax.numpy as jnp
 
+    @obs.program("skipping.mask_block")
     def kernel(vals, valid, rows_mn, rows_mx, rows_nc, ops, lits, grp,
                n_atoms):
         mn, mx, nc = vals[rows_mn], vals[rows_mx], vals[rows_nc]

@@ -71,6 +71,7 @@ def _ensure_x64() -> None:
 # ------------------------------------------------------------- sort ----
 
 @functools.partial(jax.jit, static_argnames=("num_keys",))
+@obs.program("sqlops.sort")
 def _sort_kernel(operands, num_keys: int):
     out = jax.lax.sort(operands, num_keys=num_keys, is_stable=True)
     return out[-1]
@@ -120,6 +121,7 @@ def sort_permutation(lanes: Sequence[np.ndarray],
 # --------------------------------------------------- group-by reduce ----
 
 @functools.partial(jax.jit, static_argnames=("op", "n_seg"))
+@obs.program("sqlops.segagg")
 def _segagg_kernel(codes, v, valid, op: str, n_seg: int):
     """One aggregate over dense group codes. Returns (agg[n_seg],
     valid_count[n_seg])."""
@@ -208,16 +210,18 @@ def _sharded_segagg_fn(mesh, op: str, n_seg: int):
     spec = P(REPLAY_AXIS)
     fn = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
                    out_specs=(P(), P()))
-    return jax.jit(fn)
+    return jax.jit(obs.program("sqlops.segagg_sharded")(fn))
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
+@obs.program("sqlops.group_sizes")
 def _group_sizes_kernel(codes, real, n_seg: int):
     return jax.ops.segment_sum(real.astype(jnp.int64), codes,
                                num_segments=n_seg)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
+@obs.program("sqlops.centered_sumsq")
 def _centered_sumsq_kernel(codes, v, valid, means, n_seg: int):
     """Second pass for variance: sum((v - mean[g])^2) over valid rows."""
     d = v - means[codes]
@@ -347,6 +351,7 @@ class GroupAggregator:
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
+@obs.program("sqlops.count_distinct")
 def _count_distinct_kernel(g, v, n_seg: int):
     sg, sv = jax.lax.sort((g, v), num_keys=2)
     first = jnp.concatenate([
@@ -360,12 +365,14 @@ def _count_distinct_kernel(g, v, n_seg: int):
 # ----------------------------------------------------------- join ----
 
 @jax.jit
+@obs.program("sqlops.join_codes")
 def _join_sort_kernel(codes, side, iota):
     return jax.lax.sort((codes, side, iota), num_keys=2,
                         is_stable=True)
 
 
 @jax.jit
+@obs.program("sqlops.join_lanes")
 def _join_lanes_kernel(l_vals, r_vals, n_l, n_r):
     """Sort (pad_flag, value, side) over the concatenated padded int64
     key lanes; side and iota are generated ON DEVICE (they never cross
@@ -528,7 +535,7 @@ def join_pairs_lanes(
             r_dev = jax.device_put(rp, device)
         s_pad, s_val, s_side, s_pos = (
             np.asarray(a) for a in _join_lanes_kernel(
-                l_dev, r_dev, jnp.int64(nl), jnp.int64(nr)))
+                l_dev, r_dev, np.int64(nl), np.int64(nr)))
     real = s_pad == 0
     return _expand_pairs(s_val[real], s_side[real], s_pos[real],
                          nl_pad, how)
@@ -540,6 +547,7 @@ _NEG = np.int64(-(1 << 62))
 
 
 @jax.jit
+@obs.program("sqlops.window_ranks")
 def _ranks_kernel(pb, kb):
     """Sorted-order rank family. pb[i]: row i starts a partition;
     kb[i]: row i starts an order-key run (kb includes pb positions).
@@ -582,6 +590,7 @@ def window_ranks(pb: np.ndarray, kb: np.ndarray, device=None):
 
 
 @functools.partial(jax.jit, static_argnames=("op",))
+@obs.program("sqlops.window_running")
 def _segscan_kernel(v, valid, pb, op: str):
     """Segmented running aggregate in sorted order. Partitions are
     contiguous; pb marks starts. Returns (running[n], run_count[n])."""
@@ -652,6 +661,7 @@ def window_running(v: np.ndarray, valid: np.ndarray, pb: np.ndarray,
 
 
 @jax.jit
+@obs.program("sqlops.window_peer_last")
 def _peer_last_kernel(vals, counts, kb):
     """RANGE-frame peer sharing: every row takes the running value at
     the LAST row of its order-key run."""

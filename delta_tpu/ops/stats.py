@@ -98,6 +98,7 @@ def _agg_fn_cached(n_lanes: int, n_pad: int, p_pad: int):
     import jax
     import jax.numpy as jnp
 
+    @obs.program("stats.ckpt_block")
     def kernel(vals, valid_words, parts, code_mult):
         valid = jnp.unpackbits(valid_words, axis=1, count=n_pad,
                                bitorder="little").astype(bool)
@@ -230,6 +231,7 @@ def _pack_fn_cached(n_pad: int, n_words: int):
     import jax
     import jax.numpy as jnp
 
+    @obs.program("stats.dv_pack")
     def kernel(idx):
         word = (idx >> 5).astype(jnp.int32)
         bit = jnp.left_shift(jnp.uint32(1), (idx & 31).astype(jnp.uint32))
@@ -281,6 +283,7 @@ def _decode_fn_cached(i_pad: int, w_pad: int, n_words: int):
     import jax
     import jax.numpy as jnp
 
+    @obs.program("stats.dv_decode")
     def kernel(bit_idx, bm_words, bm_pos):
         word = (bit_idx >> 5).astype(jnp.int32)
         bit = jnp.left_shift(jnp.uint32(1),

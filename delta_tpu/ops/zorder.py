@@ -131,6 +131,7 @@ def curve_order(key_words: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("curve",))
+@obs.program("zorder.curve_perm")
 def _curve_perm(stacked: jnp.ndarray, curve: str) -> jnp.ndarray:
     """One fused device program: rank -> scale -> curve key -> argsort.
     `stacked` is the [n_cols, m] uint32 key matrix — all clustering

@@ -75,7 +75,8 @@ def _append_fn_cached(mesh, d_pad: int):
     # donate the resident lane so the update is in place on device; CPU
     # backends don't implement donation and would warn on every call
     donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(fn, donate_argnums=donate)
+    return jax.jit(obs.program("replay.resident_append")(fn),
+                   donate_argnums=donate)
 
 
 class ResidentShardState:

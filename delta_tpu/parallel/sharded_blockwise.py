@@ -72,7 +72,8 @@ def _step_fn(mesh: Mesh, m: int):
         in_specs=(spec, spec, P(REPLAY_AXIS)),
         out_specs=(spec, spec),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(obs.program("replay.sharded_blockwise")(fn),
+                   donate_argnums=(0,))
 
 
 def replay_select_sharded_blockwise(

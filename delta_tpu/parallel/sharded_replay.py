@@ -106,7 +106,7 @@ def build_sharded_replay_fn(mesh: Mesh):
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, P(), P()),
     )
-    return jax.jit(fn)
+    return jax.jit(obs.program("replay.sharded_raw")(fn))
 
 
 def route_to_shards(
@@ -342,7 +342,7 @@ def _fa_fn_cached(mesh: Mesh, ref_width: int, has_sub: bool,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
     )
-    return jax.jit(fn)
+    return jax.jit(obs.program("replay.sharded_fa")(fn))
 
 
 def build_sharded_replay_fa_fn(mesh: Mesh, ref_width: int, has_sub: bool,

@@ -294,7 +294,8 @@ def _extract_file_actions(
     struct_chunks = table.column(col)
     if struct_chunks.null_count == len(struct_chunks):
         return None
-    struct_arr = struct_chunks.combine_chunks()
+    with obs.span("canonicalize.combine", rows=len(struct_chunks)):
+        struct_arr = struct_chunks.combine_chunks()
     if pa.types.is_null(struct_arr.type):
         return None
     valid = pc.is_valid(struct_arr)
@@ -302,12 +303,20 @@ def _extract_file_actions(
     sel = np.nonzero(mask)[0]
     if sel.size == 0:
         return None
-    # filter, not take: selection-by-mask over a wide struct (stats
-    # strings, partitionValues maps) is ~2x faster than row gather
-    sub = struct_arr.filter(valid)
+    with obs.span("canonicalize.filter", rows=len(struct_arr)):
+        # filter, not take: selection-by-mask over a wide struct (stats
+        # strings, partitionValues maps) is ~2x faster than row gather
+        sub = struct_arr.filter(valid)
     n = len(sub)
     is_add = col == "add"
+    with obs.span("canonicalize.columns", rows=n):
+        return _canonical_block(sub, n, is_add, versions[sel], orders[sel])
 
+
+def _canonical_block(sub: pa.StructArray, n: int, is_add: bool,
+                     versions: np.ndarray, orders: np.ndarray) -> pa.Table:
+    """The canonical-schema table of `n` selected add or remove structs
+    (`versions`/`orders` already selected to match)."""
     path = _decode_paths(_field_or_null(sub, "path", pa.string()))
     pv = _struct_to_map(_field_or_null(sub, "partitionValues", pa.map_(pa.string(), pa.string())), n)
     size = _field_or_null(sub, "size", pa.int64())
@@ -345,8 +354,8 @@ def _extract_file_actions(
             "deletion_timestamp": del_ts,
             "extended_file_metadata": ext_meta,
             "is_add": pa.array(np.full(n, is_add, dtype=bool)),
-            "version": pa.array(versions[sel], pa.int64()),
-            "order": pa.array(orders[sel], pa.int32()),
+            "version": pa.array(versions, pa.int64()),
+            "order": pa.array(orders, pa.int32()),
         },
         schema=CANONICAL_FILE_ACTION_SCHEMA,
     )
@@ -997,21 +1006,26 @@ def _columnarize_log_segment(
     def _consume_checkpoint_table(tbl: pa.Table, part_keys=None):
         nonlocal blocks
         n = tbl.num_rows
-        versions = np.full(n, cp_version, np.int64)
-        # checkpoint rows precede all commit rows at the same version;
-        # order is irrelevant within a checkpoint (keys are unique)
-        orders = np.arange(n, dtype=np.int32)
-        tracker.scan_chunk(tbl, versions, orders)
-        if small_only:
-            return  # sidecars carry only file actions — nothing to do
-        part_blocks = {}
-        for col in ("add", "remove"):
-            block = _extract_file_actions(tbl, col, versions, orders)
-            part_blocks[col] = block
-            if block is not None:
-                blocks.append(block)
-        _track_handoff(part_keys, part_blocks["add"],
-                       part_blocks["remove"])
+        with obs.span("checkpoint.canonicalize", rows=n) as sp:
+            if sp.recording:
+                sp.set_attr("bytes", tbl.nbytes)
+            versions = np.full(n, cp_version, np.int64)
+            # checkpoint rows precede all commit rows at the same
+            # version; order is irrelevant within a checkpoint (keys
+            # are unique)
+            orders = np.arange(n, dtype=np.int32)
+            with obs.span("canonicalize.small_actions", rows=n):
+                tracker.scan_chunk(tbl, versions, orders)
+            if small_only:
+                return  # sidecars carry only file actions — nothing to do
+            part_blocks = {}
+            for col in ("add", "remove"):
+                block = _extract_file_actions(tbl, col, versions, orders)
+                part_blocks[col] = block
+                if block is not None:
+                    blocks.append(block)
+            _track_handoff(part_keys, part_blocks["add"],
+                           part_blocks["remove"])
         # V2 checkpoints: resolve sidecar pointers to _sidecars/ parquet
         if "sidecar" in tbl.column_names:
             sc = tbl.column("sidecar").combine_chunks()
@@ -1022,8 +1036,23 @@ def _columnarize_log_segment(
                     for p in paths
                     if p is not None
                 ]
-                for sub in engine.parquet.read_parquet_files(sidecar_paths):
-                    _consume_checkpoint_table(sub)
+                subs = engine.parquet.read_parquet_files(sidecar_paths)
+                for _ in sidecar_paths:
+                    _consume_checkpoint_table(
+                        _read_part(lambda: next(subs)))
+
+    def _read_part(read, nbytes=None) -> pa.Table:
+        """One part's table: `read()` is the fetch and the Arrow decode
+        of exactly one file."""
+        with obs.span("checkpoint.read_part", bytes=nbytes) as sp:
+            tbl = read()
+            sp.set_attr("rows", tbl.num_rows)
+            return tbl
+
+    def _read_json_part(fstat) -> pa.Table:
+        # V2 top-level checkpoint in JSON form
+        return _read_part(lambda: pa_json.read_json(pa.BufferReader(
+            engine.fs.read_file(fstat.path))), fstat.size)
 
     def _read_checkpoint_part(path: str):
         if not small_only:
@@ -1058,9 +1087,7 @@ def _columnarize_log_segment(
         for fstat in parts:
             try:
                 if fstat.path.endswith(".json"):
-                    tbl = pa_json.read_json(pa.BufferReader(
-                        engine.fs.read_file(fstat.path)))
-                    _consume_checkpoint_table(tbl)
+                    _consume_checkpoint_table(_read_json_part(fstat))
                 else:
                     data = next(byte_iter)
                     host_reason = "unsupported-shape"
@@ -1087,7 +1114,9 @@ def _columnarize_log_segment(
                         obs.gate_fell_back("decode", "host",
                                            reason=host_reason)
                         with obs.gate_observation("decode", "host"):
-                            tbl = pq.read_table(pa.BufferReader(data))
+                            tbl = _read_part(
+                                lambda data=data: pq.read_table(
+                                    pa.BufferReader(data)), fstat.size)
                         _consume_checkpoint_table(tbl)
             except FileNotFoundError:
                 from delta_tpu.errors import LogCorruptedError
@@ -1127,7 +1156,8 @@ def _columnarize_log_segment(
                 try:
                     # sidecar reads nest inside the consume call; a
                     # vanished sidecar maps like a vanished part
-                    _consume_checkpoint_table(next(tables))
+                    _consume_checkpoint_table(
+                        _read_part(lambda: next(tables), fstat.size))
                 except FileNotFoundError:
                     from delta_tpu.errors import LogCorruptedError
 
@@ -1140,12 +1170,12 @@ def _columnarize_log_segment(
         for fstat in parts:
             try:
                 if fstat.path.endswith(".json"):
-                    # V2 top-level checkpoint in JSON form
-                    tbl = pa_json.read_json(pa.BufferReader(engine.fs.read_file(fstat.path)))
-                    _consume_checkpoint_table(tbl)
+                    _consume_checkpoint_table(_read_json_part(fstat))
                 else:
-                    for tbl in _read_checkpoint_part(fstat.path):
-                        _consume_checkpoint_table(tbl)
+                    _consume_checkpoint_table(_read_part(
+                        lambda fstat=fstat: next(
+                            _read_checkpoint_part(fstat.path)),
+                        fstat.size))
             except FileNotFoundError:
                 # selected as a complete checkpoint at LIST time, gone at
                 # read time (`DeltaErrors.missingPartFilesException`)

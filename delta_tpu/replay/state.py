@@ -108,17 +108,28 @@ class SnapshotState:
         from delta_tpu.replay.columnar import splice_stats
 
         with self._splice_lock:
-            self.file_actions_raw, self.stats_thunk = splice_stats(
-                self.file_actions_raw, self.stats_thunk)
+            if self.stats_thunk is not None:
+                with obs.span("state.splice_stats",
+                              rows=self.file_actions_raw.num_rows) as sp:
+                    self.file_actions_raw, self.stats_thunk = splice_stats(
+                        self.file_actions_raw, self.stats_thunk)
+                    if sp.recording:
+                        sp.set_attr("bytes", self.file_actions_raw.column(
+                            "stats").nbytes)
             return self.file_actions_raw
 
     @property
     def add_files_table(self) -> pa.Table:
         """Live files as an Arrow table (canonical schema)."""
         if self._add_table_cache is None:
-            self._add_table_cache = self.file_actions.filter(
-                pa.array(self.live_mask)
-            )
+            with obs.span("state.add_files_table",
+                          rows=len(self.live_mask)) as sp:
+                table = self.file_actions  # state.splice_stats, if deferred
+                with obs.span("state.filter_live", rows=table.num_rows):
+                    live = table.filter(pa.array(self.live_mask))
+                if sp.recording:
+                    sp.set_attrs(live_rows=live.num_rows, bytes=live.nbytes)
+                self._add_table_cache = live
         return self._add_table_cache
 
     @property
@@ -137,11 +148,12 @@ class SnapshotState:
     def size_in_bytes(self) -> int:
         # raw access on purpose: aggregates never touch stats, so they
         # must not trigger the deferred decode
-        sizes = np.asarray(
-            self.file_actions_raw.column("size").fill_null(0),
-            dtype=np.int64
-        )
-        return int(sizes[self.live_mask].sum())
+        with obs.span("state.size_in_bytes", rows=len(self.live_mask)):
+            sizes = np.asarray(
+                self.file_actions_raw.column("size").fill_null(0),
+                dtype=np.int64
+            )
+            return int(sizes[self.live_mask].sum())
 
     def visible_domain_metadata(self) -> Dict[str, DomainMetadata]:
         return {k: v for k, v in self.domain_metadata.items() if not v.removed}
@@ -219,8 +231,13 @@ def build_replay_keys(file_actions: pa.Table) -> tuple[np.ndarray, np.ndarray]:
 
     pd.factorize is exact (no collisions) and C-vectorized; null dv_id
     maps to code 0, real ids to 1+code."""
-    paths = file_actions.column("path").combine_chunks()
-    path_codes, _ = pd.factorize(paths.to_pandas(), sort=False)
+    n = file_actions.num_rows
+    with obs.span("keys.combine", rows=n):
+        paths = file_actions.column("path").combine_chunks()
+    with obs.span("keys.to_pandas", rows=n):
+        paths = paths.to_pandas()
+    with obs.span("keys.factorize", rows=n):
+        path_codes, _ = pd.factorize(paths, sort=False)
     dv = file_actions.column("dv_id").combine_chunks()
     if dv.null_count == len(dv):
         dv_codes = np.zeros(len(dv), dtype=np.int64)
@@ -284,20 +301,24 @@ def compute_masks_device(
         return out
     keys = columnar.replay_keys
     fa_hint = None
-    if keys is not None and len(keys.path_code) == n:
-        # the native scanner already dictionary-coded the paths in
-        # first-appearance order and emitted the delta encoding — skip
-        # the factorize pass entirely
-        path_codes = keys.path_code
-        dv_codes = _dv_codes_only(fa)
-        fa_hint = (keys.path_new, keys.refs, keys.n_uniq)
-    else:
-        path_codes, dv_codes = build_replay_keys(fa)
-    version = np.asarray(fa.column("version"), dtype=np.int64)
-    # versions fit int32 in practice (2^31 commits); assert to be safe
-    assert version.max(initial=0) < 2**31, "version overflow"
-    order = np.asarray(fa.column("order"), dtype=np.int32)
-    is_add = np.asarray(fa.column("is_add"), dtype=bool)
+    with obs.span("replay.keys", rows=n) as sp:
+        if keys is not None and len(keys.path_code) == n:
+            # the native scanner already dictionary-coded the paths in
+            # first-appearance order and emitted the delta encoding —
+            # skip the factorize pass entirely
+            path_codes = keys.path_code
+            dv_codes = _dv_codes_only(fa)
+            fa_hint = (keys.path_new, keys.refs, keys.n_uniq)
+        else:
+            path_codes, dv_codes = build_replay_keys(fa)
+        version = np.asarray(fa.column("version"), dtype=np.int64)
+        # versions fit int32 in practice (2^31 commits); assert to be safe
+        assert version.max(initial=0) < 2**31, "version overflow"
+        order = np.asarray(fa.column("order"), dtype=np.int32)
+        is_add = np.asarray(fa.column("is_add"), dtype=bool)
+        if sp.recording:
+            sp.set_attrs(factorized=fa_hint is None,
+                         bytes=fa.column("path").nbytes)
 
     mesh = getattr(engine, "mesh", None) if engine is not None else None
     n_shards = mesh.devices.size if mesh is not None else 1
@@ -375,20 +396,21 @@ def compute_masks_host(columnar: ColumnarActions) -> tuple[np.ndarray, np.ndarra
     tomb = np.zeros(n, dtype=bool)
     if n == 0:
         return live, tomb
-    paths = fa.column("path").to_pylist()
-    dvs = fa.column("dv_id").to_pylist()
-    version = np.asarray(fa.column("version"), dtype=np.int64)
-    order = np.asarray(fa.column("order"), dtype=np.int32)
-    is_add = np.asarray(fa.column("is_add"), dtype=bool)
-    rows = sorted(range(n), key=lambda i: (version[i], order[i]))
-    winner: dict = {}
-    for i in rows:
-        winner[(paths[i], dvs[i])] = i
-    for i in winner.values():
-        if is_add[i]:
-            live[i] = True
-        else:
-            tomb[i] = True
+    with obs.span("replay.host", rows=n):
+        paths = fa.column("path").to_pylist()
+        dvs = fa.column("dv_id").to_pylist()
+        version = np.asarray(fa.column("version"), dtype=np.int64)
+        order = np.asarray(fa.column("order"), dtype=np.int32)
+        is_add = np.asarray(fa.column("is_add"), dtype=bool)
+        rows = sorted(range(n), key=lambda i: (version[i], order[i]))
+        winner: dict = {}
+        for i in rows:
+            winner[(paths[i], dvs[i])] = i
+        for i in winner.values():
+            if is_add[i]:
+                live[i] = True
+            else:
+                tomb[i] = True
     return live, tomb
 
 

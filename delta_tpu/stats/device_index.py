@@ -35,6 +35,7 @@ device cost is one RTT plus the compiled atom arrays.
 from __future__ import annotations
 
 import datetime
+import functools
 import threading
 import weakref
 from typing import Dict, List, Optional, Tuple
@@ -197,6 +198,21 @@ def encode_literal(value, kind: str) -> Optional[int]:
     return None
 
 
+@functools.lru_cache(maxsize=16)
+def _unpack_valid_fn(n_pad: int):
+    """jit'd validity-word unpack: one named program in place of two
+    anonymous op-by-op ones."""
+    import jax
+    import jax.numpy as jnp
+
+    @obs.program("stats.index_upload")
+    def unpack(words):
+        return jnp.unpackbits(words, axis=1, count=n_pad,
+                              bitorder="little").astype(bool)
+
+    return jax.jit(unpack)
+
+
 class ResidentStatsIndex:
     """Per-snapshot-version stats index: the parsed Arrow table (shared
     with the host fallback ladder) plus the encoded int64 lanes, with a
@@ -235,7 +251,6 @@ class ResidentStatsIndex:
         if self._dev is not None or self.vals is None or self.released:
             return self._dev
         import jax
-        import jax.numpy as jnp
 
         from delta_tpu.ops.stats import _x64
 
@@ -244,16 +259,16 @@ class ResidentStatsIndex:
         valid_words = np.packbits(np.asarray(self.valid, bool), axis=1,
                                   bitorder="little")
         cells = lane_vals.shape[0] * n_pad
-        with obs.device_dispatch("stats.index_upload",
-                                 key=(lane_vals.shape[0], n_pad),
-                                 budget="stats-index-lanes",
-                                 units=cells) as dd, _x64():
+        with obs.span("stats.index_upload", rows=self.n,
+                      bytes=lane_vals.nbytes + valid_words.nbytes), \
+            obs.device_dispatch("stats.index_upload",
+                                key=(lane_vals.shape[0], n_pad),
+                                budget="stats-index-lanes",
+                                units=cells) as dd, _x64():
             dd.h2d("lane_vals", lane_vals)
             dd.h2d("valid_words", valid_words)
             dv = jax.device_put(lane_vals)
-            dw = jax.device_put(valid_words)
-            dvalid = jnp.unpackbits(dw, axis=1, count=n_pad,
-                                    bitorder="little").astype(bool)
+            dvalid = _unpack_valid_fn(n_pad)(jax.device_put(valid_words))
         self._dev = (dv, dvalid)
         self._hbm = hbm.register(
             self, kind=hbm.KIND_STATS_INDEX, table_path=self.table_path,
@@ -479,9 +494,14 @@ def snapshot_stats_index(state, files: pa.Table):
         if idx is not None and not idx.released:
             _REUSES.inc()
             return idx
-        idx = build_index(files,
-                          table_path=getattr(state, "table_path", None),
-                          version=getattr(state, "version", None))
+        with obs.span("stats.index_build", rows=files.num_rows) as sp:
+            idx = build_index(files,
+                              table_path=getattr(state, "table_path", None),
+                              version=getattr(state, "version", None))
+            if sp.recording:
+                sp.set_attrs(
+                    bytes=files.column("stats").nbytes,
+                    lanes=0 if idx.vals is None else len(idx.vals))
         state.stats_index = idx
         # built implicitly by ordinary filtered scans, so a state
         # dropped outside the explicit-release paths (one-shot reads,

@@ -44,7 +44,7 @@ _KIND_BY_FN = {"counter": "counters", "histogram": "histograms",
 # and must stay cataloged like any other module's — likewise the HBM
 # resident ledger (hbm.*, plus the subsumed replay/scan gauges)
 _EXEMPT_PREFIX = os.path.join("delta_tpu", "obs") + os.sep
-_NON_EXEMPT_BASENAMES = {"device.py", "bench_trend.py", "hbm.py"}
+_NON_EXEMPT_BASENAMES = {"device.py", "hbm.py"}
 
 
 def _catalog_path() -> Optional[str]:
