@@ -33,6 +33,16 @@ negative brace depth) — routes the WHOLE window back to the host
 scanner, preserving digest parity by construction: the device route
 only ever answers for content it parsed exactly.
 
+Per-line quantities are never reduced by line over the byte lane (a
+`segment_sum` / `segment_min` keyed by `line_id` lowers on the TPU to a
+scatter of one update a byte). Each is an inclusive chunked scan over
+the lane (`ops/scans.py`) read at the line's ends by a gather as long as
+the line lanes: a count is `cum[last] - cum[line_start - 1]`, the
+closing depth `depth[line_end]`, a line tag its match mask read at
+`line_start`, a key's first match the reversed running minimum of the
+match positions read at `line_start`, and its repeat the same lane read
+once more behind that match.
+
 Per-line result lanes come back as three dense blocks (int64 values,
 int32 spans, packed flags), so the D2H cost is O(lines), not O(bytes).
 Escaped string spans (backslashes in paths or stats) are flagged and
@@ -120,7 +130,7 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
     import jax
     import jax.numpy as jnp
 
-    from delta_tpu.ops.scans import cummax_1d, cumsum_1d
+    from delta_tpu.ops.scans import cummax_1d, cummin_1d, cumsum_1d
 
     n = n_pad
     big = jnp.int32(n)
@@ -128,6 +138,9 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
     def shift_in(m):
         """Previous-byte view of a mask (False shifted in at pos 0)."""
         return jnp.concatenate([jnp.zeros(1, m.dtype), m[:-1]])
+
+    def gather32(arr, idx, limit):
+        return arr[jnp.clip(idx, 0, limit)]
 
     @obs.program("json_parse.window")
     def kernel(bx, n_lines):
@@ -163,6 +176,12 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             line_end = (jnp.full(l_pad, n, jnp.int32)
                         .at[jnp.where(nl, nl_rank - 1, drop)]
                         .set(pos, mode="drop"))
+            # lines that hold a byte: line l is [line_start, last]; the
+            # tail line has no newline, lines past it are empty
+            lane = jnp.arange(l_pad, dtype=jnp.int32)
+            n_nl = nl_rank[n - 1]
+            nonempty = (lane <= n_nl) & (line_start < n)
+            last = jnp.minimum(line_end, n - 1)
 
         with jax.named_scope("parse.quotes"):
             # escape initiators: a backslash at even offset within its run
@@ -179,6 +198,12 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
                            .set(pos, mode="drop"))
             bs_cum = cumsum_1d(bs.astype(jnp.int32))
 
+        def line_sum(cum):
+            """Per-line sum of a mask, from its inclusive prefix sum."""
+            before = jnp.where(line_start > 0,
+                               gather32(cum, line_start - 1, n - 1), 0)
+            return jnp.where(nonempty, gather32(cum, last, n - 1) - before, 0)
+
         with jax.named_scope("parse.depth"):
             s_colon = colon & outside
             depth = cumsum_1d((lb & outside).astype(jnp.int32)
@@ -186,53 +211,59 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             c1 = s_colon & (depth == 1)
             c2 = s_colon & (depth == 2)
             c3 = s_colon & (depth >= 3)
+            # one scan for two counts: a line has fewer than 2^31 bytes,
+            # so n_c1 + 2 * n_c3 < 2^32 cannot wrap round to 1, and it is
+            # 1 only where n_c1 == 1 and n_c3 == 0
+            c13_ok = line_sum(cumsum_1d(
+                c1.astype(jnp.int32) + 2 * c3.astype(jnp.int32))) == 1
+            n_c2 = line_sum(cumsum_1d(c2.astype(jnp.int32)))
+            n_quotes = line_sum(q_cum)
+            depth_end = jnp.where(lane < n_nl,
+                                  gather32(depth, line_end, n - 1), 0)
+            # "some line's depth dips below 0" needs no per-line minimum
+            neg_depth = jnp.any((depth < 0) & (line_id < n_lines))
 
-            def seg_sum(m):
-                return jax.ops.segment_sum(m.astype(jnp.int32), line_id,
-                                           num_segments=l_pad)
-
-            n_c1, n_c2, n_c3 = seg_sum(c1), seg_sum(c2), seg_sum(c3)
-            n_quotes = jax.ops.segment_sum(uqi, line_id, num_segments=l_pad)
-            depth_end = (jnp.zeros(l_pad, jnp.int32)
-                         .at[jnp.where(nl, line_id, drop)]
-                         .set(depth, mode="drop"))
-            depth_min = jax.ops.segment_min(depth, line_id,
-                                            num_segments=l_pad)
+        def gather8(idx):
+            return bx[jnp.clip(idx, 0, n + _TAIL_PAD - 1)]
 
         with jax.named_scope("parse.keys"):
-            at_ls = shift_in(nl).at[0].set(True)
-
             def match(pat):
                 acc = jnp.ones(n, bool)
                 for k, ch in enumerate(pat):
                     acc = acc & (bx[k:k + n] == np.uint8(ch))
                 return acc
 
-            m_add = match(_PAT_ADD) & at_ls
-            m_rem = match(_PAT_REMOVE) & at_ls
-            is_add = seg_sum(m_add) > 0
-            is_rem = seg_sum(m_rem) > 0
+            # a line tag stands at the line's first byte or nowhere
+            first_byte = jnp.minimum(line_start, n - 1)
+            is_add = nonempty & match(_PAT_ADD)[first_byte]
+            is_rem = nonempty & match(_PAT_REMOVE)[first_byte]
             filerow = is_add | is_rem
 
-            counts, mpos = [], []
+            # per key: `one` (exactly one match in the line), `dup` (two
+            # or more) and `mpos`, the first match: n where the line has
+            # none, int32 max where the line is empty (what a minimum
+            # over no byte gives)
+            one, dup_key, mpos = [], [], []
             for _name, pat, _kind in KEY_PATTERNS:
                 m = match(pat) & uq & outside & (depth == 2)
-                counts.append(seg_sum(m))
-                mpos.append(jax.ops.segment_min(
-                    jnp.where(m, pos, big), line_id, num_segments=l_pad))
-
-        def gather8(idx):
-            return bx[jnp.clip(idx, 0, n + _TAIL_PAD - 1)]
-
-        def gather32(arr, idx, limit):
-            return arr[jnp.clip(idx, 0, limit)]
+                nxt = cummin_1d(jnp.where(m, pos, big), reverse=True)
+                first = gather32(nxt, line_start, n - 1)
+                found = nonempty & (first <= last)
+                second = jnp.where(first < n - 1,
+                                   gather32(nxt, first + 1, n - 1), big)
+                twice = found & (second <= last)
+                one.append(found & ~twice)
+                dup_key.append(twice)
+                mpos.append(jnp.where(
+                    nonempty, jnp.where(found, first, big),
+                    jnp.iinfo(jnp.int32).max))
 
         with jax.named_scope("parse.strings"):
             # string spans: [open_quote + 1, closing quote)
             span_start, span_end, span_esc, span_bad = {}, {}, {}, {}
             for i in _STR_KEYS:
                 name, pat, _ = KEY_PATTERNS[i]
-                present = counts[i] == 1
+                present = one[i]
                 o = mpos[i] + np.int32(len(pat) - 1)   # value's opening quote
                 rank = gather32(q_cum, o, n - 1)
                 close = gather32(pos_by_rank, rank, n)
@@ -250,7 +281,7 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             num_val, num_present, num_bad = {}, {}, {}
             for i in _INT_KEYS:
                 name, pat, _ = KEY_PATTERNS[i]
-                present = counts[i] == 1
+                present = one[i]
                 vs = mpos[i] + np.int32(len(pat))
                 negm = gather8(vs) == np.uint8(45)
                 base = vs + negm.astype(jnp.int32)
@@ -280,34 +311,37 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             bool_val, bool_present, bool_bad = {}, {}, {}
             for i in _BOOL_KEYS:
                 name, pat, _ = KEY_PATTERNS[i]
-                present = counts[i] == 1
+                present = one[i]
                 ch = gather8(mpos[i] + np.int32(len(pat)))
                 bool_val[name] = ch == np.uint8(116)   # 't'
                 bool_present[name] = present
                 bool_bad[name] = present & (ch != np.uint8(116)) \
                     & (ch != np.uint8(102))            # nor 'f'
 
-            matched = counts[0]
-            for c in counts[1:]:
-                matched = matched + c
+            # the key masks are disjoint by position, so a line's match
+            # count is the sum of its keys' counts; where a key repeats
+            # the line is complex whatever that sum, elsewhere each count
+            # is 0 or 1
+            matched = jnp.zeros(l_pad, jnp.int32)
             dup = jnp.zeros(l_pad, bool)
-            for c in counts:
-                dup = dup | (c > 1)
+            for o, d in zip(one, dup_key):
+                matched = matched + o.astype(jnp.int32)
+                dup = dup | d
             tail_ch = gather8(line_end - 1)
             any_bad = (span_bad["path"] | span_bad["stats"]
                        | num_bad["size"] | num_bad["mod_time"]
                        | num_bad["del_ts"]
                        | bool_bad["data_change"] | bool_bad["ext_meta"])
             complex_line = filerow & (
-                (n_c1 != 1) | (n_c2 != matched) | (n_c3 > 0) | dup
-                | (counts[0] != 1)                 # path is mandatory
+                ~c13_ok | (n_c2 != matched) | dup
+                | ~one[0]                          # path is mandatory
                 | (tail_ch != np.uint8(125))       # line must close with '}'
                 | any_bad)
 
-            valid_line = jnp.arange(l_pad, dtype=jnp.int32) < n_lines
+            valid_line = lane < n_lines
             bal_bad = valid_line & (((n_quotes & 1) != 0)
-                                    | (depth_end != 0) | (depth_min < 0))
-            window_ok = ~jnp.any(bal_bad)
+                                    | (depth_end != 0))
+            window_ok = ~(jnp.any(bal_bad) | neg_depth)
 
             vals = jnp.stack([num_val["size"], num_val["mod_time"],
                               num_val["del_ts"]])
@@ -317,12 +351,12 @@ def _parse_fn_cached(n_pad: int, l_pad: int, pallas_classes: bool):
             flags = jnp.stack([
                 is_add, is_rem, complex_line,
                 span_esc["path"], span_esc["stats"],
-                counts[1] == 1,
+                one[1],
                 num_present["size"], num_present["mod_time"],
                 num_present["del_ts"],
                 bool_present["data_change"], bool_val["data_change"],
                 bool_present["ext_meta"], bool_val["ext_meta"],
-                counts[7] == 1,
+                one[7],
             ])
             return vals, spans, flags, window_ok
 

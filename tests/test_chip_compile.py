@@ -125,12 +125,28 @@ def test_json_parse_window_1mib(on_chip):
     _assert_mosaic(compiled)
 
 
+def test_json_parse_window_product_shape(on_chip):
+    """`json-cold-load`'s window: 64 Mi bytes, 512 Ki lines. The scan
+    form reserves 3,720 MB of temporaries (the segment reduces it
+    replaced: 4,251 MB) and keeps three scatters, all of positions."""
+    n_pad, l_pad = 64 << 20, 512 << 10
+    with jax.enable_x64(True):
+        fn = json_parse._parse_fn_cached(n_pad, l_pad, True)
+        compiled = fn.lower(
+            on_chip((n_pad + json_parse._TAIL_PAD,), jnp.uint8),
+            on_chip((), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    assert compiled.as_text().count(" scatter(") == 3
+
+
 def test_chunked_scans_64m(on_chip):
     """The scans that made the parse jit uncompilable at its product
     window, at that window's length."""
     lane = on_chip((64 << 20,), jnp.int32)
     compiled = jax.jit(
-        lambda x: (scans.cumsum_1d(x), scans.cummax_1d(x))
+        lambda x: (scans.cumsum_1d(x), scans.cummax_1d(x),
+                   scans.cummin_1d(x), scans.cummin_1d(x, reverse=True))
     ).lower(lane).compile()
     _assert_fits(compiled)
 

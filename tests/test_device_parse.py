@@ -228,6 +228,312 @@ def test_parse_route_env_and_economics(monkeypatch):
     assert gate.parse_route(1 << 30, engine_enabled=True) == "host"
 
 
+# ------------------------------------------- scan-form bit parity -----------
+# The kernel forms every per-line quantity from a prefix scan read at the
+# line's ends. The reference below is the formulation it replaced, kept
+# here only: one `segment_sum` / `segment_min` by `line_id` over the byte
+# lane per quantity. Every output lane, padded lines included, and
+# `window_ok` must come out the same.
+
+@functools.lru_cache(maxsize=None)
+def _segment_reduce_reference(n_pad, l_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from delta_tpu.ops import json_parse as jp
+
+    n, big = n_pad, jnp.int32(n_pad)
+
+    def shift_in(m):
+        return jnp.concatenate([jnp.zeros(1, m.dtype), m[:-1]])
+
+    def reference(bx, n_lines):
+        b = bx[:n]
+        pos = jnp.arange(n, dtype=jnp.int32)
+        nl, quote, bs = b == 10, b == 34, b == 92
+        colon, lb, rb = b == 58, b == 123, b == 125
+
+        nli = nl.astype(jnp.int32)
+        nl_rank = jnp.cumsum(nli)
+        line_id = nl_rank - nli
+        drop = jnp.int32(l_pad)
+        line_start = (jnp.zeros(l_pad, jnp.int32)
+                      .at[jnp.where(nl, nl_rank, drop)]
+                      .set(pos + 1, mode="drop"))
+        line_end = (jnp.full(l_pad, n, jnp.int32)
+                    .at[jnp.where(nl, nl_rank - 1, drop)]
+                    .set(pos, mode="drop"))
+
+        run_start = bs & ~shift_in(bs)
+        last_rs = jax.lax.cummax(jnp.where(run_start, pos, jnp.int32(-1)))
+        initiator = bs & (((pos - last_rs) & 1) == 0)
+        uq = quote & ~shift_in(initiator)
+        uqi = uq.astype(jnp.int32)
+        q_cum = jnp.cumsum(uqi)
+        outside = ((q_cum - uqi) & 1) == 0
+        pos_by_rank = (jnp.full(n + 1, n, jnp.int32)
+                       .at[jnp.where(uq, q_cum - 1, big)]
+                       .set(pos, mode="drop"))
+        bs_cum = jnp.cumsum(bs.astype(jnp.int32))
+
+        s_colon = colon & outside
+        depth = jnp.cumsum((lb & outside).astype(jnp.int32)
+                           - (rb & outside).astype(jnp.int32))
+
+        def seg_sum(m):
+            return jax.ops.segment_sum(m.astype(jnp.int32), line_id,
+                                       num_segments=l_pad)
+
+        n_c1 = seg_sum(s_colon & (depth == 1))
+        n_c2 = seg_sum(s_colon & (depth == 2))
+        n_c3 = seg_sum(s_colon & (depth >= 3))
+        n_quotes = seg_sum(uqi)
+        depth_end = (jnp.zeros(l_pad, jnp.int32)
+                     .at[jnp.where(nl, line_id, drop)]
+                     .set(depth, mode="drop"))
+        depth_min = jax.ops.segment_min(depth, line_id, num_segments=l_pad)
+
+        at_ls = shift_in(nl).at[0].set(True)
+
+        def match(pat):
+            acc = jnp.ones(n, bool)
+            for k, ch in enumerate(pat):
+                acc = acc & (bx[k:k + n] == np.uint8(ch))
+            return acc
+
+        is_add = seg_sum(match(jp._PAT_ADD) & at_ls) > 0
+        is_rem = seg_sum(match(jp._PAT_REMOVE) & at_ls) > 0
+        filerow = is_add | is_rem
+        counts, mpos = [], []
+        for _name, pat, _kind in jp.KEY_PATTERNS:
+            m = match(pat) & uq & outside & (depth == 2)
+            counts.append(seg_sum(m))
+            mpos.append(jax.ops.segment_min(
+                jnp.where(m, pos, big), line_id, num_segments=l_pad))
+
+        def gather8(idx):
+            return bx[jnp.clip(idx, 0, n + jp._TAIL_PAD - 1)]
+
+        def gather32(arr, idx, limit):
+            return arr[jnp.clip(idx, 0, limit)]
+
+        span_start, span_end, span_esc, span_bad = {}, {}, {}, {}
+        for i in jp._STR_KEYS:
+            name, pat, _ = jp.KEY_PATTERNS[i]
+            present = counts[i] == 1
+            o = mpos[i] + np.int32(len(pat) - 1)
+            close = gather32(pos_by_rank, gather32(q_cum, o, n - 1), n)
+            start = o + 1
+            nbs = (gather32(bs_cum, close - 1, n - 1)
+                   - gather32(bs_cum, start - 1, n - 1))
+            span_start[name] = jnp.where(present, start, 0)
+            span_end[name] = jnp.where(present, close, 0)
+            span_esc[name] = present & (nbs > 0)
+            span_bad[name] = present & ((close >= line_end) | (close <= o))
+
+        num_val, num_bad = {}, {}
+        for i in jp._INT_KEYS:
+            name, pat, _ = jp.KEY_PATTERNS[i]
+            vs = mpos[i] + np.int32(len(pat))
+            negm = gather8(vs) == np.uint8(45)
+            base = vs + negm.astype(jnp.int32)
+            val = jnp.zeros(l_pad, jnp.int64)
+            active = jnp.ones(l_pad, bool)
+            term_ok = jnp.zeros(l_pad, bool)
+            ndig = jnp.zeros(l_pad, jnp.int32)
+            for j in range(jp._MAX_INT_DIGITS + 1):
+                ch = gather8(base + np.int32(j))
+                is_d = (ch >= np.uint8(48)) & (ch <= np.uint8(57))
+                take = active & is_d
+                val = jnp.where(
+                    take, val * 10 + (ch - np.uint8(48)).astype(jnp.int64),
+                    val)
+                ndig = ndig + take.astype(jnp.int32)
+                term_ok = jnp.where(
+                    active & ~is_d,
+                    (ch == np.uint8(44)) | (ch == np.uint8(125)), term_ok)
+                active = active & is_d
+            num_val[name] = jnp.where(negm, -val, val)
+            num_bad[name] = (counts[i] == 1) & (active | (ndig < 1)
+                                                | ~term_ok)
+
+        bool_val, bool_bad = {}, {}
+        for i in jp._BOOL_KEYS:
+            name, pat, _ = jp.KEY_PATTERNS[i]
+            ch = gather8(mpos[i] + np.int32(len(pat)))
+            bool_val[name] = ch == np.uint8(116)
+            bool_bad[name] = ((counts[i] == 1) & (ch != np.uint8(116))
+                              & (ch != np.uint8(102)))
+
+        matched = sum(counts[1:], counts[0])
+        dup = functools.reduce(jnp.logical_or, [c > 1 for c in counts])
+        any_bad = functools.reduce(
+            jnp.logical_or,
+            [*span_bad.values(), *num_bad.values(), *bool_bad.values()])
+        complex_line = filerow & (
+            (n_c1 != 1) | (n_c2 != matched) | (n_c3 > 0) | dup
+            | (counts[0] != 1)
+            | (gather8(line_end - 1) != np.uint8(125)) | any_bad)
+        valid_line = jnp.arange(l_pad, dtype=jnp.int32) < n_lines
+        window_ok = ~jnp.any(valid_line & (
+            ((n_quotes & 1) != 0) | (depth_end != 0) | (depth_min < 0)))
+
+        present = {p[0]: counts[i] == 1
+                   for i, p in enumerate(jp.KEY_PATTERNS)}
+        vals = jnp.stack([num_val[k] for k in ("size", "mod_time", "del_ts")])
+        spans = jnp.stack([line_start, line_end,
+                           span_start["path"], span_end["path"],
+                           span_start["stats"], span_end["stats"]])
+        flags = jnp.stack([
+            is_add, is_rem, complex_line,
+            span_esc["path"], span_esc["stats"], present["stats"],
+            present["size"], present["mod_time"], present["del_ts"],
+            present["data_change"], bool_val["data_change"],
+            present["ext_meta"], bool_val["ext_meta"],
+            present["pv_empty"]])
+        return vals, spans, flags, window_ok
+
+    return jax.jit(reference)
+
+
+_RM = _dumps({"remove": {"path": "r.parquet", "deletionTimestamp": 9,
+                         "dataChange": True}})
+_INFO = '{"commitInfo":{"timestamp":1,"operation":"WRITE"}}'
+
+
+def _fuzz_window(seed):
+    """Lines of key/value atoms in any order and number, one atom in
+    thirty structural noise, so that counts, first matches, quotes and
+    depth go wrong in every way a line can."""
+    rng = np.random.default_rng(seed)
+    pairs = ['"path":"p%d"', '"stats":"s%d"', '"size":%d',
+             '"modificationTime":%d', '"deletionTimestamp":-%d',
+             '"dataChange":true', '"dataChange":false',
+             '"extendedFileMetadata":true', '"partitionValues":{}',
+             '"tags":{"t":%d}', '"size":"%d"', '"dataChange":%d', '"foo":%d',
+             '{"k":%d}']
+    noise = ["{", "}", '"', ":", "\\", '\\"', "\n", "}}", ""]
+    lines = []
+    for _ in range(120):
+        atoms = [(noise[rng.integers(len(noise))] if rng.random() < 0.03
+                  else pairs[rng.integers(len(pairs))].replace(
+                      "%d", str(rng.integers(1000))))
+                 for _ in range(rng.integers(0, 9))]
+        head = ('{"add":{', '{"remove":{', '{"txn":{')[rng.integers(3)]
+        lines.append(head + ",".join(atoms) + "}}")
+    return "\n".join(lines) + "\n"
+
+
+def _repeat(key_value, times, then=""):
+    """One add line whose `key_value` stands `times` times, then `then`:
+    a key met once *after* the repeats must still read as present."""
+    return ('{"add":{"path":"a.parquet",' + ",".join([key_value] * times)
+            + then + "}}")
+
+
+_PARITY_WINDOWS = {
+    "simple_lines": lambda: "\n".join(
+        [_PROTO, _META, _add("a.parquet", size=12, mod=34,
+                             stats='{"numRecords":5}'), _RM, _INFO]) + "\n",
+    "empty_lines": lambda: "\n\n" + _add("a.parquet") + "\n\n\n" + _RM
+    + "\n\n",
+    "no_match_lines": lambda: "\n".join([_PROTO, _INFO, _META, "{}", "x"])
+    + "\n",
+    "key_twice": lambda: _repeat(
+        '"size":1', 1, ',"size":2,"modificationTime":5') + "\n" + _RM + "\n",
+    "key_16_times": lambda: _add("b.parquet") + "\n" + _repeat(
+        '"dataChange":true', 15, ',"dataChange":false,"size":-44') + "\n",
+    "key_256_times": lambda: _repeat(
+        '"stats":"s"', 256, ',"deletionTimestamp":8') + "\n" + _RM + "\n",
+    "key_65536_times": lambda: _RM + "\n" + _repeat(
+        '"size":3', 65536, ',"extendedFileMetadata":false') + "\n" + _RM
+    + "\n",
+    # only the colon census by depth makes these lines complex
+    "colons_300_at_depth_3": lambda: '{"add":{"path":"c.parquet",{'
+    + ",".join(['"k":1'] * 300) + '},"size":2}}\n' + _RM + "\n",
+    "one_colon_at_depth_3": lambda:
+        '{"add":{"path":"c.parquet",{"k":1},"size":2}}\n' + _RM + "\n",
+    "unknown_key_at_depth_2": lambda:
+        '{"add":{"path":"c.parquet","foo":1,"size":2}}\n' + _RM + "\n",
+    "two_colons_at_depth_1": lambda:
+        '{"add":{"path":"c.parquet","size":2},"x":{}}\n' + _RM + "\n",
+    "control_line_with_path_key": lambda:
+        '{"commitInfo":{"path":"p","size":3,"operation":"WRITE"}}\n'
+        + _add("d.parquet") + "\n",
+    "odd_quotes": lambda: _add("e.parquet") + "\n" + '{"add":{"path":"q}}'
+    + "\n" + _RM + "\n",
+    "negative_depth": lambda: _add("f.parquet") + "\n}}{{\n" + _RM + "\n",
+    "match_in_first_byte_of_a_line": lambda:
+        '{"add":{\n"size":7,"path":"g"}}\n' + _RM + "\n",
+    "match_in_last_bytes_of_a_line": lambda:
+        '{"add":{"path":"h","size":\n4}}\n{"add":{"path":"i",'
+        '"partitionValues":{}\n}}\n',
+    "match_in_last_bytes_of_the_window": lambda:
+        _RM + "\n" + '{"add":{"path":"j","modificationTime":',
+    "tail_line_without_newline": lambda: _add("k.parquet") + "\n" + _RM,
+    "window_fills_the_lane": lambda: (
+        (_add("l.parquet") + "\n") * 400)[:16383] + "}",
+    # empty and padded lines read their values at byte 0 of the window
+    "empty_lines_after_a_digit": lambda: "7\n\n" + _add("n.parquet") + "\n",
+    "empty_lines_after_a_t": lambda: "t\n\n" + _RM + "\n\n",
+    "newline_fills_the_lane": lambda: "7" + (
+        (_add("o.parquet") + "\n") * 400)[:16382] + "\n",
+    "fewer_lines_than_the_window_holds": lambda: (
+        _add("p.parquet") + "\n" + _RM + "\n}}{{\n" + '{"add":{"q\n', 2),
+    "escapes_and_percent": lambda: _add(
+        "m%20n\\\\.parquet", stats='{"a":"\\"x\\""}') + "\n" + _RM + "\n",
+    **{f"fuzz_{seed}": functools.partial(_fuzz_window, seed)
+       for seed in range(6)},
+}
+
+
+@pytest.mark.parametrize("case", _PARITY_WINDOWS)
+def test_scan_form_matches_the_segment_reduces_bit_for_bit(case):
+    import jax
+
+    from delta_tpu.ops import json_parse as jp
+    from delta_tpu.ops.replay import pad_bucket
+
+    window = _PARITY_WINDOWS[case]()
+    window, n_lines = window if isinstance(window, tuple) else (window, None)
+    window = np.frombuffer(window.encode(), np.uint8)
+    n = window.shape[0]
+    if n_lines is None:
+        n_lines = int((window == 10).sum()) + int(window[-1] != 10)
+    n_pad = pad_bucket(n, min_bucket=16384)
+    l_pad = pad_bucket(n_lines + 1)
+    lane_bytes = np.full(n_pad + jp._TAIL_PAD, 0x20, np.uint8)
+    lane_bytes[:n] = window
+    with jax.enable_x64(True):
+        got = jp._parse_fn_cached(n_pad, l_pad, False)(
+            lane_bytes, np.int32(n_lines))
+        want = _segment_reduce_reference(n_pad, l_pad)(
+            lane_bytes, np.int32(n_lines))
+    for name, g, w in zip(("vals", "spans", "flags", "window_ok"), got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(g, w), (name, np.argwhere(g != w)[:5])
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 4096, 5000, (1 << 21) + 2048])
+@pytest.mark.parametrize("scan", ["cumsum", "cummax", "cummin",
+                                  "cummin_reverse"])
+def test_chunked_scan_matches_numpy_accumulate(scan, n):
+    from delta_tpu.ops import scans
+
+    x = np.random.default_rng(n).integers(-1 << 30, 1 << 30, n,
+                                          dtype=np.int32)
+    got, want = {
+        "cumsum": lambda: (scans.cumsum_1d(x), np.cumsum(x, dtype=np.int32)),
+        "cummax": lambda: (scans.cummax_1d(x), np.maximum.accumulate(x)),
+        "cummin": lambda: (scans.cummin_1d(x), np.minimum.accumulate(x)),
+        "cummin_reverse": lambda: (
+            scans.cummin_1d(x, reverse=True),
+            np.minimum.accumulate(x[::-1])[::-1]),
+    }[scan]()
+    assert np.array_equal(np.asarray(got), want)
+
+
 # ------------------------------------------------- device DV decode ---------
 
 def _mask_parity(vals, n):

@@ -280,6 +280,35 @@ def test_compiled_hlo_carries_the_stage_scope(compiled_text, program,
     assert re.search(r'op_name="[^"]*/%s/' % re.escape(scope), text)
 
 
+def _scatters(hlo):
+    """(op_name, opcode of the combiner's root) of every scatter of a
+    compiled program: a scatter that only places values has a
+    combiner that returns its second parameter."""
+    out = []
+    for m in re.finditer(
+            r' scatter\(.*to_apply=(%[\w.\-]+).*op_name="([^"]*)"', hlo):
+        body = hlo[hlo.index("\n" + m.group(1) + " ("):]
+        root = re.search(r"ROOT \S+ = \S+ ([\w\-]+)\(", body).group(1)
+        out.append((m.group(2), root))
+    return out
+
+
+def test_parse_program_reduces_nothing_by_scatter(compiled_text):
+    """Per-line quantities come from scans read at the line ends: the
+    three scatters left place positions (line starts, line ends, quote
+    ranks), and none combines with add or min as a `segment_sum` /
+    `segment_min` over the byte lane does."""
+    found = _scatters(compiled_text["parse"])
+    assert sorted(re.search(r"parse\.\w+", name).group(0)
+                  for name, _root in found) == [
+        "parse.lines", "parse.lines", "parse.quotes"]
+    assert {root for _name, root in found} == {"parameter"}
+    probe = jax.jit(lambda x, i: jax.ops.segment_min(x, i, num_segments=8))
+    reduce = probe.lower(jnp.zeros(64, jnp.int32),
+                         jnp.zeros(64, jnp.int32)).compile().as_text()
+    assert [root for _name, root in _scatters(reduce)] == ["minimum"]
+
+
 def test_scopes_leave_the_winner_bits_alone():
     rng = np.random.default_rng(3)
     n = 5000
