@@ -142,10 +142,16 @@ def skip_mask_block(dev_vals, dev_valid, block: AtomBlock,
     # arguments, so this dispatch carries no budgeted device_put lane
     with obs.device_dispatch("skipping.mask_block", key=(a_pad, g_segs),
                              gate="skip") as dd, _x64():
+        # the resident lanes' shape: what a reader needs to count the
+        # bytes this launch has to move
+        dd.set(lanes=dev_vals.shape[0], n_pad=dev_vals.shape[1])
         keep = _skip_fn_cached(a_pad, g_segs)(
             dev_vals, dev_valid, rows_mn, rows_mx, rows_nc, ops,
             jnp.asarray(lits), grp, np.int32(block.n_atoms))
-        return dd.d2h("keep", np.asarray(keep))[:n_files]
+        dd.d2h("keep", keep.nbytes)
+    # the launch returns at once; the kernel's time is this read's
+    with obs.span("skip.wait", rows=n_files, bytes=keep.nbytes), dd.wait():
+        return np.asarray(keep)[:n_files]
 
 
 def host_skip_mask(vals: np.ndarray, valid: np.ndarray, block: AtomBlock,

@@ -514,15 +514,29 @@ def advance_state(
 
     Callers must handle protocol changes BEFORE this (fallback to full
     replay) — a new protocol can change how existing actions are read.
+
+    A resident stats index (`stats/device_index.py`) survives only an
+    EMPTY delta: any landed file action releases its host lanes and
+    device copy here, and the next filtered scan of the new state
+    parses every live file's stats and uploads the lanes again. The
+    `update.advance` span says which happened (`stats_index`).
     """
+    with obs.span("update.advance",
+                  prev_rows=prev.file_actions_raw.num_rows) as sp:
+        return _advance_state(engine, prev, delta, new_segment, sp)
+
+
+def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
     from delta_tpu.ops.replay import delta_winner_masks
 
     delta_fa = delta.file_actions_complete()  # delta stats: small, eager
     m = delta_fa.num_rows
     n_prev = prev.file_actions_raw.num_rows
     resident = prev.resident
+    sp.set_attr("delta_rows", m)
 
     if m == 0:
+        sp.set_attr("route", "empty")
         new_raw = prev.file_actions_raw
         live = prev.live_mask
         tomb = prev.tombstone_mask
@@ -532,11 +546,13 @@ def advance_state(
         # device-resident path: only the delta rows crossed the link;
         # the device re-reconciled base+delta and the returned masks
         # already cover the concatenated table
+        sp.set_attr("route", "resident")
         live, tomb = masks
         new_raw = pa.concat_tables([prev.file_actions_raw, delta_fa])
         stats_thunk = (prev.stats_thunk
                        and _chained_prev_stats(prev, delta_fa))
     else:
+        sp.set_attr("route", "host")
         if resident is not None:
             # the batch couldn't be expressed on device (DV rows,
             # capacity, ordering): residency ends here, host path takes
@@ -610,6 +626,8 @@ def advance_state(
         new_state.resident = resident
         prev.resident = None
     stats_index = prev.stats_index
+    sp.set_attr("stats_index", "none" if stats_index is None
+                else "carried" if m == 0 else "released")
     if stats_index is not None:
         if m == 0:
             # empty delta: the live-file table is unchanged, so the

@@ -115,17 +115,23 @@ class Scan:
         if data_conjuncts:
             from delta_tpu.stats.skipping import skipping_mask
 
-            stats_keep = skipping_mask(
-                files,
-                data_conjuncts,
-                self._snapshot.metadata,
-                engine=self._snapshot._engine,
-                state=self._snapshot.state,
-            )
+            # `skipping_mask` names its route, atoms and fallback
+            # conjuncts on this span
+            with obs.span("plan.skip", rows=files.num_rows,
+                          conjuncts=len(data_conjuncts)):
+                stats_keep = skipping_mask(
+                    files,
+                    data_conjuncts,
+                    self._snapshot.metadata,
+                    engine=self._snapshot._engine,
+                    state=self._snapshot.state,
+                )
             self.skipped_by_stats = int((keep & ~stats_keep).sum())
             keep &= stats_keep
 
-        result = files.filter(pa.array(keep))
+        with obs.span("plan.filter", rows=files.num_rows) as fsp:
+            result = files.filter(pa.array(keep))
+            fsp.set_attr("surviving", result.num_rows)
         self._result_cache = result
         self._report_metrics(files.num_rows, result.num_rows)
         return result

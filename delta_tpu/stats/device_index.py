@@ -22,14 +22,21 @@ file in one type-agnostic dispatch on either backend, bit-identically.
 Lifecycle mirrors `parallel/resident.py` discipline: built at most
 once per `SnapshotState` under the state's dedicated
 `_stats_index_lock` (NOT `_splice_lock` — building reads
-`add_files_table`, which takes the splice lock itself), advanced by
-`replay/state.py::advance_state` (carried over verbatim on empty
-deltas, released and lazily rebuilt otherwise), and released on
+`add_files_table`, which takes the splice lock itself), and released on
 serve-cache eviction through `release_snapshot_resident`. The device
 upload is lazy (first device-routed scan) and budgeted in
 `resources/transfer_budget.json` (`stats-index-lanes`): the lanes ship
 ONCE per version and stay HBM-resident across scans, so the per-scan
 device cost is one RTT plus the compiled atom arrays.
+
+An index lives as long as its version. `replay/state.py::advance_state`
+hands it to the new state only across an EMPTY delta; one landed file
+action releases host lanes and device copy alike, and the first
+filtered scan of the new version builds from nothing: every live file's
+stats string parsed again (`stats.index_build`), every lane uploaded
+again (`stats.index_upload`). Nothing is appended to an index. A reader
+that refreshes under ingest pays the whole build once a refresh
+(PERF.md, `ckpt-query-under-ingest`).
 """
 
 from __future__ import annotations
@@ -200,15 +207,18 @@ def encode_literal(value, kind: str) -> Optional[int]:
 
 @functools.lru_cache(maxsize=16)
 def _unpack_valid_fn(n_pad: int):
-    """jit'd validity-word unpack: one named program in place of two
-    anonymous op-by-op ones."""
+    """jit'd validity-word unpack: uint32 [R, n_pad / 32] -> bool
+    [R, n_pad], by the tree's own shift-and-mask over 32-bit words. (A
+    `jnp.unpackbits` over the uint8 words shifts 8-bit lanes, which the
+    v5e compiler takes 102 s over at a 2.6M-row index; this form, 3 s.)"""
     import jax
-    import jax.numpy as jnp
+
+    from delta_tpu.ops.replay import _unpack_bits_device
 
     @obs.program("stats.index_upload")
     def unpack(words):
-        return jnp.unpackbits(words, axis=1, count=n_pad,
-                              bitorder="little").astype(bool)
+        bits = _unpack_bits_device(words.reshape(-1))
+        return (bits != 0).reshape(words.shape[0], n_pad)
 
     return jax.jit(unpack)
 
@@ -256,8 +266,9 @@ class ResidentStatsIndex:
 
         n_pad = self.vals.shape[1]
         lane_vals = np.asarray(self.vals, np.int64)
+        # bit k of word j is file 32 j + k (n_pad is a multiple of 128)
         valid_words = np.packbits(np.asarray(self.valid, bool), axis=1,
-                                  bitorder="little")
+                                  bitorder="little").view("<u4")
         cells = lane_vals.shape[0] * n_pad
         with obs.span("stats.index_upload", rows=self.n,
                       bytes=lane_vals.nbytes + valid_words.nbytes), \

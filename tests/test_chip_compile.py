@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from delta_tpu.ops import json_parse, page_decode, pallas_kernels, skipping
 from delta_tpu.ops import replay, scans, sqlops
+from delta_tpu.stats import device_index
 
 V5E_HBM_BYTES = 16 << 30
 
@@ -46,6 +47,7 @@ def topo():
     json_parse._parse_fn_cached.cache_clear()
     page_decode._decode_fn.cache_clear()
     skipping._skip_fn_cached.cache_clear()
+    device_index._unpack_valid_fn.cache_clear()
     jax.clear_caches()
 
 
@@ -172,6 +174,15 @@ def test_skipping_mask_block_1m_files(on_chip):
             on_chip((rows, f_pad), jnp.bool_),
             atoms, atoms, atoms, atoms, on_chip((a_pad,), jnp.int64),
             atoms, on_chip((), jnp.int32)).compile()
+    _assert_fits(compiled)
+
+
+def test_stats_index_validity_unpack_2_6m_files(on_chip):
+    # the index of `ckpt-query-under-ingest`: 2.4M files pad to 2,621,440.
+    # As `jnp.unpackbits` over uint8 words this compile took 102 s
+    n_pad = 2_621_440
+    compiled = device_index._unpack_valid_fn(n_pad).lower(
+        on_chip((4, n_pad // 32), jnp.uint32)).compile()
     _assert_fits(compiled)
 
 

@@ -383,3 +383,25 @@ def test_empty_delta_carries_index_forward(tmp_table_path):
     snap2 = snap.update()
     holder = snap2.state.stats_index or snap.state.stats_index
     assert holder is idx
+
+
+@pytest.mark.parametrize("n_pad", [128, 4096, 1 << 20, (1 << 20) + (1 << 19)])
+def test_uploaded_validity_plane_is_bit_identical(n_pad):
+    """The validity plane crosses the link as packed 32-bit words and is
+    unpacked on the device by shift-and-mask: every flag of every lane
+    comes back where the host had it."""
+    from delta_tpu.stats.device_index import ResidentStatsIndex
+
+    rng = np.random.default_rng(n_pad)
+    valid = rng.random((4, n_pad)) < 0.5
+    valid[0, :3] = [True, False, True]
+    valid[3, -1] = True
+    vals = rng.integers(-2**62, 2**62, (4, n_pad))
+    idx = ResidentStatsIndex(None, vals, valid, {}, n_pad - 5)
+    dvals, dvalid = idx.device_lanes()
+    try:
+        assert dvalid.dtype == bool and dvalid.shape == (4, n_pad)
+        assert np.array_equal(np.asarray(dvalid), valid)
+        assert np.array_equal(np.asarray(dvals), vals)
+    finally:
+        idx.release()
