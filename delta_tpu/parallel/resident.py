@@ -345,11 +345,11 @@ def release_snapshot_resident(snapshot) -> None:
         resident.release()
         state.resident = None
     # the scan-planning stats index (stats/device_index.py) shares the
-    # residency lifecycle: evicting the snapshot frees its lanes too
-    stats_index = getattr(state, "stats_index", None)
-    if stats_index is not None:
-        stats_index.release()
-        state.stats_index = None
+    # residency lifecycle: evicting the snapshot frees its lanes too,
+    # and the seed of an index not built yet
+    from delta_tpu.stats.device_index import release_state_stats_index
+
+    release_state_stats_index(state)
     # the SQL operand cache (sqlengine/operands.py) shares the same
     # lifecycle: evicting the snapshot frees its column lanes too
     operand_cache = getattr(state, "operand_cache", None)

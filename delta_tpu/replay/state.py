@@ -76,6 +76,12 @@ class SnapshotState:
     # releases it through `release_snapshot_resident`.
     stats_index: Optional[object] = field(default=None, repr=False,
                                           compare=False)
+    # What `advance_state` kept of a prior version's released index
+    # (`stats/device_index.py::StatsIndexSeed`), under the same lock:
+    # the first filtered scan of this state makes its index from it
+    # and drops it; until then later advances pass it on.
+    stats_index_seed: Optional[object] = field(default=None, repr=False,
+                                               compare=False)
     # Resident SQL operand cache (sqlengine/operands.py): per-column
     # device lanes for join/group keys, built lazily per state under
     # `_operand_cache_lock`. `advance_state` carries it forward on
@@ -516,10 +522,13 @@ def advance_state(
     replay) — a new protocol can change how existing actions are read.
 
     A resident stats index (`stats/device_index.py`) survives only an
-    EMPTY delta: any landed file action releases its host lanes and
-    device copy here, and the next filtered scan of the new state
-    parses every live file's stats and uploads the lanes again. The
-    `update.advance` span says which happened (`stats_index`).
+    EMPTY delta: any landed file action releases it here, device copy
+    and ledger entry at once. The new state keeps a seed of it
+    (references to its lanes and parsed table, and `prev`'s live mask),
+    or the seed `prev` was itself still holding, and its first filtered
+    scan makes the new index from that: the rows still live, and the
+    stats of the rows landed since. The `update.advance` span says
+    which happened (`stats_index`, `stats_index_seed`).
     """
     with obs.span("update.advance",
                   prev_rows=prev.file_actions_raw.num_rows) as sp:
@@ -625,21 +634,27 @@ def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
         # buffer, so the prior state's reference is stale by definition
         new_state.resident = resident
         prev.resident = None
-    stats_index = prev.stats_index
+    with prev._stats_index_lock:
+        stats_index, seed = prev.stats_index, prev.stats_index_seed
+        prev.stats_index = prev.stats_index_seed = None
     sp.set_attr("stats_index", "none" if stats_index is None
                 else "carried" if m == 0 else "released")
-    if stats_index is not None:
-        if m == 0:
-            # empty delta: the live-file table is unchanged, so the
-            # index is still exact — ownership moves like `resident`
-            new_state.stats_index = stats_index
-            prev.stats_index = None
-        else:
-            # the prior version's lanes are stale; release the HBM now
-            # rather than waiting for eviction (the next scan of the
-            # new state rebuilds lazily)
-            stats_index.release()
-            prev.stats_index = None
+    seed_is = "none" if seed is None else "passed_on"
+    if stats_index is not None and m == 0:
+        # empty delta: the live-file table is unchanged, so the index
+        # is still exact — ownership moves like `resident`
+        new_state.stats_index = stats_index
+    elif stats_index is not None:
+        # the prior version's lanes are stale: free the HBM now rather
+        # than waiting for eviction. What the next scan of the new
+        # state needs of them goes on as a seed: from here on prior
+        # rows' live bits are only ever cleared and new rows land
+        # behind them, so it stays exact across any number of advances
+        seed = stats_index.seed(prev.live_mask)
+        stats_index.release()
+        seed_is = "none" if seed is None else "kept"
+    sp.set_attr("stats_index_seed", seed_is)
+    new_state.stats_index_seed = seed
     operand_cache = prev.operand_cache
     if operand_cache is not None:
         if m == 0:

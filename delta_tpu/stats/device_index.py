@@ -29,14 +29,25 @@ upload is lazy (first device-routed scan) and budgeted in
 ONCE per version and stay HBM-resident across scans, so the per-scan
 device cost is one RTT plus the compiled atom arrays.
 
-An index lives as long as its version. `replay/state.py::advance_state`
-hands it to the new state only across an EMPTY delta; one landed file
-action releases host lanes and device copy alike, and the first
-filtered scan of the new version builds from nothing: every live file's
-stats string parsed again (`stats.index_build`), every lane uploaded
-again (`stats.index_upload`). Nothing is appended to an index. A reader
-that refreshes under ingest pays the whole build once a refresh
-(PERF.md, `ckpt-query-under-ingest`).
+An index lives as long as its version, and the next version's is made
+from it. `replay/state.py::advance_state` hands the index itself to the
+new state across an EMPTY delta. One landed file action releases it
+(host lanes dropped, device copy and ledger entry freed at once) and
+leaves a `StatsIndexSeed` on the new state: references to the released
+index's lanes, kinds and parsed table, and the live mask they were
+built over. Live bits of prior rows are only ever cleared and new rows
+only ever land behind them, so the first filtered scan of the new
+version (`snapshot_stats_index`, span `stats.index_build`, `mode`
+`append`) keeps the seed's rows that are still live, parses the stats
+of the rows landed since under the seed's schema, and writes them
+behind: the cost is that of the rows that changed, and the result is
+what `build_index` over every live file gives. Where that cannot be
+shown from the seed (`_APPEND_FALLBACKS`, the span's
+`append_fallback`), and on a state with no seed, every live file's
+stats string is parsed (`mode` `full`). The seed's arrays are never
+written to: a reader may still plan on the prior version. Either way
+the lanes cross to the device whole, on the first device plan
+(`stats.index_upload`): nothing on the chip is patched in place.
 """
 
 from __future__ import annotations
@@ -45,6 +56,7 @@ import datetime
 import functools
 import threading
 import weakref
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -67,6 +79,8 @@ from delta_tpu.expressions.tree import (
 from delta_tpu.ops.skipping import AtomBlock
 
 _BUILDS = obs.counter("scan.stats_index_builds")
+_APPENDS = obs.counter("scan.stats_index_appends")
+_APPEND_FALLBACKS = obs.counter("scan.stats_index_append_fallbacks")
 _REUSES = obs.counter("scan.stats_index_reuses")
 # device bytes are accounted in the resident ledger (obs/hbm.py),
 # which derives the `scan.stats_index_hbm_bytes` gauge this module
@@ -126,14 +140,14 @@ def _resolve_kind(k_min: Optional[str], k_max: Optional[str]) -> Optional[str]:
     return None
 
 
-def _leaf_paths(t: pa.DataType, prefix: Tuple[str, ...] = ()) -> List[tuple]:
-    out = []
+def _typed_leaves(t, prefix: Tuple[str, ...] = ()) -> Dict[tuple, pa.DataType]:
+    """{path: type} of every leaf of a schema or struct type, in order."""
+    out = {}
     for f in t:
-        p = prefix + (f.name,)
         if pa.types.is_struct(f.type):
-            out.extend(_leaf_paths(f.type, p))
+            out.update(_typed_leaves(f.type, prefix + (f.name,)))
         else:
-            out.append(p)
+            out[prefix + (f.name,)] = f.type
     return out
 
 
@@ -223,6 +237,21 @@ def _unpack_valid_fn(n_pad: int):
     return jax.jit(unpack)
 
 
+@dataclass(frozen=True)
+class StatsIndexSeed:
+    """What is kept of an index released by a version advance, to make
+    the next one from: its lanes, kinds and parsed table (read, never
+    written to), and the live mask over the raw rows of its version,
+    whose set bits its `n` rows were."""
+
+    vals: Optional[np.ndarray]
+    valid: Optional[np.ndarray]
+    cols: Dict[tuple, Tuple[int, str]]
+    table: Optional[pa.Table]
+    n: int
+    base_live: np.ndarray
+
+
 class ResidentStatsIndex:
     """Per-snapshot-version stats index: the parsed Arrow table (shared
     with the host fallback ladder) plus the encoded int64 lanes, with a
@@ -248,6 +277,16 @@ class ResidentStatsIndex:
     @property
     def has_lanes(self) -> bool:
         return self.vals is not None and not self.released
+
+    def seed(self, base_live: np.ndarray) -> Optional[StatsIndexSeed]:
+        """The seed of the next version's index, `base_live` the live
+        mask this one was built under; None once released."""
+        with self._lock:
+            if self.released:
+                return None
+            return StatsIndexSeed(self.vals, self.valid, self.cols,
+                                  self.arrow_index._table, self.n,
+                                  base_live)
 
     def device_lanes(self):
         """(values, validity) device arrays, uploading on first use."""
@@ -315,10 +354,36 @@ class ResidentStatsIndex:
             self.released = True
 
 
+def _encode_column(mn: pa.Array, mx: pa.Array, nc: Optional[pa.Array],
+                   kind: str):
+    """The three lanes (min, max, nullCount) of one eligible column as
+    (values, validity) pairs; None when min or max cannot be encoded.
+    A nullCount that is missing or unreadable is unknown on every row."""
+    enc_mn = _encode_lane(mn, kind)
+    enc_mx = _encode_lane(mx, kind)
+    if enc_mn is None or enc_mx is None:
+        return None
+    return enc_mn, enc_mx, _encode_count(nc, len(mn))
+
+
+def _encode_count(arr: Optional[pa.Array], n: int):
+    enc = _encode_lane(arr, "int") if arr is not None else None
+    return enc if enc is not None else (np.zeros(n, np.int64),
+                                        np.zeros(n, bool))
+
+
+def _lanes_of(n_lanes: int, n: int):
+    """Zeroed lane matrix and validity plane in `n`'s pad bucket."""
+    from delta_tpu.ops.replay import pad_bucket
+
+    n_pad = pad_bucket(max(n, 1), min_bucket=128)
+    return (np.zeros((n_lanes, n_pad), np.int64),
+            np.zeros((n_lanes, n_pad), bool))
+
+
 def build_index(files: pa.Table, table_path: Optional[str] = None,
                 version: Optional[int] = None) -> ResidentStatsIndex:
     """Columnarize one snapshot version's parsed stats into lanes."""
-    from delta_tpu.ops.replay import pad_bucket
     from delta_tpu.stats.skipping import StatsIndex
 
     arrow_index = StatsIndex.from_stats_column(files.column("stats"))
@@ -341,8 +406,7 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
 
     lanes: List[Tuple[np.ndarray, np.ndarray]] = []
     cols: Dict[tuple, Tuple[int, str]] = {}
-    nr = arrow_index.num_records()
-    for path in _leaf_paths(mins.type):
+    for path in _typed_leaves(mins.type):
         mn = arrow_index.min_values(path)
         mx = arrow_index.max_values(path)
         if mn is None or mx is None:
@@ -350,33 +414,112 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
         kind = _resolve_kind(_lane_kind(mn.type), _lane_kind(mx.type))
         if kind is None:
             continue
-        enc_mn = _encode_lane(mn, kind)
-        enc_mx = _encode_lane(mx, kind)
-        if enc_mn is None or enc_mx is None:
+        encoded = _encode_column(mn, mx, arrow_index.null_count(path), kind)
+        if encoded is None:
             continue
-        nc = arrow_index.null_count(path)
-        enc_nc = _encode_lane(nc, "int") if nc is not None else None
-        if enc_nc is None:
-            enc_nc = (np.zeros(n, np.int64), np.zeros(n, bool))
         cols[path] = (len(lanes), kind)
-        lanes.extend((enc_mn, enc_mx, enc_nc))
+        lanes.extend(encoded)
     if not cols:
         return ResidentStatsIndex(arrow_index, None, None, {}, n,
                                   table_path=table_path, version=version)
+    lanes.append(_encode_count(arrow_index.num_records(), n))
 
-    enc_nr = _encode_lane(nr, "int") if nr is not None else None
-    if enc_nr is None:
-        enc_nr = (np.zeros(n, np.int64), np.zeros(n, bool))
-    lanes.append(enc_nr)
-
-    n_pad = pad_bucket(max(n, 1), min_bucket=128)
-    vals = np.zeros((len(lanes), n_pad), np.int64)
-    valid = np.zeros((len(lanes), n_pad), bool)
+    vals, valid = _lanes_of(len(lanes), n)
     for r, (ev, eva) in enumerate(lanes):
         vals[r, :n] = ev
         valid[r, :n] = eva
     return ResidentStatsIndex(arrow_index, vals, valid, cols, n,
                               table_path=table_path, version=version)
+
+
+def _cannot_append(reason: str):
+    return None, {"append_fallback": reason}
+
+
+def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
+                 files: pa.Table, table_path: Optional[str] = None,
+                 version: Optional[int] = None):
+    """The index of `files` (the live rows under `live_mask`, in raw
+    order) made from `seed`: the seed's rows that are still live, then
+    the rows landed since, their stats parsed under the seed's schema
+    and encoded under its kinds. Returns the index and the build span's
+    attributes (`rows` parsed, `dropped`), or None and the reason
+    (`append_fallback`) where the result cannot be shown equal to
+    `build_index(files)`: the caller then builds in full. Rows that
+    went never narrow the schema: a leaf that only they carried stays,
+    as a lane unknown on every row (which keeps, as no lane does)."""
+    from delta_tpu.stats.skipping import StatsIndex
+
+    if seed.vals is None:
+        return _cannot_append("seed-without-lanes")
+    n_base = len(seed.base_live)
+    still_live = live_mask[:n_base]
+    if len(still_live) != n_base:
+        return _cannot_append("row-count")
+    if (still_live & ~seed.base_live).any():
+        return _cannot_append("row-revived")    # bits are only cleared
+    survivors = still_live[seed.base_live]
+    n_kept = int(survivors.sum())
+    n_tail = int(live_mask[n_base:].sum())
+    n = files.num_rows
+    if len(survivors) != seed.n or n_kept + n_tail != n:
+        return _cannot_append("row-count")
+
+    if n_tail:
+        tail_stats = files.column("stats").slice(n_kept)
+        tail = StatsIndex.from_stats_column(tail_stats,
+                                            schema=seed.table.schema)
+        if tail._table is None:
+            # a leaf the seed lacks, a leaf of another type (an int
+            # column's first float), a non-finite token, or no stats on
+            # any new row: an inferring parse of every row may read
+            # those, under another schema than the seed's
+            return _cannot_append(_why_unread(tail_stats, seed.table.schema))
+    else:
+        tail = StatsIndex(seed.table.schema.empty_table(), 0)
+
+    vals, valid = _lanes_of(len(seed.vals), n)
+    dropped = seed.n - n_kept
+    for r in range(len(seed.vals)):
+        old_vals, old_valid = seed.vals[r, :seed.n], seed.valid[r, :seed.n]
+        vals[r, :n_kept] = old_vals[survivors] if dropped else old_vals
+        valid[r, :n_kept] = old_valid[survivors] if dropped else old_valid
+    for path, (row0, kind) in seed.cols.items():
+        encoded = _encode_column(tail.min_values(path), tail.max_values(path),
+                                 tail.null_count(path), kind)
+        if encoded is None:
+            return _cannot_append("tail-encode")
+        for r, (ev, eva) in enumerate(encoded, row0):
+            vals[r, n_kept:n] = ev
+            valid[r, n_kept:n] = eva
+    ev, eva = _encode_count(tail.num_records(), n_tail)
+    vals[-1, n_kept:n] = ev
+    valid[-1, n_kept:n] = eva
+
+    kept = seed.table.filter(pa.array(survivors)) if dropped else seed.table
+    table = pa.concat_tables([kept, tail._table]).combine_chunks()
+    idx = ResidentStatsIndex(StatsIndex(table, n), vals, valid, seed.cols,
+                             n, table_path=table_path, version=version)
+    return idx, {"rows": n_tail, "dropped": dropped}
+
+
+def _why_unread(stats: pa.ChunkedArray, schema: pa.Schema) -> str:
+    """Why rows did not read under `schema`, for the fallback's label
+    (on the way to a full build, so an inferring parse is cheap)."""
+    from delta_tpu.stats.skipping import StatsIndex
+
+    if stats.null_count == len(stats):
+        return "tail-without-stats"
+    inferred = StatsIndex.from_stats_column(stats)._table
+    if inferred is None:
+        return "tail-unparsed"
+    seed_leaves = _typed_leaves(schema)
+    for path, t in _typed_leaves(inferred.schema).items():
+        if path not in seed_leaves:
+            return "new-leaf"
+        if t != seed_leaves[path] and not pa.types.is_null(t):
+            return "leaf-type"
+    return "tail-unparsed"              # e.g. non-finite tokens, nulled
 
 
 def _compile_conj(conj: Expression,
@@ -505,13 +648,29 @@ def snapshot_stats_index(state, files: pa.Table):
         if idx is not None and not idx.released:
             _REUSES.inc()
             return idx
+        table_path = getattr(state, "table_path", None)
+        version = getattr(state, "version", None)
+        seed = getattr(state, "stats_index_seed", None)
         with obs.span("stats.index_build", rows=files.num_rows) as sp:
-            idx = build_index(files,
-                              table_path=getattr(state, "table_path", None),
-                              version=getattr(state, "version", None))
+            idx, stats = None, files.column("stats")
+            if seed is not None:
+                state.stats_index_seed = None
+                idx, attrs = append_index(seed, state.live_mask, files,
+                                          table_path, version)
+                sp.set_attrs(**attrs)
+            if idx is not None:
+                stats = stats.slice(files.num_rows - attrs["rows"])
+                sp.set_attr("mode", "append")
+                _APPENDS.inc()
+            else:
+                if seed is not None:
+                    _APPEND_FALLBACKS.inc()
+                idx = build_index(files, table_path, version)
+                sp.set_attr("mode", "full")
+                _BUILDS.inc()
             if sp.recording:
                 sp.set_attrs(
-                    bytes=files.column("stats").nbytes,
+                    bytes=stats.nbytes,
                     lanes=0 if idx.vals is None else len(idx.vals))
         state.stats_index = idx
         # built implicitly by ordinary filtered scans, so a state
@@ -521,14 +680,16 @@ def snapshot_stats_index(state, files: pa.Table):
         # the explicit paths — same contract as the operand cache in
         # sqlengine/operands.py)
         weakref.finalize(state, ResidentStatsIndex.release, idx)
-        _BUILDS.inc()
         return idx
 
 
 def release_state_stats_index(state) -> None:
-    """Release a state's resident index, if any (duck-typed like
-    `parallel/resident.py::release_snapshot_resident`)."""
+    """Release a state's resident index and the seed of one, if any
+    (duck-typed: `parallel/resident.py::release_snapshot_resident`
+    passes whatever it was given)."""
     idx = getattr(state, "stats_index", None)
     if idx is not None:
         idx.release()
         state.stats_index = None
+    if getattr(state, "stats_index_seed", None) is not None:
+        state.stats_index_seed = None   # the next index's host lanes

@@ -54,7 +54,13 @@ class StatsIndex:
         self.n = n
 
     @staticmethod
-    def from_stats_column(stats_col: pa.ChunkedArray) -> "StatsIndex":
+    def from_stats_column(stats_col: pa.ChunkedArray,
+                          schema: Optional[pa.Schema] = None) -> "StatsIndex":
+        """Parse one stats string a row. With `schema` (the parsed
+        schema of rows these will stand behind, `stats/device_index.py`)
+        nothing is inferred and nothing rewritten: a value that does
+        not read as the schema's type, or a key the schema lacks, gives
+        an index with no table."""
         n = len(stats_col)
         arr = stats_col.combine_chunks() if isinstance(stats_col, pa.ChunkedArray) else stats_col
         if n == 0 or arr.null_count == n:
@@ -68,10 +74,15 @@ class StatsIndex:
         # newline in a stats row is structural whitespace — flatten it.
         filled = pc.replace_substring(filled, pattern="\r", replacement=" ")
         filled = pc.replace_substring(filled, pattern="\n", replacement=" ")
+        options = None if schema is None else pa_json.ParseOptions(
+            explicit_schema=schema, unexpected_field_behavior="error")
         joined = ("\n".join(filled.to_pylist()) + "\n").encode()
         try:
-            parsed = pa_json.read_json(pa.BufferReader(joined))
+            parsed = pa_json.read_json(pa.BufferReader(joined),
+                                       parse_options=options)
         except pa.ArrowInvalid:
+            if schema is not None:
+                return StatsIndex(None, n)
             # A non-finite float stat serializes as the string "NaN" /
             # "Infinity" / "-Infinity" (see collection.py); ONE such
             # file makes Arrow's JSON inference see a string/number mix
@@ -366,7 +377,10 @@ def skipping_mask(
                 translated.append(t)
         conjuncts = translated
     fallback = conjuncts
-    if rs is not None and rs.has_lanes:
+    # read once: a version advance on another thread releases the index
+    # (its fields go to None) while this plan still holds the arrays
+    vals, valid = (rs.vals, rs.valid) if rs is not None else (None, None)
+    if vals is not None and valid is not None:
         from delta_tpu.ops import skipping as ops_skipping
         from delta_tpu.parallel.gate import skip_route
         from delta_tpu.stats.device_index import compile_conjuncts
@@ -409,7 +423,7 @@ def skipping_mask(
             if route == "host":
                 with obs.gate_observation("skip", "host"):
                     keep &= ops_skipping.host_skip_mask(
-                        rs.vals, rs.valid, block, n)
+                        vals, valid, block, n)
             obs.set_attrs(skip_route=route, skip_atoms=block.n_atoms,
                           skip_fallback_conjuncts=len(fallback))
     for conj in fallback:

@@ -50,10 +50,12 @@ def _files(stats_rows):
     })
 
 
-def _three_routes(files, conjuncts, metadata=None):
-    """(arrow, twin, device) keep-masks for one corpus entry."""
+def _three_routes(files, conjuncts, metadata=None, state=None):
+    """(arrow, twin, device) keep-masks for one corpus entry, the twin
+    and the kernel over `state`'s resident index (a fresh one when no
+    state is given)."""
     arrow = skipping_mask(files, conjuncts, metadata)
-    st = _FakeState(files)
+    st = state if state is not None else _FakeState(files)
     old = os.environ.get("DELTA_TPU_DEVICE_SKIP")
     try:
         os.environ["DELTA_TPU_DEVICE_SKIP"] = "off"
@@ -285,40 +287,81 @@ def test_column_mapping_physical_names_parity(tmp_table_path):
     assert (arrow == twin).all() and (twin == device).all()
 
 
-def test_index_lifecycle_end_to_end(tmp_table_path):
+def test_index_lifecycle_end_to_end(tmp_table_path, monkeypatch):
     from delta_tpu.expressions import col as tcol, lit as tlit
+    from delta_tpu.obs import hbm
     from delta_tpu.parallel.resident import release_snapshot_resident
 
+    def on_chip():
+        return [r for r in hbm.residents()
+                if r["kind"] == hbm.KIND_STATS_INDEX
+                and r["table_path"] == str(tmp_table_path)]
+
+    monkeypatch.setenv("DELTA_TPU_DEVICE_SKIP", "force")
     builds = obs.counter("scan.stats_index_builds")
+    appends = obs.counter("scan.stats_index_appends")
     dta.write_table(
         tmp_table_path,
         pa.table({"id": pa.array(np.arange(500, dtype=np.int64))}),
         target_rows_per_file=100,
     )
     snap = Table.for_path(tmp_table_path).latest_snapshot()
-    b0 = builds.value
+    b0, a0 = builds.value, appends.value
     flt = (tcol("id") >= tlit(0)) & (tcol("id") < tlit(100))
     assert snap.scan(filter=flt).add_files_table().num_rows == 1
     assert snap.scan(filter=flt).add_files_table().num_rows == 1
     # two scans of one version: ONE build, the second plan reuses it
     assert builds.value == b0 + 1
-    assert snap.state.stats_index is not None
+    old_index = snap.state.stats_index
+    assert old_index is not None
+    assert [r["version"] for r in on_chip()] == [snap.version]
 
     # update() with a real delta produces a fresh state; the old
-    # version's index was released by advance_state and the next scan
-    # builds against the new version exactly once
+    # version's index is released by advance_state, device copy and
+    # ledger entry at once, and what the next scan needs of it goes on
+    # as a seed
     dta.write_table(
         tmp_table_path,
         pa.table({"id": pa.array(np.arange(500, 600, dtype=np.int64))}))
     snap2 = snap.update()
     assert snap2.state.stats_index is None
     assert snap.state.stats_index is None  # released, not leaked
-    assert snap2.scan(filter=flt).add_files_table().num_rows == 1
-    assert builds.value == b0 + 2
+    assert old_index.released and old_index.vals is None
+    assert on_chip() == []
+    assert snap2.state.stats_index_seed is not None
+    assert snap.state.stats_index_seed is None
 
-    # eviction discipline: release_snapshot_resident frees the index
+    # the next scan brings the index to the new version from the seed:
+    # no build from nothing, one append, the seed consumed
+    assert snap2.scan(filter=flt).add_files_table().num_rows == 1
+    assert builds.value == b0 + 1
+    assert appends.value == a0 + 1
+    assert snap2.state.stats_index_seed is None
+    assert snap2.state.stats_index.version == snap2.version
+    assert [r["version"] for r in on_chip()] == [snap2.version]
+
+    # a reader still holding the old snapshot plans on it correctly,
+    # by a build from nothing (its index went with the advance)
+    late = (tcol("id") >= tlit(500)) & (tcol("id") < tlit(600))
+    assert snap.scan(filter=late).add_files_table().num_rows == 0
+    assert snap2.scan(filter=late).add_files_table().num_rows == 1
+    assert snap.scan(filter=flt).add_files_table().num_rows == 1
+    assert builds.value == b0 + 2
+    assert appends.value == a0 + 1
+
+    # eviction discipline: release_snapshot_resident frees the index,
+    # and a seed no scan has consumed
     release_snapshot_resident(snap2)
     assert snap2.state.stats_index is None
+    dta.write_table(
+        tmp_table_path,
+        pa.table({"id": pa.array(np.arange(600, 700, dtype=np.int64))}))
+    snap3 = snap.update()
+    assert snap3.state.stats_index_seed is not None
+    release_snapshot_resident(snap3)
+    assert snap3.state.stats_index_seed is None
+    release_snapshot_resident(snap)
+    assert on_chip() == []
 
 
 def test_skip_route_gate():
@@ -405,3 +448,400 @@ def test_uploaded_validity_plane_is_bit_identical(n_pad):
         assert np.array_equal(np.asarray(dvals), vals)
     finally:
         idx.release()
+
+
+# ---- the index brought to a new version from the one before ----
+
+_PROTOCOL = {"protocol": {"minReaderVersion": 1, "minWriterVersion": 2}}
+_METADATA = {"metaData": {
+    "id": "appended-index", "format": {"provider": "parquet", "options": {}},
+    "schemaString": json.dumps({"type": "struct", "fields": [
+        {"name": n, "type": t, "nullable": True, "metadata": {}}
+        for n, t in (("a", "long"), ("f", "double"), ("s", "string"))]}),
+    "partitionColumns": [], "configuration": {}}}
+
+
+def _file_stats(fid):
+    """Stats of file `fid`: an int, a float and a string column."""
+    return _stats(10, {"a": fid * 10, "f": fid * 1.5 - 40.25, "s": f"k{fid:05d}"},
+                  {"a": fid * 10 + 9, "f": fid * 1.5 - 39.5, "s": f"k{fid:05d}z"},
+                  {"a": fid % 3, "f": 0, "s": 0})
+
+
+class _Log:
+    """A table written commit by commit as raw JSON, so that a test
+    chooses every stats string and every remove."""
+
+    def __init__(self, root, n_base):
+        self.root = str(root)
+        self.log = os.path.join(self.root, "_delta_log")
+        os.makedirs(self.log)
+        self.version = -1
+        self.next_fid = 0
+        self.commit(extra=[_PROTOCOL, _METADATA])
+        self.commit(adds=self.new_files(n_base))
+
+    def new_files(self, k, stats=_file_stats):
+        fids = range(self.next_fid, self.next_fid + k)
+        self.next_fid += k
+        return [(f"part-{fid:05d}.parquet", stats(fid)) for fid in fids]
+
+    def commit(self, adds=(), removes=(), extra=()):
+        self.version += 1
+        actions = list(extra)
+        actions += [{"remove": {"path": p, "deletionTimestamp": self.version,
+                                "dataChange": True}} for p in removes]
+        actions += [{"add": {"path": p, "partitionValues": {}, "size": 100,
+                             "modificationTime": self.version,
+                             "dataChange": True, "stats": st}}
+                    for p, st in adds]
+        with open(os.path.join(self.log, f"{self.version:020d}.json"),
+                  "w") as f:
+            f.writelines(json.dumps(a) + "\n" for a in actions)
+
+
+_CONJUNCTS = [
+    [Comparison("<", col("a"), lit(300))],
+    [Comparison(">=", col("f"), lit(100.0)), Comparison("<", col("a"), lit(1200))],
+    [Comparison(">=", col("a"), lit(1250))],
+    # a string column goes down the Arrow ladder, over the parsed table
+    [Comparison(">=", col("s"), lit("k00125")), Comparison(">", col("a"), lit(40))],
+    [IsNull(col("a"))],
+    [Or(Comparison("=", col("a"), lit(55)), Comparison(">", col("f"), lit(150.0)))],
+]
+
+
+def _live_paths(snapshot):
+    return snapshot.state.add_files_table.column("path").to_pylist()
+
+
+_INDEX_COUNTERS = [obs.counter("scan.stats_index_" + name)
+                   for name in ("builds", "appends", "append_fallbacks")]
+
+
+def _assert_index_as_built_from_nothing(snapshot):
+    """The state's resident index, and every route's keep-mask over it,
+    equal what a build over every live file's stats string gives.
+    Returns how the state came by its index: (builds from nothing,
+    appends, appends that fell back), counted round that one call."""
+    from delta_tpu.stats.device_index import build_index, snapshot_stats_index
+
+    state = snapshot.state
+    files = state.add_files_table
+    before = [c.value for c in _INDEX_COUNTERS]
+    idx = snapshot_stats_index(state, files)
+    how = tuple(c.value - b for c, b in zip(_INDEX_COUNTERS, before))
+    ref = build_index(files)
+    n = ref.n
+    assert idx.n == n == files.num_rows
+    assert list(idx.cols.items()) == list(ref.cols.items())
+    assert idx.vals.shape == ref.vals.shape == idx.valid.shape
+    assert np.array_equal(idx.vals, ref.vals)           # the padding too
+    assert np.array_equal(idx.valid, ref.valid)
+    assert not idx.vals[:, n:].any() and not idx.valid[:, n:].any()
+    assert idx.arrow_index.n == n
+    assert idx.arrow_index._table.equals(ref.arrow_index._table)
+    assert idx.arrow_index._table.schema.equals(ref.arrow_index._table.schema)
+    for conjs in _CONJUNCTS:
+        arrow, twin, device = _three_routes(files, conjs, state=state)
+        assert (arrow == twin).all() and (twin == device).all(), conjs
+        fresh = _three_routes(files, conjs)[1]
+        assert (twin == fresh).all(), conjs
+    return how
+
+
+def _null_some(fid):
+    return None if fid % 3 == 0 else _file_stats(fid)
+
+
+def _adds(log, rng, live):
+    """Three refreshes, each of one commit that only adds."""
+    for _ in range(3):
+        log.commit(adds=log.new_files(int(rng.integers(1, 9))))
+        yield "scan"
+
+
+def _removes_of_checkpoint_files(log, rng, live):
+    for _ in range(3):
+        gone = rng.choice(live()[:100], int(rng.integers(1, 6)), replace=False)
+        log.commit(adds=log.new_files(int(rng.integers(0, 5))),
+                   removes=list(gone))
+        yield "scan"
+
+
+def _removes_of_delta_files(log, rng, live):
+    """Files of an earlier delta go: one the index already holds, and
+    one that landed and went between two scans."""
+    log.commit(adds=log.new_files(6))
+    yield "scan"
+    log.commit(adds=log.new_files(4), removes=live()[-3:-1])
+    yield "scan"
+    log.commit(adds=log.new_files(5))
+    yield "update"
+    log.commit(removes=[live()[-2], live()[3]])
+    yield "scan"
+    log.commit(removes=live()[-4:])             # a delta of removes alone
+    yield "scan"
+
+
+def _removed_and_added_again(log, rng, live):
+    back = live()[7]
+    log.commit(removes=[back])
+    yield "scan"
+    log.commit(adds=[(back, _file_stats(700))])
+    yield "scan"
+    again = live()[11]
+    log.commit(removes=[again])
+    log.commit(adds=[(again, _file_stats(701))] + log.new_files(2))
+    yield "scan"                                # both in one refresh
+    log.commit(adds=[(again, _file_stats(702))])    # re-added while live
+    yield "scan"
+
+
+def _rows_without_stats(log, rng, live):
+    for _ in range(3):
+        log.commit(adds=log.new_files(7, stats=_null_some),
+                   removes=[live()[int(rng.integers(0, 100))]])
+        yield "scan"
+
+
+def _several_updates_between_scans(log, rng, live):
+    for _ in range(2):
+        for _ in range(3):
+            log.commit(adds=log.new_files(int(rng.integers(1, 6))),
+                       removes=[live()[int(rng.integers(0, 110))]])
+            yield "update"
+        yield "scan"
+
+
+def _empty_delta_after_a_landed_one(log, rng, live):
+    log.commit(adds=log.new_files(3), removes=live()[:2])
+    yield "update"
+    log.commit(extra=[{"txn": {"appId": "writer", "version": 1}}])
+    yield "scan"                                # the seed came through
+    log.commit(adds=log.new_files(2))
+    yield "update"
+    log.commit(extra=[{"txn": {"appId": "writer", "version": 2}}])
+    yield "update"
+    log.commit(extra=[{"txn": {"appId": "writer", "version": 3}}])
+    yield "scan"
+
+
+def _across_a_pad_bucket(log, rng, live):
+    from delta_tpu.stats.device_index import snapshot_stats_index
+
+    def n_pad(snapshot):
+        state = snapshot.state
+        return snapshot_stats_index(
+            state, state.add_files_table).vals.shape[1]
+
+    assert n_pad((yield "snapshot")) == 128
+    log.commit(adds=log.new_files(5), removes=live()[:1])
+    assert n_pad((yield "scan")) == 128         # 124 rows
+    log.commit(adds=log.new_files(20), removes=live()[:3])
+    assert n_pad((yield "scan")) == 256         # 141 rows
+    log.commit(removes=live()[:30])
+    assert n_pad((yield "scan")) == 128         # and back: 111 rows
+
+
+@pytest.mark.parametrize("start", ["json", "checkpoint", "resident"])
+@pytest.mark.parametrize("sequence", [
+    _adds, _removes_of_checkpoint_files, _removes_of_delta_files,
+    _removed_and_added_again, _rows_without_stats,
+    _several_updates_between_scans, _empty_delta_after_a_landed_one,
+    _across_a_pad_bucket], ids=lambda f: f.__name__.strip("_"))
+def test_appended_index_equals_one_built_from_nothing(
+        tmp_path, sequence, start):
+    """Over seeded random sequences of commits, the index that each
+    refresh makes from the version before equals `build_index` over
+    every live file, and plans the same files on all three routes:
+    on a state loaded from commits, from a checkpoint, and one whose
+    replay state is resident on the device (`update.advance` then takes
+    the masks of old rows and new from the device)."""
+    from delta_tpu.engine.tpu import TpuEngine
+
+    engine = TpuEngine(replay_shards=8) if start == "resident" else None
+    for seed in range(3):
+        rng = np.random.default_rng([seed, len(sequence.__name__)])
+        log = _Log(tmp_path / f"t{seed}", n_base=120)
+        table = Table.for_path(log.root, engine)
+        if start == "checkpoint":
+            table.checkpoint()
+            table = Table.for_path(log.root)
+        holder = [table.latest_snapshot()]
+        assert _assert_index_as_built_from_nothing(holder[0]) == (1, 0, 0)
+        assert (holder[0].state.resident is not None) == (start == "resident")
+        scans = 0
+        steps = sequence(log, rng, lambda: _live_paths(holder[0]))
+        step = next(steps)
+        while True:
+            if step != "snapshot":
+                holder[0] = holder[0].update()
+                assert holder[0].version == log.version
+            if step == "scan":
+                # an append, not a build from every stats string, and
+                # not one that fell back
+                assert _assert_index_as_built_from_nothing(
+                    holder[0]) == (0, 1, 0)
+                scans += 1
+            try:
+                step = steps.send(holder[0])
+            except StopIteration:
+                break
+        assert scans >= 2
+        # and the table as a process that never saw the versions between
+        fresh = Table.for_path(log.root).latest_snapshot()
+        assert _live_paths(fresh) == _live_paths(holder[0])
+
+
+def _float_in_an_int_column(fid):
+    return _file_stats(fid).replace(f'"a": {fid * 10},', f'"a": {fid * 10}.5,')
+
+
+def _a_new_column(fid):
+    st = json.loads(_file_stats(fid))
+    for group in ("minValues", "maxValues", "nullCount"):
+        st[group]["b"] = fid
+    return json.dumps(st)
+
+
+def _a_nan_token(fid):
+    if fid % 2:
+        return _file_stats(fid)
+    return _stats(10, {"a": fid * 10, "f": "NaN", "s": "k"},
+                  {"a": fid * 10 + 9, "f": "NaN", "s": "kz"},
+                  {"a": 0, "f": 0, "s": 0})
+
+
+def _counts_alone(fid):
+    return _stats(10)
+
+
+@pytest.mark.parametrize("base_stats,delta_stats,reason", [
+    (_file_stats, _float_in_an_int_column, "leaf-type"),
+    (_file_stats, _a_new_column, "new-leaf"),
+    (_file_stats, _a_nan_token, "tail-unparsed"),
+    (_file_stats, lambda fid: None, "tail-without-stats"),
+    (_counts_alone, _file_stats, "seed-without-lanes"),
+], ids=["int-to-float", "new-column", "nan-token", "no-stats",
+        "seed-without-lanes"])
+def test_append_falls_back_to_a_full_build(tmp_path, base_stats,
+                                           delta_stats, reason):
+    """Where the delta's stats do not read under the seed's schema, or
+    the seed has no lanes, the refresh builds from every live file,
+    says why, and plans what a fresh load of the table plans."""
+    log = _Log(tmp_path / "t", n_base=0)
+    log.commit(adds=log.new_files(40, stats=base_stats))
+    snap = Table.for_path(log.root).latest_snapshot()
+    conjs = _CONJUNCTS[1]
+    skipping_mask(snap.state.add_files_table, conjs, None, state=snap.state)
+    log.commit(adds=log.new_files(6, stats=delta_stats),
+               removes=_live_paths(snap)[:2])
+    snap = snap.update()
+    assert snap.state.stats_index_seed is not None
+    obs.set_trace_mode("on")
+    try:
+        obs.reset_trace_buffer()
+        assert _assert_index_as_built_from_nothing(snap) == (1, 0, 1)
+        build = [s.to_dict()["attrs"] for s in obs.get_finished_spans()
+                 if s.name == "stats.index_build"][0]
+    finally:
+        obs.set_trace_mode(None)
+        obs.reset_trace_buffer()
+    assert build["mode"] == "full" and build["append_fallback"] == reason
+    assert build["rows"] == 44
+    assert snap.state.stats_index_seed is None
+    fresh = Table.for_path(log.root).latest_snapshot()
+    files, fresh_files = snap.state.add_files_table, fresh.state.add_files_table
+    for conjs in _CONJUNCTS:
+        keep = skipping_mask(files, conjs, None, state=snap.state)
+        fresh_keep = skipping_mask(fresh_files, conjs, None,
+                                   state=fresh.state)
+        assert files.filter(pa.array(keep)).column("path").to_pylist() == \
+            fresh_files.filter(pa.array(fresh_keep)).column(
+                "path").to_pylist()
+    # the full build's index seeds the next refresh like any other
+    log.commit(adds=log.new_files(3, stats=delta_stats))
+    snap = snap.update()
+    _assert_index_as_built_from_nothing(snap)
+
+
+def test_build_span_says_append_and_counts_rows(tmp_path):
+    log = _Log(tmp_path / "t", n_base=50)
+    snap = Table.for_path(log.root).latest_snapshot()
+    _assert_index_as_built_from_nothing(snap)
+    obs.set_trace_mode("on")
+    try:
+        obs.reset_trace_buffer()
+        log.commit(adds=log.new_files(8), removes=_live_paths(snap)[:3])
+        snap = snap.update()
+        log.commit(adds=log.new_files(2), removes=_live_paths(snap)[-1:])
+        snap = snap.update()
+        assert _assert_index_as_built_from_nothing(snap) == (0, 1, 0)
+        spans = [(s.name, s.to_dict()["attrs"])
+                 for s in obs.get_finished_spans()]
+    finally:
+        obs.set_trace_mode(None)
+        obs.reset_trace_buffer()
+    assert [a["stats_index_seed"] for name, a in spans
+            if name == "update.advance"] == ["kept", "passed_on"]
+    build = [a for name, a in spans if name == "stats.index_build"][0]
+    assert build["mode"] == "append" and "append_fallback" not in build
+    assert build["rows"] == 9 and build["dropped"] == 3 and build["lanes"] == 7
+    assert build["bytes"] > 0
+
+
+def test_scans_race_refreshes_and_every_plan_is_its_versions(tmp_path):
+    """Readers plan on whatever snapshot is newest, and on the one
+    before, while a writer lands commits and refreshes: the seed and
+    the index change hands under `_stats_index_lock`, and every plan
+    is the plan of its own version."""
+    import sys
+
+    log = _Log(tmp_path / "t", n_base=60)
+    table = Table.for_path(log.root)
+    newest = [table.latest_snapshot()]
+    live_at = {newest[0].version: _live_paths(newest[0])}
+    conjs = [Comparison("<", col("a"), lit(300))]
+    errors, plans, done = [], [0], threading.Event()
+
+    def wanted(version):        # file `fid` has a in [10 fid, 10 fid + 9]
+        return [p for p in live_at[version] if int(p[5:10]) * 10 < 300]
+
+    def reader():
+        try:
+            prior = snap = newest[0]
+            while not done.is_set() or plans[0] < 20:
+                prior, snap = snap, newest[0]
+                for s in (snap, prior):
+                    files = s.state.add_files_table
+                    keep = skipping_mask(files, conjs, None, state=s.state)
+                    got = files.filter(pa.array(keep)).column("path")
+                    assert got.to_pylist() == wanted(s.version), s.version
+                    plans[0] += 1
+        except Exception as e:          # raised by the main thread
+            errors.append(e)
+
+    readers = [threading.Thread(target=reader) for _ in range(3)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in readers:
+            t.start()
+        for k in range(12):
+            live = live_at[newest[0].version]
+            log.commit(adds=log.new_files(3), removes=[live[k], live[-1]])
+            snap = newest[0].update()
+            live_at[snap.version] = _live_paths(snap)
+            newest[0] = snap
+        done.set()
+        for t in readers:
+            t.join(timeout=60)
+    finally:
+        done.set()
+        sys.setswitchinterval(interval)
+    if errors:
+        raise errors[0]
+    assert not any(t.is_alive() for t in readers)
+    assert plans[0] >= 20
+    _assert_index_as_built_from_nothing(newest[0])
