@@ -5,9 +5,8 @@ reference harness (`benchmarks/src/main/scala/benchmark/
 TPCDSBenchmarkQueries.scala`, itself generated from the public TPC-DS
 v2.4 templates). Texts are UNMODIFIED — the sqlengine runs them
 as-is; `tests/test_tpcds.py` validates every result against an
-independent sqlite oracle on seeded data (`benchmarks/tpcds_data.py`),
-and `python -m benchmarks.run --benchmark tpcds` times them
-(reports under `benchmarks/reports/`).
+independent sqlite oracle on seeded data (`benchmarks/tpcds_data.py`).
+Nothing times them yet: a cell at upstream's 1 GB scale is ROADMAP B3.
 
 The ONLY reference key not present is q16: its shipped text references
 a non-existent column (`d_date_skq`) and cannot run on any engine.

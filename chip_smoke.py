@@ -30,7 +30,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-FILES_PER_COMMIT = 100   # benchmarks/workloads.py SCALES["medium"]
+FILES_PER_COMMIT = 100   # BASELINE.json configs[1]: 10M adds / 100k commits
 APPEND_COMMITS = 100
 DATA_ROWS = 4_000_000
 DATA_FILES = 64

@@ -830,12 +830,12 @@ def dump_gate_log(path: str) -> int:
 def export_device_merit(gates: Optional[List[dict]] = None,
                         dispatches: Optional[List[dict]] = None
                         ) -> Dict[str, object]:
-    """Distill the session's records into a fresh DEVICE_MERIT.json-
-    shaped capture: link bandwidth from observed (h2d_bytes, wall) pairs
-    bucketed at the 8 MB fast-chunk boundary, replay_fa workload rates
-    from joined gate decisions, conditions stamped. This is the artifact
-    the ROADMAP's deferred real-TPU capture produces by just running the
-    bench with device obs on."""
+    """Distill the session's records into a link-model capture (the
+    shape `DELTA_TPU_LINK_MODEL` reads): link bandwidth from observed
+    (h2d_bytes, wall) pairs bucketed at the 8 MB fast-chunk boundary,
+    replay_fa workload rates from joined gate decisions, conditions
+    stamped. A run on the chip with device obs on produces it; none is
+    committed (ROADMAP A1)."""
     gates = get_gate_records() if gates is None else gates
     dispatches = get_dispatch_records() if dispatches is None else dispatches
     fast, slow = [], []

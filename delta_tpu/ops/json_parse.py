@@ -1,9 +1,10 @@
 """Device JSON field extraction for the commit-replay hot path.
 
 PAPER.md names JSON action parsing as one of the components that "must
-become XLA/Pallas device kernels — not Python loops", and BASELINE.md
-r05 pinned the warm path's floor at the ~270 MB/s per-byte C++
-field-extraction scan. This module is the device half of that lever:
+become XLA/Pallas device kernels — not Python loops"; the host's
+alternative is the per-byte C++ field-extraction scan, which the gate
+prices at 270 MB/s (a placeholder, not measured on this device:
+`parallel/gate.py`). This module is the device half of that lever:
 one contiguous newline-terminated commit-window byte buffer ships to
 device as a single uint8 lane (the `json-parse-window` plane in
 `resources/transfer_budget.json`), and a batched data-parallel pass

@@ -1,19 +1,19 @@
 """Pallas TPU kernels for the hottest per-row ops.
 
-Two kernels with identical jnp fallbacks (used automatically off-TPU or
-via `interpret=True` on CPU):
+Three kernels, each with an identical jnp body (used off-TPU, or the
+kernel itself runs under `interpret=True` on CPU):
 
 - `interleave_bits_tiled`: the OPTIMIZE ZORDER curve-key op. One VMEM
   pass per [8, 128] tile computes all output words — the 32·k-step bit
   loop stays in registers instead of materializing 32·k intermediate
   arrays for XLA to fuse.
-- `segmented_minmax`: per-file min/max/count over a [files, rows] batch
-  with a validity mask — the stats-collection reduction when many data
-  files are written in one call (stats for the skipping index,
-  `StatisticsCollection.scala:257` role).
+- `byte_class_tiled`: the six structural byte classes of the device
+  JSON parse (`ops/json_parse.py`).
+- `shift_extract_tiled`: the data-dependent bit-field extract of the
+  batched checkpoint page decode (`ops/page_decode.py`).
 
-Layout notes: rows are padded to 128 lanes; tiles are (8, 128) float32 /
-int32 per the TPU tiling table.
+Layout notes: rows are padded to 128 lanes; tiles are (8, 128) uint32
+and (32, 128) uint8 per the TPU tiling table.
 """
 
 from __future__ import annotations
@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from jax.experimental import pallas as pl
 
@@ -95,73 +94,6 @@ def interleave_bits_auto(cols, n_bits: int = 32):
 
 
 # ---------------------------------------------------------------------------
-# segmented min/max/count (stats collection)
-# ---------------------------------------------------------------------------
-
-
-def _minmax_kernel(in_ref, mask_ref, min_ref, max_ref, cnt_ref):
-    """in/mask: [8, R]; outputs: [8, 128] (stats broadcast into lane 0)."""
-    x = in_ref[:]
-    valid = mask_ref[:]
-    big = jnp.float32(jnp.inf)
-    mn = jnp.min(jnp.where(valid, x, big), axis=1, keepdims=True)
-    mx = jnp.max(jnp.where(valid, x, -big), axis=1, keepdims=True)
-    cnt = jnp.sum(valid.astype(jnp.float32), axis=1, keepdims=True)
-    min_ref[:] = jnp.broadcast_to(mn, (_SUBLANES, _LANES))
-    max_ref[:] = jnp.broadcast_to(mx, (_SUBLANES, _LANES))
-    cnt_ref[:] = jnp.broadcast_to(cnt, (_SUBLANES, _LANES))
-
-
-@jax.jit
-def segmented_minmax(values: jnp.ndarray, valid: jnp.ndarray):
-    """values/valid: [F, R] float32/bool, F a multiple of 8, R of 128.
-    Returns (min[F], max[F], valid_count[F]) — min/max over valid entries
-    (±inf when a file has no valid rows)."""
-    f, r = values.shape
-    assert f % _SUBLANES == 0 and r % _LANES == 0, (f, r)
-    grid = (f // _SUBLANES,)
-    spec_in = pl.BlockSpec((_SUBLANES, r), lambda i: (i, 0))
-    spec_out = pl.BlockSpec((_SUBLANES, _LANES), lambda i: (i, 0))
-    mn, mx, cnt = pl.pallas_call(
-        _minmax_kernel,
-        grid=grid,
-        in_specs=[spec_in, spec_in],
-        out_specs=(spec_out, spec_out, spec_out),
-        out_shape=(
-            jax.ShapeDtypeStruct((f, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((f, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((f, _LANES), jnp.float32),
-        ),
-        interpret=_use_interpret(),
-        name="segmented_minmax",
-    )(values.astype(jnp.float32), valid)
-    return mn[:, 0], mx[:, 0], cnt[:, 0].astype(jnp.int32)
-
-
-def batched_file_stats(values: np.ndarray, valid: np.ndarray):
-    """Host wrapper: pad [F, R] to tile multiples, run the kernel, return
-    numpy (min, max, null_count, num_records) per file. x32 pinned for
-    the same Mosaic reason as interleave_bits_auto."""
-    with _x32():
-        return _batched_file_stats_impl(values, valid)
-
-
-def _batched_file_stats_impl(values: np.ndarray, valid: np.ndarray):
-    f, r = values.shape
-    fpad = (-f) % _SUBLANES
-    rpad = (-r) % _LANES
-    v = np.pad(values.astype(np.float32), ((0, fpad), (0, rpad)))
-    m = np.pad(valid.astype(bool), ((0, fpad), (0, rpad)))
-    mn, mx, cnt = segmented_minmax(jnp.asarray(v), jnp.asarray(m))
-    mn = np.asarray(mn)[:f]
-    mx = np.asarray(mx)[:f]
-    cnt = np.asarray(cnt)[:f]
-    num_records = np.full(f, r, dtype=np.int64)
-    null_count = num_records - cnt
-    return mn, mx, null_count, num_records
-
-
-# ---------------------------------------------------------------------------
 # JSON structural byte classes (device action parse)
 # ---------------------------------------------------------------------------
 
@@ -216,92 +148,6 @@ def byte_class_tiled(b: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# parquet bit-packed group decode (checkpoint page decoder)
-# ---------------------------------------------------------------------------
-
-
-def _check_unpack_width(w: int, allow_zero: bool = False) -> None:
-    """Typed guard for the bit-unpack primitive. A corrupt page header
-    can carry any width byte; before this guard a w>32 silently wrapped
-    the value mask (`1 << w` mod 2^32) and decoded garbage."""
-    lo = 0 if allow_zero else 1
-    if not isinstance(w, (int, np.integer)) or not lo <= int(w) <= 32:
-        from delta_tpu.errors import InvalidArgumentError
-
-        raise InvalidArgumentError(
-            f"bit-packed width must be in [{lo}, 32], got {w!r}")
-
-
-def _unpack_kernel(w: int, in_ref, out_ref):
-    """in_ref: [w, 8, 128] uint32 (word-index-major, like the
-    interleave kernel's layout); out_ref: [32, 8, 128] uint32 values.
-
-    One Parquet bit-packed GROUP is 32 values x w bits = w u32 words;
-    value j of a group lives at bit j*w, so its word index j*w//32 and
-    shift j*w%32 are STATIC per j — the 32-step loop unrolls into pure
-    vector shifts/ors over the [8, 128] group tile (the exact inverse
-    of `_interleave_kernel`)."""
-    mask = jnp.uint32((1 << w) - 1) if w < 32 else jnp.uint32(0xFFFFFFFF)
-    for j in range(32):
-        bitpos = j * w
-        lo, sh = divmod(bitpos, 32)
-        v = in_ref[lo] >> jnp.uint32(sh)
-        if sh + w > 32:
-            v = v | (in_ref[lo + 1] << jnp.uint32(32 - sh))
-        out_ref[j] = v & mask
-
-
-@functools.partial(jax.jit, static_argnames=("w",))
-def unpack_bitpacked_tiled(packed: jnp.ndarray, w: int) -> jnp.ndarray:
-    """packed: [w, G] uint32 (word-major: packed[k, g] = word k of
-    group g; G a multiple of 1024) -> [G * 32] uint32 values, group-
-    major (value j of group g at g*32 + j)."""
-    _check_unpack_width(w)
-    g = packed.shape[1]
-    assert g % _TILE == 0, g
-    tiles = g // _TILE
-    shaped = packed.reshape(w, tiles * _SUBLANES, _LANES)
-    out = pl.pallas_call(
-        functools.partial(_unpack_kernel, w),
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec((w, _SUBLANES, _LANES), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((32, _SUBLANES, _LANES), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((32, tiles * _SUBLANES, _LANES),
-                                       jnp.uint32),
-        interpret=_use_interpret(),
-        name="unpack_bitpacked_tiled",
-    )(shaped)
-    # [32, G] -> group-major [G, 32] -> flat
-    return out.reshape(32, -1).T.reshape(-1)
-
-
-def unpack_bitpacked(packed_words: np.ndarray, w: int,
-                     n_groups: int, device=None) -> jnp.ndarray:
-    """Decode `n_groups` Parquet bit-packed groups (32 values x w bits
-    each) from a flat little-endian u32 word stream. Pallas when
-    available, jnp fallback with identical semantics. Returns a device
-    array of n_groups*32 uint32 values. w must be in [0, 32]; w == 0 is
-    the valid all-zero run, anything outside raises
-    InvalidArgumentError instead of wrapping the value mask."""
-    _check_unpack_width(w, allow_zero=True)
-    if w == 0:
-        return jnp.zeros(n_groups * 32, jnp.uint32)
-    need = n_groups * w
-    padded_groups = -(-max(n_groups, 1) // _TILE) * _TILE
-    buf = np.zeros(padded_groups * w, np.uint32)
-    buf[:need] = packed_words[:need]
-    # [G, w] group-major words -> [w, G] word-major for the kernel
-    shaped = np.ascontiguousarray(buf.reshape(padded_groups, w).T)
-    # Mosaic lowers this kernel with i32 grid indexing; a process that
-    # enabled global x64 (the SQL spine does) would otherwise feed it
-    # i64 index maps and fail to legalize — dtypes here are explicit,
-    # so pin x32 semantics for the call
-    with _x32():
-        arr = jax.device_put(shaped, device)
-        return unpack_bitpacked_tiled(arr, w)[:n_groups * 32]
-
-
-# ---------------------------------------------------------------------------
 # variable-shift bit-field extract (batched checkpoint page decode)
 # ---------------------------------------------------------------------------
 #
@@ -309,9 +155,9 @@ def unpack_bitpacked(packed_words: np.ndarray, w: int,
 # bit-packed hybrid position of a checkpoint part into four u32 lanes:
 # the 32-bit little-endian window at the value's byte offset (`lo`),
 # the spill byte above it (`hi`), the in-byte shift (`sh`, 0..7) and
-# the run's bit width (`w`, 0..32). Unlike `unpack_bitpacked` the shift
-# is DATA-dependent (each element belongs to a different run), so the
-# extract is elementwise rather than a static unrolled group loop.
+# the run's bit width (`w`, 0..32). The shift is DATA-dependent (each
+# element belongs to a different run), so the extract is elementwise
+# rather than a static unrolled group loop.
 
 
 def _shift_extract_body(lo, hi, sh, w):

@@ -68,9 +68,9 @@ DEFAULT_SHARDED_MIN_ROWS = 4_000_000
 _FA_BYTES_PER_ROW = 0.25
 
 # JSON-parse routing estimates: the host C++ field-extraction scan
-# measured ~270 MB/s on one vCPU (BASELINE.md r05); the device
-# structural scan is planned at ~2 GB/s — both deliberately coarse,
-# the gate only needs the crossover's order of magnitude.
+# at ~270 MB/s on one vCPU, the device structural scan at ~2 GB/s —
+# both placeholders, not measured on this device (the chip's own
+# readings are in PERF.md; ROADMAP A1 re-prices the gate from them).
 _HOST_SCAN_BPS = 270e6
 _DEVICE_PARSE_BPS = 2e9
 

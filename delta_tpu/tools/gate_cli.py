@@ -4,23 +4,22 @@ The dispatch profiler (`obs.device`) journals two record types when
 ``DELTA_TPU_DEVICE_OBS=on``: ``gate_decision`` (route chosen, inputs,
 per-route predicted cost, joined observed cost, signed calibration
 error) and ``device_dispatch`` (per-kernel wall time, compile flag,
-audited transfer bytes). `obs.dump_gate_log(path)` — called by the
-bench harness — serializes both as JSONL; this tool turns that artifact
-into the answer the link-model economics actually need: *how wrong are
-the DEVICE_MERIT predictions on this hardware, per gate, per route?*
+audited transfer bytes). `obs.dump_gate_log(path)` serializes both as
+JSONL; this tool turns that artifact into the answer the link-model
+economics actually need: *how wrong are the link model's predictions
+on this hardware, per gate, per route?*
 
 Usage::
 
     delta-gate gate_log.jsonl                 # calibration table
     delta-gate gate_log.jsonl --dispatches    # per-kernel dispatch rollup
     delta-gate gate_log.jsonl --json          # summary as JSON
-    delta-gate gate_log.jsonl --merit out.json  # fresh DEVICE_MERIT capture
+    delta-gate gate_log.jsonl --merit out.json  # link-model capture
     python -m delta_tpu.tools.gate_cli ...    # same, without the script
 
-``--merit`` distills the log into a DEVICE_MERIT.json-shaped capture
-(observed link bandwidth, replay workload rates, capture conditions) —
-running the bench on real hardware with device obs on and exporting
-here IS the ROADMAP's deferred merit recapture.
+``--merit`` distills the log into a link-model capture (observed link
+bandwidth, replay workload rates, capture conditions), the shape
+``DELTA_TPU_LINK_MODEL`` reads back.
 """
 
 from __future__ import annotations

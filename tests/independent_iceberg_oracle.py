@@ -8,7 +8,7 @@ with ADDED/EXISTING status. Shares ZERO code with
 `delta_tpu.interop` — including Avro: the object-container-file
 decoder below is written from the Avro 1.11 specification
 (https://avro.apache.org/docs/1.11.1/specification/), the same way
-`tests/independent_oracle.py` re-reads the Delta log from
+`chipbench/reference/oracle.py` re-reads the Delta log from
 PROTOCOL.md.
 
 Reference counterpart: real Iceberg libraries reading UniForm output

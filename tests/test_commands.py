@@ -489,6 +489,30 @@ def test_vacuum_full_enables_lite_on_cleaned_log(tmp_table_path):
     assert res.num_deleted == 1
 
 
+def test_vacuum_full_holds_watermark_over_a_skewed_survivor(tmp_table_path):
+    """FULL deletes by the file's mtime, LITE by the tombstone's
+    timestamp. A tombstoned file whose mtime lies ahead survives FULL;
+    the watermark then stays where it was, so that a later LITE still
+    reads the commit that removed the file."""
+    table = _mk_table(tmp_table_path, n=100, n_commits=2)
+    delete(table, col("id") < lit(100))  # tombstones the first file
+    live = set(table.latest_snapshot().state.add_files_table
+               .column("path").to_pylist())
+    (gone,) = [f for f in os.listdir(tmp_table_path)
+               if f.endswith(".parquet") and f not in live]
+    gone = os.path.join(tmp_table_path, gone)
+    ahead = time.time() + 3600
+    os.utime(gone, (ahead, ahead))
+    res = vacuum(table, retention_hours=0)  # FULL
+    assert res.num_deleted == 0 and os.path.exists(gone)
+    info = os.path.join(tmp_table_path, "_delta_log", "_last_vacuum_info")
+    assert not os.path.exists(info)
+    os.utime(gone, (0, 0))
+    lite = vacuum(table, retention_hours=0, vacuum_type="LITE")
+    assert lite.num_deleted == 1 and not os.path.exists(gone)
+    assert lite.eligible_start_commit_version == 0
+
+
 def test_vacuum_sql_modifier_order(tmp_table_path):
     """Reference grammar (`DeltaSqlBase.g4:198`) accepts modifiers in
     any order: LITE before RETAIN must parse too."""

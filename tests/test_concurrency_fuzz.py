@@ -269,7 +269,7 @@ def test_multi_writer_fuzz(tmp_table_path, seed):
     from delta_tpu.engine.host import HostEngine
     from delta_tpu.engine.tpu import TpuEngine
 
-    from tests.independent_oracle import read_table_state
+    from chipbench.reference.oracle import read_table_state
 
     oracle = read_table_state(tmp_table_path).summary()
     for eng in (HostEngine(), TpuEngine()):

@@ -8,7 +8,7 @@ within what the DECLARED protocol permits (a v2 table's log must be
 readable by a reader that knows nothing of table features).
 
 Each case also round-trips through the independent oracle parser
-(tests/independent_oracle.py) so conformance is not self-certified."""
+(chipbench/reference/oracle.py) so conformance is not self-certified."""
 
 import json
 import os
@@ -20,7 +20,7 @@ import pytest
 import delta_tpu.api as dta
 from delta_tpu.models.actions import actions_from_commit_bytes
 from delta_tpu.table import Table
-from tests.independent_oracle import read_table_state
+from chipbench.reference.oracle import read_table_state
 
 
 def _batch(start=0, n=10):

@@ -75,8 +75,8 @@ def test_disabled_path_is_shared_stateless_noop():
 
 def test_disabled_dispatch_overhead_is_negligible():
     """The off-mode funnel must cost nanoseconds, not microseconds —
-    it sits on per-block hot loops. Gate at a generous 5us/call so a
-    loaded CI box cannot flake; the bench asserts the real <2% bound."""
+    it sits on per-block hot loops. The bound is a generous 5us/call so
+    a loaded CI box cannot flake; nothing holds a tighter one."""
     obs.set_device_obs_mode("off")
     n = 20_000
     t0 = time.perf_counter_ns()

@@ -16,7 +16,6 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
-from delta_tpu.errors import InvalidArgumentError
 
 # real Delta logs are compact; the device kernel's patterns key on the
 # compact form, and anything else routes the window to the host parser
@@ -633,20 +632,6 @@ def test_load_deletion_vector_mask_routes(tmp_path, monkeypatch):
 
 
 # ------------------------------------------------- bit-width guards ---------
-
-def test_unpack_width_guards():
-    from delta_tpu.ops.pallas_kernels import unpack_bitpacked
-
-    words = np.zeros(4, np.uint32)
-    with pytest.raises(InvalidArgumentError):
-        unpack_bitpacked(words, 33, 1)
-    with pytest.raises(InvalidArgumentError):
-        unpack_bitpacked(words, -1, 1)
-    with pytest.raises(InvalidArgumentError):
-        unpack_bitpacked(words, "8", 1)
-    # w=0 stays legal at this layer (all-zero groups)
-    assert np.asarray(unpack_bitpacked(np.zeros(0, np.uint32), 0, 1)).sum() == 0
-
 
 def test_hybrid_width_guard_surfaces_decode_error():
     from delta_tpu.log.page_decode import DecodeUnsupported, parse_hybrid

@@ -5,7 +5,7 @@ pyarrow only, no delta_tpu code).
 
 This is the mechanism a shared parser bug cannot survive: the fixtures'
 `expected.json` digests were written by hand from the commit contents,
-the oracle (tests/independent_oracle.py) reimplements replay from
+the oracle (chipbench/reference/oracle.py) reimplements replay from
 PROTOCOL.md with no shared code, and both product engines must agree
 with both. The reverse direction (oracle reads tables OUR writer
 produced, including checkpoints and DV deletes) closes the loop.
@@ -23,7 +23,7 @@ from delta_tpu.engine.host import HostEngine
 from delta_tpu.engine.tpu import TpuEngine
 from delta_tpu.table import Table
 
-from tests.independent_oracle import read_table_state
+from chipbench.reference.oracle import read_table_state
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "golden_fixtures")
 FIXTURE_NAMES = sorted(

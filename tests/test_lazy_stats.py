@@ -60,7 +60,7 @@ def test_checkpoint_written_after_lazy_load_roundtrips(tmp_table_path):
 
 
 def test_oracle_agreement_after_lazy_load(tmp_table_path):
-    from tests.independent_oracle import read_table_state
+    from chipbench.reference.oracle import read_table_state
 
     _mk(tmp_table_path)
     snap = Table.for_path(tmp_table_path, TpuEngine()).latest_snapshot()

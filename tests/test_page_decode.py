@@ -1,7 +1,7 @@
-"""Device checkpoint-page decoder (`log/page_decode.py` + the Pallas
-bit-unpack kernel) vs the Arrow reader as oracle: kernel-level width
-fuzz, page-level parity on synthetic parquet (nulls, multiple row
-groups, dictionary + plain fallbacks), real checkpoint files incl. the
+"""Device checkpoint-page decoder (`log/page_decode.py`) vs the Arrow
+reader as oracle: page-level parity on synthetic parquet (nulls,
+multiple row groups, dictionary + plain fallbacks, every bit width
+through `shift_extract`), real checkpoint files incl. the
 golden fixtures, and the hybrid grafted read equaling a plain Arrow
 read. The reference hand-rolls this decode in
 `kernel-defaults/.../internal/parquet/ParquetFileReader.java`."""
@@ -21,33 +21,7 @@ from delta_tpu.log.page_decode import (
     read_checkpoint_column,
     read_checkpoint_part_hybrid,
 )
-from delta_tpu.ops.pallas_kernels import unpack_bitpacked
 from delta_tpu.table import Table
-
-
-# ---- kernel: every width vs a bit-level reference packer -------------
-
-def _pack_reference(vals, w):
-    bits = np.zeros(len(vals) * w, np.uint8)
-    for i, v in enumerate(vals):
-        for b in range(w):
-            bits[i * w + b] = (int(v) >> b) & 1
-    words = np.zeros(-(-len(bits) // 32), np.uint32)
-    for i, bit in enumerate(bits):
-        if bit:
-            words[i // 32] |= np.uint32(1) << np.uint32(i % 32)
-    return words
-
-
-@pytest.mark.parametrize("w", [1, 2, 3, 4, 5, 7, 8, 11, 16, 21, 31, 32])
-def test_unpack_kernel_widths(w):
-    rng = np.random.default_rng(w)
-    n_groups = 9
-    vals = (rng.integers(0, 1 << 62, n_groups * 32, dtype=np.uint64)
-            & np.uint64((1 << w) - 1)).astype(np.uint64)
-    out = np.asarray(unpack_bitpacked(_pack_reference(vals, w), w,
-                                      n_groups))
-    assert np.array_equal(out, vals.astype(np.uint32))
 
 
 # ---- page-level parity on synthetic parquet --------------------------
@@ -87,6 +61,35 @@ def test_flat_int64_with_nulls(tmp_path, codec):
         pa.int64())})
     p = _roundtrip(t, tmp_path, compression=codec)
     _column_parity(p, "x")
+
+
+@pytest.mark.parametrize("w", [1, 2, 3, 4, 5, 7, 8, 11, 16])
+def test_dictionary_index_widths(tmp_path, monkeypatch, w):
+    """A dictionary of 2**w entries has w-bit indices: every width the
+    hybrid streams of a checkpoint can carry goes through the plan and
+    `shift_extract`, against Arrow's reading of the same file."""
+    from delta_tpu.log import page_decode
+
+    widths = []
+    plan_hybrid = page_decode._plan_hybrid
+
+    def spy(st, base_off, data, pos, width, n, **kw):
+        widths.append(width)
+        return plan_hybrid(st, base_off, data, pos, width, n, **kw)
+
+    monkeypatch.setattr(page_decode, "_plan_hybrid", spy)
+    rng = np.random.default_rng(w)
+    domain = rng.permutation(1 << 20)[:1 << w] * 1_000_003
+    n = max(4 << w, 4_096)
+    idx = np.concatenate([np.arange(1 << w), rng.integers(0, 1 << w,
+                                                          n - (1 << w))])
+    mask = rng.random(n) < 0.05
+    t = pa.table({"x": pa.array(
+        [None if m else int(v) for v, m in zip(domain[idx], mask)],
+        pa.int64())})
+    p = _roundtrip(t, tmp_path, compression="none")
+    _column_parity(p, "x")
+    assert w in widths, widths  # the index stream really had that width
 
 
 def test_plain_fallback_high_cardinality(tmp_path):

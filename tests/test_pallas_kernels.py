@@ -2,10 +2,8 @@
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from delta_tpu.ops.pallas_kernels import (
-    batched_file_stats,
     interleave_bits_auto,
     interleave_bits_tiled,
 )
@@ -28,21 +26,3 @@ def test_interleave_auto_fallback_on_ragged():
     ref = np.asarray(interleave_bits([jnp.asarray(c) for c in cols]))
     got = np.asarray(interleave_bits_auto([jnp.asarray(c) for c in cols]))
     np.testing.assert_array_equal(got, ref)
-
-
-def test_segmented_minmax():
-    rng = np.random.default_rng(2)
-    f, r = 10, 300
-    values = rng.normal(size=(f, r)).astype(np.float32)
-    valid = rng.random((f, r)) < 0.9
-    valid[3] = False  # one all-null file
-    mn, mx, null_count, num_records = batched_file_stats(values, valid)
-    for i in range(f):
-        sel = values[i][valid[i]]
-        if sel.size:
-            assert mn[i] == pytest.approx(sel.min())
-            assert mx[i] == pytest.approx(sel.max())
-        else:
-            assert np.isinf(mn[i])
-        assert null_count[i] == r - valid[i].sum()
-        assert num_records[i] == r

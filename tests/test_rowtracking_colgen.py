@@ -120,6 +120,32 @@ def test_identity_rejects_explicit(tmp_table_path):
         dta.write_table(tmp_table_path, explicit)
 
 
+def test_merge_update_all_rejects_identity_column(tmp_table_path):
+    """`UPDATE SET *` assigns every same-named source column, so it is
+    refused like an explicit assignment when the source carries the
+    identity column."""
+    from delta_tpu.commands.merge import merge
+    from delta_tpu.expressions import col
+
+    schema = StructType([identity_field("pk"), StructField("name", STRING),
+                         StructField("n", LONG)])
+    dta.write_table(tmp_table_path, pa.table({
+        "name": pa.array(["a", "b"]), "n": pa.array([1, 2], pa.int64())}),
+        schema=schema)
+    table = Table.for_path(tmp_table_path)
+    on = col("target.name") == col("source.name")
+    with_pk = pa.table({"pk": pa.array([7], pa.int64()),
+                        "name": pa.array(["a"]),
+                        "n": pa.array([10], pa.int64())})
+    with pytest.raises(DeltaError) as e:
+        merge(table, with_pk, on=on).when_matched_update_all().execute()
+    assert e.value.error_class == \
+        "DELTA_IDENTITY_COLUMNS_UPDATE_NOT_SUPPORTED"
+    out = dta.read_table(tmp_table_path).sort_by("pk")
+    assert out.column("pk").to_pylist() == [1, 2]
+    assert out.column("n").to_pylist() == [1, 2]
+
+
 # -- generated columns ------------------------------------------------------
 
 
