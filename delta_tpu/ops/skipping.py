@@ -9,9 +9,16 @@ bitplane, resident on device across scans of one snapshot version. A
 scan's conjunct list is compiled into flat *atom* arrays — one atom per
 `col op lit` comparison, grouped so that OR-alternatives share a group
 id — and this module evaluates every atom against every file in ONE
-jitted call: gather the three stat rows per atom, apply the per-op
-"known false" predicate, segment-fold atoms into per-group skip
-verdicts, and AND the groups into a single keep mask (one bool D2H).
+jitted call, in place: for each atom slot it reads that atom's three
+stat rows where they lie in the resident `[R, n_pad]` arrays (a
+one-row `dynamic_slice`, which XLA fuses into the comparison), applies
+the per-op "known false" predicate to one `[n_pad]` vector, and folds
+it into two carried `[n_pad]` flags — "every atom of the open group so
+far is known false" and "some closed group skips" — so temporaries are
+O(n_pad) whatever the number of atoms, and no `[atoms, n_pad]` copy of
+the lanes is ever made. Slots come in buckets from 2 (2, 4, 8, ...):
+a two-atom range plan runs a two-slot program, and rows, op codes and
+literals are run-time arguments, never compile keys (one bool D2H).
 
 Kleene semantics match the host Arrow path by construction: an atom is
 *known false* for a file only when the deciding stat is present and
@@ -50,6 +57,9 @@ class AtomBlock(NamedTuple):
     `rows_mn/rows_mx/rows_nc` index lane-matrix rows (the index builder
     lays column c out as rows 3c/3c+1/3c+2, numRecords last); `grp`
     assigns each atom to an OR-group; groups are ANDed into the mask.
+    A group's atoms are adjacent (`compile_conjuncts` emits them group
+    by group, ids dense and ascending): the device kernel closes a
+    group where `grp` changes.
     """
 
     rows_mn: np.ndarray  # int32 [A] min-lane row per atom
@@ -85,30 +95,39 @@ def _known_false(xp, mn, mx, nc, nr, vmn, vmx, vnc, vnr, ops, lits):
 
 
 @functools.lru_cache(maxsize=32)
-def _skip_fn_cached(a_pad: int, g_segs: int):
-    """jit'd keep-mask kernel for `a_pad` atom slots folding into
-    `g_segs` segments (last segment is the pad-atom sink)."""
+def _skip_fn_cached(a_pad: int):
+    """jit'd keep-mask kernel for `a_pad` atom slots, unrolled: each
+    slot is a handful of fused elementwise passes over its own rows."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
+
+    def row(lanes, r):
+        return lax.dynamic_slice_in_dim(lanes, r, 1, axis=0)
 
     @obs.program("skipping.mask_block")
     def kernel(vals, valid, rows_mn, rows_mx, rows_nc, ops, lits, grp,
                n_atoms):
-        mn, mx, nc = vals[rows_mn], vals[rows_mx], vals[rows_nc]
-        vmn, vmx, vnc = valid[rows_mn], valid[rows_mx], valid[rows_nc]
         nr, vnr = vals[-1][None, :], valid[-1][None, :]
-        kf = _known_false(jnp, mn, mx, nc, nr, vmn, vmx, vnc, vnr,
-                          ops, lits)
-        pad = (jnp.arange(a_pad, dtype=jnp.int32) >= n_atoms)[:, None]
-        # pad atoms are routed to the sink segment with kf=True so they
-        # can never unskip a real group nor skip anything themselves
-        kf = jnp.where(pad, True, kf)
-        g_min = jax.ops.segment_min(kf.astype(jnp.int32), grp,
-                                    num_segments=g_segs)
-        counts = jax.ops.segment_sum(
-            jnp.where(pad[:, 0], 0, 1), grp, num_segments=g_segs)
-        skip_g = (g_min == 1) & (counts > 0)[:, None]
-        return ~jnp.any(skip_g[: g_segs - 1], axis=0)
+        # the open group: every atom so far known false; and whether a
+        # group closed before it skips the file
+        open_kf = jnp.ones(vals.shape[1], dtype=bool)
+        skip = jnp.zeros(vals.shape[1], dtype=bool)
+        for i in range(a_pad):
+            kf = _known_false(
+                jnp, row(vals, rows_mn[i]), row(vals, rows_mx[i]),
+                row(vals, rows_nc[i]), nr, row(valid, rows_mn[i]),
+                row(valid, rows_mx[i]), row(valid, rows_nc[i]), vnr,
+                ops[i:i + 1], lits[i:i + 1])[0]
+            # pad slots are one trailing group whose atoms are never
+            # known false: it closes the last real group, skips nothing
+            kf = kf & (i < n_atoms)
+            if i:
+                closes = grp[i] != grp[i - 1]
+                skip = skip | (closes & open_kf)
+                open_kf = open_kf | closes
+            open_kf = open_kf & kf
+        return ~(skip | open_kf)
 
     return jax.jit(kernel)
 
@@ -122,9 +141,7 @@ def skip_mask_block(dev_vals, dev_valid, block: AtomBlock,
 
     from delta_tpu.ops.replay import pad_bucket
 
-    a_pad = pad_bucket(max(block.n_atoms, 1), min_bucket=16)
-    g_pad = pad_bucket(max(block.n_groups, 1), min_bucket=16)
-    g_segs = g_pad + 1
+    a_pad = pad_bucket(block.n_atoms, min_bucket=2)
 
     def _pad(a, fill, dtype):
         out = np.full(a_pad, fill, dtype=dtype)
@@ -136,21 +153,23 @@ def skip_mask_block(dev_vals, dev_valid, block: AtomBlock,
     rows_nc = _pad(block.rows_nc, 0, np.int32)
     ops = _pad(block.ops, 0, np.int32)
     lits = _pad(block.lits, 0, np.int64)
-    grp = _pad(block.grp, g_segs - 1, np.int32)
+    grp = _pad(block.grp, block.n_groups, np.int32)
     # the index lanes are HBM-resident (budgeted at upload in
     # stats/device_index.py); the per-scan atom arrays ride as jit
     # arguments, so this dispatch carries no budgeted device_put lane
-    with obs.device_dispatch("skipping.mask_block", key=(a_pad, g_segs),
+    with obs.device_dispatch("skipping.mask_block", key=a_pad,
                              gate="skip") as dd, _x64():
         # the resident lanes' shape: what a reader needs to count the
         # bytes this launch has to move
         dd.set(lanes=dev_vals.shape[0], n_pad=dev_vals.shape[1])
-        keep = _skip_fn_cached(a_pad, g_segs)(
+        keep = _skip_fn_cached(a_pad)(
             dev_vals, dev_valid, rows_mn, rows_mx, rows_nc, ops,
             jnp.asarray(lits), grp, np.int32(block.n_atoms))
         dd.d2h("keep", keep.nbytes)
     # the launch returns at once; the kernel's time is this read's
-    with obs.span("skip.wait", rows=n_files, bytes=keep.nbytes), dd.wait():
+    # (`a_pad` - `atoms` of its slots were padding)
+    with obs.span("skip.wait", rows=n_files, bytes=keep.nbytes,
+                  atoms=block.n_atoms, a_pad=a_pad), dd.wait():
         return np.asarray(keep)[:n_files]
 
 

@@ -165,16 +165,26 @@ def test_page_decode_part(on_chip):
     _assert_mosaic(compiled)
 
 
-def test_skipping_mask_block_1m_files(on_chip):
-    rows, f_pad, a_pad, g_segs = 4, 1 << 20, 16, 17
+@pytest.mark.parametrize("f_pad,a_pad", [
+    (1 << 20, 16),
+    # `ckpt-query-under-ingest`: 2.4M files, a range plan's two atoms
+    (2_621_440, 2),
+])
+def test_skipping_mask_block(on_chip, f_pad, a_pad):
+    rows = 4
     atoms = on_chip((a_pad,), jnp.int32)
     with jax.enable_x64(True):
-        compiled = skipping._skip_fn_cached(a_pad, g_segs).lower(
+        compiled = skipping._skip_fn_cached(a_pad).lower(
             on_chip((rows, f_pad), jnp.int64),
             on_chip((rows, f_pad), jnp.bool_),
             atoms, atoms, atoms, atoms, on_chip((a_pad,), jnp.int64),
             atoms, on_chip((), jnp.int32)).compile()
     _assert_fits(compiled)
+    # no `[a_pad, f_pad]` copy of the lanes: the v5e compiler's
+    # temporaries (the int64 lanes' 32-bit halves among them) stay a
+    # few dozen bytes a file however many slots the program has
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * f_pad
+    assert " while(" not in compiled.as_text()
 
 
 def test_stats_index_validity_unpack_2_6m_files(on_chip):

@@ -205,7 +205,7 @@ def _programs():
          "json_parse.window"),
         (page_decode._decode_fn(1024, 128, 128, 1024, 1024, 1024, True,
                                 False), "page_decode.part"),
-        (skipping._skip_fn_cached(16, 17), "skipping.mask_block"),
+        (skipping._skip_fn_cached(2), "skipping.mask_block"),
         (sqlops._segagg_kernel, "sqlops.segagg"),
         (pallas_kernels.interleave_bits_tiled, "interleave_bits_tiled"),
         (pallas_kernels.byte_class_tiled, "byte_class_tiled"),
