@@ -1,0 +1,10 @@
+"""Entry points: over the operations that follow a landed commit with
+no checkpoint (`refresh`), the median of the program's `table.update`
+plus the `scan.plan` after it: the sibling cell's `refresh_ms`, by the
+outer span."""
+
+from chipbench import op_spans
+
+
+def read(run):
+    return op_spans.median_ms(run, "refresh", "table.update", "scan.plan")

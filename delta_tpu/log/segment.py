@@ -56,7 +56,13 @@ class _IncrementalUnavailable(Exception):
     """The log can't be advanced incrementally from the given segment —
     a checkpoint/compaction landed past it, or the listing has a gap
     (concurrent log cleanup). The caller falls back to a full load;
-    this is a control-flow signal, never a user-facing error."""
+    this is a control-flow signal, never a user-facing error. `reason`
+    names the cause for the `snapshot.update` span and its counter:
+    `checkpoint`, `compacted_delta` or `gap`."""
+
+    def __init__(self, reason: str, message: str):
+        super().__init__(message)
+        self.reason = reason
 
 
 def extend_log_segment(fs, prev: LogSegment):
@@ -114,11 +120,13 @@ def _extend_log_segment(fs, prev: LogSegment):
             ci = CheckpointInstance.parse(fstat.path)
             if ci is not None and ci.version > prev.version:
                 raise _IncrementalUnavailable(
+                    "checkpoint",
                     f"checkpoint appeared at version {ci.version}")
         elif filenames.COMPACTED_DELTA_FILE_RE.match(name):
             _, hi = filenames.compacted_delta_versions(fstat.path)
             if hi > prev.version:
                 raise _IncrementalUnavailable(
+                    "compacted_delta",
                     f"compacted delta appeared covering up to {hi}")
     if not new_deltas:
         return None
@@ -126,6 +134,7 @@ def _extend_log_segment(fs, prev: LogSegment):
     versions = [v for v, _ in new_deltas]
     if versions != list(range(start, versions[-1] + 1)):
         raise _IncrementalUnavailable(
+            "gap",
             f"non-contiguous new commits {versions[:5]}..., expected "
             f"[{start}, {versions[-1]}]")
 

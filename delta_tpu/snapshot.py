@@ -28,6 +28,16 @@ _log = logging.getLogger(__name__)
 _CHECKPOINT_FALLBACKS = obs.counter("snapshot.checkpoint_fallbacks")
 _TORN_FALLBACKS = obs.counter("snapshot.torn_commit_fallbacks")
 _CRC_QUARANTINED = obs.counter("snapshot.crc_quarantined")
+# why an update() could not advance the retained state, as the
+# `snapshot.update` span's `reason` names it
+_UPDATE_FALLBACKS = {
+    "checkpoint": obs.counter("snapshot.update_fallbacks.checkpoint"),
+    "compacted_delta": obs.counter(
+        "snapshot.update_fallbacks.compacted_delta"),
+    "gap": obs.counter("snapshot.update_fallbacks.gap"),
+    "protocol": obs.counter("snapshot.update_fallbacks.protocol"),
+    "no_state": obs.counter("snapshot.update_fallbacks.no_state"),
+}
 
 # a commit file in a FileNotFoundError message (vs a checkpoint part)
 _COMMIT_JSON_RE = re.compile(r"\d{20}\.json")
@@ -305,6 +315,13 @@ class Snapshot:
         must fall back to a full `latest_snapshot()` load. The advanced
         state is bit-identical to a cold replay at the same version.
         """
+        return self._update(engine)[0]
+
+    def _update(self, engine=None):
+        """`update()`, with the reason beside the snapshot where the
+        retained state could not be advanced (`Table.update` names it
+        on its own span): `checkpoint`, `compacted_delta`, `gap`,
+        `protocol` or `no_state`, else None."""
         from delta_tpu.log.segment import (
             _IncrementalUnavailable,
             extend_log_segment,
@@ -315,29 +332,34 @@ class Snapshot:
                       from_version=self.version) as sp:
             try:
                 ext = extend_log_segment(eng.fs, self._segment)
-            except _IncrementalUnavailable:
-                sp.set_attr("outcome", "fallback_full_load")
-                return None
-            if ext is None:
-                sp.set_attr("outcome", "unchanged")
-                return self
-            new_segment, new_deltas = ext
-            advanced = self._update_advance(eng, new_segment, new_deltas)
-            if advanced is None:
-                sp.set_attr("outcome", "fallback_full_load")
+            except _IncrementalUnavailable as e:
+                advanced, reason = None, e.reason
             else:
+                if ext is None:
+                    sp.set_attr("outcome", "unchanged")
+                    return self, None
+                new_segment, new_deltas = ext
+                advanced, reason = self._update_advance(
+                    eng, new_segment, new_deltas)
+            if reason is None:
                 sp.set_attrs(outcome="advanced",
                              to_version=new_segment.version,
                              new_commits=len(new_deltas))
-            return advanced
+            else:
+                sp.set_attrs(outcome="fallback_full_load", reason=reason)
+                _UPDATE_FALLBACKS[reason].inc()
+            return advanced, reason
 
     def _update_advance(self, eng, new_segment, new_deltas):
+        """(snapshot, None), or (what `update()` returns, the reason)
+        where nothing retained was advanced."""
         if self._state is None:
             # no replayed state retained to advance — a lazy snapshot
             # over the extended segment costs the same as advancing
             # would, and the parsed-commit cache still spares any
             # re-parse of commits this segment shares with prior loads
-            return Snapshot(self._table, new_segment, self._engine)
+            return (Snapshot(self._table, new_segment, self._engine),
+                    "no_state")
 
         import dataclasses
 
@@ -357,12 +379,12 @@ class Snapshot:
         if delta.protocol is not None:
             # a protocol change can alter how existing actions must be
             # read — never replay across it incrementally
-            return None
+            return None, "protocol"
         with hbm.table_scope(self._table.path):
             new_state = advance_state(eng, self._state, delta, new_segment)
         snap = Snapshot(self._table, new_segment, self._engine)
         snap._state = new_state
-        return snap
+        return snap, None
 
     def _advanced_with_blobs(self, blobs) -> Optional["Snapshot"]:
         """Advance with commit bytes already in memory (the post-commit

@@ -665,6 +665,8 @@ def snapshot_stats_index(state, files: pa.Table):
             else:
                 if seed is not None:
                     _APPEND_FALLBACKS.inc()
+                else:   # a state loaded in full: nothing to append to
+                    sp.set_attr("reason", "no_seed")
                 idx = build_index(files, table_path, version)
                 sp.set_attr("mode", "full")
                 _BUILDS.inc()

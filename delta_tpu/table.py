@@ -134,12 +134,19 @@ class Table:
         `latest_snapshot()` load when there is no usable cached snapshot
         or incremental maintenance is unavailable (checkpoint boundary,
         listing gap, protocol change, coordinated tables)."""
-        with obs.span("table.update", table=self.path):
+        with obs.span("table.update", table=self.path) as sp:
             with self._lock:
                 cached = self._cached_snapshot
             if cached is None or self._coordinated:
+                sp.set_attrs(outcome="full_load", reason=(
+                    "no_state" if cached is None else "coordinated"))
                 return self.latest_snapshot()
-            advanced = cached.update()
+            advanced, reason = cached._update()
+            if reason is not None:
+                sp.set_attrs(outcome="full_load", reason=reason)
+            else:
+                sp.set_attr("outcome", "unchanged" if advanced is cached
+                            else "advanced")
             if advanced is None:
                 # full-load fallback: the cached snapshot's device-
                 # resident replay state (if any) can't be advanced
