@@ -53,12 +53,13 @@ class CorruptLogError(DeltaError):
 
 
 class _IncrementalUnavailable(Exception):
-    """The log can't be advanced incrementally from the given segment —
-    a checkpoint/compaction landed past it, or the listing has a gap
-    (concurrent log cleanup). The caller falls back to a full load;
-    this is a control-flow signal, never a user-facing error. `reason`
-    names the cause for the `snapshot.update` span and its counter:
-    `checkpoint`, `compacted_delta` or `gap`."""
+    """The given segment can't be extended — a checkpoint/compaction
+    landed past it, or the listing has a gap (concurrent log cleanup).
+    The caller rebuilds the segment (`Table.update`: over the held
+    state where it can, else with a full load); this is a control-flow
+    signal, never a user-facing error. `reason` names the cause for
+    the `snapshot.update` span and its counter: `checkpoint`,
+    `compacted_delta` or `gap`."""
 
     def __init__(self, reason: str, message: str):
         super().__init__(message)
@@ -76,11 +77,14 @@ def extend_log_segment(fs, prev: LogSegment):
     commit FileStatus entries.
 
     Raises _IncrementalUnavailable when a checkpoint or compacted delta
-    newer than `prev.version` appeared (the canonical segment for the
-    new version starts from that checkpoint — rebuilding keeps segments
-    identical to what a cold load would produce), or when the new
-    commit versions aren't contiguous with `prev` (log cleanup raced
-    the listing).
+    newer than `prev.version` appeared: the canonical segment for the
+    new version starts from that checkpoint, and a segment is only ever
+    what a cold load would list, so this one cannot be *extended*. The
+    held state can still be advanced: `Table.update` replays the
+    commits `list_commits_after` shows and has `build_log_segment`
+    list the new version's segment, as a cold load would. Also raised
+    when the new commit versions aren't contiguous with `prev` (log
+    cleanup raced the listing).
     """
     with obs.span("log.list_incremental", log_path=prev.log_path,
                   from_version=prev.version) as sp:
@@ -90,7 +94,25 @@ def extend_log_segment(fs, prev: LogSegment):
         return ext
 
 
-def _extend_log_segment(fs, prev: LogSegment):
+def list_commits_after(fs, prev: LogSegment) -> List[FileStatus]:
+    """The single-commit files with version > `prev.version`, ascending
+    and contiguous from `prev.version + 1`, as one prefix listing shows
+    them: what a held state at `prev.version` replays to reach the
+    last of them, whatever checkpoints or compacted deltas are listed
+    beside them. Empty when none is listed; raises
+    _IncrementalUnavailable("gap") where one is missing."""
+    with obs.span("log.list_commits", log_path=prev.log_path,
+                  from_version=prev.version) as sp:
+        commits = _contiguous_commits(_list_after(fs, prev)[0],
+                                      prev.version + 1)
+        sp.set_attr("new_commits", len(commits))
+        return commits
+
+
+def _list_after(fs, prev: LogSegment):
+    """One prefix listing past `prev.version`: the single commits there
+    as (version, FileStatus), and as (reason, message) the first file
+    that keeps `prev` from being extended, if any."""
     start = prev.version + 1
     prefix = filenames.listing_prefix(prev.log_path, start)
     # same stat-skipping policy as build_log_segment: commit entries
@@ -109,6 +131,7 @@ def _extend_log_segment(fs, prev: LogSegment):
                                  error_class="DELTA_EMPTY_DIRECTORY")
 
     new_deltas: List[tuple] = []
+    blocker = None
     delta_match = filenames.DELTA_FILE_RE.match
     for fstat in listing:
         name = filenames.file_name(fstat.path)
@@ -116,29 +139,40 @@ def _extend_log_segment(fs, prev: LogSegment):
             v = int(name.split(".", 1)[0])
             if v >= start:
                 new_deltas.append((v, fstat))
+        elif blocker is not None:
+            continue
         elif filenames.CHECKPOINT_FILE_RE.match(name) and fstat.size > 0:
             ci = CheckpointInstance.parse(fstat.path)
             if ci is not None and ci.version > prev.version:
-                raise _IncrementalUnavailable(
-                    "checkpoint",
-                    f"checkpoint appeared at version {ci.version}")
+                blocker = ("checkpoint",
+                           f"checkpoint appeared at version {ci.version}")
         elif filenames.COMPACTED_DELTA_FILE_RE.match(name):
             _, hi = filenames.compacted_delta_versions(fstat.path)
             if hi > prev.version:
-                raise _IncrementalUnavailable(
-                    "compacted_delta",
-                    f"compacted delta appeared covering up to {hi}")
-    if not new_deltas:
-        return None
+                blocker = ("compacted_delta",
+                           f"compacted delta appeared covering up to {hi}")
     new_deltas.sort(key=lambda t: t[0])
+    return new_deltas, blocker
+
+
+def _contiguous_commits(new_deltas: List[tuple],
+                        start: int) -> List[FileStatus]:
     versions = [v for v, _ in new_deltas]
-    if versions != list(range(start, versions[-1] + 1)):
+    if versions != list(range(start, start + len(versions))):
         raise _IncrementalUnavailable(
             "gap",
             f"non-contiguous new commits {versions[:5]}..., expected "
             f"[{start}, {versions[-1]}]")
+    return [f for _, f in new_deltas]
 
-    files = [f for _, f in new_deltas]
+
+def _extend_log_segment(fs, prev: LogSegment):
+    new_deltas, blocker = _list_after(fs, prev)
+    if blocker is not None:
+        raise _IncrementalUnavailable(*blocker)
+    if not new_deltas:
+        return None
+    files = _contiguous_commits(new_deltas, prev.version + 1)
     last_ts = max(prev.last_commit_timestamp,
                   max(f.modification_time for f in files))
     if files[-1].modification_time == 0:
@@ -154,7 +188,7 @@ def _extend_log_segment(fs, prev: LogSegment):
 
     seg = dataclasses.replace(
         prev,
-        version=versions[-1],
+        version=new_deltas[-1][0],
         deltas=list(prev.deltas) + files,
         last_commit_timestamp=last_ts,
     )

@@ -309,10 +309,13 @@ class Snapshot:
 
         Returns `self` when nothing new landed (zero reads, zero
         parses), a new Snapshot sharing this one's columnar arrays when
-        commits appended cleanly, or None when incremental maintenance
-        is unavailable — a checkpoint/compaction boundary intervened, a
-        listing gap appeared, or the protocol changed — and the caller
-        must fall back to a full `latest_snapshot()` load. The advanced
+        commits appended cleanly, or None when this snapshot's segment
+        cannot be extended — a checkpoint/compaction boundary
+        intervened, a listing gap appeared, or the protocol changed —
+        and the caller must get the new version's segment another way:
+        a full `latest_snapshot()` load, or at a checkpoint what
+        `Table.update` does, which lists the segment anew and advances
+        this snapshot's state over the commits between. The advanced
         state is bit-identical to a cold replay at the same version.
         """
         return self._update(engine)[0]
@@ -352,7 +355,9 @@ class Snapshot:
 
     def _update_advance(self, eng, new_segment, new_deltas):
         """(snapshot, None), or (what `update()` returns, the reason)
-        where nothing retained was advanced."""
+        where nothing retained was advanced. `new_segment` is the new
+        version's, extended or listed anew; `new_deltas` are the commits
+        from this version to it."""
         if self._state is None:
             # no replayed state retained to advance — a lazy snapshot
             # over the extended segment costs the same as advancing

@@ -14,13 +14,46 @@ from typing import Optional
 
 from delta_tpu import obs
 from delta_tpu.engine.tpu import default_engine
-from delta_tpu.errors import TableNotFoundError
+from delta_tpu.errors import DeltaError, TableNotFoundError
 from delta_tpu.log.last_checkpoint import read_last_checkpoint
-from delta_tpu.log.segment import build_log_segment
+from delta_tpu.log.segment import (
+    _IncrementalUnavailable,
+    build_log_segment,
+    list_commits_after,
+)
 from delta_tpu.snapshot import Snapshot
 from delta_tpu.utils import filenames
 
 _log = logging.getLogger(__name__)
+
+# When `update()` meets a checkpoint past the held version it advances
+# the held state over the commits between and lists the new version's
+# segment anew; nothing of the checkpoint is read. The full load stays
+# the way a held state sheds rows it no longer needs and the cheaper
+# way to catch up from far behind: two fixed rules, weighed from what
+# the state and the listing show (`Table._cross_checkpoint`;
+# docs/incremental_update.md has the sums).
+#
+# A state gains its commits' rows at every advance and never drops one.
+# Once the rows that are neither live nor a tombstone (an add since
+# removed or re-added, a remove whose file came back: what a cold load
+# would not hold) pass this share of the rows held, the crossing
+# reloads. Every new tombstone supersedes a row, so this bounds what a
+# long-lived reader keeps of expired tombstones too. At the
+# benchmark's table (2.4M rows, 100 a commit, 20 of them superseding):
+# one 4.3 s reload (ledger, PR 31: `crossing_refresh_ms` 4,457) in
+# 40,000 commits, among 4,000 crossings of 0.5 s.
+CROSSING_MAX_SUPERSEDED_SHARE = 1 / 8
+# Replaying a commit on the host costs ~0.6 ms (PERF.md §4: 5,000
+# commits in 3.14 s on the host route), reloading and re-indexing a
+# held row ~1.8 us (ledger, PR 31: 4,457 ms a crossing at 2.4M rows):
+# a commit is worth 333 rows. A reader more commits behind than its
+# rows are worth loads the checkpoint: at 2.4M rows from 7,200 commits,
+# a day of a writer's commits every 12 s.
+CROSSING_ROWS_PER_COMMIT = 333
+
+_CROSSINGS = obs.counter("snapshot.checkpoint_crossings")
+_CROSSING_RELOADS = obs.counter("snapshot.checkpoint_crossing_reloads")
 
 
 class Table:
@@ -130,10 +163,15 @@ class Table:
         """Return the latest snapshot, advancing the cached one
         incrementally when possible (the `DeltaLog.update()` fast path):
         LIST only commits past the cached version and replay just those
-        on top of the retained state. Falls back to the full
+        on top of the retained state. Where a checkpoint has landed past
+        the cached version the state is advanced all the same and the
+        segment listed anew, as a cold load would list it
+        (`_cross_checkpoint`). Falls back to the full
         `latest_snapshot()` load when there is no usable cached snapshot
-        or incremental maintenance is unavailable (checkpoint boundary,
-        listing gap, protocol change, coordinated tables)."""
+        or incremental maintenance is unavailable (compacted delta,
+        listing gap, protocol change, coordinated tables), and at a
+        checkpoint when the held state has rows enough to shed or
+        commits enough to replay that the load is the better way."""
         with obs.span("table.update", table=self.path) as sp:
             with self._lock:
                 cached = self._cached_snapshot
@@ -142,6 +180,8 @@ class Table:
                     "no_state" if cached is None else "coordinated"))
                 return self.latest_snapshot()
             advanced, reason = cached._update()
+            if reason == "checkpoint":
+                advanced, reason = self._cross_checkpoint(cached, sp)
             if reason is not None:
                 sp.set_attrs(outcome="full_load", reason=reason)
             else:
@@ -165,6 +205,60 @@ class Table:
                     else:
                         advanced = cur  # a racing full load got further
             return advanced
+
+    def _cross_checkpoint(self, cached: Snapshot, sp):
+        """`update()` where a checkpoint is listed past `cached`: a
+        checkpoint at `v` is the replay of every commit up to `v`, which
+        is what the held state is once it has been advanced over them.
+        (snapshot, None) with the state advanced over the commits past
+        `cached.version` and the segment `build_log_segment` lists for
+        the last of them; (None, why the table is loaded in full
+        instead): `checkpoint` where the state cannot be advanced (none
+        retained, a commit missing, a protocol action among them, with
+        `not_advanced` beside it), `superseded_rows` or `commits_behind`
+        where it could and the load is the better way."""
+        def not_advanced(why):
+            sp.set_attr("not_advanced", why)
+            return None, "checkpoint"
+
+        fs = self.engine.fs
+        state = cached._state
+        if state is None:
+            return not_advanced("no_state")
+        try:
+            commits = list_commits_after(fs, cached.log_segment)
+        except _IncrementalUnavailable:
+            commits = []
+        if not commits:
+            return not_advanced("gap")
+        rows = state.file_actions_raw.num_rows
+        superseded = (rows - int(state.live_mask.sum())
+                      - int(state.tombstone_mask.sum()))
+        if superseded > rows * CROSSING_MAX_SUPERSEDED_SHARE:
+            _CROSSING_RELOADS.inc()
+            return None, "superseded_rows"
+        if len(commits) * CROSSING_ROWS_PER_COMMIT > rows:
+            _CROSSING_RELOADS.inc()
+            return None, "commits_behind"
+        # the segment of the last commit THIS listing saw: one that
+        # lands before the next listing is the next update()'s
+        target = filenames.delta_version(commits[-1].path)
+        hint = read_last_checkpoint(fs, self.log_path)
+        try:
+            segment = build_log_segment(
+                fs, self.log_path, target_version=target,
+                checkpoint_hint=(
+                    hint.version
+                    if hint is not None and hint.version <= target
+                    else cached.log_segment.checkpoint_version))
+        except DeltaError:  # clean-up raced the second listing
+            return not_advanced("gap")
+        advanced, why = cached._update_advance(self.engine, segment, commits)
+        if why is not None:
+            return not_advanced(why)
+        _CROSSINGS.inc()
+        sp.set_attrs(crossed="checkpoint", commits=len(commits))
+        return advanced, None
 
     def notify_commit(self, version: int, data: bytes) -> None:
         """Post-commit handoff: a transaction that just wrote commit
