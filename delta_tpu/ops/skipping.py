@@ -167,9 +167,14 @@ def skip_mask_block(dev_vals, dev_valid, block: AtomBlock,
             jnp.asarray(lits), grp, np.int32(block.n_atoms))
         dd.d2h("keep", keep.nbytes)
     # the launch returns at once; the kernel's time is this read's
-    # (`a_pad` - `atoms` of its slots were padding)
+    # (`a_pad` - `atoms` of its slots were padding). `rows_read`: the
+    # distinct lane rows the atoms name, and numRecords: what of the
+    # index this launch has to read, however wide the index is
+    rows_read = 1 + len(np.unique(np.concatenate(
+        [block.rows_mn, block.rows_mx, block.rows_nc])))
     with obs.span("skip.wait", rows=n_files, bytes=keep.nbytes,
-                  atoms=block.n_atoms, a_pad=a_pad), dd.wait():
+                  atoms=block.n_atoms, a_pad=a_pad,
+                  rows_read=rows_read), dd.wait():
         return np.asarray(keep)[:n_files]
 
 

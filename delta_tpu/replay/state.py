@@ -132,7 +132,7 @@ class SnapshotState:
                           rows=len(self.live_mask)) as sp:
                 table = self.file_actions  # state.splice_stats, if deferred
                 with obs.span("state.filter_live", rows=table.num_rows):
-                    live = table.filter(pa.array(self.live_mask))
+                    live = _filter_rows(table, self.live_mask)
                 if sp.recording:
                     sp.set_attrs(live_rows=live.num_rows, bytes=live.nbytes)
                 self._add_table_cache = live
@@ -669,6 +669,35 @@ def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
             operand_cache.release()
             prev.operand_cache = None
     return new_state
+
+
+# Arrow's pool (mimalloc) keeps blocks of up to 2 GiB in its arenas; a
+# filtered string column's buffer may double once, so a column is
+# filtered whole only while it is under half of that
+_FILTER_WHOLE_BYTES = 1 << 30
+_FILTER_SLICE_BYTES = 1 << 28
+
+
+def _filter_rows(table: pa.Table, mask: np.ndarray) -> pa.Table:
+    """`table.filter(mask)`; over a table with a column of more than
+    1 GiB, a slice of rows at a time (zero-copy slices of ~256 MiB of
+    that column, so the rows kept are copied once, as before). Arrow
+    sizes a filtered string column's data buffer by the column's mean
+    value length and doubles it when the rows kept turn out a byte
+    longer: on a stats column of 1.2 GB (a table of a real row's width)
+    that is a 2.4 GB block, past what Arrow's pool keeps in its arenas,
+    so it is mapped, page-faulted and unmapped anew at every refresh,
+    1.8 s where the filter takes 0.27 s, for as long as the lengths of
+    the rows that went happen to fall that way (PERF.md, Findings,
+    PR 33). By slices a buffer that doubles stays a block the pool
+    keeps. A narrower table takes the one call it always took."""
+    widest = max((col.nbytes for col in table.columns), default=0)
+    if widest <= _FILTER_WHOLE_BYTES:
+        return table.filter(pa.array(mask))
+    rows = max(1, table.num_rows * _FILTER_SLICE_BYTES // widest)
+    sliced = pa.Table.from_batches(table.to_batches(max_chunksize=rows),
+                                   schema=table.schema)
+    return sliced.filter(pa.array(mask))
 
 
 def _chained_prev_stats(prev: SnapshotState, delta_fa: Optional[pa.Table]):

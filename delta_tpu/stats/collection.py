@@ -64,7 +64,10 @@ def _json_value(v: Any) -> Any:
             return "Infinity" if v > 0 else "-Infinity"
         return v
     if isinstance(v, dt.datetime):
-        return v.strftime("%Y-%m-%dT%H:%M:%S.%f%z") or v.isoformat()
+        # ISO-8601 with the offset as `+00:00`, the form upstream's
+        # readers and the typed stats reader (`stats/skipping.py`) take
+        # (`%z` alone gave `+0000`, which JSON inference reads as text)
+        return v.isoformat(timespec="microseconds")
     if isinstance(v, dt.date):
         return v.isoformat()
     if isinstance(v, bytes):

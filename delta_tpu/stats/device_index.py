@@ -82,6 +82,14 @@ _BUILDS = obs.counter("scan.stats_index_builds")
 _APPENDS = obs.counter("scan.stats_index_appends")
 _APPEND_FALLBACKS = obs.counter("scan.stats_index_append_fallbacks")
 _REUSES = obs.counter("scan.stats_index_reuses")
+# leaves that carry min/max stats and got no lane, by why: an index
+# that cannot read a table's schema shows here, not in a scan's bill
+_UNINDEXED = {
+    "string": obs.counter("scan.stats_index_unindexed_leaves.string"),
+    "decimal": obs.counter("scan.stats_index_unindexed_leaves.decimal"),
+    "unparsed": obs.counter("scan.stats_index_unindexed_leaves.unparsed"),
+    "other": obs.counter("scan.stats_index_unindexed_leaves.other"),
+}
 # device bytes are accounted in the resident ledger (obs/hbm.py),
 # which derives the `scan.stats_index_hbm_bytes` gauge this module
 # used to maintain by hand
@@ -114,7 +122,11 @@ def _enc_f64(a: np.ndarray) -> np.ndarray:
 
 
 def _lane_kind(t: pa.DataType) -> Optional[str]:
-    """Encoding kind for a parsed stat leaf type; None = ineligible."""
+    """Encoding kind for a parsed stat leaf type; None = ineligible.
+    `tstz` is an instant (a Delta `timestamp`: microseconds since the
+    epoch in UTC, whatever zone the type names), `ts` a wall clock (a
+    `timestamp_ntz`, a `date`): both are microsecond lanes, and a
+    literal of the one kind never compares with a lane of the other."""
     if pa.types.is_boolean(t):
         return "bool"
     if pa.types.is_integer(t):
@@ -122,10 +134,21 @@ def _lane_kind(t: pa.DataType) -> Optional[str]:
     if pa.types.is_floating(t):
         return "float"
     if pa.types.is_timestamp(t):
-        return None if t.tz is not None else "ts"
+        return "ts" if t.tz is None else "tstz"
     if pa.types.is_date(t):
         return "ts"
     return None
+
+
+def _why_no_lane(t: pa.DataType, delta_type: Optional[str]) -> str:
+    """The `scan.stats_index_unindexed_leaves.<why>` of a leaf with
+    min/max stats and no lane."""
+    if delta_type == "decimal":
+        return "decimal"
+    if pa.types.is_string(t) or pa.types.is_large_string(t):
+        # text the schema calls a time did not read as one
+        return "string" if delta_type in (None, "string") else "unparsed"
+    return "other" if _lane_kind(t) is None else "unparsed"
 
 
 def _resolve_kind(k_min: Optional[str], k_max: Optional[str]) -> Optional[str]:
@@ -171,8 +194,9 @@ def _encode_lane(arr: pa.Array, kind: str):
                 valid &= np.abs(raw) <= _F64_EXACT_INT
             valid &= ~np.isnan(f)
             enc = _enc_f64(f)
-        elif kind == "ts":
-            ts = arr.cast(pa.timestamp("us"))
+        elif kind in ("ts", "tstz"):
+            tz = arr.type.tz if pa.types.is_timestamp(arr.type) else None
+            ts = arr.cast(pa.timestamp("us", tz=tz))
             enc = np.asarray(pc.fill_null(ts.cast(pa.int64()), 0), np.int64)
         else:
             return None
@@ -183,7 +207,15 @@ def _encode_lane(arr: pa.Array, kind: str):
 
 def encode_literal(value, kind: str) -> Optional[int]:
     """Encode a predicate literal into the lane's int64 order; None =
-    not exactly representable -> the conjunct falls back to Arrow."""
+    not exactly representable -> the conjunct falls back to Arrow.
+
+    Times: a zone-aware `datetime` against a `tstz` lane (a Delta
+    `timestamp`) is its UTC instant, whatever its offset; a zone-less
+    `datetime`, a `date` or ISO text against a `ts` lane
+    (`timestamp_ntz`, `date`) is that wall clock. Across the two there
+    is no answer without a session time zone, and none is assumed: the
+    literal compiles to nothing, the Arrow ladder refuses it too, the
+    files are kept and `scan.skip_uncompared_conjuncts` counts it."""
     if value is None:
         return None
     if kind == "bool":
@@ -205,6 +237,12 @@ def encode_literal(value, kind: str) -> Optional[int]:
             if np.isnan(f):
                 return None
             return int(_enc_f64(np.asarray([f]))[0])
+        return None
+    if kind == "tstz":
+        if isinstance(value, datetime.datetime) \
+                and value.utcoffset() is not None:
+            s = pa.scalar(value).cast(pa.timestamp("us", tz="UTC"))
+            return s.value
         return None
     if kind == "ts":
         if isinstance(value, datetime.datetime) and value.tzinfo is not None:
@@ -250,6 +288,7 @@ class StatsIndexSeed:
     table: Optional[pa.Table]
     n: int
     base_live: np.ndarray
+    unindexed: Dict[str, int]
 
 
 class ResidentStatsIndex:
@@ -261,12 +300,15 @@ class ResidentStatsIndex:
                  valid: Optional[np.ndarray],
                  cols: Dict[tuple, Tuple[int, str]], n: int,
                  table_path: Optional[str] = None,
-                 version: Optional[int] = None):
+                 version: Optional[int] = None,
+                 unindexed: Optional[Dict[str, int]] = None):
         self._lock = threading.Lock()
         self.arrow_index = arrow_index
         self.vals = vals          # int64 [R, n_pad] or None
         self.valid = valid        # bool  [R, n_pad] or None
         self.cols = cols          # {physical name_path: (min row, kind)}
+        # {why: leaves with min/max stats and no lane} (`_why_no_lane`)
+        self.unindexed = unindexed or {}
         self.n = n
         self.table_path = table_path
         self.version = version
@@ -286,7 +328,7 @@ class ResidentStatsIndex:
                 return None
             return StatsIndexSeed(self.vals, self.valid, self.cols,
                                   self.arrow_index._table, self.n,
-                                  base_live)
+                                  base_live, self.unindexed)
 
     def device_lanes(self):
         """(values, validity) device arrays, uploading on first use."""
@@ -382,11 +424,18 @@ def _lanes_of(n_lanes: int, n: int):
 
 
 def build_index(files: pa.Table, table_path: Optional[str] = None,
-                version: Optional[int] = None) -> ResidentStatsIndex:
-    """Columnarize one snapshot version's parsed stats into lanes."""
-    from delta_tpu.stats.skipping import StatsIndex
+                version: Optional[int] = None,
+                metadata=None) -> ResidentStatsIndex:
+    """Columnarize one snapshot version's parsed stats into lanes. With
+    the table's `metadata`, the stat leaves its schema names are typed
+    by it (`stats/skipping.py::stat_leaf_types`): a `timestamp` gets a
+    `tstz` lane, a `timestamp_ntz` a `ts` lane, a `decimal` none (its
+    stats parse as floats, which would compare inexactly)."""
+    from delta_tpu.stats.skipping import StatsIndex, stat_leaf_types
 
-    arrow_index = StatsIndex.from_stats_column(files.column("stats"))
+    leaf_types = {} if metadata is None else stat_leaf_types(metadata)
+    arrow_index = StatsIndex.from_stats_column(files.column("stats"),
+                                               leaf_types=leaf_types)
     n = arrow_index.n
     table = arrow_index._table
     if table is None:
@@ -406,22 +455,27 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
 
     lanes: List[Tuple[np.ndarray, np.ndarray]] = []
     cols: Dict[tuple, Tuple[int, str]] = {}
+    unindexed: Dict[str, int] = {}
     for path in _typed_leaves(mins.type):
         mn = arrow_index.min_values(path)
         mx = arrow_index.max_values(path)
-        if mn is None or mx is None:
+        if mn is None or mx is None or pa.types.is_null(mn.type):
             continue
-        kind = _resolve_kind(_lane_kind(mn.type), _lane_kind(mx.type))
-        if kind is None:
-            continue
-        encoded = _encode_column(mn, mx, arrow_index.null_count(path), kind)
+        delta_type = leaf_types.get(path)
+        kind = None if delta_type == "decimal" else _resolve_kind(
+            _lane_kind(mn.type), _lane_kind(mx.type))
+        encoded = None if kind is None else _encode_column(
+            mn, mx, arrow_index.null_count(path), kind)
         if encoded is None:
+            why = _why_no_lane(mn.type, delta_type)
+            unindexed[why] = unindexed.get(why, 0) + 1
             continue
         cols[path] = (len(lanes), kind)
         lanes.extend(encoded)
     if not cols:
         return ResidentStatsIndex(arrow_index, None, None, {}, n,
-                                  table_path=table_path, version=version)
+                                  table_path=table_path, version=version,
+                                  unindexed=unindexed)
     lanes.append(_encode_count(arrow_index.num_records(), n))
 
     vals, valid = _lanes_of(len(lanes), n)
@@ -429,7 +483,8 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
         vals[r, :n] = ev
         valid[r, :n] = eva
     return ResidentStatsIndex(arrow_index, vals, valid, cols, n,
-                              table_path=table_path, version=version)
+                              table_path=table_path, version=version,
+                              unindexed=unindexed)
 
 
 def _cannot_append(reason: str):
@@ -438,7 +493,7 @@ def _cannot_append(reason: str):
 
 def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
                  files: pa.Table, table_path: Optional[str] = None,
-                 version: Optional[int] = None):
+                 version: Optional[int] = None, metadata=None):
     """The index of `files` (the live rows under `live_mask`, in raw
     order) made from `seed`: the seed's rows that are still live, then
     the rows landed since, their stats parsed under the seed's schema
@@ -448,7 +503,7 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
     `build_index(files)`: the caller then builds in full. Rows that
     went never narrow the schema: a leaf that only they carried stays,
     as a lane unknown on every row (which keeps, as no lane does)."""
-    from delta_tpu.stats.skipping import StatsIndex
+    from delta_tpu.stats.skipping import StatsIndex, stat_leaf_types
 
     if seed.vals is None:
         return _cannot_append("seed-without-lanes")
@@ -467,8 +522,10 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
 
     if n_tail:
         tail_stats = files.column("stats").slice(n_kept)
-        tail = StatsIndex.from_stats_column(tail_stats,
-                                            schema=seed.table.schema)
+        tail = StatsIndex.from_stats_column(
+            tail_stats, schema=seed.table.schema,
+            leaf_types=None if metadata is None
+            else stat_leaf_types(metadata))
         if tail._table is None:
             # a leaf the seed lacks, a leaf of another type (an int
             # column's first float), a non-finite token, or no stats on
@@ -499,7 +556,8 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
     kept = seed.table.filter(pa.array(survivors)) if dropped else seed.table
     table = pa.concat_tables([kept, tail._table]).combine_chunks()
     idx = ResidentStatsIndex(StatsIndex(table, n), vals, valid, seed.cols,
-                             n, table_path=table_path, version=version)
+                             n, table_path=table_path, version=version,
+                             unindexed=seed.unindexed)
     return idx, {"rows": n_tail, "dropped": dropped}
 
 
@@ -631,8 +689,9 @@ def compile_conjuncts(conjuncts: List[Expression],
     return block, fallback
 
 
-def snapshot_stats_index(state, files: pa.Table):
-    """The state's resident index, building it on first use. Returns
+def snapshot_stats_index(state, files: pa.Table, metadata=None):
+    """The state's resident index, building it on first use, its stat
+    leaves typed by the schema of the table's `metadata`. Returns
     None when `state` can't host one or `files` isn't the state's own
     live-file table (e.g. the conflict checker's stats subsets)."""
     lock = getattr(state, "_stats_index_lock", None)
@@ -656,7 +715,7 @@ def snapshot_stats_index(state, files: pa.Table):
             if seed is not None:
                 state.stats_index_seed = None
                 idx, attrs = append_index(seed, state.live_mask, files,
-                                          table_path, version)
+                                          table_path, version, metadata)
                 sp.set_attrs(**attrs)
             if idx is not None:
                 stats = stats.slice(files.num_rows - attrs["rows"])
@@ -667,13 +726,17 @@ def snapshot_stats_index(state, files: pa.Table):
                     _APPEND_FALLBACKS.inc()
                 else:   # a state loaded in full: nothing to append to
                     sp.set_attr("reason", "no_seed")
-                idx = build_index(files, table_path, version)
+                idx = build_index(files, table_path, version, metadata)
                 sp.set_attr("mode", "full")
                 _BUILDS.inc()
+            for why, leaves in idx.unindexed.items():
+                _UNINDEXED[why].inc(leaves)
             if sp.recording:
                 sp.set_attrs(
                     bytes=stats.nbytes,
-                    lanes=0 if idx.vals is None else len(idx.vals))
+                    lanes=0 if idx.vals is None else len(idx.vals),
+                    columns=len(idx.cols),
+                    unindexed=sum(idx.unindexed.values()))
         state.stats_index = idx
         # built implicitly by ordinary filtered scans, so a state
         # dropped outside the explicit-release paths (one-shot reads,

@@ -22,7 +22,7 @@ conjuncts — but only when both counts are present.
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 import pyarrow as pa
@@ -44,6 +44,106 @@ from delta_tpu.expressions.tree import (
 
 _DEVICE_PLANS = obs.counter("scan.device_plans")
 _DEVICE_FALLBACKS = obs.counter("scan.device_fallbacks")
+_UNCOMPARED = obs.counter("scan.skip_uncompared_conjuncts")
+
+_ARROW_ERRS = (pa.ArrowInvalid, pa.ArrowNotImplementedError,
+               pa.ArrowTypeError)
+
+# What a stat leaf is read as where the table's schema calls it one of
+# these: a Delta `timestamp` is an instant (UTC microseconds whatever
+# offset the writer spelled it with), a `timestamp_ntz` a wall clock.
+_TIMESTAMP_LEAVES = {"timestamp": pa.timestamp("us", tz="UTC"),
+                     "timestamp_ntz": pa.timestamp("us")}
+# Writers truncate a timestamp stat to the millisecond (PROTOCOL.md,
+# "Per-file Statistics"), so a stored max stands for any instant within
+# its millisecond: it is read as stored + 1 ms, here and nowhere else
+# (upstream `DataSkippingReader` does the same), so the lanes, their
+# numpy twin and the Arrow ladder all see the widened value.
+_TIMESTAMP_MAX_SLACK_US = 1000
+
+
+def stat_leaf_types(metadata) -> Dict[tuple, str]:
+    """{leaf path as the stats JSON keys it: Delta primitive type} of
+    the table's schema (`decimal(p,s)` as `decimal`); physical names
+    under column mapping. Arrays and maps carry no stats."""
+    from delta_tpu.models.schema import PrimitiveType, StructType
+
+    mapped = metadata.configuration.get(
+        "delta.columnMapping.mode", "none") != "none"
+
+    def walk(struct, prefix, out):
+        for f in struct.fields:
+            path = prefix + (f.physical_name if mapped else f.name,)
+            if isinstance(f.dataType, StructType):
+                walk(f.dataType, path, out)
+            elif isinstance(f.dataType, PrimitiveType):
+                out[path] = ("decimal" if f.dataType.is_decimal
+                             else f.dataType.name)
+        return out
+
+    return walk(metadata.schema, (), {})
+
+
+def _typed_leaf(leaf: pa.Array, delta_type: Optional[str], widen: bool,
+                cast: bool) -> pa.Array:
+    target = _TIMESTAMP_LEAVES.get(delta_type)
+    if target is None:
+        return leaf
+    if leaf.type != target:
+        if not cast:
+            return leaf
+        try:    # ISO-8601 with `Z` or an offset; zone-less for an ntz
+            leaf = leaf.cast(target)
+        except _ARROW_ERRS:
+            return leaf     # stays as parsed: off the lanes, counted
+    if widen:
+        us = pc.min_element_wise(
+            leaf.cast(pa.int64()),
+            pa.scalar(np.iinfo(np.int64).max - _TIMESTAMP_MAX_SLACK_US))
+        leaf = pc.add(us, _TIMESTAMP_MAX_SLACK_US).cast(target)
+    return leaf
+
+
+def _typed_struct(arr: pa.StructArray, prefix: tuple, leaf_types, widen,
+                  cast) -> pa.StructArray:
+    children, fields, changed = [], [], False
+    for i, f in enumerate(arr.type):
+        child, path = arr.field(i), prefix + (f.name,)
+        if pa.types.is_struct(f.type):
+            new = _typed_struct(child, path, leaf_types, widen, cast)
+        else:
+            new = _typed_leaf(child, leaf_types.get(path), widen, cast)
+        changed |= new is not child
+        children.append(new)
+        fields.append(f if new is child else pa.field(f.name, new.type))
+    if not changed:
+        return arr
+    return pa.StructArray.from_arrays(
+        children, fields=fields,
+        mask=arr.is_null() if arr.null_count else None)
+
+
+def _typed_stats(parsed: pa.Table, leaf_types: Dict[tuple, str],
+                 cast: bool) -> pa.Table:
+    """`parsed` with the `minValues` / `maxValues` leaves that the
+    table's schema calls `timestamp` or `timestamp_ntz` read as such
+    (JSON inference takes a time with a fraction or a zone for a
+    string), and each such max widened by its writer's millisecond.
+    Without `cast` only leaves already of that type are widened (rows
+    parsed under a typed schema). A table with no such column is
+    returned as it came."""
+    if not any(t in _TIMESTAMP_LEAVES for t in leaf_types.values()):
+        return parsed
+    for group in ("minValues", "maxValues"):
+        i = parsed.schema.get_field_index(group)
+        if i < 0 or not pa.types.is_struct(parsed.schema.field(i).type):
+            continue
+        col = parsed.column(i).combine_chunks()
+        typed = _typed_struct(col, (), leaf_types, group == "maxValues",
+                              cast)
+        if typed is not col:
+            parsed = parsed.set_column(i, group, typed)
+    return parsed
 
 
 class StatsIndex:
@@ -54,13 +154,17 @@ class StatsIndex:
         self.n = n
 
     @staticmethod
-    def from_stats_column(stats_col: pa.ChunkedArray,
-                          schema: Optional[pa.Schema] = None) -> "StatsIndex":
+    def from_stats_column(
+            stats_col: pa.ChunkedArray, schema: Optional[pa.Schema] = None,
+            leaf_types: Optional[Dict[tuple, str]] = None) -> "StatsIndex":
         """Parse one stats string a row. With `schema` (the parsed
         schema of rows these will stand behind, `stats/device_index.py`)
         nothing is inferred and nothing rewritten: a value that does
         not read as the schema's type, or a key the schema lacks, gives
-        an index with no table."""
+        an index with no table. With `leaf_types` (`stat_leaf_types` of
+        the table's schema) the leaves it names are typed by it
+        (`_typed_stats`); a leaf it lacks, and every leaf where it is
+        not given, keeps the type JSON inference gave it."""
         n = len(stats_col)
         arr = stats_col.combine_chunks() if isinstance(stats_col, pa.ChunkedArray) else stats_col
         if n == 0 or arr.null_count == n:
@@ -102,6 +206,8 @@ class StatsIndex:
                 return StatsIndex(None, n)
         if parsed.num_rows != n:
             return StatsIndex(None, n)
+        if leaf_types:
+            parsed = _typed_stats(parsed, leaf_types, cast=schema is None)
         return StatsIndex(parsed, n)
 
     def _leaf(self, group: str, name_path: tuple) -> Optional[np.ndarray]:
@@ -148,9 +254,13 @@ def _max_truncated(maxv) -> Optional[pa.Array]:
                             pa.scalar(MAX_STRING_PREFIX_LENGTH))
 
 
-def _cmp_keep(op: str, minv, maxv, lit_arr) -> Optional[pa.Array]:
+def _cmp_keep(op: str, minv, maxv, lit_arr,
+              uncompared: list) -> Optional[pa.Array]:
     """Keep-condition (nullable bool Arrow array) for `col op lit` given
-    min/max arrays; None = cannot decide (keep).
+    min/max arrays; None = cannot decide (keep). Where the stats are
+    there and Arrow cannot compare them with the literal (a zone-less
+    `datetime` against a `timestamp` leaf, text against a number), the
+    file is kept and `uncompared` is told.
 
     String maxValues get prefix-aware semantics: a truncated max is only
     a lower bound on the true max (tie-broken upward), so `maxv >= lit`
@@ -191,16 +301,22 @@ def _cmp_keep(op: str, minv, maxv, lit_arr) -> Optional[pa.Array]:
             if trunc is not None:
                 all_eq = pc.and_kleene(all_eq, pc.invert(trunc))
             return pc.invert(all_eq)
-    except (pa.ArrowInvalid, pa.ArrowNotImplementedError, pa.ArrowTypeError):
+    except _ARROW_ERRS:
+        if any(a is not None and not pa.types.is_null(a.type)
+               for a in (minv, maxv)):
+            uncompared.append(op)
         return None
     return None
 
 
-def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
-    """Nullable keep-mask for one conjunct; None/null = keep."""
+def _conjunct_keep(conj: Expression, index: StatsIndex,
+                   uncompared: list) -> Optional[pa.Array]:
+    """Nullable keep-mask for one conjunct; None/null = keep. Each
+    comparison that a column's stats were there for and Arrow refused
+    leaves an entry in `uncompared`."""
     if isinstance(conj, Or):
-        left = _conjunct_keep(conj.left, index)
-        right = _conjunct_keep(conj.right, index)
+        left = _conjunct_keep(conj.left, index, uncompared)
+        right = _conjunct_keep(conj.right, index, uncompared)
         if left is None or right is None:
             return None
         return pc.or_kleene(left, right)
@@ -221,7 +337,7 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
             lit_arr = pa.scalar(lit.value)
         except pa.ArrowInvalid:
             return None
-        keep = _cmp_keep(op, minv, maxv, lit_arr)
+        keep = _cmp_keep(op, minv, maxv, lit_arr, uncompared)
         # additionally: an all-null column can't match col op lit
         nc = index.null_count(colref.name_path)
         nr = index.num_records()
@@ -229,8 +345,8 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
             try:
                 not_all_null = pc.less(nc, nr)
                 keep = not_all_null if keep is None else pc.and_kleene(keep, not_all_null)
-            except (pa.ArrowInvalid, pa.ArrowNotImplementedError, pa.ArrowTypeError):
-                pass
+            except _ARROW_ERRS:
+                uncompared.append("nullCount")
         return keep
     if isinstance(conj, IsNull):
         child = conj.child
@@ -240,7 +356,8 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
                 return None
             try:
                 return pc.greater(nc, pa.scalar(0))
-            except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+            except _ARROW_ERRS:
+                uncompared.append("nullCount")
                 return None
         return None
     if isinstance(conj, IsNotNull):
@@ -252,7 +369,8 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
                 return None
             try:
                 return pc.less(nc, nr)
-            except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+            except _ARROW_ERRS:
+                uncompared.append("nullCount")
                 return None
         return None
     if isinstance(conj, In):
@@ -269,9 +387,11 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
                 lo = hi = None
             if lo is not None:
                 k_lo = _conjunct_keep(
-                    Comparison(">=", conj.child, Literal(lo)), index)
+                    Comparison(">=", conj.child, Literal(lo)), index,
+                    uncompared)
                 k_hi = _conjunct_keep(
-                    Comparison("<=", conj.child, Literal(hi)), index)
+                    Comparison("<=", conj.child, Literal(hi)), index,
+                    uncompared)
                 if k_lo is not None and k_hi is not None:
                     pre = pc.and_kleene(k_lo, k_hi)
                 elif k_lo is not None or k_hi is not None:
@@ -285,7 +405,8 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
                     return pre  # nothing survives the range — done
             keeps = None
             for v in conj.values:
-                k = _conjunct_keep(Comparison("=", conj.child, Literal(v)), index)
+                k = _conjunct_keep(Comparison("=", conj.child, Literal(v)),
+                                   index, uncompared)
                 if k is None:
                     return pre
                 keeps = k if keeps is None else pc.or_kleene(keeps, k)
@@ -298,12 +419,12 @@ def _conjunct_keep(conj: Expression, index: StatsIndex) -> Optional[pa.Array]:
         if isinstance(inner, Comparison):
             neg = {"=": "!=", "!=": "=", "<": ">=", "<=": ">", ">": "<=", ">=": "<"}
             return _conjunct_keep(
-                Comparison(neg[inner.op], inner.left, inner.right), index
-            )
+                Comparison(neg[inner.op], inner.left, inner.right), index,
+                uncompared)
         if isinstance(inner, IsNull):
-            return _conjunct_keep(IsNotNull(inner.child), index)
+            return _conjunct_keep(IsNotNull(inner.child), index, uncompared)
         if isinstance(inner, IsNotNull):
-            return _conjunct_keep(IsNull(inner.child), index)
+            return _conjunct_keep(IsNull(inner.child), index, uncompared)
         return None
     return None
 
@@ -360,9 +481,12 @@ def skipping_mask(
     if state is not None:
         from delta_tpu.stats.device_index import snapshot_stats_index
 
-        rs = snapshot_stats_index(state, files)
+        rs = snapshot_stats_index(state, files, metadata)
     index = rs.arrow_index if rs is not None \
-        else StatsIndex.from_stats_column(files.column("stats"))
+        else StatsIndex.from_stats_column(
+            files.column("stats"),
+            leaf_types=None if metadata is None
+            else stat_leaf_types(metadata))
     if index._table is None:
         return keep
     if (
@@ -426,11 +550,17 @@ def skipping_mask(
                         vals, valid, block, n)
             obs.set_attrs(skip_route=route, skip_atoms=block.n_atoms,
                           skip_fallback_conjuncts=len(fallback))
+    uncompared = 0
     for conj in fallback:
-        mask = _conjunct_keep(conj, index)
+        refused: list = []
+        mask = _conjunct_keep(conj, index, refused)
+        uncompared += bool(refused)
         if mask is None:
             continue
         # null (missing stats for that file) -> keep
         filled = pc.fill_null(mask, True)
         keep &= np.asarray(filled, dtype=bool)
+    if uncompared:
+        _UNCOMPARED.inc(uncompared)
+    obs.set_attrs(uncompared=uncompared)
     return keep

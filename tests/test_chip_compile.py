@@ -165,13 +165,16 @@ def test_page_decode_part(on_chip):
     _assert_mosaic(compiled)
 
 
-@pytest.mark.parametrize("f_pad,a_pad", [
-    (1 << 20, 16),
+@pytest.mark.parametrize("rows,f_pad,a_pad", [
+    (4, 1 << 20, 16),
     # `ckpt-query-under-ingest`: 2.4M files, a range plan's two atoms
-    (2_621_440, 2),
+    (4, 2_621_440, 2),
+    # `bids-query-under-ingest`: four indexed columns of seven (13 lanes);
+    # an event-time window alone, and with five auctions listed (7 atoms)
+    (13, 2_621_440, 2),
+    (13, 2_621_440, 8),
 ])
-def test_skipping_mask_block(on_chip, f_pad, a_pad):
-    rows = 4
+def test_skipping_mask_block(on_chip, rows, f_pad, a_pad):
     atoms = on_chip((a_pad,), jnp.int32)
     with jax.enable_x64(True):
         compiled = skipping._skip_fn_cached(a_pad).lower(
@@ -181,18 +184,21 @@ def test_skipping_mask_block(on_chip, f_pad, a_pad):
             atoms, on_chip((), jnp.int32)).compile()
     _assert_fits(compiled)
     # no `[a_pad, f_pad]` copy of the lanes: the v5e compiler's
-    # temporaries (the int64 lanes' 32-bit halves among them) stay a
-    # few dozen bytes a file however many slots the program has
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * f_pad
+    # temporaries (the 32-bit halves of every int64 lane of the index
+    # among them, whichever rows the atoms name) stay under 16 bytes a
+    # lane a file however many slots the program has
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows * f_pad
     assert " while(" not in compiled.as_text()
 
 
-def test_stats_index_validity_unpack_2_6m_files(on_chip):
-    # the index of `ckpt-query-under-ingest`: 2.4M files pad to 2,621,440.
+@pytest.mark.parametrize("rows", [4, 13])
+def test_stats_index_validity_unpack_2_6m_files(on_chip, rows):
+    # the index of `ckpt-query-under-ingest` (4 lanes) and of
+    # `bids-query-under-ingest` (13): 2.4M files pad to 2,621,440.
     # As `jnp.unpackbits` over uint8 words this compile took 102 s
     n_pad = 2_621_440
     compiled = device_index._unpack_valid_fn(n_pad).lower(
-        on_chip((4, n_pad // 32), jnp.uint32)).compile()
+        on_chip((rows, n_pad // 32), jnp.uint32)).compile()
     _assert_fits(compiled)
 
 

@@ -288,3 +288,39 @@ def test_checkpoint_hook_runs_off_incremental_state(tmp_path):
     cold = _cold(tmp_path).latest_snapshot()
     assert cold.version == 4
     assert cold.num_files == 4
+
+
+def test_a_wide_live_table_is_filtered_by_slices_and_equals_one_filter(
+        monkeypatch):
+    """`state.filter_live` copies the rows kept of a table with a column
+    past 1 GiB a slice at a time, so that no buffer of that column
+    doubles past what Arrow's pool keeps (replay/state.py::_filter_rows);
+    the rows are the same, and a narrower table takes `Table.filter`."""
+    import numpy as np
+    import pyarrow as pa
+
+    from delta_tpu.replay import state
+
+    n = 20_000
+    table = pa.table({
+        "path": pa.chunked_array([
+            pa.array([f"part-{i:010d}" for i in range(n - 5)]),
+            pa.array(list("abcde")), pa.array([], pa.string())]),
+        "size": pa.chunked_array([pa.array(range(n - 5)), pa.array(range(5)),
+                                  pa.array([], pa.int64())])})
+    mask = np.random.default_rng(7).random(n) < 0.9
+    want = table.filter(pa.array(mask))
+    whole = state._filter_rows(table, mask)
+    assert whole.equals(want)
+    assert [len(c) for c in whole.column("path").chunks] == [
+        len(c) for c in want.column("path").chunks]
+    # the same table, were its widest column past the pool's half block
+    wide = table.column("path").nbytes
+    monkeypatch.setattr(state, "_FILTER_WHOLE_BYTES", wide - 1)
+    monkeypatch.setattr(state, "_FILTER_SLICE_BYTES", wide // 8)
+    sliced = state._filter_rows(table, mask)
+    assert sliced.equals(want)
+    chunks = [len(c) for c in sliced.column("path").chunks]
+    assert len(chunks) >= 8 and max(chunks) <= n // 8 + 1
+    empty = state._filter_rows(table.slice(0, 0), np.zeros(0, bool))
+    assert empty.num_rows == 0 and empty.schema == table.schema
