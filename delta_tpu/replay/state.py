@@ -41,6 +41,7 @@ from delta_tpu.replay.columnar import ColumnarActions, columnarize_log_segment
 # same registry instrument as parallel/resident.py: the cataloged
 # fallback counter for the replay route (route-contract lint)
 _ROUTE_FALLBACKS = obs.counter("replay.resident_fallbacks")
+_LIVE_TABLE_BUILDS = obs.counter("state.live_table_builds")
 
 
 @dataclass
@@ -70,7 +71,7 @@ class SnapshotState:
                                        compare=False)
     # Resident scan-planning stats index (stats/device_index.py):
     # built at most once per state under `_stats_index_lock` — a
-    # dedicated lock because the build reads `add_files_table`, which
+    # dedicated lock because the build reads `file_actions`, which
     # takes `_splice_lock` itself. `advance_state` carries it forward
     # on empty deltas and releases it otherwise; serve-cache eviction
     # releases it through `release_snapshot_resident`.
@@ -96,6 +97,7 @@ class SnapshotState:
     table_path: Optional[str] = None
 
     _add_table_cache: Optional[pa.Table] = None
+    _live_rows_cache: Optional[np.ndarray] = None
     _tombstone_table_cache: Optional[pa.Table] = None
     _splice_lock: object = field(default_factory=threading.Lock,
                                  repr=False, compare=False)
@@ -126,17 +128,54 @@ class SnapshotState:
 
     @property
     def add_files_table(self) -> pa.Table:
-        """Live files as an Arrow table (canonical schema)."""
+        """Live files as an Arrow table (canonical schema): a copy of
+        every column of every live row, for the callers that want them
+        all. A scan with a filter plans over `live_rows` and copies
+        only what it keeps (`live_subset`)."""
         if self._add_table_cache is None:
             with obs.span("state.add_files_table",
                           rows=len(self.live_mask)) as sp:
                 table = self.file_actions  # state.splice_stats, if deferred
-                with obs.span("state.filter_live", rows=table.num_rows):
+                with obs.span("state.filter_live", rows=table.num_rows,
+                              **{"as": "table"}):
                     live = _filter_rows(table, self.live_mask)
                 if sp.recording:
                     sp.set_attrs(live_rows=live.num_rows, bytes=live.nbytes)
+                _LIVE_TABLE_BUILDS.inc()
                 self._add_table_cache = live
         return self._add_table_cache
+
+    @property
+    def live_rows(self) -> np.ndarray:
+        """Row numbers of the live files in `file_actions`, ascending:
+        row `i` of `add_files_table`, and of the resident stats index,
+        is row `live_rows[i]` of the rows held. The same narrowing as
+        `add_files_table`'s, by index and not by copy: made once a
+        state, under the same span."""
+        if self._live_rows_cache is None:
+            with obs.span("state.filter_live", rows=len(self.live_mask),
+                          **{"as": "rows"}):
+                self._live_rows_cache = np.flatnonzero(self.live_mask)
+        return self._live_rows_cache
+
+    def live_columns(self, names) -> pa.Table:
+        """Columns `names` of `add_files_table`, filtered out of the
+        rows held without building the rest."""
+        return _filter_rows(self.file_actions.select(names), self.live_mask)
+
+    def live_subset(self, keep: np.ndarray) -> pa.Table:
+        """`add_files_table.filter(keep)` read straight out of the rows
+        held, `keep` a mask over the live rows: same schema, same rows,
+        same order. Few survivors are gathered by their row numbers;
+        past a fixed share of the rows held a boolean filter of them
+        is the faster way to the same table."""
+        table = self.file_actions
+        rows = self.live_rows[np.flatnonzero(keep)]
+        if len(rows) * _GATHER_SHARE <= table.num_rows:
+            return gather_rows(table, rows)
+        mask = np.zeros(table.num_rows, dtype=bool)
+        mask[rows] = True
+        return _filter_rows(table, mask)
 
     @property
     def tombstones_table(self) -> pa.Table:
@@ -607,6 +646,12 @@ def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
         stats_thunk = (prev.stats_thunk
                        and _chained_prev_stats(prev, delta_fa))
 
+    if m and not stats_thunk:
+        # with a decode pending, the stats column still comes chunk by
+        # chunk from the chain of thunks: merged then, the other
+        # columns' chunks would no longer line up with its own
+        new_raw = _merge_small_chunks(new_raw)
+
     set_txns = dict(prev.set_transactions)
     set_txns.update(delta.set_transactions)
     domains = dict(prev.domain_metadata)
@@ -698,6 +743,61 @@ def _filter_rows(table: pa.Table, mask: np.ndarray) -> pa.Table:
     sliced = pa.Table.from_batches(table.to_batches(max_chunksize=rows),
                                    schema=table.schema)
     return sliced.filter(pa.array(mask))
+
+
+# A gather copies only the rows it keeps, a filter walks every row held:
+# the filter wins once the rows kept pass this share of them (1 / N)
+_GATHER_SHARE = 4
+
+
+def gather_rows(table: pa.Table, rows: np.ndarray) -> pa.Table:
+    """The rows of `table` numbered `rows` (ascending), as
+    `table.combine_chunks().take(rows)` gives them, without combining:
+    each number is resolved against the chunks' offsets and only the
+    chunks it touches are read, a run of neighbours as a slice (a
+    commit's files, kept whole), scattered rows by a take. (`Table.take`
+    on a chunked table concatenates every column before it takes,
+    pyarrow 25.0: 68 ms for 6,000 rows of 3M in two chunks, where this
+    takes under one.)"""
+    batches = table.to_batches()
+    ends = np.cumsum([b.num_rows for b in batches])
+    cuts = np.searchsorted(rows, ends)  # rows[cuts[i-1]:cuts[i]]: batch i's
+    taken, lo, start = [], 0, 0
+    for batch, end, hi in zip(batches, ends, cuts):
+        if hi > lo:
+            first, last = int(rows[lo]) - start, int(rows[hi - 1]) - start
+            if last - first == hi - lo - 1:
+                taken.append(batch.slice(first, hi - lo))
+            else:
+                taken.append(batch.take(pa.array(rows[lo:hi] - start)))
+        lo, start = hi, end
+    return pa.Table.from_batches(taken, schema=table.schema)
+
+
+# An advance lands its commits' rows behind the table as one more
+# chunk, and a scan resolves its survivors chunk by chunk
+# (`gather_rows`): the small chunks at the table's end are merged once
+# there are more than this many, so a held table keeps tens of chunks
+# over any number of advances. A chunk that has grown to
+# `_SMALL_CHUNK_ROWS` is left alone: no merge copies more than that and
+# the chunks just landed, and the rows loaded first are never copied.
+_MAX_SMALL_CHUNKS = 64
+_SMALL_CHUNK_ROWS = 1 << 16
+
+
+def _merge_small_chunks(table: pa.Table) -> pa.Table:
+    lengths = [len(c) for c in max(
+        table.columns, key=lambda col: col.num_chunks).chunks]
+    small = rows = 0
+    for n in reversed(lengths[1:]):     # the first chunk stays where it is
+        if n >= _SMALL_CHUNK_ROWS:
+            break
+        small, rows = small + 1, rows + n
+    if small <= _MAX_SMALL_CHUNKS:
+        return table
+    at = table.num_rows - rows
+    return pa.concat_tables([table.slice(0, at),
+                             table.slice(at).combine_chunks()])
 
 
 def _chained_prev_stats(prev: SnapshotState, delta_fa: Optional[pa.Table]):

@@ -29,6 +29,8 @@ from delta_tpu import obs
 from delta_tpu.expressions.tree import Expression, split_conjuncts
 from delta_tpu.models.actions import AddFile
 
+_FROM_HELD_ROWS = obs.counter("scan.plans_from_held_rows")
+
 
 class ScanBuilder:
     def __init__(self, snapshot):
@@ -61,15 +63,13 @@ class Scan:
     def snapshot(self):
         return self._snapshot
 
-    def _partition_batch(self, files: pa.Table) -> pa.Table:
+    def _partition_batch(self, partition_values: pa.ChunkedArray) -> pa.Table:
         """Reconstruct typed partition-column values from the
         partitionValues string map (protocol Partition Value Serialization)."""
         from delta_tpu.stats.partition import partition_values_to_columns
 
-        return partition_values_to_columns(
-            files.column("partition_values"),
-            self._snapshot.metadata,
-        )
+        return partition_values_to_columns(partition_values,
+                                           self._snapshot.metadata)
 
     def add_files_table(self) -> pa.Table:
         """Surviving AddFiles (canonical columnar schema) after pruning."""
@@ -84,11 +84,19 @@ class Scan:
             return result
 
     def _plan(self, sp) -> pa.Table:
-        files = self._snapshot.state.add_files_table
-        sp.set_attr("total_files", files.num_rows)
-        if self.filter is None or files.num_rows == 0:
+        """With no filter, the state's live table. With one, the plan
+        is made over the state's live rows, row `i` the `i`-th live row
+        held, as the resident stats index has them, and the survivors
+        are read straight out of the rows held: the live table is never
+        built for it."""
+        state = self._snapshot.state
+        n = 0 if self.filter is None else len(state.live_rows)
+        if n == 0:
+            files = state.add_files_table
+            sp.set_attr("total_files", files.num_rows)
             self._result_cache = files
             return files
+        sp.set_attr("total_files", n)
 
         partition_cols = set(self._snapshot.partition_columns)
         conjuncts = split_conjuncts(self.filter)
@@ -103,37 +111,43 @@ class Scan:
         part_ids = {id(c) for c in part_conjuncts}
         data_conjuncts = [c for c in conjuncts if id(c) not in part_ids]
 
-        keep = np.ones(files.num_rows, dtype=bool)
+        keep = None     # every live row, until a conjunct says otherwise
         if part_conjuncts:
-            batch = self._partition_batch(files)
+            batch = self._partition_batch(state.live_columns(
+                ["partition_values"]).column(0))
             from delta_tpu.expressions.eval import evaluate_predicate_host
 
+            keep = np.ones(n, dtype=bool)
             for c in part_conjuncts:
                 keep &= evaluate_predicate_host(c, batch)
-            self.partition_pruned = int((~keep).sum())
+            self.partition_pruned = n - int(np.count_nonzero(keep))
 
         if data_conjuncts:
             from delta_tpu.stats.skipping import skipping_mask
 
             # `skipping_mask` names its route, atoms and fallback
             # conjuncts on this span
-            with obs.span("plan.skip", rows=files.num_rows,
+            with obs.span("plan.skip", rows=n,
                           conjuncts=len(data_conjuncts)):
                 stats_keep = skipping_mask(
-                    files,
+                    None,
                     data_conjuncts,
                     self._snapshot.metadata,
                     engine=self._snapshot._engine,
-                    state=self._snapshot.state,
+                    state=state,
                 )
-            self.skipped_by_stats = int((keep & ~stats_keep).sum())
-            keep &= stats_keep
+            keep = stats_keep if keep is None else keep & stats_keep
 
-        with obs.span("plan.filter", rows=files.num_rows) as fsp:
-            result = files.filter(pa.array(keep))
+        with obs.span("plan.filter", rows=n) as fsp:
+            result = state.live_subset(keep)
             fsp.set_attr("surviving", result.num_rows)
+        # counted from what survived: a pass over a mask of every live
+        # row is a tenth of a plan at 2.4M files
+        self.skipped_by_stats = (n - self.partition_pruned
+                                 - result.num_rows)
+        _FROM_HELD_ROWS.inc()
         self._result_cache = result
-        self._report_metrics(files.num_rows, result.num_rows)
+        self._report_metrics(n, result.num_rows)
         return result
 
     def _report_metrics(self, total: int, surviving: int) -> None:

@@ -22,7 +22,7 @@ file in one type-agnostic dispatch on either backend, bit-identically.
 Lifecycle mirrors `parallel/resident.py` discipline: built at most
 once per `SnapshotState` under the state's dedicated
 `_stats_index_lock` (NOT `_splice_lock` — building reads
-`add_files_table`, which takes the splice lock itself), and released on
+`file_actions`, which takes the splice lock itself), and released on
 serve-cache eviction through `release_snapshot_resident`. The device
 upload is lazy (first device-routed scan) and budgeted in
 `resources/transfer_budget.json` (`stats-index-lanes`): the lanes ship
@@ -39,9 +39,10 @@ built over. Live bits of prior rows are only ever cleared and new rows
 only ever land behind them, so the first filtered scan of the new
 version (`snapshot_stats_index`, span `stats.index_build`, `mode`
 `append`) keeps the seed's rows that are still live, parses the stats
-of the rows landed since under the seed's schema, and writes them
-behind: the cost is that of the rows that changed, and the result is
-what `build_index` over every live file gives. Where that cannot be
+of the rows landed since (read out of the rows the state holds, past
+the seed's) under the seed's schema, and writes them behind: the cost
+is that of the rows that changed, and the result is what `build_index`
+over every live file gives. Where that cannot be
 shown from the seed (`_APPEND_FALLBACKS`, the span's
 `append_fallback`), and on a state with no seed, every live file's
 stats string is parsed (`mode` `full`). The seed's arrays are never
@@ -492,17 +493,19 @@ def _cannot_append(reason: str):
 
 
 def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
-                 files: pa.Table, table_path: Optional[str] = None,
+                 tail_stats: pa.ChunkedArray,
+                 table_path: Optional[str] = None,
                  version: Optional[int] = None, metadata=None):
-    """The index of `files` (the live rows under `live_mask`, in raw
-    order) made from `seed`: the seed's rows that are still live, then
-    the rows landed since, their stats parsed under the seed's schema
-    and encoded under its kinds. Returns the index and the build span's
-    attributes (`rows` parsed, `dropped`), or None and the reason
-    (`append_fallback`) where the result cannot be shown equal to
-    `build_index(files)`: the caller then builds in full. Rows that
-    went never narrow the schema: a leaf that only they carried stays,
-    as a lane unknown on every row (which keeps, as no lane does)."""
+    """The index of the live rows under `live_mask`, in raw order, made
+    from `seed`: the seed's rows that are still live, then the rows
+    landed since, their stats (`tail_stats`, one string a live row past
+    the seed's) parsed under the seed's schema and encoded under its
+    kinds. Returns the index and the build span's attributes (`rows`
+    parsed, `dropped`), or None and the reason (`append_fallback`)
+    where the result cannot be shown equal to `build_index` over every
+    live file: the caller then builds in full. Rows that went never
+    narrow the schema: a leaf that only they carried stays, as a lane
+    unknown on every row (which keeps, as no lane does)."""
     from delta_tpu.stats.skipping import StatsIndex, stat_leaf_types
 
     if seed.vals is None:
@@ -516,12 +519,11 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
     survivors = still_live[seed.base_live]
     n_kept = int(survivors.sum())
     n_tail = int(live_mask[n_base:].sum())
-    n = files.num_rows
-    if len(survivors) != seed.n or n_kept + n_tail != n:
+    n = n_kept + n_tail
+    if len(survivors) != seed.n or len(tail_stats) != n_tail:
         return _cannot_append("row-count")
 
     if n_tail:
-        tail_stats = files.column("stats").slice(n_kept)
         tail = StatsIndex.from_stats_column(
             tail_stats, schema=seed.table.schema,
             leaf_types=None if metadata is None
@@ -689,19 +691,25 @@ def compile_conjuncts(conjuncts: List[Expression],
     return block, fallback
 
 
-def snapshot_stats_index(state, files: pa.Table, metadata=None):
+def snapshot_stats_index(state, files: Optional[pa.Table] = None,
+                         metadata=None):
     """The state's resident index, building it on first use, its stat
-    leaves typed by the schema of the table's `metadata`. Returns
-    None when `state` can't host one or `files` isn't the state's own
+    leaves typed by the schema of the table's `metadata`. `files` None
+    is a scan over the state's own live rows: what stats strings the
+    build needs (a refresh: those of the rows landed since the seed)
+    are read out of the rows held, and the live table is never asked
+    for. A caller that holds that table may pass it. Returns None when
+    `state` can't host an index or `files` isn't the state's own
     live-file table (e.g. the conflict checker's stats subsets)."""
     lock = getattr(state, "_stats_index_lock", None)
     if lock is None:
         return None
-    try:
-        if state.add_files_table is not files:
+    if files is not None:
+        try:
+            if state.add_files_table is not files:
+                return None
+        except AttributeError:
             return None
-    except AttributeError:
-        return None
     with lock:
         idx = state.stats_index
         if idx is not None and not idx.released:
@@ -710,15 +718,20 @@ def snapshot_stats_index(state, files: pa.Table, metadata=None):
         table_path = getattr(state, "table_path", None)
         version = getattr(state, "version", None)
         seed = getattr(state, "stats_index_seed", None)
-        with obs.span("stats.index_build", rows=files.num_rows) as sp:
-            idx, stats = None, files.column("stats")
+        n = files.num_rows if files is not None else len(state.live_rows)
+        with obs.span("stats.index_build", rows=n) as sp:
+            idx = None
             if seed is not None:
                 state.stats_index_seed = None
-                idx, attrs = append_index(seed, state.live_mask, files,
+                # only an advanced `SnapshotState` has a seed: the
+                # tail's stats are in the rows it holds, past the seed's
+                n_base = len(seed.base_live)
+                stats = state.file_actions.column("stats").slice(
+                    n_base).filter(pa.array(state.live_mask[n_base:]))
+                idx, attrs = append_index(seed, state.live_mask, stats,
                                           table_path, version, metadata)
                 sp.set_attrs(**attrs)
             if idx is not None:
-                stats = stats.slice(files.num_rows - attrs["rows"])
                 sp.set_attr("mode", "append")
                 _APPENDS.inc()
             else:
@@ -726,6 +739,9 @@ def snapshot_stats_index(state, files: pa.Table, metadata=None):
                     _APPEND_FALLBACKS.inc()
                 else:   # a state loaded in full: nothing to append to
                     sp.set_attr("reason", "no_seed")
+                if files is None:
+                    files = state.live_columns(["stats"])
+                stats = files.column("stats")
                 idx = build_index(files, table_path, version, metadata)
                 sp.set_attr("mode", "full")
                 _BUILDS.inc()

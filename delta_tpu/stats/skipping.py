@@ -457,7 +457,7 @@ def _to_physical(expr: Expression, schema) -> Optional[Expression]:
 
 
 def skipping_mask(
-    files: pa.Table,
+    files: Optional[pa.Table],
     conjuncts: List[Expression],
     metadata,
     engine=None,
@@ -472,8 +472,12 @@ def skipping_mask(
     chosen by `parallel/gate.py::skip_route` — and only the remainder
     (string/complex/missing-stats columns, inexact literals) walks the
     per-conjunct Arrow ladder below. Both routes AND into the same
-    mask, so the result is route-independent by construction."""
-    n = files.num_rows
+    mask, so the result is route-independent by construction.
+
+    `files` None is a scan over the `SnapshotState`'s own live rows
+    (`scan.py`): the mask is over them, in the order held, and the
+    index reads what stats strings it needs out of the rows held."""
+    n = files.num_rows if files is not None else len(state.live_rows)
     keep = np.ones(n, dtype=bool)
     if n == 0 or not conjuncts:
         return keep
