@@ -89,6 +89,7 @@ from delta_tpu.obs.trace import (
     MODE_OFF,
     MODE_ON,
     MODE_VERBOSE,
+    PHASE_SPAN_ROWS,
     Span,
     add_event,
     add_exporter,
@@ -129,6 +130,7 @@ __all__ = [
     "MODE_OFF",
     "MODE_ON",
     "MODE_VERBOSE",
+    "PHASE_SPAN_ROWS",
     "Breach",
     "Counter",
     "FlightRecorder",

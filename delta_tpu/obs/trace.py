@@ -324,6 +324,15 @@ class _SpanCtx:
         return False
 
 
+# A span that times one phase of a pass over a table is worth its own
+# name once the table is large: from this many rows it is recorded under
+# `on`, below it under `verbose` alone (`span(..., _verbose=rows <
+# PHASE_SPAN_ROWS)`). A refresh of a few thousand rows is a few
+# milliseconds, its parent says it all, and a profile of a small run
+# keeps its handful of names.
+PHASE_SPAN_ROWS = 1 << 16
+
+
 def span(name: str, _verbose: bool = False, **attrs):
     """Open a span named `name` with initial attributes `attrs`.
 

@@ -575,30 +575,33 @@ def advance_state(
 
 
 def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
-    from delta_tpu.ops.replay import delta_winner_masks
-
     delta_fa = delta.file_actions_complete()  # delta stats: small, eager
     m = delta_fa.num_rows
     n_prev = prev.file_actions_raw.num_rows
     resident = prev.resident
     sp.set_attr("delta_rows", m)
 
+    # the phases below are spans under `on` from PHASE_SPAN_ROWS rows held
+    small = n_prev < obs.PHASE_SPAN_ROWS
+    masks = None
+    if m and resident is not None:
+        with obs.span("advance.resident_append", _verbose=small,
+                      rows=m) as ph:
+            masks = resident.append(delta_fa, n_prev)
+            ph.set_attr("appended", masks is not None)
     if m == 0:
         sp.set_attr("route", "empty")
         new_raw = prev.file_actions_raw
         live = prev.live_mask
         tomb = prev.tombstone_mask
         stats_thunk = prev.stats_thunk and _chained_prev_stats(prev, None)
-    elif resident is not None and (
-            masks := resident.append(delta_fa, n_prev)) is not None:
+    elif masks is not None:
         # device-resident path: only the delta rows crossed the link;
         # the device re-reconciled base+delta and the returned masks
         # already cover the concatenated table
         sp.set_attr("route", "resident")
         live, tomb = masks
-        new_raw = pa.concat_tables([prev.file_actions_raw, delta_fa])
-        stats_thunk = (prev.stats_thunk
-                       and _chained_prev_stats(prev, delta_fa))
+        new_raw, stats_thunk, _ = _land_rows(prev, delta_fa)
     else:
         sp.set_attr("route", "host")
         if resident is not None:
@@ -608,50 +611,25 @@ def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
             resident.release()
             resident = None
             prev.resident = None
-        d_paths = delta_fa.column("path").to_pylist()
-        d_dv = delta_fa.column("dv_id").to_pylist()
-        d_keys = list(zip(d_paths, d_dv))
-        d_live, d_tomb, winner = delta_winner_masks(
-            d_keys,
-            np.asarray(delta_fa.column("version"), np.int64),
-            np.asarray(delta_fa.column("order"), np.int32),
-            np.asarray(delta_fa.column("is_add"), bool),
-        )
-        prev_live = prev.live_mask.copy()
-        prev_tomb = prev.tombstone_mask.copy()
-        if n_prev:
-            # candidate prior rows: active AND path touched by the delta
-            # (one vectorized hash probe over the big column; the exact
-            # (path, dv_id) check runs only on the few candidates)
-            touched = pa.array(sorted({p for p, _ in winner}), pa.string())
-            import pyarrow.compute as pc
+        live, tomb = _advance_masks_host(prev, delta_fa, small)
+        with obs.span("advance.table", _verbose=small) as ph:
+            new_raw, stats_thunk, merged = _land_rows(prev, delta_fa)
+            ph.set_attrs(chunks=new_raw.column("path").num_chunks,
+                         merged_rows=merged)
+        with obs.span("advance.carry", _verbose=small,
+                      commit_infos=len(prev.commit_infos)):
+            return _carry_over(prev, delta, new_segment, new_raw, live, tomb,
+                               stats_thunk, resident, sp)
+    return _carry_over(prev, delta, new_segment, new_raw, live, tomb,
+                       stats_thunk, resident, sp)
 
-            hit = np.asarray(
-                pc.is_in(prev.file_actions_raw.column("path"),
-                         value_set=touched).combine_chunks(),
-                dtype=bool)
-            cand = np.nonzero(hit & (prev_live | prev_tomb))[0]
-            if cand.size:
-                sub = prev.file_actions_raw.take(
-                    pa.array(cand, pa.int64()))
-                for j, p, dv in zip(cand,
-                                    sub.column("path").to_pylist(),
-                                    sub.column("dv_id").to_pylist()):
-                    if (p, dv) in winner:
-                        prev_live[j] = False
-                        prev_tomb[j] = False
-        new_raw = pa.concat_tables([prev.file_actions_raw, delta_fa])
-        live = np.concatenate([prev_live, d_live])
-        tomb = np.concatenate([prev_tomb, d_tomb])
-        stats_thunk = (prev.stats_thunk
-                       and _chained_prev_stats(prev, delta_fa))
 
-    if m and not stats_thunk:
-        # with a decode pending, the stats column still comes chunk by
-        # chunk from the chain of thunks: merged then, the other
-        # columns' chunks would no longer line up with its own
-        new_raw = _merge_small_chunks(new_raw)
-
+def _carry_over(prev, delta, new_segment, new_raw, live, tomb, stats_thunk,
+                resident, sp) -> SnapshotState:
+    """The new state round its rows and masks: what the delta did not
+    replace carried over from `prev`, and what `prev` held on the
+    device handed on (an empty delta) or released."""
+    m = new_raw.num_rows - prev.file_actions_raw.num_rows
     set_txns = dict(prev.set_transactions)
     set_txns.update(delta.set_transactions)
     domains = dict(prev.domain_metadata)
@@ -714,6 +692,77 @@ def _advance_state(engine, prev, delta, new_segment, sp) -> SnapshotState:
             operand_cache.release()
             prev.operand_cache = None
     return new_state
+
+
+def _advance_masks_host(prev, delta_fa, small: bool):
+    """(live, tombstone) masks over `prev`'s rows and the delta's behind
+    them: the delta's own winners, and every held row that one of them
+    supersedes cleared. Three phases, a span each (`small`: under
+    `verbose` alone)."""
+    import pyarrow.compute as pc
+
+    from delta_tpu.ops.replay import delta_winner_masks
+
+    n_prev = prev.file_actions_raw.num_rows
+    with obs.span("advance.delta_keys", _verbose=small,
+                  rows=delta_fa.num_rows) as ph:
+        d_paths = delta_fa.column("path").to_pylist()
+        d_dv = delta_fa.column("dv_id").to_pylist()
+        d_keys = list(zip(d_paths, d_dv))
+        d_live, d_tomb, winner = delta_winner_masks(
+            d_keys,
+            np.asarray(delta_fa.column("version"), np.int64),
+            np.asarray(delta_fa.column("order"), np.int32),
+            np.asarray(delta_fa.column("is_add"), bool),
+        )
+        ph.set_attr("winners", len(winner))
+    with obs.span("advance.probe", _verbose=small, rows=n_prev) as ph:
+        touched = sorted({p for p, _ in winner})
+        cand = cleared = np.zeros(0, np.int64)
+        if n_prev:
+            # candidate prior rows: active AND path touched by the delta
+            # (one vectorized hash probe over the big column; the exact
+            # (path, dv_id) check runs only on the few candidates)
+            hit = np.asarray(
+                pc.is_in(prev.file_actions_raw.column("path"),
+                         value_set=pa.array(touched, pa.string())
+                         ).combine_chunks(),
+                dtype=bool)
+            cand = np.nonzero(hit & (prev.live_mask | prev.tombstone_mask))[0]
+        if cand.size:
+            sub = prev.file_actions_raw.take(pa.array(cand, pa.int64()))
+            cleared = np.asarray(
+                [j for j, p, dv in zip(cand,
+                                       sub.column("path").to_pylist(),
+                                       sub.column("dv_id").to_pylist())
+                 if (p, dv) in winner], np.int64)
+        ph.set_attrs(touched=len(touched), candidates=int(cand.size),
+                     cleared=int(cleared.size))
+    with obs.span("advance.masks", _verbose=small,
+                  rows=n_prev + delta_fa.num_rows) as ph:
+        prev_live = prev.live_mask.copy()
+        prev_tomb = prev.tombstone_mask.copy()
+        prev_live[cleared] = False
+        prev_tomb[cleared] = False
+        live = np.concatenate([prev_live, d_live])
+        tomb = np.concatenate([prev_tomb, d_tomb])
+        ph.set_attr("bytes", live.nbytes + tomb.nbytes)
+    return live, tomb
+
+
+def _land_rows(prev, delta_fa):
+    """The held rows with the delta's behind them, the pending stats
+    decode chained on, if there is one, and the rows of the table's end
+    that were copied into one chunk (0: none)."""
+    new_raw = pa.concat_tables([prev.file_actions_raw, delta_fa])
+    stats_thunk = prev.stats_thunk and _chained_prev_stats(prev, delta_fa)
+    merged = 0
+    if not stats_thunk:
+        # with a decode pending, the stats column still comes chunk by
+        # chunk from the chain of thunks: merged then, the other
+        # columns' chunks would no longer line up with its own
+        new_raw, merged = _merge_small_chunks(new_raw)
+    return new_raw, stats_thunk, merged
 
 
 # Arrow's pool (mimalloc) keeps blocks of up to 2 GiB in its arenas; a
@@ -785,7 +834,9 @@ _MAX_SMALL_CHUNKS = 64
 _SMALL_CHUNK_ROWS = 1 << 16
 
 
-def _merge_small_chunks(table: pa.Table) -> pa.Table:
+def _merge_small_chunks(table: pa.Table) -> tuple[pa.Table, int]:
+    """`table` with the small chunks at its end made one, and the rows
+    that copied (0 where there were too few to merge)."""
     lengths = [len(c) for c in max(
         table.columns, key=lambda col: col.num_chunks).chunks]
     small = rows = 0
@@ -794,10 +845,10 @@ def _merge_small_chunks(table: pa.Table) -> pa.Table:
             break
         small, rows = small + 1, rows + n
     if small <= _MAX_SMALL_CHUNKS:
-        return table
+        return table, 0
     at = table.num_rows - rows
     return pa.concat_tables([table.slice(0, at),
-                             table.slice(at).combine_chunks()])
+                             table.slice(at).combine_chunks()]), rows
 
 
 def _chained_prev_stats(prev: SnapshotState, delta_fa: Optional[pa.Table]):

@@ -347,10 +347,13 @@ class ResidentStatsIndex:
         from delta_tpu.ops.stats import _x64
 
         n_pad = self.vals.shape[1]
-        lane_vals = np.asarray(self.vals, np.int64)
-        # bit k of word j is file 32 j + k (n_pad is a multiple of 128)
-        valid_words = np.packbits(np.asarray(self.valid, bool), axis=1,
-                                  bitorder="little").view("<u4")
+        with obs.span("index.pack_valid",
+                      _verbose=self.n < obs.PHASE_SPAN_ROWS,
+                      lanes=len(self.vals), bytes=self.valid.nbytes):
+            lane_vals = np.asarray(self.vals, np.int64)
+            # bit k of word j is file 32 j + k (n_pad is a multiple of 128)
+            valid_words = np.packbits(np.asarray(self.valid, bool), axis=1,
+                                      bitorder="little").view("<u4")
         cells = lane_vals.shape[0] * n_pad
         with obs.span("stats.index_upload", rows=self.n,
                       bytes=lane_vals.nbytes + valid_words.nbytes), \
@@ -435,13 +438,26 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
     from delta_tpu.stats.skipping import StatsIndex, stat_leaf_types
 
     leaf_types = {} if metadata is None else stat_leaf_types(metadata)
-    arrow_index = StatsIndex.from_stats_column(files.column("stats"),
-                                               leaf_types=leaf_types)
+    small = files.num_rows < obs.PHASE_SPAN_ROWS
+    with obs.span("index.parse", _verbose=small, rows=files.num_rows):
+        arrow_index = StatsIndex.from_stats_column(files.column("stats"),
+                                                   leaf_types=leaf_types)
+    with obs.span("index.encode", _verbose=small, rows=arrow_index.n) as ph:
+        vals, valid, cols, unindexed = _encode_all(arrow_index, leaf_types)
+        ph.set_attr("lanes", 0 if vals is None else len(vals))
+    return ResidentStatsIndex(arrow_index, vals, valid, cols, arrow_index.n,
+                              table_path=table_path, version=version,
+                              unindexed=unindexed)
+
+
+def _encode_all(arrow_index, leaf_types):
+    """(lane matrix, validity plane, {leaf: (min row, kind)}, {why: leaves
+    left off the lanes}) of every row of a parsed index; no matrix where
+    no leaf is eligible."""
     n = arrow_index.n
     table = arrow_index._table
     if table is None:
-        return ResidentStatsIndex(arrow_index, None, None, {}, n,
-                                  table_path=table_path, version=version)
+        return None, None, {}, {}
 
     names = table.column_names
     mins = table.column("minValues").combine_chunks() \
@@ -451,8 +467,7 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
     if (mins is None or maxs is None
             or not pa.types.is_struct(mins.type)
             or not pa.types.is_struct(maxs.type)):
-        return ResidentStatsIndex(arrow_index, None, None, {}, n,
-                                  table_path=table_path, version=version)
+        return None, None, {}, {}
 
     lanes: List[Tuple[np.ndarray, np.ndarray]] = []
     cols: Dict[tuple, Tuple[int, str]] = {}
@@ -474,18 +489,14 @@ def build_index(files: pa.Table, table_path: Optional[str] = None,
         cols[path] = (len(lanes), kind)
         lanes.extend(encoded)
     if not cols:
-        return ResidentStatsIndex(arrow_index, None, None, {}, n,
-                                  table_path=table_path, version=version,
-                                  unindexed=unindexed)
+        return None, None, {}, unindexed
     lanes.append(_encode_count(arrow_index.num_records(), n))
 
     vals, valid = _lanes_of(len(lanes), n)
     for r, (ev, eva) in enumerate(lanes):
         vals[r, :n] = ev
         valid[r, :n] = eva
-    return ResidentStatsIndex(arrow_index, vals, valid, cols, n,
-                              table_path=table_path, version=version,
-                              unindexed=unindexed)
+    return vals, valid, cols, unindexed
 
 
 def _cannot_append(reason: str):
@@ -523,40 +534,53 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
     if len(survivors) != seed.n or len(tail_stats) != n_tail:
         return _cannot_append("row-count")
 
-    if n_tail:
-        tail = StatsIndex.from_stats_column(
-            tail_stats, schema=seed.table.schema,
-            leaf_types=None if metadata is None
-            else stat_leaf_types(metadata))
-        if tail._table is None:
-            # a leaf the seed lacks, a leaf of another type (an int
-            # column's first float), a non-finite token, or no stats on
-            # any new row: an inferring parse of every row may read
-            # those, under another schema than the seed's
-            return _cannot_append(_why_unread(tail_stats, seed.table.schema))
-    else:
-        tail = StatsIndex(seed.table.schema.empty_table(), 0)
+    small = seed.n < obs.PHASE_SPAN_ROWS
+    with obs.span("index.parse", _verbose=small, rows=n_tail):
+        if n_tail:
+            tail = StatsIndex.from_stats_column(
+                tail_stats, schema=seed.table.schema,
+                leaf_types=None if metadata is None
+                else stat_leaf_types(metadata))
+        else:
+            tail = StatsIndex(seed.table.schema.empty_table(), 0)
+    if tail._table is None:
+        # a leaf the seed lacks, a leaf of another type (an int
+        # column's first float), a non-finite token, or no stats on
+        # any new row: an inferring parse of every row may read
+        # those, under another schema than the seed's
+        return _cannot_append(_why_unread(tail_stats, seed.table.schema))
 
-    vals, valid = _lanes_of(len(seed.vals), n)
     dropped = seed.n - n_kept
-    for r in range(len(seed.vals)):
-        old_vals, old_valid = seed.vals[r, :seed.n], seed.valid[r, :seed.n]
-        vals[r, :n_kept] = old_vals[survivors] if dropped else old_vals
-        valid[r, :n_kept] = old_valid[survivors] if dropped else old_valid
-    for path, (row0, kind) in seed.cols.items():
-        encoded = _encode_column(tail.min_values(path), tail.max_values(path),
-                                 tail.null_count(path), kind)
-        if encoded is None:
-            return _cannot_append("tail-encode")
-        for r, (ev, eva) in enumerate(encoded, row0):
-            vals[r, n_kept:n] = ev
-            valid[r, n_kept:n] = eva
-    ev, eva = _encode_count(tail.num_records(), n_tail)
-    vals[-1, n_kept:n] = ev
-    valid[-1, n_kept:n] = eva
+    with obs.span("index.compact_lanes", _verbose=small,
+                  lanes=len(seed.vals), rows=seed.n, dropped=dropped) as ph:
+        vals, valid = _lanes_of(len(seed.vals), n)
+        for r in range(len(seed.vals)):
+            old_vals, old_valid = seed.vals[r, :seed.n], seed.valid[r, :seed.n]
+            vals[r, :n_kept] = old_vals[survivors] if dropped else old_vals
+            valid[r, :n_kept] = old_valid[survivors] if dropped else old_valid
+        ph.set_attr("bytes", vals.nbytes + valid.nbytes)
+    with obs.span("index.encode", _verbose=small, rows=n_tail,
+                  lanes=len(seed.vals)):
+        for path, (row0, kind) in seed.cols.items():
+            encoded = _encode_column(tail.min_values(path),
+                                     tail.max_values(path),
+                                     tail.null_count(path), kind)
+            if encoded is None:
+                return _cannot_append("tail-encode")
+            for r, (ev, eva) in enumerate(encoded, row0):
+                vals[r, n_kept:n] = ev
+                valid[r, n_kept:n] = eva
+        ev, eva = _encode_count(tail.num_records(), n_tail)
+        vals[-1, n_kept:n] = ev
+        valid[-1, n_kept:n] = eva
 
-    kept = seed.table.filter(pa.array(survivors)) if dropped else seed.table
-    table = pa.concat_tables([kept, tail._table]).combine_chunks()
+    with obs.span("index.compact_table", _verbose=small, rows=seed.n,
+                  columns=seed.table.num_columns) as ph:
+        kept = seed.table.filter(pa.array(survivors)) if dropped \
+            else seed.table
+        table = pa.concat_tables([kept, tail._table]).combine_chunks()
+        if ph.recording:
+            ph.set_attr("bytes", table.nbytes)
     idx = ResidentStatsIndex(StatsIndex(table, n), vals, valid, seed.cols,
                              n, table_path=table_path, version=version,
                              unindexed=seed.unindexed)
@@ -719,6 +743,7 @@ def snapshot_stats_index(state, files: Optional[pa.Table] = None,
         version = getattr(state, "version", None)
         seed = getattr(state, "stats_index_seed", None)
         n = files.num_rows if files is not None else len(state.live_rows)
+        small = n < obs.PHASE_SPAN_ROWS
         with obs.span("stats.index_build", rows=n) as sp:
             idx = None
             if seed is not None:
@@ -726,8 +751,11 @@ def snapshot_stats_index(state, files: Optional[pa.Table] = None,
                 # only an advanced `SnapshotState` has a seed: the
                 # tail's stats are in the rows it holds, past the seed's
                 n_base = len(seed.base_live)
-                stats = state.file_actions.column("stats").slice(
-                    n_base).filter(pa.array(state.live_mask[n_base:]))
+                with obs.span("index.read_stats", _verbose=small) as ph:
+                    stats = state.file_actions.column("stats").slice(
+                        n_base).filter(pa.array(state.live_mask[n_base:]))
+                    if ph.recording:
+                        ph.set_attrs(rows=len(stats), bytes=stats.nbytes)
                 idx, attrs = append_index(seed, state.live_mask, stats,
                                           table_path, version, metadata)
                 sp.set_attrs(**attrs)
@@ -740,7 +768,13 @@ def snapshot_stats_index(state, files: Optional[pa.Table] = None,
                 else:   # a state loaded in full: nothing to append to
                     sp.set_attr("reason", "no_seed")
                 if files is None:
-                    files = state.live_columns(["stats"])
+                    with obs.span("index.read_stats",
+                                  _verbose=small) as ph:
+                        files = state.live_columns(["stats"])
+                        if ph.recording:
+                            ph.set_attrs(
+                                rows=files.num_rows,
+                                bytes=files.column("stats").nbytes)
                 stats = files.column("stats")
                 idx = build_index(files, table_path, version, metadata)
                 sp.set_attr("mode", "full")
