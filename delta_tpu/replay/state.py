@@ -563,7 +563,8 @@ def advance_state(
     A resident stats index (`stats/device_index.py`) survives only an
     EMPTY delta: any landed file action releases it here, device copy
     and ledger entry at once. The new state keeps a seed of it
-    (references to its lanes and parsed table, and `prev`'s live mask),
+    (references to its lanes, to its parsed rows as it carried them,
+    no table made of them here, and `prev`'s live mask),
     or the seed `prev` was itself still holding, and its first filtered
     scan makes the new index from that: the rows still live, and the
     stats of the rows landed since. The `update.advance` span says

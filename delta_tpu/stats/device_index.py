@@ -34,7 +34,7 @@ from it. `replay/state.py::advance_state` hands the index itself to the
 new state across an EMPTY delta. One landed file action releases it
 (host lanes dropped, device copy and ledger entry freed at once) and
 leaves a `StatsIndexSeed` on the new state: references to the released
-index's lanes, kinds and parsed table, and the live mask they were
+index's lanes, kinds and parsed rows, and the live mask they were
 built over. Live bits of prior rows are only ever cleared and new rows
 only ever land behind them, so the first filtered scan of the new
 version (`snapshot_stats_index`, span `stats.index_build`, `mode`
@@ -42,7 +42,12 @@ version (`snapshot_stats_index`, span `stats.index_build`, `mode`
 of the rows landed since (read out of the rows the state holds, past
 the seed's) under the seed's schema, and writes them behind: the cost
 is that of the rows that changed, and the result is what `build_index`
-over every live file gives. Where that cannot be
+over every live file gives. The lanes are what a refresh brings
+forward. The parsed Arrow table, which only the fallback ladder reads,
+is derived: an append hands its rows on as they came
+(`stats/skipping.py::ParsedPieces`: the pieces, and which of their rows
+have gone), and `StatsIndex` makes the one table of them when a reader
+first asks for a leaf, under its lock, once. Where that cannot be
 shown from the seed (`_APPEND_FALLBACKS`, the span's
 `append_fallback`), and on a state with no seed, every live file's
 stats string is parsed (`mode` `full`). The seed's arrays are never
@@ -78,11 +83,16 @@ from delta_tpu.expressions.tree import (
     Or,
 )
 from delta_tpu.ops.skipping import AtomBlock
+from delta_tpu.stats.skipping import ParsedPieces
 
 _BUILDS = obs.counter("scan.stats_index_builds")
 _APPENDS = obs.counter("scan.stats_index_appends")
 _APPEND_FALLBACKS = obs.counter("scan.stats_index_append_fallbacks")
 _REUSES = obs.counter("scan.stats_index_reuses")
+# an append that handed the parsed rows on as pieces; the table made of
+# them for a reader counts in `scan.stats_index_table_builds`
+# (`stats/skipping.py::ParsedPieces.combined`)
+_TABLE_DEFERRED = obs.counter("scan.stats_index_table_deferred")
 # leaves that carry min/max stats and got no lane, by why: an index
 # that cannot read a table's schema shows here, not in a scan's bill
 _UNINDEXED = {
@@ -279,23 +289,25 @@ def _unpack_valid_fn(n_pad: int):
 @dataclass(frozen=True)
 class StatsIndexSeed:
     """What is kept of an index released by a version advance, to make
-    the next one from: its lanes, kinds and parsed table (read, never
-    written to), and the live mask over the raw rows of its version,
-    whose set bits its `n` rows were."""
+    the next one from: its lanes, kinds and parsed rows (read, never
+    written to; the rows as the index carried them,
+    `stats/skipping.py::ParsedPieces`: a seed makes no table), and the
+    live mask over the raw rows of its version, whose set bits its `n`
+    rows were."""
 
     vals: Optional[np.ndarray]
     valid: Optional[np.ndarray]
     cols: Dict[tuple, Tuple[int, str]]
-    table: Optional[pa.Table]
+    parsed: Optional[ParsedPieces]
     n: int
     base_live: np.ndarray
     unindexed: Dict[str, int]
 
 
 class ResidentStatsIndex:
-    """Per-snapshot-version stats index: the parsed Arrow table (shared
-    with the host fallback ladder) plus the encoded int64 lanes, with a
-    lazily uploaded device copy."""
+    """Per-snapshot-version stats index: the parsed stats rows (the
+    host fallback ladder's, an Arrow table once it asks) plus the
+    encoded int64 lanes, with a lazily uploaded device copy."""
 
     def __init__(self, arrow_index, vals: Optional[np.ndarray],
                  valid: Optional[np.ndarray],
@@ -328,7 +340,7 @@ class ResidentStatsIndex:
             if self.released:
                 return None
             return StatsIndexSeed(self.vals, self.valid, self.cols,
-                                  self.arrow_index._table, self.n,
+                                  self.arrow_index.carried(), self.n,
                                   base_live, self.unindexed)
 
     def device_lanes(self):
@@ -535,20 +547,21 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
         return _cannot_append("row-count")
 
     small = seed.n < obs.PHASE_SPAN_ROWS
+    schema = seed.parsed.schema
     with obs.span("index.parse", _verbose=small, rows=n_tail):
         if n_tail:
             tail = StatsIndex.from_stats_column(
-                tail_stats, schema=seed.table.schema,
+                tail_stats, schema=schema,
                 leaf_types=None if metadata is None
                 else stat_leaf_types(metadata))
         else:
-            tail = StatsIndex(seed.table.schema.empty_table(), 0)
-    if tail._table is None:
+            tail = StatsIndex(schema.empty_table(), 0)
+    if tail.schema is None:
         # a leaf the seed lacks, a leaf of another type (an int
         # column's first float), a non-finite token, or no stats on
         # any new row: an inferring parse of every row may read
         # those, under another schema than the seed's
-        return _cannot_append(_why_unread(tail_stats, seed.table.schema))
+        return _cannot_append(_why_unread(tail_stats, schema))
 
     dropped = seed.n - n_kept
     with obs.span("index.compact_lanes", _verbose=small,
@@ -574,14 +587,15 @@ def append_index(seed: StatsIndexSeed, live_mask: np.ndarray,
         vals[-1, n_kept:n] = ev
         valid[-1, n_kept:n] = eva
 
-    with obs.span("index.compact_table", _verbose=small, rows=seed.n,
-                  columns=seed.table.num_columns) as ph:
-        kept = seed.table.filter(pa.array(survivors)) if dropped \
-            else seed.table
-        table = pa.concat_tables([kept, tail._table]).combine_chunks()
-        if ph.recording:
-            ph.set_attr("bytes", table.nbytes)
-    idx = ResidentStatsIndex(StatsIndex(table, n), vals, valid, seed.cols,
+    # the parsed rows go on as they came, the seed's pieces and the
+    # tail's behind them: only the ladder reads them, and the table is
+    # made when it first does (`StatsIndex._table`)
+    parsed = seed.parsed.advanced(
+        np.flatnonzero(~survivors) if dropped else np.zeros(0, np.int64),
+        tail._table)
+    if isinstance(parsed, ParsedPieces):
+        _TABLE_DEFERRED.inc()
+    idx = ResidentStatsIndex(StatsIndex(parsed, n), vals, valid, seed.cols,
                              n, table_path=table_path, version=version,
                              unindexed=seed.unindexed)
     return idx, {"rows": n_tail, "dropped": dropped}

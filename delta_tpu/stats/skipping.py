@@ -22,7 +22,9 @@ conjuncts — but only when both counts are present.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import threading
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Union
 
 import numpy as np
 import pyarrow as pa
@@ -45,6 +47,7 @@ from delta_tpu.expressions.tree import (
 _DEVICE_PLANS = obs.counter("scan.device_plans")
 _DEVICE_FALLBACKS = obs.counter("scan.device_fallbacks")
 _UNCOMPARED = obs.counter("scan.skip_uncompared_conjuncts")
+_TABLE_BUILDS = obs.counter("scan.stats_index_table_builds")
 
 _ARROW_ERRS = (pa.ArrowInvalid, pa.ArrowNotImplementedError,
                pa.ArrowTypeError)
@@ -146,12 +149,111 @@ def _typed_stats(parsed: pa.Table, leaf_types: Dict[tuple, str],
     return parsed
 
 
-class StatsIndex:
-    """Parsed stats for a batch of files."""
+# Rows of `ParsedPieces` that are no longer a version's stay where they
+# are until they pass this share of the rows held (1 / N, the rule a
+# crossing sends a state to the full load by): then the pieces are
+# combined without them, which costs what every refresh paid before the
+# table was deferred, once in (rows held / N) dropped rows.
+_DEAD_ROWS_SHARE = 8
 
-    def __init__(self, table: Optional[pa.Table], n: int):
-        self._table = table
+
+@dataclass(frozen=True)
+class ParsedPieces:
+    """The parsed stats rows of an index brought forward from the one
+    before, as they were handed on and not yet as one table: `table`,
+    whose chunks are the pieces (the seed's, then one parsed tail an
+    append, none of them copied), and `dead`, the numbers of its rows,
+    ascending, that are no version's any more. Read, never written to:
+    the index before and the seed between hold the same buffers."""
+
+    table: pa.Table
+    dead: np.ndarray
+
+    @property
+    def schema(self) -> pa.Schema:
+        return self.table.schema
+
+    def advanced(self, dropped: np.ndarray,
+                 tail: pa.Table) -> Union["ParsedPieces", pa.Table]:
+        """The rows of the next version: these less `dropped` (numbers
+        among the rows still a version's, ascending), `tail`'s behind
+        them. O(dropped + dead) look-ups and no copy of a row, until
+        one of two fixed rules bounds what is carried: the small pieces
+        at the end are merged as the held rows' chunks are
+        (`replay/state.py::_merge_small_chunks`), and past
+        `_DEAD_ROWS_SHARE` the rows are combined here and now (a
+        table)."""
+        from delta_tpu.replay.state import _merge_small_chunks
+
+        dead = self.dead
+        if len(dropped):
+            # dead row i has dead[i] - i live rows before it, so that
+            # many of the live rows come before it and the rest after
+            before = np.searchsorted(dead - np.arange(len(dead)), dropped,
+                                     side="right")
+            at = dropped + before
+            dead = np.insert(dead, np.searchsorted(dead, at), at)
+        table = pa.concat_tables([self.table, tail]) if tail.num_rows \
+            else self.table
+        if len(dead) * _DEAD_ROWS_SHARE > table.num_rows:
+            return ParsedPieces(table, dead).combined()
+        return ParsedPieces(_merge_small_chunks(table)[0], dead)
+
+    def combined(self) -> pa.Table:
+        """One table of the rows that are this version's, in order."""
+        table = self.table
+        with obs.span("index.compact_table",
+                      _verbose=table.num_rows < obs.PHASE_SPAN_ROWS,
+                      rows=table.num_rows, columns=table.num_columns,
+                      deferred_pieces=table.column(0).num_chunks) as ph:
+            if len(self.dead):
+                keep = np.ones(table.num_rows, bool)
+                keep[self.dead] = False
+                table = table.filter(pa.array(keep))
+            table = table.combine_chunks()
+            if ph.recording:
+                ph.set_attr("bytes", table.nbytes)
+        _TABLE_BUILDS.inc()
+        return table
+
+
+class StatsIndex:
+    """Parsed stats for a batch of files: one Arrow table, a row a
+    file. An index brought forward from the one before
+    (`stats/device_index.py::append_index`) is given the rows as
+    `ParsedPieces` and makes the table of them when a reader first asks
+    for a leaf, once; one that no reader asks never does."""
+
+    def __init__(self, rows: Union[pa.Table, ParsedPieces, None], n: int):
+        self._lock = threading.Lock()
+        self._rows = rows
         self.n = n
+
+    @property
+    def schema(self) -> Optional[pa.Schema]:
+        """The parsed table's schema, None where no stats parsed;
+        combines nothing."""
+        rows = self._rows
+        return None if rows is None else rows.schema
+
+    @property
+    def _table(self) -> Optional[pa.Table]:
+        rows = self._rows
+        if isinstance(rows, ParsedPieces):
+            with self._lock:
+                rows = self._rows
+                if isinstance(rows, ParsedPieces):
+                    rows = self._rows = rows.combined()
+        return rows
+
+    def carried(self) -> Optional[ParsedPieces]:
+        """The rows as the next version's index takes them on: the
+        pieces while no reader has asked for the table, the table as
+        one piece once one has."""
+        rows = self._rows
+        if rows is None or isinstance(rows, ParsedPieces):
+            return rows
+        return ParsedPieces(rows, np.zeros(0, np.int64))
 
     @staticmethod
     def from_stats_column(
@@ -212,20 +314,26 @@ class StatsIndex:
 
     def _leaf(self, group: str, name_path: tuple) -> Optional[np.ndarray]:
         """Return (values, valid) for e.g. group='minValues', col path.
-        None when the column isn't in the index."""
-        if self._table is None or group not in self._table.column_names:
+        None when the column isn't in the index, which the schema says:
+        only a leaf that is there has the table made for it."""
+        schema = self.schema
+        if schema is None or group not in schema.names:
             return None
-        arr = self._table.column(group).combine_chunks()
-        if not pa.types.is_struct(arr.type):
+        t = schema.field(group).type
+        if not pa.types.is_struct(t):
             return None
         for part in name_path:
-            if not pa.types.is_struct(arr.type) or arr.type.get_field_index(part) < 0:
+            if not pa.types.is_struct(t) or t.get_field_index(part) < 0:
                 return None
+            t = t.field(part).type
+        arr = self._table.column(group).combine_chunks()
+        for part in name_path:
             arr = pc.struct_field(arr, part)
         return arr
 
     def num_records(self):
-        if self._table is None or "numRecords" not in self._table.column_names:
+        schema = self.schema
+        if schema is None or "numRecords" not in schema.names:
             return None
         return self._table.column("numRecords").combine_chunks()
 
@@ -491,7 +599,7 @@ def skipping_mask(
             files.column("stats"),
             leaf_types=None if metadata is None
             else stat_leaf_types(metadata))
-    if index._table is None:
+    if index.schema is None:
         return keep
     if (
         metadata is not None

@@ -3,7 +3,10 @@
 `stats.index_upload`, on a streaming table at a test's size on the CPU
 (built as `tests/chipbench/test_chipbench_stream.py` builds its
 `traced_ops`): where each span sits, what it says, that a plan which
-follows no landed commit opens none of them, that tracing changes
+follows no landed commit opens none of them, that `index.compact_table`
+(PR 36) opens under the first plan of a version that walks the fallback
+ladder and under no refresh whose plans compile to the lanes, that
+tracing changes
 nothing of the state a refresh returns, that the harness's idle-gap
 table names the child and not the parent, and that mode `on` records the
 phases from `obs.PHASE_SPAN_ROWS` rows held and not below."""
@@ -36,12 +39,15 @@ BUILD_APPEND = {
     "index.parse": {"rows"},
     "index.compact_lanes": {"lanes", "rows", "dropped", "bytes"},
     "index.encode": {"rows", "lanes"},
-    "index.compact_table": {"rows", "columns", "bytes"},
 }
 BUILD_FULL = {name: BUILD_APPEND[name] for name in (
     "index.read_stats", "index.parse", "index.encode")}
 PACK = {"index.pack_valid": {"lanes", "bytes"}}
-NEW = {**ADVANCE, **BUILD_APPEND, **PACK, "advance.resident_append": set()}
+# the parsed table, made when a plan first walks the ladder
+LADDER = {"index.compact_table": {"rows", "columns", "bytes",
+                                  "deferred_pieces"}}
+NEW = {**ADVANCE, **BUILD_APPEND, **PACK, **LADDER,
+       "advance.resident_append": set()}
 
 
 class Traced:
@@ -85,6 +91,16 @@ def traced(fn, mode="verbose"):
         obs.reset_trace_buffer()
 
 
+def plan_by_ladder(snapshot):
+    """`LO <= x < HI` with the upper bound as a float, which no int lane
+    takes: that conjunct walks the Arrow ladder, and keeps the same
+    files."""
+    from delta_tpu.expressions import col, lit
+
+    pred = (col("x") >= lit(LO)) & (col("x") < lit(HI - 0.5))
+    return snapshot.scan(filter=pred).file_paths()
+
+
 def land_empty_commit(manifest):
     """A commit that carries no file action: a writer's `txn` alone."""
     version = manifest.version + 1
@@ -98,9 +114,11 @@ def land_empty_commit(manifest):
 @pytest.fixture(scope="module")
 def ops(tmp_path_factory):
     """On the kernel's route: the index's first build on a state loaded
-    in full, a plan that follows no landed commit, a refresh, a refresh
-    over a commit with no file action, all traced; and beside them, on
-    the same table made twice, the same refresh with tracing off."""
+    in full, a plan that follows no landed commit, a refresh, the first
+    plan of that version that walks the ladder and the one after it, a
+    refresh over a commit with no file action, all traced; and beside
+    them, on the same table made twice, the same refresh with tracing
+    off."""
     from delta_tpu import obs
 
     with pytest.MonkeyPatch.context() as mp:
@@ -125,6 +143,9 @@ def ops(tmp_path_factory):
         (on, paths), out["append"] = traced(refresh)
         assert len(paths) == len(m.scan_expected(LO, HI))
         out["on"] = on
+        for kind in ("ladder", "ladder_again"):
+            got, out[kind] = traced(lambda: plan_by_ladder(on))
+            assert sorted(got) == sorted(paths)
 
         quiet = deltastream.generate(str(tmp_path_factory.mktemp("off")),
                                      PARAMS, seed=5)
@@ -147,7 +168,8 @@ def ops(tmp_path_factory):
 CASES = ([("append", name, "update.advance") for name in ADVANCE]
          + [("append", name, "stats.index_build") for name in BUILD_APPEND]
          + [("full", name, "stats.index_build") for name in BUILD_FULL]
-         + [("append", "index.pack_valid", "plan.skip"),
+         + [("ladder", "index.compact_table", "plan.skip"),
+            ("append", "index.pack_valid", "plan.skip"),
             ("full", "index.pack_valid", "plan.skip")])
 
 
@@ -210,9 +232,11 @@ def test_the_phases_say_what_happened(ops):
     assert compact["rows"] == ops["full"].one("index.encode")["attrs"]["rows"]
     assert compact["bytes"] == 4 * 4096 * 9     # int64 lanes, bool plane
     assert attrs("index.encode") == {"rows": 80, "lanes": 4}
-    table = attrs("index.compact_table")
-    assert table["rows"] == compact["rows"] and table["columns"] >= 3
-    assert table["bytes"] > 0
+    # the full build's table and the refresh's tail, the 20 rows that
+    # went still among them
+    table = attrs("index.compact_table", "ladder")
+    assert table["rows"] == compact["rows"] + 80 and table["columns"] >= 3
+    assert table["bytes"] > 0 and table["deferred_pieces"] == 2
     assert attrs("index.pack_valid") == {"lanes": 4, "bytes": 4 * 4096}
 
     full = attrs("index.read_stats", "full")
@@ -220,6 +244,19 @@ def test_the_phases_say_what_happened(ops):
     assert attrs("index.encode", "full")["lanes"] == 4
     assert not ops["full"].named("index.compact_lanes")
     assert not ops["full"].named("index.compact_table")
+
+
+@pytest.mark.parametrize("kind", ["append", "full", "plan", "empty",
+                                  "ladder_again"])
+def test_the_table_is_made_for_the_first_ladder_plan_alone(ops, kind):
+    """No refresh whose plans compile to the lanes, no first build (it
+    has the table in hand), no later plan of a version makes the parsed
+    table: the first one that walks the ladder did."""
+    assert ops[kind].named("plan.skip")
+    assert not ops[kind].named("index.compact_table")
+    if kind == "ladder_again":
+        skip = ops[kind].one("plan.skip")["attrs"]
+        assert skip["skip_fallback_conjuncts"] == 1
 
 
 @pytest.mark.parametrize("kind", ["append", "full"])
@@ -256,23 +293,26 @@ def test_tracing_off_leaves_no_span_and_the_same_state(ops):
     assert np.array_equal(lanes_on.valid, lanes_off.valid)
 
 
-@pytest.mark.parametrize("child,parent", [
-    ("advance.probe", "update.advance"),
-    ("index.compact_lanes", "stats.index_build"),
-    ("index.pack_valid", "plan.skip"),
+@pytest.mark.parametrize("kind,child,parent", [
+    ("append", "advance.probe", "update.advance"),
+    ("append", "index.compact_lanes", "stats.index_build"),
+    ("append", "index.pack_valid", "plan.skip"),
+    ("ladder", "index.compact_table", "plan.skip"),
 ])
-def test_an_idle_moment_goes_to_the_child_not_the_parent(ops, child, parent):
+def test_an_idle_moment_goes_to_the_child_not_the_parent(ops, kind, child,
+                                                         parent):
     """The harness's own table (`trace_reduce.idle_by_host`) over the
-    written-out refresh, on a chip that ran nothing: the time a child is
-    open is the child's, and what is left of its parent the parent's."""
-    spans = ops["append"].spans
+    written-out operation, on a chip that ran nothing: the time a child
+    is open is the child's, and what is left of its parent the
+    parent's."""
+    spans = ops[kind].spans
     host = [(s["name"], s["start_unix_ns"],
              s["start_unix_ns"] + s["duration_ns"]) for s in spans]
-    top, kid = ops["append"].one(parent), ops["append"].one(child)
+    top, kid = ops[kind].one(parent), ops[kind].one(child)
     window = (top["start_unix_ns"], top["start_unix_ns"] + top["duration_ns"])
     idle = dict(trace_reduce.Reduced(window, [[]]).idle_by_host(host))
     assert idle[child] == pytest.approx(kid["duration_ns"] / 1e9, rel=0.02)
-    covered = sum(s["duration_ns"] for s in ops["append"].children(parent))
+    covered = sum(s["duration_ns"] for s in ops[kind].children(parent))
     assert idle.get(parent, 0) <= (top["duration_ns"] - covered) / 1e9 + 1e-4
 
 
@@ -292,9 +332,10 @@ def test_mode_on_records_the_phases_of_a_large_table_alone(tmp_path):
         m.land(1)
 
         def refresh():
-            return system.plan(system.refresh(table), LO, HI)
+            snap = system.refresh(table)
+            return snap, system.plan(snap, LO, HI)
 
-        _, got = traced(refresh, mode="on")
+        (snap, _), got = traced(refresh, mode="on")
         rows[name] = got.one("update.advance")["attrs"]["prev_rows"]
         # `index.pack_valid` apart: it is there where the plan took the
         # kernel's route (`ops` forces it for as long as it lives)
@@ -302,6 +343,8 @@ def test_mode_on_records_the_phases_of_a_large_table_alone(tmp_path):
         assert got.one("stats.index_build")["attrs"]["mode"] == "append"
         assert phases == (set() if name == "small" else
                           set(ADVANCE) | set(BUILD_APPEND))
+        _, got = traced(lambda: plan_by_ladder(snap), mode="on")
+        assert len(got.named("index.compact_table")) == (name == "large")
     assert rows["small"] < obs.PHASE_SPAN_ROWS <= rows["large"]
 
 
