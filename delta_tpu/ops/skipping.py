@@ -70,6 +70,10 @@ class AtomBlock(NamedTuple):
     grp: np.ndarray      # int32 [A] OR-group id, dense in [0, n_groups)
     n_atoms: int
     n_groups: int
+    # for the plan's span, read by no kernel: the atoms on `decimal`
+    # lanes, and the conjuncts that were an OR over ANDs, distributed
+    decimal_atoms: int = 0
+    distributed: int = 0
 
 
 def _known_false(xp, mn, mx, nc, nr, vmn, vmx, vnc, vnr, ops, lits):

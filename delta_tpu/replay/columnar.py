@@ -31,6 +31,7 @@ import pyarrow.compute as pc
 import pyarrow.json as pa_json
 
 from delta_tpu import obs
+from delta_tpu.utils.chunks import pieces
 from delta_tpu.models.actions import (
     CommitInfo,
     DomainMetadata,
@@ -281,6 +282,10 @@ def _decode_paths(arr: pa.Array) -> pa.Array:
     return pa.array([unquote(p) if p is not None and "%" in p else p for p in py], pa.string())
 
 
+# the rule `replay/state.py::_filter_rows` has, for the same column
+_COMBINE_WHOLE_BYTES = 1 << 30
+
+
 def _extract_file_actions(
     table: pa.Table,
     col: str,
@@ -294,8 +299,56 @@ def _extract_file_actions(
     struct_chunks = table.column(col)
     if struct_chunks.null_count == len(struct_chunks):
         return None
+    is_add = col == "add"
+    if struct_chunks.nbytes > _COMBINE_WHOLE_BYTES:
+        # a checkpoint of a table at a fact table's width: its stats
+        # strings pass what one chunk's offsets reach, so the rows are
+        # brought to the canonical schema a chunk of the file's at a
+        # time, as they lie. A narrower column takes the one call it
+        # always took
+        blocks, at = [], 0
+        for piece in pieces(struct_chunks, _COMBINE_WHOLE_BYTES, join=False):
+            rows = slice(at, at + len(piece))
+            at += len(piece)
+            block = _extract_structs(piece, is_add, versions[rows],
+                                     orders[rows])
+            if block is not None:
+                blocks.append(block)
+        if not blocks:
+            return None
+        # whoever gathers rows out of the table pays for each chunk of
+        # each column (`replay/state.py::gather_rows`). While the copy
+        # that takes is no more than the limit twice, the rows are
+        # joined into pieces under the limit, every column at the same
+        # rows; wider (a second copy of 4 GB beside the file's table is
+        # what the host may not have), the wide column stays the file's
+        # chunks and every column under the limit is made one chunk
+        table = pa.concat_tables(blocks)
+        widest = max(table.columns, key=lambda col: col.nbytes)
+        if widest.nbytes > 2 * _COMBINE_WHOLE_BYTES:
+            return pa.Table.from_arrays(
+                [col if col.nbytes > _COMBINE_WHOLE_BYTES
+                 else pa.chunked_array([col.combine_chunks()], col.type)
+                 for col in table.columns], schema=table.schema)
+        ends, rows, size = [], 0, 0
+        for chunk in widest.chunks:
+            if size and size + chunk.nbytes > _COMBINE_WHOLE_BYTES:
+                ends.append(rows)
+                size = 0
+            rows, size = rows + len(chunk), size + chunk.nbytes
+        return pa.concat_tables(
+            [table.slice(lo, hi - lo).combine_chunks()
+             for lo, hi in zip([0] + ends, ends + [rows])])
     with obs.span("canonicalize.combine", rows=len(struct_chunks)):
         struct_arr = struct_chunks.combine_chunks()
+    return _extract_structs(struct_arr, is_add, versions, orders)
+
+
+def _extract_structs(struct_arr: pa.Array, is_add: bool,
+                     versions: np.ndarray,
+                     orders: np.ndarray) -> Optional[pa.Table]:
+    """The add (or remove) structs of `struct_arr` that are there, in
+    the canonical schema."""
     if pa.types.is_null(struct_arr.type):
         return None
     valid = pc.is_valid(struct_arr)
@@ -305,10 +358,11 @@ def _extract_file_actions(
         return None
     with obs.span("canonicalize.filter", rows=len(struct_arr)):
         # filter, not take: selection-by-mask over a wide struct (stats
-        # strings, partitionValues maps) is ~2x faster than row gather
-        sub = struct_arr.filter(valid)
+        # strings, partitionValues maps) is ~2x faster than row gather;
+        # where every row is of the kind, nothing is copied
+        sub = struct_arr if sel.size == len(struct_arr) \
+            else struct_arr.filter(valid)
     n = len(sub)
-    is_add = col == "add"
     with obs.span("canonicalize.columns", rows=n):
         return _canonical_block(sub, n, is_add, versions[sel], orders[sel])
 

@@ -731,7 +731,13 @@ def _advance_masks_host(prev, delta_fa, small: bool):
                 dtype=bool)
             cand = np.nonzero(hit & (prev.live_mask | prev.tombstone_mask))[0]
         if cand.size:
-            sub = prev.file_actions_raw.take(pa.array(cand, pa.int64()))
+            # the candidates' two key columns, gathered out of the
+            # chunks: `Table.take` concatenates every column of a
+            # chunked table first (a copy of the whole held table at
+            # every refresh; past 2 GiB in an Arrow `string` column it
+            # cannot be done at all)
+            sub = gather_rows(prev.file_actions_raw.select(
+                ["path", "dv_id"]), cand)
             cleared = np.asarray(
                 [j for j, p, dv in zip(cand,
                                        sub.column("path").to_pylist(),
@@ -773,6 +779,10 @@ _FILTER_WHOLE_BYTES = 1 << 30
 _FILTER_SLICE_BYTES = 1 << 28
 
 
+def _widest_column(table: pa.Table) -> int:
+    return max((col.nbytes for col in table.columns), default=0)
+
+
 def _filter_rows(table: pa.Table, mask: np.ndarray) -> pa.Table:
     """`table.filter(mask)`; over a table with a column of more than
     1 GiB, a slice of rows at a time (zero-copy slices of ~256 MiB of
@@ -786,7 +796,7 @@ def _filter_rows(table: pa.Table, mask: np.ndarray) -> pa.Table:
     the rows that went happen to fall that way (PERF.md, Findings,
     PR 33). By slices a buffer that doubles stays a block the pool
     keeps. A narrower table takes the one call it always took."""
-    widest = max((col.nbytes for col in table.columns), default=0)
+    widest = _widest_column(table)
     if widest <= _FILTER_WHOLE_BYTES:
         return table.filter(pa.array(mask))
     rows = max(1, table.num_rows * _FILTER_SLICE_BYTES // widest)
@@ -808,7 +818,28 @@ def gather_rows(table: pa.Table, rows: np.ndarray) -> pa.Table:
     commit's files, kept whole), scattered rows by a take. (`Table.take`
     on a chunked table concatenates every column before it takes,
     pyarrow 25.0: 68 ms for 6,000 rows of 3M in two chunks, where this
-    takes under one.)"""
+    takes under one.) A take costs ~0.1 ms a chunk touched whatever it
+    takes, so where the columns lie in different chunks (a checkpoint's
+    stats past 1 GiB stay the file's twenty, the narrow columns are one
+    each: `replay/columnar.py::_extract_file_actions`) the columns of
+    each layout are gathered together and apart from the others'."""
+    if len({col.num_chunks for col in table.columns}) <= 1:
+        return _gather_batches(table, rows)     # one layout, or as good
+    layouts: dict = {}
+    for i, col in enumerate(table.columns):
+        layouts.setdefault(tuple(len(c) for c in col.chunks), []).append(i)
+    columns = [None] * table.num_columns
+    for mine in layouts.values():
+        for i, col in zip(mine, _gather_batches(table.select(mine),
+                                                rows).columns):
+            columns[i] = col
+    return pa.Table.from_arrays(columns, schema=table.schema)
+
+
+def _gather_batches(table: pa.Table, rows: np.ndarray) -> pa.Table:
+    """`gather_rows` of a table whose columns share their chunks (of
+    any table, at a take for every stretch between two chunk ends of
+    any column: `to_batches` cuts at them all)."""
     batches = table.to_batches()
     ends = np.cumsum([b.num_rows for b in batches])
     cuts = np.searchsorted(rows, ends)  # rows[cuts[i-1]:cuts[i]]: batch i's

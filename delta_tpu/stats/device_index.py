@@ -58,7 +58,9 @@ the lanes cross to the device whole, on the first device plan
 
 from __future__ import annotations
 
+import collections
 import datetime
+import decimal
 import functools
 import threading
 import weakref
@@ -72,6 +74,7 @@ import pyarrow.compute as pc
 from delta_tpu import obs
 from delta_tpu.obs import hbm
 from delta_tpu.expressions.tree import (
+    And,
     Column,
     Comparison,
     Expression,
@@ -83,12 +86,20 @@ from delta_tpu.expressions.tree import (
     Or,
 )
 from delta_tpu.ops.skipping import AtomBlock
-from delta_tpu.stats.skipping import ParsedPieces
+from delta_tpu.stats.skipping import (
+    DECIMAL_LANE_PRECISION,
+    ParsedPieces,
+    decimal_literal,
+)
 
 _BUILDS = obs.counter("scan.stats_index_builds")
 _APPENDS = obs.counter("scan.stats_index_appends")
 _APPEND_FALLBACKS = obs.counter("scan.stats_index_append_fallbacks")
 _REUSES = obs.counter("scan.stats_index_reuses")
+# an OR over ANDs compiled to the kernel's AND of OR-groups, and one
+# whose product of sides passed `IN_LIST_ATOM_LIMIT` atoms (the ladder's)
+_DISTRIBUTED = obs.counter("scan.skip_disjunctions_distributed")
+_TOO_WIDE = obs.counter("scan.skip_disjunctions_too_wide")
 # an append that handed the parsed rows on as pieces; the table made of
 # them for a reader counts in `scan.stats_index_table_builds`
 # (`stats/skipping.py::ParsedPieces.combined`)
@@ -122,6 +133,9 @@ IN_LIST_ATOM_LIMIT = 64
 _ARROW_ERRS = (pa.ArrowInvalid, pa.ArrowNotImplementedError,
                pa.ArrowTypeError)
 
+# the kinds of lane (`_lane_kind`), in the order `lane_kinds` names them
+_LANE_KINDS = ("bool", "int", "float", "ts", "tstz", "decimal")
+
 
 def _enc_f64(a: np.ndarray) -> np.ndarray:
     """Order-preserving float64 -> int64 total-order encoding (sign-
@@ -137,7 +151,16 @@ def _lane_kind(t: pa.DataType) -> Optional[str]:
     `tstz` is an instant (a Delta `timestamp`: microseconds since the
     epoch in UTC, whatever zone the type names), `ts` a wall clock (a
     `timestamp_ntz`, a `date`): both are microsecond lanes, and a
-    literal of the one kind never compares with a lane of the other."""
+    literal of the one kind never compares with a lane of the other.
+    `decimal:<s>` is a `decimal(p,s)` of p <= 18 as its unscaled value
+    (`1234.56` at scale 2 is 123456), the scale part of the kind: the
+    stat was read from its digits into a `decimal128(p,s)`
+    (`stats/skipping.py::_decimal_reader_schema`), never through a
+    double."""
+    if pa.types.is_decimal128(t):
+        return (f"decimal:{t.scale}"
+                if t.precision <= DECIMAL_LANE_PRECISION
+                and 0 <= t.scale <= t.precision else None)
     if pa.types.is_boolean(t):
         return "bool"
     if pa.types.is_integer(t):
@@ -154,7 +177,7 @@ def _lane_kind(t: pa.DataType) -> Optional[str]:
 def _why_no_lane(t: pa.DataType, delta_type: Optional[str]) -> str:
     """The `scan.stats_index_unindexed_leaves.<why>` of a leaf with
     min/max stats and no lane."""
-    if delta_type == "decimal":
+    if (delta_type or "").startswith("decimal"):
         return "decimal"
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         # text the schema calls a time did not read as one
@@ -209,6 +232,12 @@ def _encode_lane(arr: pa.Array, kind: str):
             tz = arr.type.tz if pa.types.is_timestamp(arr.type) else None
             ts = arr.cast(pa.timestamp("us", tz=tz))
             enc = np.asarray(pc.fill_null(ts.cast(pa.int64()), 0), np.int64)
+        elif _lane_kind(arr.type) == kind:      # decimal:<scale>
+            # the unscaled value is the low word of the 128 bits (little
+            # endian), whole within 18 digits
+            words = np.frombuffer(arr.buffers()[1], np.int64,
+                                  count=2 * len(arr), offset=16 * arr.offset)
+            enc = np.where(valid, words[::2], 0)
         else:
             return None
         return enc, valid
@@ -233,11 +262,22 @@ def encode_literal(value, kind: str) -> Optional[int]:
         return int(value) if isinstance(value, bool) else None
     if isinstance(value, bool):
         return None
-    if kind == "int":
-        if isinstance(value, (int, np.integer)):
-            v = int(value)
-            return v if -(1 << 63) <= v < (1 << 63) else None
-        return None
+    if kind == "int" or kind.startswith("decimal:"):
+        # an `int`, a `decimal.Decimal` or digits in text, where it is
+        # exact at the lane's scale (0 of an `int` lane, which takes no
+        # text: `4000.5` against a `long` is the ladder's, never 4000)
+        exact = None if kind == "int" and isinstance(value, str) \
+            else decimal_literal(value)
+        if exact is None:
+            return None
+        scale = 0 if kind == "int" else int(kind[len("decimal:"):])
+        with decimal.localcontext() as ctx:
+            ctx.prec = 80
+            unscaled = exact.scaleb(scale)
+            if unscaled != unscaled.to_integral_value():
+                return None
+            v = int(unscaled)
+        return v if -(1 << 63) <= v < (1 << 63) else None
     if kind == "float":
         if isinstance(value, (int, np.integer)):
             if abs(int(value)) > _F64_EXACT_INT:
@@ -441,19 +481,27 @@ def _lanes_of(n_lanes: int, n: int):
 
 def build_index(files: pa.Table, table_path: Optional[str] = None,
                 version: Optional[int] = None,
-                metadata=None) -> ResidentStatsIndex:
-    """Columnarize one snapshot version's parsed stats into lanes. With
+                metadata=None,
+                rows: Optional[np.ndarray] = None) -> ResidentStatsIndex:
+    """Columnarize one snapshot version's parsed stats into lanes; with
+    `rows`, of those rows of `files` alone (the rows a state holds and
+    its live mask: the index of the live ones, no copy made of their
+    strings). With
     the table's `metadata`, the stat leaves its schema names are typed
     by it (`stats/skipping.py::stat_leaf_types`): a `timestamp` gets a
-    `tstz` lane, a `timestamp_ntz` a `ts` lane, a `decimal` none (its
-    stats parse as floats, which would compare inexactly)."""
+    `tstz` lane, a `timestamp_ntz` a `ts` lane, a `decimal(p,s)` of
+    p <= 18 a `decimal:<s>` lane of its unscaled value, read from the
+    stat's digits; a wider decimal none (its stats parse as floats,
+    which would compare inexactly)."""
     from delta_tpu.stats.skipping import StatsIndex, stat_leaf_types
 
     leaf_types = {} if metadata is None else stat_leaf_types(metadata)
-    small = files.num_rows < obs.PHASE_SPAN_ROWS
-    with obs.span("index.parse", _verbose=small, rows=files.num_rows):
+    n = files.num_rows if rows is None else int(np.count_nonzero(rows))
+    small = n < obs.PHASE_SPAN_ROWS
+    with obs.span("index.parse", _verbose=small, rows=n):
         arrow_index = StatsIndex.from_stats_column(files.column("stats"),
-                                                   leaf_types=leaf_types)
+                                                   leaf_types=leaf_types,
+                                                   rows=rows)
     with obs.span("index.encode", _verbose=small, rows=arrow_index.n) as ph:
         vals, valid, cols, unindexed = _encode_all(arrow_index, leaf_types)
         ph.set_attr("lanes", 0 if vals is None else len(vals))
@@ -472,26 +520,27 @@ def _encode_all(arrow_index, leaf_types):
         return None, None, {}, {}
 
     names = table.column_names
-    mins = table.column("minValues").combine_chunks() \
+    mins = table.schema.field("minValues").type \
         if "minValues" in names else None
-    maxs = table.column("maxValues").combine_chunks() \
+    maxs = table.schema.field("maxValues").type \
         if "maxValues" in names else None
-    if (mins is None or maxs is None
-            or not pa.types.is_struct(mins.type)
-            or not pa.types.is_struct(maxs.type)):
+    if (mins is None or maxs is None or not pa.types.is_struct(mins)
+            or not pa.types.is_struct(maxs)):
         return None, None, {}, {}
 
     lanes: List[Tuple[np.ndarray, np.ndarray]] = []
     cols: Dict[tuple, Tuple[int, str]] = {}
     unindexed: Dict[str, int] = {}
-    for path in _typed_leaves(mins.type):
+    for path in _typed_leaves(mins):
         mn = arrow_index.min_values(path)
         mx = arrow_index.max_values(path)
         if mn is None or mx is None or pa.types.is_null(mn.type):
             continue
         delta_type = leaf_types.get(path)
-        kind = None if delta_type == "decimal" else _resolve_kind(
-            _lane_kind(mn.type), _lane_kind(mx.type))
+        kind = _resolve_kind(_lane_kind(mn.type), _lane_kind(mx.type))
+        if (delta_type or "").startswith("decimal") \
+                and not (kind or "").startswith("decimal:"):
+            kind = None     # read as doubles (p > 18): would compare inexactly
         encoded = None if kind is None else _encode_column(
             mn, mx, arrow_index.null_count(path), kind)
         if encoded is None:
@@ -505,9 +554,9 @@ def _encode_all(arrow_index, leaf_types):
     lanes.append(_encode_count(arrow_index.num_records(), n))
 
     vals, valid = _lanes_of(len(lanes), n)
-    for r, (ev, eva) in enumerate(lanes):
-        vals[r, :n] = ev
-        valid[r, :n] = eva
+    lanes.reverse()     # each lane goes as it is written: never two of all
+    for r in range(len(lanes)):
+        vals[r, :n], valid[r, :n] = lanes.pop()
     return vals, valid, cols, unindexed
 
 
@@ -621,10 +670,18 @@ def _why_unread(stats: pa.ChunkedArray, schema: pa.Schema) -> str:
 
 
 def _compile_conj(conj: Expression,
-                  cols: Dict[tuple, Tuple[int, str]]):
+                  cols: Dict[tuple, Tuple[int, str]],
+                  notes: Optional[set] = None):
     """Compile one conjunct to a list of OR-groups of atom triples
-    (min_row, op_code, encoded literal); None = not compilable (the
-    conjunct joins the Arrow fallback ladder)."""
+    (min_row, op_code, encoded literal), the AND of which it is; None =
+    not compilable (the conjunct joins the Arrow fallback ladder).
+    An AND is its sides' groups one after the other. An OR of sides
+    with several groups is distributed: `(a1 AND a2) OR (b1 AND b2)` is
+    the four groups `(ai OR bj)`, each pairing of a group of either
+    side, while the atoms that come to stay within
+    `IN_LIST_ATOM_LIMIT`; wider, it compiles to nothing. `notes` is
+    told `distributed` or `too_wide`."""
+    notes = set() if notes is None else notes
     if isinstance(conj, Comparison):
         sides = (conj.left, conj.right)
         if isinstance(sides[0], Column) and isinstance(sides[1], Literal):
@@ -640,14 +697,21 @@ def _compile_conj(conj: Expression,
         if enc is None:
             return None
         return [[(ent[0], _OP_CODES[op], enc)]]
-    if isinstance(conj, Or):
-        left = _compile_conj(conj.left, cols)
-        right = _compile_conj(conj.right, cols)
-        if left is None or right is None or len(left) != 1 or len(right) != 1:
-            # an AND nested under OR doesn't flatten into atom groups;
-            # the host ladder keeps it (it returns None there too)
+    if isinstance(conj, (And, Or)):
+        left = _compile_conj(conj.left, cols, notes)
+        right = _compile_conj(conj.right, cols, notes)
+        if left is None or right is None:
             return None
-        return [left[0] + right[0]]
+        if isinstance(conj, And):
+            return left + right
+        if len(left) == len(right) == 1:
+            return [left[0] + right[0]]
+        groups = [lg + rg for lg in left for rg in right]
+        if sum(len(g) for g in groups) > IN_LIST_ATOM_LIMIT:
+            notes.add("too_wide")
+            return None
+        notes.add("distributed")
+        return groups
     if isinstance(conj, (IsNull, IsNotNull)):
         child = conj.child
         ent = cols.get(child.name_path) if isinstance(child, Column) else None
@@ -678,11 +742,12 @@ def _compile_conj(conj: Expression,
         inner = conj.child
         if isinstance(inner, Comparison):
             return _compile_conj(
-                Comparison(_NEG[inner.op], inner.left, inner.right), cols)
+                Comparison(_NEG[inner.op], inner.left, inner.right), cols,
+                notes)
         if isinstance(inner, IsNull):
-            return _compile_conj(IsNotNull(inner.child), cols)
+            return _compile_conj(IsNotNull(inner.child), cols, notes)
         if isinstance(inner, IsNotNull):
-            return _compile_conj(IsNull(inner.child), cols)
+            return _compile_conj(IsNull(inner.child), cols, notes)
         return None
     return None
 
@@ -700,12 +765,18 @@ def compile_conjuncts(conjuncts: List[Expression],
     lits: List[int] = []
     grp: List[int] = []
     fallback: List[Expression] = []
-    n_groups = 0
+    n_groups = distributed = 0
     for conj in conjuncts:
-        groups = _compile_conj(conj, index.cols)
+        notes: set = set()
+        groups = _compile_conj(conj, index.cols, notes)
         if groups is None:
+            if "too_wide" in notes:
+                _TOO_WIDE.inc()
             fallback.append(conj)
             continue
+        if "distributed" in notes:
+            _DISTRIBUTED.inc()
+            distributed += 1
         for g in groups:
             for (row0, code, enc) in g:
                 rows_mn.append(row0)
@@ -716,6 +787,8 @@ def compile_conjuncts(conjuncts: List[Expression],
     if not rows_mn:
         return None, fallback
     rmn = np.asarray(rows_mn, np.int32)
+    decimal_rows = [row0 for row0, kind in index.cols.values()
+                    if kind.startswith("decimal:")]
     block = AtomBlock(
         rows_mn=rmn,
         rows_mx=rmn + 1,
@@ -725,6 +798,8 @@ def compile_conjuncts(conjuncts: List[Expression],
         grp=np.asarray(grp, np.int32),
         n_atoms=len(rows_mn),
         n_groups=n_groups,
+        decimal_atoms=int(np.isin(rmn, decimal_rows).sum()),
+        distributed=distributed,
     )
     return block, fallback
 
@@ -781,25 +856,38 @@ def snapshot_stats_index(state, files: Optional[pa.Table] = None,
                     _APPEND_FALLBACKS.inc()
                 else:   # a state loaded in full: nothing to append to
                     sp.set_attr("reason", "no_seed")
+                live = None
                 if files is None:
+                    # the live rows' strings are read where they lie,
+                    # a piece at a time as they are parsed: at a fact
+                    # table's width a copy of them is 4 GB held for the
+                    # length of the build
                     with obs.span("index.read_stats",
                                   _verbose=small) as ph:
-                        files = state.live_columns(["stats"])
+                        files = state.file_actions.select(["stats"])
+                        live = state.live_mask
                         if ph.recording:
                             ph.set_attrs(
-                                rows=files.num_rows,
-                                bytes=files.column("stats").nbytes)
+                                rows=n, bytes=files.column("stats").nbytes)
                 stats = files.column("stats")
-                idx = build_index(files, table_path, version, metadata)
+                idx = build_index(files, table_path, version, metadata,
+                                  rows=live)
                 sp.set_attr("mode", "full")
                 _BUILDS.inc()
             for why, leaves in idx.unindexed.items():
                 _UNINDEXED[why].inc(leaves)
             if sp.recording:
+                kinds = collections.Counter(
+                    kind.partition(":")[0] for _, kind in idx.cols.values())
                 sp.set_attrs(
                     bytes=stats.nbytes,
                     lanes=0 if idx.vals is None else len(idx.vals),
                     columns=len(idx.cols),
+                    lane_kinds=",".join(f"{k}:{n}"
+                                        for k, n in sorted(
+                                            kinds.items(),
+                                            key=lambda kn: _LANE_KINDS.index(
+                                                kn[0]))),
                     unindexed=sum(idx.unindexed.values()))
         state.stats_index = idx
         # built implicitly by ordinary filtered scans, so a state

@@ -22,6 +22,7 @@ conjuncts — but only when both counts are present.
 
 from __future__ import annotations
 
+import decimal
 import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Union
@@ -32,7 +33,9 @@ import pyarrow.compute as pc
 import pyarrow.json as pa_json
 
 from delta_tpu import obs
+from delta_tpu.utils.chunks import pieces
 from delta_tpu.expressions.tree import (
+    And,
     Column,
     Comparison,
     Expression,
@@ -64,11 +67,87 @@ _TIMESTAMP_LEAVES = {"timestamp": pa.timestamp("us", tz="UTC"),
 # numpy twin and the Arrow ladder all see the widened value.
 _TIMESTAMP_MAX_SLACK_US = 1000
 
+# A `decimal(p,s)` stat is read from its own digits (an explicit
+# `decimal128(p,s)` in the JSON reader's schema: inference reads
+# `19876.54` as a double, and at p = 18 no double holds the value),
+# while its unscaled value fits the index's int64 lanes. Wider ones
+# parse as doubles, off the lanes, as before.
+DECIMAL_LANE_PRECISION = 18
+_DECIMAL_DIGITS = 38            # decimal128's
+
+
+def decimal_lane_type(delta_type: Optional[str]) -> Optional[pa.DataType]:
+    """`decimal128(p, s)` of a leaf the schema calls `decimal(p,s)` with
+    p <= `DECIMAL_LANE_PRECISION` and 0 <= s <= p; None of any other."""
+    if not delta_type or not delta_type.startswith("decimal"):
+        return None
+    from delta_tpu.models.schema import PrimitiveType
+
+    p, s = PrimitiveType(delta_type).decimal_precision_scale()
+    if p > DECIMAL_LANE_PRECISION or not 0 <= s <= p:
+        return None
+    return pa.decimal128(p, s)
+
+
+def decimal_literal(value, floats: bool = False) -> Optional[decimal.Decimal]:
+    """A predicate literal as the exact decimal it states: an `int`, a
+    finite `decimal.Decimal`, or digits in text; with `floats` (the
+    ladder, which compares at any scale) a `float` too, as the binary
+    fraction it is (`700.5` is 700.5, `0.1` is 0.1000000000000000055...).
+    None of a `bool` and anything else."""
+    if isinstance(value, bool):
+        return None
+    if floats and isinstance(value, (float, np.floating)):
+        value = float(value)
+        return decimal.Decimal(value) if np.isfinite(value) else None
+    if isinstance(value, (int, np.integer)):
+        return decimal.Decimal(int(value))
+    if isinstance(value, str):
+        try:
+            value = decimal.Decimal(value)
+        except decimal.InvalidOperation:
+            return None
+    if isinstance(value, decimal.Decimal) and value.is_finite():
+        return value
+    return None
+
+
+def _decimal_reader_schema(leaf_types: Dict[tuple, str],
+                           wide: bool) -> Optional[pa.Schema]:
+    """The part of the stats' schema that is given to the JSON reader
+    and not inferred: the `minValues` / `maxValues` leaves that are
+    decimal lanes, as `decimal128(p,s)`; with `wide`, with every place
+    decimal128 has behind the point, so that a stat with more places
+    than its column's scale still reads (and is told apart afterwards,
+    `_typed_leaf`). None where the table has no such column."""
+    tree: dict = {}
+    for path, delta_type in leaf_types.items():
+        t = decimal_lane_type(delta_type)
+        if t is None:
+            continue
+        if wide:
+            t = pa.decimal128(_DECIMAL_DIGITS,
+                              _DECIMAL_DIGITS - (t.precision - t.scale))
+        node = tree
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = t
+    if not tree:
+        return None
+
+    def struct(node):
+        return pa.struct([(k, struct(v) if isinstance(v, dict) else v)
+                          for k, v in node.items()])
+
+    group = struct(tree)
+    return pa.schema([("minValues", group), ("maxValues", group)])
+
 
 def stat_leaf_types(metadata) -> Dict[tuple, str]:
     """{leaf path as the stats JSON keys it: Delta primitive type} of
-    the table's schema (`decimal(p,s)` as `decimal`); physical names
-    under column mapping. Arrays and maps carry no stats."""
+    the table's schema (a decimal by its whole name, `decimal(7,2)`);
+    physical names under column mapping. Arrays and maps carry no
+    stats."""
     from delta_tpu.models.schema import PrimitiveType, StructType
 
     mapped = metadata.configuration.get(
@@ -80,8 +159,7 @@ def stat_leaf_types(metadata) -> Dict[tuple, str]:
             if isinstance(f.dataType, StructType):
                 walk(f.dataType, path, out)
             elif isinstance(f.dataType, PrimitiveType):
-                out[path] = ("decimal" if f.dataType.is_decimal
-                             else f.dataType.name)
+                out[path] = f.dataType.name
         return out
 
     return walk(metadata.schema, (), {})
@@ -89,6 +167,19 @@ def stat_leaf_types(metadata) -> Dict[tuple, str]:
 
 def _typed_leaf(leaf: pa.Array, delta_type: Optional[str], widen: bool,
                 cast: bool) -> pa.Array:
+    exact = decimal_lane_type(delta_type)
+    if exact is not None:
+        if cast and pa.types.is_decimal(leaf.type) and leaf.type != exact:
+            # read with every place decimal128 has: a stat with more
+            # places than the column's scale is unknown in its slot,
+            # never rounded into it
+            try:
+                rounded = pc.round(leaf, exact.scale)
+                leaf = pc.if_else(pc.equal(rounded, leaf), rounded,
+                                  pa.scalar(None, leaf.type)).cast(exact)
+            except _ARROW_ERRS:
+                pass    # stays as parsed: off the lanes, counted
+        return leaf
     target = _TIMESTAMP_LEAVES.get(delta_type)
     if target is None:
         return leaf
@@ -127,15 +218,18 @@ def _typed_struct(arr: pa.StructArray, prefix: tuple, leaf_types, widen,
 
 
 def _typed_stats(parsed: pa.Table, leaf_types: Dict[tuple, str],
-                 cast: bool) -> pa.Table:
+                 cast: bool, wide_decimals: bool = False) -> pa.Table:
     """`parsed` with the `minValues` / `maxValues` leaves that the
     table's schema calls `timestamp` or `timestamp_ntz` read as such
     (JSON inference takes a time with a fraction or a zone for a
     string), and each such max widened by its writer's millisecond.
     Without `cast` only leaves already of that type are widened (rows
-    parsed under a typed schema). A table with no such column is
+    parsed under a typed schema). With `wide_decimals` (the rows were
+    read under `_decimal_reader_schema`'s wide form) the decimal leaves
+    are brought to their column's type. A table with no such column is
     returned as it came."""
-    if not any(t in _TIMESTAMP_LEAVES for t in leaf_types.values()):
+    if not (wide_decimals or any(t in _TIMESTAMP_LEAVES
+                                 for t in leaf_types.values())):
         return parsed
     for group in ("minValues", "maxValues"):
         i = parsed.schema.get_field_index(group)
@@ -217,6 +311,82 @@ class ParsedPieces:
         return table
 
 
+# The stats strings of a held state are parsed a piece of the column at
+# a time: an Arrow `string` column's offsets are 32-bit a chunk, so a
+# column past 2 GiB (a fact table's width: ~1.6 KB of stats a file,
+# 2.4M files) can be neither combined nor joined into one buffer. A
+# column under one piece is parsed in the one call it always was.
+_PARSE_PIECE_BYTES = 1 << 28
+
+
+def _parse_piece(arr: pa.Array, options, null_tokens: bool) -> pa.Table:
+    """One `pyarrow.json.read_json` over the rows of `arr`, a line a
+    row (raises `pa.ArrowInvalid` as the reader does)."""
+    # substitute "{}" for null rows to keep row alignment
+    filled = pc.fill_null(arr, "{}")
+    # pretty-printed stats embed raw newlines, which would desync the
+    # one-row-per-line framing below (parsed.num_rows != n -> ALL
+    # skipping silently disabled). Raw newlines are illegal inside a
+    # JSON string value (they must be escaped as \n), so every literal
+    # newline in a stats row is structural whitespace — flatten it.
+    filled = pc.replace_substring(filled, pattern="\r", replacement=" ")
+    filled = pc.replace_substring(filled, pattern="\n", replacement=" ")
+    if null_tokens:
+        for tok in ('"NaN"', '"Infinity"', '"-Infinity"'):
+            filled = pc.replace_substring_regex(
+                filled, pattern=r":\s*" + tok, replacement=":null")
+    # each row with a newline behind it: the values of the result lie
+    # end to end in its data buffer, which is the file to read (no
+    # Python list of every row)
+    lines = pc.binary_join_element_wise(filled, "", "\n")
+    offsets = np.frombuffer(lines.buffers()[1], np.int32,
+                            count=len(lines) + 1, offset=4 * lines.offset)
+    joined = lines.buffers()[2].slice(int(offsets[0]),
+                                      int(offsets[-1] - offsets[0]))
+    return pa_json.read_json(pa.BufferReader(joined), parse_options=options)
+
+
+def _kept_pieces(stats_col, rows: Optional[np.ndarray]):
+    """`pieces` of the column, each narrowed to its rows of the mask."""
+    at = 0
+    for arr in pieces(stats_col, _PARSE_PIECE_BYTES):
+        if rows is not None:
+            mine = rows[at:at + len(arr)]
+            at += len(arr)
+            if not mine.all():
+                arr = arr.filter(pa.array(mine))
+        if len(arr):
+            yield arr
+
+
+def _parse_pieces(stats_col, options, null_tokens: bool,
+                  rows: Optional[np.ndarray] = None) -> Optional[pa.Table]:
+    """The rows of `stats_col` (those of the mask `rows`) parsed piece
+    by piece and the parsed tables one behind the other (their chunks,
+    nothing copied); None where the reader refuses a piece. Where the pieces were inferred
+    to different schemas (a leaf all null in one, an int column's
+    first float in another), every piece is read again under the
+    schema that takes them all, which is what inference over every row
+    at once comes to."""
+    try:
+        tables = [_parse_piece(arr, options, null_tokens)
+                  for arr in _kept_pieces(stats_col, rows)]
+        if not tables:
+            return None
+        if len(tables) == 1 or all(t.schema == tables[0].schema
+                                   for t in tables[1:]):
+            return pa.concat_tables(tables)
+        unified = pa.unify_schemas([t.schema for t in tables],
+                                   promote_options="permissive")
+        under = pa_json.ParseOptions(explicit_schema=unified,
+                                     unexpected_field_behavior="error")
+        return pa.concat_tables(
+            [_parse_piece(arr, under, null_tokens)
+             for arr in _kept_pieces(stats_col, rows)])
+    except _ARROW_ERRS:
+        return None
+
+
 class StatsIndex:
     """Parsed stats for a batch of files: one Arrow table, a row a
     file. An index brought forward from the one before
@@ -258,37 +428,40 @@ class StatsIndex:
     @staticmethod
     def from_stats_column(
             stats_col: pa.ChunkedArray, schema: Optional[pa.Schema] = None,
-            leaf_types: Optional[Dict[tuple, str]] = None) -> "StatsIndex":
-        """Parse one stats string a row. With `schema` (the parsed
-        schema of rows these will stand behind, `stats/device_index.py`)
-        nothing is inferred and nothing rewritten: a value that does
-        not read as the schema's type, or a key the schema lacks, gives
-        an index with no table. With `leaf_types` (`stat_leaf_types` of
-        the table's schema) the leaves it names are typed by it
+            leaf_types: Optional[Dict[tuple, str]] = None,
+            rows: Optional[np.ndarray] = None) -> "StatsIndex":
+        """Parse one stats string a row, a piece of the column at a
+        time (`_parse_pieces`); with `rows` (a mask over the column:
+        the live rows of the rows a state holds) only those, each piece
+        narrowed as it is parsed, so that no copy of every live row's
+        string is made beside the column. With `schema` (the parsed schema of
+        rows these will stand behind, `stats/device_index.py`) nothing
+        is inferred and nothing rewritten: a value that does not read
+        as the schema's type, or a key the schema lacks, gives an index
+        with no table. With `leaf_types` (`stat_leaf_types` of the
+        table's schema) the leaves it names are typed by it: a decimal
+        lane's by the reader itself, from the stat's digits
+        (`_decimal_reader_schema`), a time's afterwards
         (`_typed_stats`); a leaf it lacks, and every leaf where it is
         not given, keeps the type JSON inference gave it."""
-        n = len(stats_col)
-        arr = stats_col.combine_chunks() if isinstance(stats_col, pa.ChunkedArray) else stats_col
-        if n == 0 or arr.null_count == n:
+        n = len(stats_col) if rows is None else int(np.count_nonzero(rows))
+        if n == 0 or stats_col.null_count == len(stats_col):
             return StatsIndex(None, n)
-        # one-shot parse: substitute "{}" for null rows to keep row alignment
-        filled = pc.fill_null(arr, "{}")
-        # pretty-printed stats embed raw newlines, which would desync the
-        # one-row-per-line framing below (parsed.num_rows != n -> ALL
-        # skipping silently disabled). Raw newlines are illegal inside a
-        # JSON string value (they must be escaped as \n), so every literal
-        # newline in a stats row is structural whitespace — flatten it.
-        filled = pc.replace_substring(filled, pattern="\r", replacement=" ")
-        filled = pc.replace_substring(filled, pattern="\n", replacement=" ")
-        options = None if schema is None else pa_json.ParseOptions(
-            explicit_schema=schema, unexpected_field_behavior="error")
-        joined = ("\n".join(filled.to_pylist()) + "\n").encode()
-        try:
-            parsed = pa_json.read_json(pa.BufferReader(joined),
-                                       parse_options=options)
-        except pa.ArrowInvalid:
-            if schema is not None:
-                return StatsIndex(None, n)
+        if schema is not None:
+            attempts = [(pa_json.ParseOptions(
+                explicit_schema=schema, unexpected_field_behavior="error"),
+                False, False)]
+        else:
+            # the decimal leaves exactly; then with room for a stat of
+            # more places than its scale; then as JSON inference reads
+            # them, doubles (off the lanes, as a column past p = 18)
+            attempts = []
+            for wide in (False, True):
+                typed = _decimal_reader_schema(leaf_types or {}, wide)
+                if typed is not None:
+                    attempts.append((pa_json.ParseOptions(
+                        explicit_schema=typed,
+                        unexpected_field_behavior="infer"), False, wide))
             # A non-finite float stat serializes as the string "NaN" /
             # "Infinity" / "-Infinity" (see collection.py); ONE such
             # file makes Arrow's JSON inference see a string/number mix
@@ -298,18 +471,18 @@ class StatsIndex:
             # correctness: a raw `:"NaN"` byte sequence cannot occur
             # inside a JSON string value (its quote would be escaped),
             # so only whole stat values can match.
-            for tok in ('"NaN"', '"Infinity"', '"-Infinity"'):
-                filled = pc.replace_substring_regex(
-                    filled, pattern=r":\s*" + tok, replacement=":null")
-            joined = ("\n".join(filled.to_pylist()) + "\n").encode()
-            try:
-                parsed = pa_json.read_json(pa.BufferReader(joined))
-            except pa.ArrowInvalid:
-                return StatsIndex(None, n)
+            attempts += [(None, False, False), (None, True, False)]
+        for options, null_tokens, wide in attempts:
+            parsed = _parse_pieces(stats_col, options, null_tokens, rows)
+            if parsed is not None:
+                break
+        else:
+            return StatsIndex(None, n)
         if parsed.num_rows != n:
             return StatsIndex(None, n)
         if leaf_types:
-            parsed = _typed_stats(parsed, leaf_types, cast=schema is None)
+            parsed = _typed_stats(parsed, leaf_types, cast=schema is None,
+                                  wide_decimals=wide)
         return StatsIndex(parsed, n)
 
     def _leaf(self, group: str, name_path: tuple) -> Optional[np.ndarray]:
@@ -326,10 +499,12 @@ class StatsIndex:
             if not pa.types.is_struct(t) or t.get_field_index(part) < 0:
                 return None
             t = t.field(part).type
-        arr = self._table.column(group).combine_chunks()
+        # the leaf of each chunk, then one array of it: combining the
+        # group first copies every leaf of it for each one asked for
+        arr = self._table.column(group)
         for part in name_path:
             arr = pc.struct_field(arr, part)
-        return arr
+        return arr.combine_chunks()
 
     def num_records(self):
         schema = self.schema
@@ -428,6 +603,15 @@ def _conjunct_keep(conj: Expression, index: StatsIndex,
         if left is None or right is None:
             return None
         return pc.or_kleene(left, right)
+    if isinstance(conj, And):
+        # under an OR (`split_conjuncts` takes the ones above it): a
+        # file is pruned by either side, and a side with no answer
+        # stands for "keep", as upstream's `DataSkippingReader`
+        left = _conjunct_keep(conj.left, index, uncompared)
+        right = _conjunct_keep(conj.right, index, uncompared)
+        if left is None or right is None:
+            return right if left is None else left
+        return pc.and_kleene(left, right)
     if isinstance(conj, Comparison):
         sides = (conj.left, conj.right)
         if isinstance(sides[0], Column) and isinstance(sides[1], Literal):
@@ -441,8 +625,17 @@ def _conjunct_keep(conj: Expression, index: StatsIndex,
             return None
         minv = index.min_values(colref.name_path)
         maxv = index.max_values(colref.name_path)
+        value = lit.value
+        if any(a is not None and pa.types.is_decimal(a.type)
+               for a in (minv, maxv)):
+            # exactly or not at all: Arrow would compare a `float`
+            # through doubles, so it is given the fraction the float is
+            value = decimal_literal(value, floats=True)
+            if value is None:
+                uncompared.append(op)
+                minv = maxv = None
         try:
-            lit_arr = pa.scalar(lit.value)
+            lit_arr = pa.scalar(value)
         except pa.ArrowInvalid:
             return None
         keep = _cmp_keep(op, minv, maxv, lit_arr, uncompared)
@@ -661,7 +854,10 @@ def skipping_mask(
                     keep &= ops_skipping.host_skip_mask(
                         vals, valid, block, n)
             obs.set_attrs(skip_route=route, skip_atoms=block.n_atoms,
-                          skip_fallback_conjuncts=len(fallback))
+                          skip_fallback_conjuncts=len(fallback),
+                          atoms=block.n_atoms, groups=block.n_groups,
+                          decimal_atoms=block.decimal_atoms,
+                          distributed=block.distributed)
     uncompared = 0
     for conj in fallback:
         refused: list = []

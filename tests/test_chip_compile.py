@@ -173,6 +173,11 @@ def test_page_decode_part(on_chip):
     # an event-time window alone, and with five auctions listed (7 atoms)
     (13, 2_621_440, 2),
     (13, 2_621_440, 8),
+    # `sales-query-under-ingest`: all 23 columns of `store_sales` indexed
+    # (70 lanes); a range of sold dates alone, and with one of Query 28's
+    # buckets, its OR over three ranges distributed (28 atoms)
+    (70, 2_621_440, 2),
+    (70, 2_621_440, 32),
 ])
 def test_skipping_mask_block(on_chip, rows, f_pad, a_pad):
     atoms = on_chip((a_pad,), jnp.int32)
@@ -191,10 +196,11 @@ def test_skipping_mask_block(on_chip, rows, f_pad, a_pad):
     assert " while(" not in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows", [4, 13])
+@pytest.mark.parametrize("rows", [4, 13, 70])
 def test_stats_index_validity_unpack_2_6m_files(on_chip, rows):
-    # the index of `ckpt-query-under-ingest` (4 lanes) and of
-    # `bids-query-under-ingest` (13): 2.4M files pad to 2,621,440.
+    # the index of `ckpt-query-under-ingest` (4 lanes), of
+    # `bids-query-under-ingest` (13) and of `sales-query-under-ingest`
+    # (70): 2.4M files pad to 2,621,440.
     # As `jnp.unpackbits` over uint8 words this compile took 102 s
     n_pad = 2_621_440
     compiled = device_index._unpack_valid_fn(n_pad).lower(

@@ -75,9 +75,9 @@ def test_leaf_types_follow_the_schema_and_its_physical_names():
     mapped = Metadata(id="t", schemaString=text, partitionColumns=[],
                       configuration={"delta.columnMapping.mode": "name"})
     assert stat_leaf_types(plain) == {("a", "t"): "timestamp_ntz",
-                                      ("a", "d"): "decimal"}
+                                      ("a", "d"): "decimal(10,2)"}
     assert stat_leaf_types(mapped) == {("c1", "c2"): "timestamp_ntz",
-                                       ("c1", "d"): "decimal"}
+                                       ("c1", "d"): "decimal(10,2)"}
 
 
 FORMS = {   # the instant 2024-01-01T00:00:08.7Z, as writers spell it
@@ -133,7 +133,7 @@ def test_a_table_without_a_timestamp_parses_as_it_did():
 
 def test_lanes_for_numbers_and_times_and_a_count_of_the_rest():
     md = metadata_of(("auction", "long"), ("dateTime", "timestamp"),
-                     ("channel", "string"), ("amount", "decimal(10,2)"),
+                     ("channel", "string"), ("amount", "decimal(20,2)"),
                      ("when", "timestamp"))
     rows = [stats_row(
         {"auction": i, "dateTime": FORMS["upstream-Z"], "channel": "a",
