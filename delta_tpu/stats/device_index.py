@@ -54,6 +54,15 @@ stats string is parsed (`mode` `full`). The seed's arrays are never
 written to: a reader may still plan on the prior version. Either way
 the lanes cross to the device whole, on the first device plan
 (`stats.index_upload`): nothing on the chip is patched in place.
+
+On the host the lanes are int64 throughout (the seed, `append_index`,
+the numpy twin). On the chip, which has no 64-bit integers, they are
+resident in the form the kernel computes on: high halves `int32`, low
+halves `uint32`, validity `bool`, each `[R, n_pad / 128, 128]` so that
+a lane row is whole tiles. The int64 rows cross the link as they are
+and are split there, once an upload (`_halves_fn`,
+`scan.stats_index_lane_splits`), a few rows at a time so that the chip
+never holds the index twice.
 """
 
 from __future__ import annotations
@@ -104,6 +113,9 @@ _TOO_WIDE = obs.counter("scan.skip_disjunctions_too_wide")
 # them for a reader counts in `scan.stats_index_table_builds`
 # (`stats/skipping.py::ParsedPieces.combined`)
 _TABLE_DEFERRED = obs.counter("scan.stats_index_table_deferred")
+# the int64 lanes split into 32-bit halves on the chip: once an upload
+# (a refresh, or the re-upload after an eviction), never at a launch
+_LANE_SPLITS = obs.counter("scan.stats_index_lane_splits")
 # leaves that carry min/max stats and got no lane, by why: an index
 # that cannot read a table's schema shows here, not in a scan's bill
 _UNINDEXED = {
@@ -135,6 +147,14 @@ _ARROW_ERRS = (pa.ArrowInvalid, pa.ArrowNotImplementedError,
 
 # the kinds of lane (`_lane_kind`), in the order `lane_kinds` names them
 _LANE_KINDS = ("bool", "int", "float", "ts", "tstz", "decimal")
+
+# the files of one tile row of the chip's vectors (128 lanes): the lanes
+# are padded to a multiple of it (`_lanes_of`), and a lane row of the
+# resident index is `[n_pad / TILE_FILES, TILE_FILES]`
+TILE_FILES = 128
+
+# lane rows that cross to the chip, and are there as int64, at a time
+_UPLOAD_ROWS = 4
 
 
 def _enc_f64(a: np.ndarray) -> np.ndarray:
@@ -308,22 +328,37 @@ def encode_literal(value, kind: str) -> Optional[int]:
     return None
 
 
-@functools.lru_cache(maxsize=16)
-def _unpack_valid_fn(n_pad: int):
-    """jit'd validity-word unpack: uint32 [R, n_pad / 32] -> bool
-    [R, n_pad], by the tree's own shift-and-mask over 32-bit words. (A
+@functools.cache
+def _halves_fn():
+    """jit'd arrival of `_UPLOAD_ROWS` lane rows: the int64 values
+    `[k, n_pad]` split into their 32-bit halves (the chip has no 64-bit
+    integers: left int64, the compiler splits every lane of the index at
+    every launch that reads it), the validity words `uint32 [k, n_pad /
+    32]` unpacked by the tree's own shift-and-mask over 32-bit words (a
     `jnp.unpackbits` over the uint8 words shifts 8-bit lanes, which the
-    v5e compiler takes 102 s over at a 2.6M-row index; this form, 3 s.)"""
+    v5e compiler takes 102 s over at a 2.6M-row index; this form, 3 s),
+    and the three written as rows `r0`.. of the resident arrays, in
+    place: those are donated, so the chip holds the index once and a
+    piece's int64 form beside it. (Split whole, a 70-lane index takes
+    its 1.5 GB of int64, 1.65 GB of results and 1.7 GB of temporaries
+    at once, donated or not: a donor of another shape is not reused.)"""
     import jax
+    import jax.numpy as jnp
+    from jax import lax
 
     from delta_tpu.ops.replay import _unpack_bits_device
 
     @obs.program("stats.index_upload")
-    def unpack(words):
-        bits = _unpack_bits_device(words.reshape(-1))
-        return (bits != 0).reshape(words.shape[0], n_pad)
+    def place(high, low, valid, vals, words, r0):
+        tiles = (vals.shape[0],) + high.shape[1:]
+        rows = ((vals >> 32).astype(jnp.int32), vals.astype(jnp.uint32),
+                _unpack_bits_device(words.reshape(-1)) != 0)
+        return tuple(
+            lax.dynamic_update_slice_in_dim(whole, part.reshape(tiles), r0,
+                                            axis=0)
+            for whole, part in zip((high, low, valid), rows))
 
-    return jax.jit(unpack)
+    return jax.jit(place, donate_argnums=(0, 1, 2))
 
 
 @dataclass(frozen=True)
@@ -347,7 +382,10 @@ class StatsIndexSeed:
 class ResidentStatsIndex:
     """Per-snapshot-version stats index: the parsed stats rows (the
     host fallback ladder's, an Arrow table once it asks) plus the
-    encoded int64 lanes, with a lazily uploaded device copy."""
+    encoded int64 lanes, with a lazily uploaded device copy: the lanes'
+    32-bit halves and their validity, `[R, n_pad / TILE_FILES,
+    TILE_FILES]` each (`device_lanes`), which an eviction drops and the
+    next plan uploads, and splits, anew."""
 
     def __init__(self, arrow_index, vals: Optional[np.ndarray],
                  valid: Optional[np.ndarray],
@@ -384,7 +422,8 @@ class ResidentStatsIndex:
                                   base_live, self.unindexed)
 
     def device_lanes(self):
-        """(values, validity) device arrays, uploading on first use."""
+        """(high halves, low halves, validity) device arrays, uploading
+        on first use."""
         with self._lock:
             dev = self._upload_locked()
             if dev is not None:
@@ -392,35 +431,59 @@ class ResidentStatsIndex:
             return dev
 
     def _upload_locked(self):
+        """The device copy: (high int32, low uint32, valid bool), each
+        `[R, n_pad / TILE_FILES, TILE_FILES]`, so that a lane row is
+        whole tiles of the chip's vectors. The host's int64 rows cross
+        `_UPLOAD_ROWS` at a time and are split there (`_halves_fn`), the
+        next piece on its way while one is placed and no further ahead:
+        the chip never holds a second copy of the index."""
         if self._dev is not None or self.vals is None or self.released:
             return self._dev
         import jax
+        import jax.numpy as jnp
 
         from delta_tpu.ops.stats import _x64
 
-        n_pad = self.vals.shape[1]
+        n_lanes, n_pad = self.vals.shape
         with obs.span("index.pack_valid",
                       _verbose=self.n < obs.PHASE_SPAN_ROWS,
-                      lanes=len(self.vals), bytes=self.valid.nbytes):
+                      lanes=n_lanes, bytes=self.valid.nbytes):
             lane_vals = np.asarray(self.vals, np.int64)
             # bit k of word j is file 32 j + k (n_pad is a multiple of 128)
             valid_words = np.packbits(np.asarray(self.valid, bool), axis=1,
                                       bitorder="little").view("<u4")
-        cells = lane_vals.shape[0] * n_pad
-        with obs.span("stats.index_upload", rows=self.n,
+        tiles = (n_lanes, n_pad // TILE_FILES, TILE_FILES)
+        place = _halves_fn()
+        with obs.span("stats.index_upload", rows=self.n, form="halves",
                       bytes=lane_vals.nbytes + valid_words.nbytes), \
             obs.device_dispatch("stats.index_upload",
-                                key=(lane_vals.shape[0], n_pad),
+                                key=(n_lanes, n_pad),
                                 budget="stats-index-lanes",
-                                units=cells) as dd, _x64():
+                                units=n_lanes * n_pad) as dd, _x64():
             dd.h2d("lane_vals", lane_vals)
             dd.h2d("valid_words", valid_words)
-            dv = jax.device_put(lane_vals)
-            dvalid = _unpack_valid_fn(n_pad)(jax.device_put(valid_words))
-        self._dev = (dv, dvalid)
+            dev = tuple(jnp.zeros(tiles, t)
+                        for t in (jnp.int32, jnp.uint32, jnp.bool_))
+            sent = None     # the piece on its way: rows, words, first row
+            for r0 in (*range(0, n_lanes, _UPLOAD_ROWS), None):
+                if sent is not None:
+                    # delta-lint: disable=jit-sync (audited: the piece
+                    # before has been placed before the one after next
+                    # is sent: two pieces' int64 on the chip at most)
+                    dev[0].block_until_ready()
+                    dev = place(*dev, *sent)
+                if r0 is not None:
+                    rows = slice(r0, r0 + _UPLOAD_ROWS)
+                    # delta-lint: disable=transfer-budget (audited: the
+                    # two budgeted lanes themselves, every row of both
+                    # once, recorded whole above)
+                    sent = jax.device_put(
+                        (lane_vals[rows], valid_words[rows])) + (np.int32(r0),)
+        _LANE_SPLITS.inc()
+        self._dev = dev
         self._hbm = hbm.register(
             self, kind=hbm.KIND_STATS_INDEX, table_path=self.table_path,
-            version=self.version, arrays=(dv, dvalid),
+            version=self.version, arrays=dev,
             rebuild_cost_class="cheap",  # lazy re-upload from host lanes
             evictor=self.evict_device,
         )
@@ -474,7 +537,7 @@ def _lanes_of(n_lanes: int, n: int):
     """Zeroed lane matrix and validity plane in `n`'s pad bucket."""
     from delta_tpu.ops.replay import pad_bucket
 
-    n_pad = pad_bucket(max(n, 1), min_bucket=128)
+    n_pad = pad_bucket(max(n, 1), min_bucket=TILE_FILES)
     return (np.zeros((n_lanes, n_pad), np.int64),
             np.zeros((n_lanes, n_pad), bool))
 

@@ -834,7 +834,7 @@ def skipping_mask(
                         keep &= device_faults.shed_retry(
                             "skip",
                             lambda: ops_skipping.skip_mask_block(
-                                lanes[0], lanes[1], block, n))
+                                *lanes, block, n))
                         gate_mod.route_ok("skip")
                         _DEVICE_PLANS.inc()
                         if fallback:

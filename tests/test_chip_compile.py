@@ -9,6 +9,7 @@ fixture, after this file's first test has started: only the xdist worker
 that is handed this file loads the TPU compiler.
 """
 
+import math
 import os
 
 import jax
@@ -47,7 +48,7 @@ def topo():
     json_parse._parse_fn_cached.cache_clear()
     page_decode._decode_fn.cache_clear()
     skipping._skip_fn_cached.cache_clear()
-    device_index._unpack_valid_fn.cache_clear()
+    device_index._halves_fn.cache_clear()
     jax.clear_caches()
 
 
@@ -180,32 +181,58 @@ def test_page_decode_part(on_chip):
     (70, 2_621_440, 32),
 ])
 def test_skipping_mask_block(on_chip, rows, f_pad, a_pad):
+    tiles = (rows, f_pad // device_index.TILE_FILES, device_index.TILE_FILES)
     atoms = on_chip((a_pad,), jnp.int32)
-    with jax.enable_x64(True):
-        compiled = skipping._skip_fn_cached(a_pad).lower(
-            on_chip((rows, f_pad), jnp.int64),
-            on_chip((rows, f_pad), jnp.bool_),
-            atoms, atoms, atoms, atoms, on_chip((a_pad,), jnp.int64),
-            atoms, on_chip((), jnp.int32)).compile()
+    compiled = skipping._skip_fn_cached(a_pad).lower(
+        on_chip(tiles, jnp.int32), on_chip(tiles, jnp.uint32),
+        on_chip(tiles, jnp.bool_),
+        atoms, atoms, atoms, atoms, atoms, on_chip((a_pad,), jnp.uint32),
+        atoms, on_chip((), jnp.int32)).compile()
     _assert_fits(compiled)
-    # no `[a_pad, f_pad]` copy of the lanes: the v5e compiler's
-    # temporaries (the 32-bit halves of every int64 lane of the index
-    # among them, whichever rows the atoms name) stay under 16 bytes a
-    # lane a file however many slots the program has
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows * f_pad
-    assert " while(" not in compiled.as_text()
+    text = compiled.as_text()
+    # the index is resident as 32-bit halves: no launch splits an int64
+    # lane (as int64 the program split all `rows` of them, whichever the
+    # atoms named: 1.5 GB of temporaries at 70 lanes)
+    assert "X64Split" not in text
+    # no `[a_pad, f_pad]` copy of the lanes, and nothing that grows with
+    # the index: under 64 bytes a file whatever `rows`
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * f_pad
+    # a lane row is whole tiles: never one sublane of every 8-row tile
+    slices = [line.split(" dynamic-slice(")[0] for line in text.splitlines()
+              if " dynamic-slice(" in line and f"[1,{tiles[1]},128]" in line]
+    assert len(slices) >= 3
+    assert all("T(8,128)" in sl and "T(1,128)" not in sl for sl in slices)
+    assert " while(" not in text
 
 
-@pytest.mark.parametrize("rows", [4, 13, 70])
-def test_stats_index_validity_unpack_2_6m_files(on_chip, rows):
+@pytest.mark.parametrize("rows,piece", [(4, 4), (13, 4), (13, 1), (70, 4),
+                                        (70, 2)])
+def test_stats_index_upload_2_6m_files(on_chip, rows, piece):
     # the index of `ckpt-query-under-ingest` (4 lanes), of
     # `bids-query-under-ingest` (13) and of `sales-query-under-ingest`
-    # (70): 2.4M files pad to 2,621,440.
-    # As `jnp.unpackbits` over uint8 words this compile took 102 s
+    # (70): 2.4M files pad to 2,621,440; a whole piece of the upload and
+    # the last one. As `jnp.unpackbits` over uint8 words the validity's
+    # unpack took 102 s to compile
     n_pad = 2_621_440
-    compiled = device_index._unpack_valid_fn(n_pad).lower(
-        on_chip((rows, n_pad // 32), jnp.uint32)).compile()
+    assert piece in (device_index._UPLOAD_ROWS,
+                     rows % device_index._UPLOAD_ROWS)
+    tiles = (rows, n_pad // device_index.TILE_FILES, device_index.TILE_FILES)
+    resident = (on_chip(tiles, jnp.int32), on_chip(tiles, jnp.uint32),
+                on_chip(tiles, jnp.bool_))
+    with jax.enable_x64(True):
+        compiled = device_index._halves_fn().lower(
+            *resident, on_chip((piece, n_pad), jnp.int64),
+            on_chip((piece, n_pad // 32), jnp.uint32),
+            on_chip((), jnp.int32)).compile()
     _assert_fits(compiled)
+    ma = compiled.memory_analysis()
+    # the resident arrays are donated and written in place: the chip
+    # holds the index once, and beside it a piece's int64 rows and what
+    # splitting them takes (split whole, 70 lanes take 1.53 GB of
+    # argument, 1.65 GB of results and 1.68 GB of temporaries at once)
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in resident)
+    assert ma.alias_size_in_bytes == held
+    assert ma.temp_size_in_bytes <= 3 * 8 * piece * n_pad
 
 
 def test_sql_group_aggregate_4m_rows(on_chip):

@@ -428,26 +428,182 @@ def test_empty_delta_carries_index_forward(tmp_table_path):
     assert holder is idx
 
 
-@pytest.mark.parametrize("n_pad", [128, 4096, 1 << 20, (1 << 20) + (1 << 19)])
-def test_uploaded_validity_plane_is_bit_identical(n_pad):
-    """The validity plane crosses the link as packed 32-bit words and is
-    unpacked on the device by shift-and-mask: every flag of every lane
-    comes back where the host had it."""
+def _host_form(lanes):
+    """(int64 values, validity) `[R, n_pad]` of a device copy (high,
+    low, validity), each `[R, n_pad / 128, 128]`."""
+    high, low, valid = (np.asarray(a) for a in lanes)
+    assert (high.dtype, low.dtype, valid.dtype) == (np.int32, np.uint32, bool)
+    assert high.shape == low.shape == valid.shape and high.shape[2] == 128
+    flat = (high.shape[0], -1)
+    vals = (high.astype(np.int64) << 32) | low.astype(np.int64)
+    return vals.reshape(flat), valid.reshape(flat)
+
+
+@pytest.mark.parametrize("lanes,n_pad", [
+    (4, 128), (4, 4096), (4, 1 << 20), (4, (1 << 20) + (1 << 19)),
+    # more rows than cross at a time: two pieces, and nine
+    (13, 4096), (70, 1024)])
+def test_uploaded_index_is_bit_identical(lanes, n_pad):
+    """The lanes cross the link as int64 and are split on the device
+    into their 32-bit halves; the validity plane crosses as packed
+    32-bit words and is unpacked there by shift-and-mask: every value
+    and every flag of every lane comes back where the host had it."""
     from delta_tpu.stats.device_index import ResidentStatsIndex
 
-    rng = np.random.default_rng(n_pad)
-    valid = rng.random((4, n_pad)) < 0.5
+    rng = np.random.default_rng(n_pad + lanes)
+    valid = rng.random((lanes, n_pad)) < 0.5
     valid[0, :3] = [True, False, True]
-    valid[3, -1] = True
-    vals = rng.integers(-2**62, 2**62, (4, n_pad))
+    valid[-1, -1] = True
+    vals = rng.integers(-2**63, 2**63 - 1, (lanes, n_pad), endpoint=True)
+    vals[:, :4] = [-2**63, -1, 2**31, 2**63 - 1]
     idx = ResidentStatsIndex(None, vals, valid, {}, n_pad - 5)
-    dvals, dvalid = idx.device_lanes()
     try:
-        assert dvalid.dtype == bool and dvalid.shape == (4, n_pad)
-        assert np.array_equal(np.asarray(dvalid), valid)
-        assert np.array_equal(np.asarray(dvals), vals)
+        dvals, dvalid = _host_form(idx.device_lanes())
+        assert dvals.shape == (lanes, n_pad)
+        assert np.array_equal(dvalid, valid)
+        assert np.array_equal(dvals, vals)
     finally:
         idx.release()
+
+
+# ---- the kernel over halves decides every comparison as int64 does ----
+
+_I64 = np.iinfo(np.int64)
+# the ends of int64, of its halves, and values whose high halves are
+# equal and whose low halves lie either side of 2^31 (where a signed
+# comparison of the low half would turn)
+_EDGES = [_I64.min, -2**32 - 1, -1, 0, 2**31 - 1, 2**31, 2**32 - 1, 2**32,
+          _I64.max, (5 << 32) + 2**31 - 1, (5 << 32) + 2**31,
+          (-7 << 32) + 2**31 - 1, (-7 << 32) + 2**31]
+_STAT_ROWS = ("min", "max", "nullCount", "numRecords")
+
+
+def _edge_index(invalid):
+    """One column's lanes over every (min, max) pair of `_EDGES`, each
+    with nullCount none, some and all of numRecords (an edge itself on
+    every third file, so `nc == nr` is decided at the edges too);
+    `invalid` names the stat row whose flags are off on every other
+    file."""
+    from delta_tpu.stats.device_index import ResidentStatsIndex
+
+    pairs = [(a, b) for a in _EDGES for b in _EDGES]
+    n = 3 * len(pairs)
+    n_pad = 512
+    assert n <= n_pad
+    vals = np.zeros((4, n_pad), np.int64)
+    valid = np.zeros((4, n_pad), bool)
+    valid[:, :n] = True
+    for k, (a, b) in enumerate(pairs):
+        nr = _EDGES[k % len(_EDGES)] if k % 3 == 0 else 10
+        vals[0, 3 * k: 3 * k + 3] = a
+        vals[1, 3 * k: 3 * k + 3] = b
+        vals[2, 3 * k: 3 * k + 3] = [0, 3, nr]
+        vals[3, 3 * k: 3 * k + 3] = nr
+    if invalid is not None:
+        valid[_STAT_ROWS.index(invalid), :n:2] = False
+    return ResidentStatsIndex(None, vals, valid, {}, n), n
+
+
+def _atoms(ops, lits, sizes):
+    """An `AtomBlock` on column 0: `ops[i]` against `lits[i]`, grouped
+    by `sizes`."""
+    from delta_tpu.ops.skipping import AtomBlock
+
+    n = len(ops)
+    assert sum(sizes) == n
+    rows = np.zeros(n, np.int32)
+    return AtomBlock(
+        rows_mn=rows, rows_mx=rows + 1, rows_nc=rows + 2,
+        ops=np.asarray(ops, np.int32), lits=np.asarray(lits, np.int64),
+        grp=np.repeat(np.arange(len(sizes)), sizes).astype(np.int32),
+        n_atoms=n, n_groups=len(sizes))
+
+
+def _edge_cases():
+    # every op alone against every edge (one atom of two slots), with
+    # each stat row's validity off in turn
+    for op in range(8):
+        for invalid in (None,) + _STAT_ROWS:
+            yield pytest.param(("op", op, invalid),
+                               id=f"op{op}-{invalid or 'valid'}")
+    # atoms that fill their slots, so that a group closes on the last
+    # one; and fewer atoms than slots
+    for sizes in ([1, 1], [2], [3, 1], [1, 3], [2, 2, 2, 2], [1, 6, 1],
+                  [8], [1], [2, 1], [1, 2, 2], [4, 3, 6], [13]):
+        yield pytest.param(("groups", sizes, None),
+                           id="groups-" + "-".join(map(str, sizes)))
+
+
+@pytest.mark.parametrize("case", _edge_cases())
+def test_kernel_over_halves_equals_the_twin_at_the_edges(case):
+    from delta_tpu.ops import skipping as ops_skipping
+
+    kind, what, invalid = case
+    idx, n = _edge_index(invalid)
+    if kind == "op":
+        blocks = [_atoms([what], [lit], [1]) for lit in _EDGES]
+    else:
+        rng = np.random.default_rng(sum(what) * 31 + len(what))
+        k = sum(what)
+        blocks = [_atoms(rng.integers(0, 8, k), rng.choice(_EDGES, k), what)
+                  for _ in range(6)]
+    try:
+        lanes = idx.device_lanes()
+        kept = set()
+        for block in blocks:
+            twin = ops_skipping.host_skip_mask(idx.vals, idx.valid, block, n)
+            device = ops_skipping.skip_mask_block(*lanes, block, n)
+            assert device.dtype == np.bool_ and device.shape == (n,)
+            assert np.array_equal(device, twin), (
+                block.ops, block.lits, np.flatnonzero(device != twin)[:8])
+            kept.add(int(twin.sum()))
+        # the case decides something: not every launch keeps every file
+        # or none (an op alone against thirteen literals, six drawn sets
+        # of atoms)
+        assert kept - {0, n}
+    finally:
+        idx.release()
+
+
+@pytest.mark.parametrize("lanes", [4, 13])
+def test_lanes_are_split_once_an_upload_never_a_launch(lanes):
+    """`scan.stats_index_lane_splits`: +1 when the index crosses to the
+    chip, +0 for each plan on it, +1 again when an evicted index is
+    uploaded anew; the span says in which form the lanes are resident,
+    and the kernel's program takes no int64."""
+    from delta_tpu.ops import skipping as ops_skipping
+    from delta_tpu.stats.device_index import ResidentStatsIndex
+
+    splits = obs.counter("scan.stats_index_lane_splits")
+    rng = np.random.default_rng(lanes)
+    vals = rng.integers(-2**40, 2**40, (lanes, 256))
+    idx = ResidentStatsIndex(None, vals, np.ones((lanes, 256), bool), {}, 250)
+    block = _atoms([3, 0], [-5, 2**33], [1, 1])
+    twin = ops_skipping.host_skip_mask(idx.vals, idx.valid, block, 250)
+    obs.set_trace_mode("on")
+    try:
+        obs.reset_trace_buffer()
+        before = splits.value
+        first = idx.device_lanes()
+        assert splits.value == before + 1
+        for _ in range(3):
+            assert idx.device_lanes()[0] is first[0]
+            assert np.array_equal(
+                ops_skipping.skip_mask_block(*first, block, 250), twin)
+        assert splits.value == before + 1
+        idx.evict_device()
+        again = idx.device_lanes()
+        assert again[0] is not first[0]
+        assert splits.value == before + 2
+        assert np.array_equal(
+            ops_skipping.skip_mask_block(*again, block, 250), twin)
+        uploads = [s for s in obs.get_finished_spans()
+                   if s.name == "stats.index_upload"]
+    finally:
+        obs.set_trace_mode(None)
+        idx.release()
+    assert [s.attrs["form"] for s in uploads] == ["halves", "halves"]
+    assert all(str(a.dtype) in ("int32", "uint32", "bool") for a in again)
 
 
 # ---- the index brought to a new version from the one before ----
@@ -1002,13 +1158,13 @@ def test_kernel_temporaries_do_not_grow_with_the_atom_slots(a_pad):
 
     from delta_tpu.ops import skipping as ops_skipping
 
-    lanes, n_pad = 4, 2_621_440
+    tiles = (4, 2_621_440 // 128, 128)
     atoms = jax.ShapeDtypeStruct((a_pad,), jnp.int32)
-    with jax.enable_x64(True):
-        compiled = ops_skipping._skip_fn_cached(a_pad).lower(
-            jax.ShapeDtypeStruct((lanes, n_pad), jnp.int64),
-            jax.ShapeDtypeStruct((lanes, n_pad), jnp.bool_),
-            atoms, atoms, atoms, atoms,
-            jax.ShapeDtypeStruct((a_pad,), jnp.int64), atoms,
-            jax.ShapeDtypeStruct((), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes <= 16 * n_pad
+    compiled = ops_skipping._skip_fn_cached(a_pad).lower(
+        jax.ShapeDtypeStruct(tiles, jnp.int32),
+        jax.ShapeDtypeStruct(tiles, jnp.uint32),
+        jax.ShapeDtypeStruct(tiles, jnp.bool_),
+        atoms, atoms, atoms, atoms, atoms,
+        jax.ShapeDtypeStruct((a_pad,), jnp.uint32), atoms,
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 16 * 2_621_440
