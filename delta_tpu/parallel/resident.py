@@ -14,11 +14,16 @@ slot->row scatter, and path dictionary — rebuilds the full live and
 tombstone masks without probing the base table at all.
 
 Lifecycle: established by `compute_masks_device` (replay/state.py) when
-the sharded route runs on chronological, DV-free input; ownership moves
+the sharded route runs on DV-free input (in whatever row order the
+parser left it: the payload maps slots to the caller's rows); ownership moves
 `ColumnarActions` -> `SnapshotState` -> the advanced state (the append
 kernel donates the key buffer, so exactly one state may own it);
-released when a snapshot falls back to a full load (`table.py`) or is
-evicted from the serve cache (`serve/cache.py`). Any append the state
+released when a snapshot falls back to a full load (`table.py`), is
+evicted from the serve cache (`serve/cache.py`), or is simply dropped:
+residency ends with its owner, so a state nobody refers to any longer
+gives its lanes back without a call (a finalizer of the resident state
+releases the ledger entry; the buffers go with the last reference).
+Any append the state
 cannot express (DV rows, batches older than the resident tail, capacity
 overflow) returns None and the caller falls back to the host delta
 path, dropping residency; in-batch disorder is sorted away, not
@@ -30,6 +35,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -40,6 +46,8 @@ from delta_tpu.obs import hbm
 _H2D_BYTES = obs.counter("replay.h2d_bytes")
 _APPENDS = obs.counter("replay.resident_appends")
 _FALLBACKS = obs.counter("replay.resident_fallbacks")
+_ESTABLISHED = obs.counter("replay.resident_established")
+_RELEASED = obs.counter("replay.resident_released")
 # device bytes pinned by resident key lanes are accounted in the
 # process-wide resident ledger (obs/hbm.py), which also derives the
 # `replay.resident_hbm_bytes` gauge this module used to maintain
@@ -79,6 +87,15 @@ def _append_fn_cached(mesh, d_pad: int):
                    donate_argnums=donate)
 
 
+def _dropped(handle) -> None:
+    """Finalizer of a resident state that nobody released: its owner was
+    dropped, which ends residency as `release()` does. The ledger entry
+    goes as a release (an owner that ends is no leak); the buffers went
+    with the last reference."""
+    handle.release()
+    _RELEASED.inc()
+
+
 class ResidentShardState:
     """Host bookkeeping + device key lane for one resident snapshot."""
 
@@ -100,6 +117,11 @@ class ResidentShardState:
             arrays=(payload.key_sh,),
             rebuild_cost_class="expensive",  # full sharded replay
         )
+        # registered after the ledger's own leak finalizer, so it runs
+        # before it and leaves it nothing to report
+        self._end = weakref.finalize(self, _dropped, self._hbm)
+        self._end.atexit = False
+        _ESTABLISHED.inc()
         self.n_real = np.asarray(payload.n_real, np.int64).copy()
         self.add = np.unpackbits(
             payload.add_words.view(np.uint8).reshape(self.n_shards, -1),
@@ -298,6 +320,8 @@ class ResidentShardState:
             if self.key_sh is not None:
                 self.key_sh = None
                 self._hbm.release()
+                self._end.detach()
+                _RELEASED.inc()
 
 
 def establish_resident(payload, file_actions,
@@ -308,9 +332,10 @@ def establish_resident(payload, file_actions,
     payload's rows came from (same row order)."""
     try:
         with obs.span("replay.resident_establish", rows=payload.n):
+            # the chunks as they lie: a load that is never refreshed
+            # (every cold load) pays for no copy of its paths
             return ResidentShardState(
-                payload, file_actions.column("path").combine_chunks(),
-                path_codes)
+                payload, file_actions.column("path"), path_codes)
     # delta-lint: disable=except-swallow (audited: residency is an
     # optimization; any establishment failure must degrade to the
     # non-resident path, never fail the load)

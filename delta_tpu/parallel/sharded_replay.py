@@ -68,6 +68,9 @@ from delta_tpu.parallel.mesh import REPLAY_AXIS, make_mesh
 # replay operand bytes shipped host->device, read by the residency
 # tests and the bench transfer accounting.
 _H2D_BYTES = obs.counter("replay.h2d_bytes")
+_LAUNCHES = obs.counter("replay.sharded_launches")
+# the one collective of the replay, by name in a profile
+PSUM_SCOPE = "replay.psum"
 
 
 # --------------------------------------------------------------- raw path
@@ -91,8 +94,9 @@ def _shard_kernel(key, is_add, size):
     # global aggregates over the ICI (padding rows: add=False, size=0)
     local_live = jnp.sum(live_s.astype(jnp.int32))
     local_bytes = jnp.sum(jnp.where(live_s, s_size, 0.0))
-    num_live = lax.psum(local_live, REPLAY_AXIS)
-    live_bytes = lax.psum(local_bytes, REPLAY_AXIS)
+    with jax.named_scope(PSUM_SCOPE):
+        num_live = lax.psum(local_live, REPLAY_AXIS)
+        live_bytes = lax.psum(local_bytes, REPLAY_AXIS)
     return live[None], tomb[None], num_live, live_bytes
 
 
@@ -318,7 +322,8 @@ def _shard_kernel_fa(ref_width: int, has_sub: bool, want_key: bool = False):
         local_live = jnp.sum(live_bits.astype(jnp.int32))
         # the only cross-device exchange in the whole replay: one scalar
         # psum over the ICI (int32 — exact)
-        num_live = lax.psum(local_live, REPLAY_AXIS)
+        with jax.named_scope(PSUM_SCOPE):
+            num_live = lax.psum(local_live, REPLAY_AXIS)
         if want_key:
             return winner_words[None], num_live, key[None]
         return winner_words[None], num_live
@@ -384,10 +389,13 @@ def sharded_replay_select(
     the native scanner's in-scan dictionary (refs unused here — the
     sharded route re-derives per-shard refs from the codes).
 
-    `resident_sink`: when the FA route runs with chronological input and
-    no DV lane, a `ResidentPayload` is appended so the caller can keep
-    the per-shard state device-resident (see parallel/resident.py);
-    otherwise the list is left untouched."""
+    `resident_sink`: when the FA route runs with no DV lane, a
+    `ResidentPayload` is appended so the caller can keep the per-shard
+    state device-resident (see parallel/resident.py); otherwise the list
+    is left untouched. Rows that arrive out of chronological order (the
+    generic parser hands a tail's adds before its removes) are routed in
+    that order and the payload's scatter names the caller's rows, so
+    residency does not hang on which parser read the commits."""
     if mesh is None:
         mesh = make_mesh()
     n = len(path_key)
@@ -397,7 +405,7 @@ def sharded_replay_select(
     n_shards = mesh.devices.size
 
     size_orig = size  # original row order, for the exact host aggregate
-    with obs.span("replay.shard_route", rows=n, shards=n_shards):
+    with obs.span("replay.shard_route", rows=n, shards=n_shards) as sp:
         perm = None
         if not chrono_ok(np.asarray(version), np.asarray(order)):
             perm = np.lexsort((order, version)).astype(np.int64)
@@ -420,12 +428,19 @@ def sharded_replay_select(
                 path_key, dv_key,
                 np.arange(n, dtype=np.int64), np.zeros(n, np.int64),
                 is_add, size, n_shards)
+        if sp.recording:
+            # how evenly the path key fills the shards, and what a
+            # shard is padded to
+            per_shard = (fa.n_real if fa is not None
+                         else (scatter >= 0).sum(axis=1))
+            sp.set_attrs(rows_min=int(per_shard.min()),
+                         rows_max=int(per_shard.max()),
+                         m=fa.m if fa is not None else scatter.shape[1])
     spec = NamedSharding(mesh, P(REPLAY_AXIS, None))
     live_bytes = None
     if fa is not None:
         has_sub = fa.sub_radix > 1
-        want_key = (resident_sink is not None and perm is None
-                    and not has_sub)
+        want_key = resident_sink is not None and not has_sub
         ops = [fa.flag_words, *fa.ref_planes]
         if has_sub:
             ops += [np.uint32(fa.sub_radix), fa.sub_idx, fa.sub_val]
@@ -440,6 +455,8 @@ def sharded_replay_select(
                                  budget="sharded-replay-fa-plane",
                                  units=fa_rows, gate="replay",
                                  route="sharded") as dd:
+            dd.set(shards=n_shards, m=fa.m, ref_planes=len(fa.ref_planes),
+                   want_key=want_key)
             dd.h2d("flag_words", fa.flag_words)
             dd.h2d("add_words", fa.add_words)
             for i, rp in enumerate(fa.ref_planes):
@@ -456,29 +473,35 @@ def sharded_replay_select(
                                             has_sub, want_key)
             with obs.span("replay.shard_reconcile", shards=n_shards,
                           route="fa"):
+                _LAUNCHES.inc()
                 if want_key:
                     winner_sh, num_live, key_sh = fn(*device_ops)
                 else:
                     winner_sh, num_live = fn(*device_ops)
-                winner_words = dd.d2h("winner_words", np.asarray(winner_sh))
+                # the launch returns at once; this read is where the
+                # host waits for the chips
+                with obs.span("replay.wait", rows=n) as sp:
+                    winner_words = dd.d2h("winner_words",
+                                          np.asarray(winner_sh))
+                    sp.set_attr("bytes", winner_words.nbytes)
         if want_key:
+            # slots hold the rows in chronological order; the caller's
+            # masks are in its own
+            rows = fa.scatter if perm is None else np.where(
+                fa.scatter >= 0, perm[fa.scatter], -1).astype(np.int32)
             resident_sink.append(ResidentPayload(
                 key_sh=key_sh, mesh=mesh, m=fa.m,
                 n_real=fa.n_real.reshape(-1).astype(np.int64),
-                add_words=fa.add_words, scatter=fa.scatter, n=n,
+                add_words=fa.add_words, scatter=rows, n=n,
                 n_uniq=(int(np.asarray(path_key).max()) + 1) if n else 0))
-        add_words = fa.add_words
-        live_words = winner_words & add_words
-        tomb_words = winner_words & ~add_words
-        flat_live = _unpack_bits(live_words.ravel(), n_shards * fa.m)
-        flat_tomb = _unpack_bits(tomb_words.ravel(), n_shards * fa.m)
         scatter = fa.scatter
-        m = fa.m
     else:
         nbytes = sum(int(o.nbytes) for o in operands)
         with obs.device_dispatch("replay.sharded_raw",
                                  key=(n_shards, operands[0].shape[1]),
                                  gate="replay", route="sharded") as dd:
+            dd.set(shards=n_shards, m=operands[0].shape[1], ref_planes=0,
+                   want_key=False)
             dd.h2d("operands", nbytes)
             with obs.span("replay.shard_transfer", nbytes=nbytes,
                           route="raw"):
@@ -488,23 +511,32 @@ def sharded_replay_select(
             fn = _cached_fn(mesh)
             with obs.span("replay.shard_reconcile", shards=n_shards,
                           route="raw"):
+                _LAUNCHES.inc()
                 live_sh, tomb_sh, num_live, live_bytes = fn(*device_ops)
-                flat_live = np.asarray(live_sh).ravel()
-                flat_tomb = np.asarray(tomb_sh).ravel()
-        m = operands[0].shape[1]
+                with obs.span("replay.wait", rows=n) as sp:
+                    flat_live = np.asarray(live_sh).ravel()
+                    flat_tomb = np.asarray(tomb_sh).ravel()
+                    sp.set_attr("bytes", flat_live.nbytes + flat_tomb.nbytes)
 
-    live = np.zeros(n, dtype=bool)
-    tomb = np.zeros(n, dtype=bool)
-    flat_scatter = scatter.ravel()
-    sel = flat_scatter >= 0
-    live[flat_scatter[sel]] = flat_live[sel]
-    tomb[flat_scatter[sel]] = flat_tomb[sel]
-    if perm is not None:
-        inv_live = np.zeros(n, dtype=bool)
-        inv_tomb = np.zeros(n, dtype=bool)
-        inv_live[perm] = live
-        inv_tomb[perm] = tomb
-        live, tomb = inv_live, inv_tomb
+    with obs.span("replay.shard_gather", rows=n, shards=n_shards,
+                  _verbose=n < obs.PHASE_SPAN_ROWS):
+        if fa is not None:
+            live_words = winner_words & fa.add_words
+            tomb_words = winner_words & ~fa.add_words
+            flat_live = _unpack_bits(live_words.ravel(), n_shards * fa.m)
+            flat_tomb = _unpack_bits(tomb_words.ravel(), n_shards * fa.m)
+        live = np.zeros(n, dtype=bool)
+        tomb = np.zeros(n, dtype=bool)
+        flat_scatter = scatter.ravel()
+        sel = flat_scatter >= 0
+        live[flat_scatter[sel]] = flat_live[sel]
+        tomb[flat_scatter[sel]] = flat_tomb[sel]
+        if perm is not None:
+            inv_live = np.zeros(n, dtype=bool)
+            inv_tomb = np.zeros(n, dtype=bool)
+            inv_live[perm] = live
+            inv_tomb[perm] = tomb
+            live, tomb = inv_live, inv_tomb
 
     n_live = int(num_live)
     if size_orig is not None:

@@ -242,3 +242,41 @@ def test_sql_group_aggregate_4m_rows(on_chip):
             on_chip((n,), jnp.int32), on_chip((n,), jnp.int64),
             on_chip((n,), jnp.bool_), op="sum", n_seg=256).compile()
     _assert_fits(compiled)
+
+
+def test_sharded_replay_fa_6m_rows_on_four_chips(topo):
+    """The mesh route's `shard_map` program at the shape a cold load of
+    `deltalog-10m-ckpt10` gives it (6,000,380 rows: 1,500,095 a shard in
+    a bucket of 1,572,864; three byte planes of 128 refs; the key lane
+    kept), for the four chips of the described host: what the compiler
+    refuses costs no four-chip call."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from delta_tpu.parallel import sharded_replay
+    from delta_tpu.parallel.mesh import REPLAY_AXIS
+
+    shards = 4
+    mesh = Mesh(np.array(topo.devices[:shards]), (REPLAY_AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(REPLAY_AXIS, None))
+    m = replay.pad_bucket(1_500_095)
+    width = replay.key_byte_width(1_500_095)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct((shards, *dims), dtype, sharding=rows)
+
+    words = shape((m // 32,), jnp.uint32)
+    fn = sharded_replay.build_sharded_replay_fa_fn(mesh, width, False, True)
+    try:
+        compiled = fn.lower(words, *[shape((128,), jnp.uint8)] * width,
+                            shape((1,), jnp.int32), words).compile()
+    finally:
+        sharded_replay._fa_fn_cached.cache_clear()
+    _assert_fits(compiled)
+    ma = compiled.memory_analysis()
+    # a chip's own: its lane of m keys kept, the winner words, the count
+    assert ma.output_size_in_bytes < 2 * (m * 4 + m // 8)
+    text = compiled.as_text()
+    # one collective, the count's, under its scope
+    assert text.count(" all-reduce(") == 1
+    assert "replay.psum/psum" in text
