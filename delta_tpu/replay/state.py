@@ -37,6 +37,7 @@ from delta_tpu.models.actions import (
     SetTransaction,
 )
 from delta_tpu.replay.columnar import ColumnarActions, columnarize_log_segment
+from delta_tpu.replay.path_codes import first_appearance_codes
 
 # same registry instrument as parallel/resident.py: the cataloged
 # fallback counter for the replay route (route-contract lint)
@@ -272,24 +273,21 @@ def _row_to_remove(r: dict) -> RemoveFile:
 
 
 def build_replay_keys(file_actions: pa.Table) -> tuple[np.ndarray, np.ndarray]:
-    """Dictionary-encode (path, dv_id) into two int32 code arrays.
+    """Dictionary-encode (path, dv_id) into two uint32 code arrays.
 
-    pd.factorize is exact (no collisions) and C-vectorized; null dv_id
-    maps to code 0, real ids to 1+code."""
+    Path codes are `pd.factorize(paths, sort=False)`'s, first appearance
+    first, exact (Arrow's tables compare the bytes), coded over many small
+    tables at once from `path_codes.DEAL_MIN_ROWS` rows
+    (`replay/path_codes.py`); null dv_id maps to code 0, real ids to
+    1+code."""
     n = file_actions.num_rows
     with obs.span("keys.combine", rows=n):
         paths = file_actions.column("path").combine_chunks()
-    with obs.span("keys.to_pandas", rows=n):
-        paths = paths.to_pandas()
-    with obs.span("keys.factorize", rows=n):
-        path_codes, _ = pd.factorize(paths, sort=False)
-    dv = file_actions.column("dv_id").combine_chunks()
-    if dv.null_count == len(dv):
-        dv_codes = np.zeros(len(dv), dtype=np.int64)
-    else:
-        codes, _ = pd.factorize(dv.to_pandas(), sort=False, use_na_sentinel=True)
-        dv_codes = codes + 1  # NaN sentinel -1 -> 0
-    return path_codes.astype(np.uint32), dv_codes.astype(np.uint32)
+    with obs.span("keys.factorize", rows=n) as sp:
+        path_codes, engaged = first_appearance_codes(paths)
+        if sp.recording:
+            sp.set_attrs(**engaged)
+    return path_codes, _dv_codes_only(file_actions)
 
 
 def _dv_codes_only(file_actions: pa.Table) -> np.ndarray:

@@ -95,6 +95,19 @@ def shared_pool() -> DeltaThreadPool:
     return _SHARED
 
 
+_SCAN: Optional[DeltaThreadPool] = None
+
+
+def scan_pool() -> DeltaThreadPool:
+    """The process-wide pool for CPU-bound leaf work in native code that
+    releases the GIL (Arrow kernels, numpy passes): as many workers as
+    `default_scan_threads()` counts, so it never oversubscribes."""
+    global _SCAN
+    if _SCAN is None:
+        _SCAN = DeltaThreadPool("scan", default_scan_threads())
+    return _SCAN
+
+
 def parallel_map(fn: Callable[[T], R], items: Sequence[T],
                  min_parallel: int = 8) -> List[R]:
     """Ordered parallel map over an I/O-bound function; falls back to a
