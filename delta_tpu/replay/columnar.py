@@ -267,14 +267,40 @@ def _normalize_dv(arr: pa.Array, n: int) -> tuple[pa.Array, pa.Array]:
     return dv_struct, _dv_unique_id(storage, path_or_inline, offset, valid_mask, n)
 
 
-_URI_ESCAPE = pc.match_substring  # detection helper (see _decode_paths)
+# bytes compared at a time in `_any_percent`, so that the comparison's
+# result stays in the cache: the 55 MB of 2.4M paths on the chip's host
+# (PR 44) take 5.6 ms in blocks of 256 KiB and 68.6 ms compared whole
+# (`match_substring` a string 98.3, `re.search` over the buffer 17.6)
+_PERCENT_BLOCK = 1 << 18
+
+
+def _any_percent(arr) -> bool:
+    """Whether a `%` (0x25) lies in the data bytes between the first and
+    the last offset of the rows given (a slice's neighbours are not
+    read): one pass over bytes, no kernel a string. The bytes a null
+    slot still spans are read with the rest, so they can send a column
+    to the exact pass and can never make it skip one."""
+    for chunk in (arr.chunks if isinstance(arr, pa.ChunkedArray) else [arr]):
+        if pa.types.is_null(chunk.type) or not len(chunk):
+            continue
+        _, offsets, data = chunk.buffers()
+        if data is None:
+            continue
+        width = np.dtype(np.int64 if pa.types.is_large_string(chunk.type)
+                         else np.int32)
+        ends = np.frombuffer(offsets, width, len(chunk) + 1,
+                             chunk.offset * width.itemsize)
+        span = np.frombuffer(data, np.uint8)[int(ends[0]):int(ends[-1])]
+        for lo in range(0, len(span), _PERCENT_BLOCK):
+            if (span[lo:lo + _PERCENT_BLOCK] == 0x25).any():
+                return True
+    return False
 
 
 def _decode_paths(arr: pa.Array) -> pa.Array:
     """Percent-decode RFC 2396 path URIs. Fast path: untouched when no '%'
     appears (the common case for writer-generated UUID file names)."""
-    has_escape = pc.any(pc.match_substring(pc.fill_null(arr, ""), "%")).as_py()
-    if not has_escape:
+    if not _any_percent(arr):
         return arr
     from urllib.parse import unquote
 
@@ -310,7 +336,7 @@ def _extract_file_actions(
         for piece in pieces(struct_chunks, _COMBINE_WHOLE_BYTES, join=False):
             rows = slice(at, at + len(piece))
             at += len(piece)
-            block = _extract_structs(piece, is_add, versions[rows],
+            block = _extract_structs([piece], is_add, versions[rows],
                                      orders[rows])
             if block is not None:
                 blocks.append(block)
@@ -339,39 +365,95 @@ def _extract_file_actions(
         return pa.concat_tables(
             [table.slice(lo, hi - lo).combine_chunks()
              for lo, hi in zip([0] + ends, ends + [rows])])
-    with obs.span("canonicalize.combine", rows=len(struct_chunks)):
-        struct_arr = struct_chunks.combine_chunks()
-    return _extract_structs(struct_arr, is_add, versions, orders)
+    return _extract_structs(struct_chunks.chunks, is_add, versions, orders)
 
 
-def _extract_structs(struct_arr: pa.Array, is_add: bool,
+# A run of present rows travels as a slice where the runs are at least
+# this long on average, and through `filter` under it. On the chip's host
+# (PR 44; 600k add structs of the cold-load cell's checkpoint, every
+# second run there, ms by slices / by `filter`): runs of 16 rows 73.2 /
+# 25.5, 64 22.2 / 21.5, 128 14.3 / 21.7, 1,024 7.2 / 19.3 (a slice costs
+# ~3.5 us whatever it holds): the two meet between 64 and 128
+_VIEW_MIN_RUN_ROWS = 128
+
+_ROWS_VIEWED = obs.counter("canonicalize.rows_viewed")
+_ROWS_FILTERED = obs.counter("canonicalize.rows_filtered")
+
+
+def _present_runs(chunks: Sequence[pa.Array]) -> Tuple[np.ndarray, np.ndarray]:
+    """The runs of rows that are there, as (starts, stops) over the rows
+    of `chunks` laid end to end, from each chunk's validity read once. A
+    run ends with its chunk, so each is a slice of one chunk."""
+    starts, stops, at = [np.empty(0, np.int64)], [np.empty(0, np.int64)], 0
+    for chunk in chunks:
+        n = len(chunk)
+        if n and chunk.null_count == 0:
+            starts.append(np.array([at]))
+            stops.append(np.array([at + n]))
+        elif chunk.null_count < n:
+            steps = np.diff(np.asarray(pc.is_valid(chunk)).view(np.int8),
+                            prepend=0, append=0)
+            starts.append(np.flatnonzero(steps == 1) + at)
+            stops.append(np.flatnonzero(steps == -1) + at)
+        at += n
+    return np.concatenate(starts), np.concatenate(stops)
+
+
+def _extract_structs(chunks: Sequence[pa.Array], is_add: bool,
                      versions: np.ndarray,
                      orders: np.ndarray) -> Optional[pa.Table]:
-    """The add (or remove) structs of `struct_arr` that are there, in
-    the canonical schema."""
-    if pa.types.is_null(struct_arr.type):
+    """The add (or remove) structs of `chunks` that are there, in the
+    canonical schema, one chunk a column. The selection rides on the one
+    concatenation: long runs of present rows go into it as slices of
+    the chunks they lie in, short ones (adds and removes interleaved)
+    as each chunk's `filter`."""
+    if pa.types.is_null(chunks[0].type):
         return None
-    valid = pc.is_valid(struct_arr)
-    mask = np.asarray(valid, dtype=bool)
-    sel = np.nonzero(mask)[0]
-    if sel.size == 0:
-        return None
-    with obs.span("canonicalize.filter", rows=len(struct_arr)):
-        # filter, not take: selection-by-mask over a wide struct (stats
-        # strings, partitionValues maps) is ~2x faster than row gather;
-        # where every row is of the kind, nothing is copied
-        sub = struct_arr if sel.size == len(struct_arr) \
-            else struct_arr.filter(valid)
-    n = len(sub)
-    with obs.span("canonicalize.columns", rows=n):
-        return _canonical_block(sub, n, is_add, versions[sel], orders[sel])
+    rows = sum(len(c) for c in chunks)
+    with obs.span("canonicalize.filter", rows=rows) as sp:
+        starts, stops = _present_runs(chunks)
+        kept = int((stops - starts).sum())
+        if not kept:
+            return None
+        # runs that meet at a chunk's end are one run of the column
+        runs = len(starts) - int((starts[1:] == stops[:-1]).sum())
+        view = runs == 1 or kept >= runs * _VIEW_MIN_RUN_ROWS
+        sp.set_attrs(runs=runs, kept="view" if view else "filter",
+                     rows_kept=kept)
+        if view:
+            _ROWS_VIEWED.inc(kept)
+            bounds = np.cumsum([0] + [len(c) for c in chunks])
+            which = np.searchsorted(bounds, starts, side="right") - 1
+            parts = [chunks[i].slice(lo - bounds[i], hi - lo)
+                     for i, lo, hi in zip(which.tolist(), starts.tolist(),
+                                          stops.tolist())]
+        else:
+            _ROWS_FILTERED.inc(kept)
+            parts = [c.drop_null() for c in chunks if c.null_count < len(c)]
+        if runs == 1:
+            # the rows' tags follow as views too
+            tagged = slice(int(starts[0]), int(stops[-1]))
+        else:
+            steps = np.zeros(rows + 1, np.int8)
+            steps[starts] = 1
+            steps[stops] -= 1
+            tagged = np.cumsum(steps[:-1], dtype=np.int8).view(bool)
+    with obs.span("canonicalize.combine", rows=kept):
+        # one chunk a column is what every later gather relies on
+        # (`replay/state.py::gather_rows` pays for each chunk it touches)
+        sub = parts[0] if len(parts) == 1 else pa.concat_arrays(parts)
+    with obs.span("canonicalize.columns", rows=kept):
+        return _canonical_block(sub, kept, is_add, versions[tagged],
+                                orders[tagged])
 
 
 def _canonical_block(sub: pa.StructArray, n: int, is_add: bool,
                      versions: np.ndarray, orders: np.ndarray) -> pa.Table:
     """The canonical-schema table of `n` selected add or remove structs
     (`versions`/`orders` already selected to match)."""
-    path = _decode_paths(_field_or_null(sub, "path", pa.string()))
+    raw_path = _field_or_null(sub, "path", pa.string())
+    path = _decode_paths(raw_path)
+    obs.set_attr("escaped", int(path is not raw_path))
     pv = _struct_to_map(_field_or_null(sub, "partitionValues", pa.map_(pa.string(), pa.string())), n)
     size = _field_or_null(sub, "size", pa.int64())
     mod_time = _field_or_null(sub, "modificationTime", pa.int64())
@@ -452,6 +534,46 @@ def _prune_nones(d):
     return d
 
 
+def _present_rows(chunk: pa.Array) -> np.ndarray:
+    """The numbers of the rows of `chunk` that are there, rising, from
+    its validity bits as they lie: the bytes that hold a row are found
+    first, so a column of 2.4M rows all null but one (what a projected
+    read of a checkpoint hands over, in one chunk) costs its bitmap's
+    300 KB and not a byte a row."""
+    n, validity = len(chunk), chunk.buffers()[0]
+    if validity is None:
+        return np.arange(n)
+    first = chunk.offset // 8
+    packed = np.frombuffer(validity, np.uint8)[
+        first:(chunk.offset + n + 7) // 8]
+    holds = np.flatnonzero(packed)
+    byte, bit = np.nonzero(
+        np.unpackbits(packed[holds], bitorder="little").reshape(-1, 8))
+    rows = (first + holds[byte]) * 8 + bit - chunk.offset
+    return rows[(rows >= 0) & (rows < n)]
+
+
+def _present_small_rows(table: pa.Table, cols: Sequence[str]):
+    """(column, row number, body) of every row of the small-action
+    columns `cols` that is there, column by column and in row order. A
+    chunk with no such row (a checkpoint's are all but one) is passed
+    over by its `null_count`; no column is ever combined."""
+    for col in cols:
+        if col not in table.column_names:
+            continue
+        column = table.column(col)
+        if pa.types.is_null(column.type):
+            continue
+        at = 0
+        for chunk in column.chunks:
+            if chunk.null_count < len(chunk):
+                sel = _present_rows(chunk)
+                bodies = chunk.take(pa.array(sel, pa.int64())).to_pylist()
+                for i, body in zip(sel.tolist(), bodies):
+                    yield col, at + i, body
+            at += len(chunk)
+
+
 @dataclass
 class _SmallActionTracker:
     """Latest-seen-wins resolution for O(commits) actions."""
@@ -463,25 +585,18 @@ class _SmallActionTracker:
     commit_infos: Dict[int, CommitInfo] = field(default_factory=dict)
 
     def scan_chunk(self, table: pa.Table, versions: np.ndarray, orders: np.ndarray):
-        for col, handler in (
-            ("protocol", self._on_protocol),
-            ("metaData", self._on_metadata),
-            ("txn", self._on_txn),
-            ("domainMetadata", self._on_domain),
-            ("commitInfo", self._on_commit_info),
-        ):
-            if col not in table.column_names:
-                continue
-            arr = table.column(col).combine_chunks()
-            if pa.types.is_null(arr.type):
-                continue
-            mask = np.asarray(pc.is_valid(arr), dtype=bool)
-            sel = np.nonzero(mask)[0]
-            if sel.size == 0:
-                continue
-            rows = arr.take(pa.array(sel, pa.int64())).to_pylist()
-            for i, row in zip(sel, rows):
-                handler(int(versions[i]), int(orders[i]), _prune_nones(row))
+        handlers = self._handlers()
+        for col, i, row in _present_small_rows(table, tuple(handlers)):
+            handlers[col](int(versions[i]), int(orders[i]), _prune_nones(row))
+
+    def _handlers(self) -> dict:
+        return {
+            "protocol": self._on_protocol,
+            "metaData": self._on_metadata,
+            "txn": self._on_txn,
+            "domainMetadata": self._on_domain,
+            "commitInfo": self._on_commit_info,
+        }
 
     def _on_protocol(self, v, o, row):
         if (v, o) > self.protocol[:2]:
@@ -509,13 +624,7 @@ class _SmallActionTracker:
     def scan_pylist(self, rows: Sequence[Tuple[int, int, dict]]):
         """Consume (version, order, {action-key: body}) rows — the
         native scanner's non-file-action lines."""
-        handlers = {
-            "protocol": self._on_protocol,
-            "metaData": self._on_metadata,
-            "txn": self._on_txn,
-            "domainMetadata": self._on_domain,
-            "commitInfo": self._on_commit_info,
-        }
+        handlers = self._handlers()
         for v, o, row in rows:
             for key, body in row.items():
                 h = handlers.get(key)
@@ -763,21 +872,9 @@ def _extract_small_rows(
     `others` format: (version, order, {action-key: body}). Lets a cached
     generic parse feed `_SmallActionTracker.scan_pylist` on later loads
     without re-touching the Arrow chunk."""
-    rows: List[Tuple[int, int, dict]] = []
-    for col in (*SMALL_ACTION_COLUMNS, "commitInfo"):
-        if col not in table.column_names:
-            continue
-        arr = table.column(col).combine_chunks()
-        if pa.types.is_null(arr.type):
-            continue
-        mask = np.asarray(pc.is_valid(arr), dtype=bool)
-        sel = np.nonzero(mask)[0]
-        if sel.size == 0:
-            continue
-        vals = arr.take(pa.array(sel, pa.int64())).to_pylist()
-        for i, row in zip(sel, vals):
-            rows.append((int(versions[i]), int(orders[i]), {col: row}))
-    return rows
+    return [(int(versions[i]), int(orders[i]), {col: row})
+            for col, i, row in _present_small_rows(
+                table, (*SMALL_ACTION_COLUMNS, "commitInfo"))]
 
 
 class _OnceThunk:

@@ -4,6 +4,7 @@ and stage scopes of the jitted steps, the compiler's events on the
 dispatch record, and the disabled path, which must stay the shared
 no-op singletons with no listener and no JAX import."""
 
+import json
 import os
 import re
 import subprocess
@@ -14,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
+import pyarrow.parquet as pq
 import pytest
 
 import delta_tpu.api as dta
@@ -165,6 +167,19 @@ def test_span_attributes_count_the_tables_work(loads, route, table_path):
     read = attrs("checkpoint.read_part")
     assert read["bytes"] == os.path.getsize(os.path.join(log, part))
     assert read["rows"] == attrs("checkpoint.canonicalize")["rows"]
+    # the part's adds are one run behind the protocol and metaData rows:
+    # they reach the canonical table as a view, never through `filter`
+    [ckpt_file] = [a for a in _named(spans, "canonicalize.filter")
+                   if a["attrs"]["rows"] == read["rows"]]
+    assert ckpt_file["attrs"] == {"rows": read["rows"], "kept": "view",
+                                  "runs": 1, "rows_kept": COMMITS - 1}
+    assert attrs("canonicalize.small_actions")["rows"] == read["rows"]
+    [combine] = [a for a in _named(spans, "canonicalize.combine")
+                 if a["parent_id"] == ckpt_file["parent_id"]]
+    assert combine["attrs"]["rows"] == COMMITS - 1
+    [columns] = [a for a in _named(spans, "canonicalize.columns")
+                 if a["parent_id"] == ckpt_file["parent_id"]]
+    assert columns["attrs"] == {"rows": COMMITS - 1, "escaped": 0}
     if route == "device":
         assert attrs("replay.pack")["rows"] == n
         assert attrs("replay.launch")["bytes"] == \
@@ -177,6 +192,62 @@ def test_span_attributes_count_the_tables_work(loads, route, table_path):
     assert size == sum(
         os.path.getsize(os.path.join(table_path, p))
         for p in live.column("path").to_pylist())
+
+
+def _interleaved_part(tmp_path):
+    """A table behind a classic checkpoint whose adds and retained
+    removes lie row by row in turn, as Spark's hash-ordered state writes
+    them: this library's own checkpoint of a one-file table, rewritten
+    in its own schema."""
+    n = 600
+    path = str(tmp_path / "t")
+    dta.write_table(path, pa.table({"x": pa.array([1], pa.int64())}),
+                    mode="error", engine=TpuEngine())
+    Table.for_path(path, engine=TpuEngine()).checkpoint()
+    log = os.path.join(path, "_delta_log")
+    [part] = [f for f in os.listdir(log) if f.endswith(".parquet")]
+    own = pq.read_table(os.path.join(log, part))
+    head = [r for r in own.to_pylist() if r["add"] is None]
+    rows = [{"add": {"path": f"f{i}.parquet", "partitionValues": [],
+                     "size": 1, "modificationTime": 1, "dataChange": False}}
+            if i % 2 == 0 else
+            {"remove": {"path": f"f{i}.parquet", "dataChange": False,
+                        "deletionTimestamp": 1 << 60}}
+            for i in range(n)]
+    pq.write_table(pa.Table.from_pylist(head + rows, schema=own.schema),
+                   os.path.join(log, part))
+    os.remove(os.path.join(log, part.replace(".checkpoint.parquet", ".crc")))
+    with open(os.path.join(log, "_last_checkpoint"), "w") as f:
+        f.write('{"version":0,"size":%d}' % (len(head) + n))
+    return path, n
+
+
+def test_an_interleaved_part_goes_through_filter_and_the_counters_add_up(
+        tmp_path):
+    with open(os.path.join(ROOT, "delta_tpu", "resources",
+                           "metric_names.json")) as f:
+        catalog = json.load(f)["counters"]
+    viewed, filtered = (obs.counter(f"canonicalize.rows_{how}")
+                        for how in ("viewed", "filtered"))
+    assert {viewed.name, filtered.name} <= set(catalog)
+    path, n = _interleaved_part(tmp_path)
+    clear_parse_cache()
+    before = viewed.value, filtered.value
+    obs.set_trace_mode("on")
+    obs.reset_trace_buffer()
+    snap = Table.for_path(path, engine=TpuEngine()).latest_snapshot()
+    assert snap.num_files == n // 2
+    spans = [s.to_dict() for s in obs.get_finished_spans()]
+    found = {s["attrs"]["kept"]: s["attrs"]
+             for s in _named(spans, "canonicalize.filter")}
+    # the adds and the removes of the part, each a row in two
+    assert [s["attrs"]["kept"] for s in _named(
+        spans, "canonicalize.filter")] == ["filter", "filter"]
+    assert found["filter"]["runs"] == found["filter"]["rows_kept"] == n // 2
+    canonicalized = sum(s["attrs"]["rows"]
+                        for s in _named(spans, "canonicalize.columns"))
+    assert canonicalized == n == snap.state.file_actions_raw.num_rows
+    assert (viewed.value - before[0], filtered.value - before[1]) == (0, n)
 
 
 def test_the_wait_joins_the_dispatch_record_and_the_gate(loads):
