@@ -7,9 +7,10 @@ host<->device link than the 1-2 bits/row the host encoder produces. The
 device owns the regular columnar work:
 
 - snapshot state reconstruction: jit'd sort + segmented last-wins reduce
-  (`delta_tpu.ops.replay`; blockwise >HBM variant in
-  `ops.replay_blockwise`), optionally sharded over a
-  `jax.sharding.Mesh` (`delta_tpu.parallel`);
+  (`delta_tpu.ops.replay`), blockwise past HBM, sharded over a
+  `jax.sharding.Mesh` or on the host twin: which of them runs is
+  `parallel/gate.py::replay_kernel`'s answer, from this engine's `mesh`
+  and the row count;
 - MERGE match-finding: sort/segment equi-join (`delta_tpu.ops.join`);
 - data-skipping predicate evaluation over the stats index
   (`delta_tpu.stats.skipping`);

@@ -28,7 +28,6 @@ from delta_tpu.obs.device import (
     device_obs_enabled,
     device_obs_mode,
     dump_gate_log,
-    export_device_merit,
     flush_gate_decisions,
     gate_fell_back,
     gate_observation,
@@ -153,7 +152,6 @@ __all__ = [
     "device_obs_enabled",
     "device_obs_mode",
     "dump_gate_log",
-    "export_device_merit",
     "flush_gate_decisions",
     "gate_fell_back",
     "gate_observation",

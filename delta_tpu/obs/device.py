@@ -29,16 +29,15 @@ the runtime half of both:
   excluded, and undeclared lanes are violations only for
   ``device_put_exhaustive`` entries). Overruns bump
   `device.budget_violations`; ``strict`` mode raises.
-- **Gate calibration** — every `replay_route`/`parse_route`/`skip_route`
-  decision emits a structured record (inputs, predicted per-route cost,
-  chosen route, reason) which later observations join: device routes
+- **Gate calibration** — every `parallel/gate.py` route decision emits
+  a structured record (inputs, predicted per-route cost, chosen route,
+  reason) which later observations join: device routes
   join automatically at `device_dispatch` exit, host routes through
   ``gate_observation(gate, "host")``, and mid-flight fallbacks are
   marked by ``gate_fell_back()`` with the fallback cost accumulated
   onto the same record. The per-decision relative error between
   observed and predicted-for-the-chosen-route lands in the
-  `gate.calibration_error` histogram and the `delta-gate` CLI; a bench
-  run's records export as a fresh DEVICE_MERIT-shaped capture.
+  `gate.calibration_error` histogram and the `delta-gate` CLI.
 
 Gating mirrors `trace.py`: ``DELTA_TPU_DEVICE_OBS=off|on|strict``
 (default off). The disabled path is a true no-op — `device_dispatch()`
@@ -732,11 +731,6 @@ CAPTURE_ENV_KEYS = (
     "DELTA_TPU_DEVICE_PARSE",
     "DELTA_TPU_DEVICE_SKIP",
     "DELTA_TPU_DEVICE_DECODE",
-    "DELTA_TPU_LINK_MODEL",
-    "DELTA_TPU_LINK_H2D_BPS",
-    "DELTA_TPU_LINK_RTT_S",
-    "DELTA_TPU_H2D_CHUNK",
-    "DELTA_TPU_SHARDED_MIN_ROWS",
     "DELTA_TPU_RESIDENT",
     "DELTA_TPU_DEVICE_CKPT_STATS",
     "DELTA_TPU_DEVICE_DV_PACK",
@@ -810,7 +804,7 @@ def conditions_fingerprint(cond) -> str:
                      "cache_state"))
 
 
-# -- artifacts: gate log + DEVICE_MERIT capture ------------------------------
+# -- artifacts: the gate log -------------------------------------------------
 
 
 def dump_gate_log(path: str) -> int:
@@ -825,52 +819,6 @@ def dump_gate_log(path: str) -> int:
                 {k: v for k, v in rec.items() if not k.startswith("_")},
                 sort_keys=True) + "\n")
     return len(gates) + len(dispatches)
-
-
-def export_device_merit(gates: Optional[List[dict]] = None,
-                        dispatches: Optional[List[dict]] = None
-                        ) -> Dict[str, object]:
-    """Distill the session's records into a link-model capture (the
-    shape `DELTA_TPU_LINK_MODEL` reads): link bandwidth from observed
-    (h2d_bytes, wall) pairs bucketed at the 8 MB fast-chunk boundary,
-    replay_fa workload rates from joined gate decisions, conditions
-    stamped. A run on the chip with device obs on produces it; none is
-    committed (ROADMAP A1)."""
-    gates = get_gate_records() if gates is None else gates
-    dispatches = get_dispatch_records() if dispatches is None else dispatches
-    fast, slow = [], []
-    for d in dispatches:
-        nb, ns = d.get("h2d_bytes", 0), d.get("wall_ns", 0)
-        if nb and ns and not d.get("compile"):
-            (fast if nb <= (8 << 20) else slow).append(nb / (ns / 1e9))
-    link: Dict[str, object] = {"h2d_bytes_per_s": {}}
-    if fast:
-        link["h2d_bytes_per_s"][str(8 << 20)] = sorted(fast)[len(fast) // 2]
-    if slow:
-        link["h2d_bytes_per_s"][str(64 << 20)] = sorted(slow)[len(slow) // 2]
-    replay: Dict[str, object] = {}
-    host_s, dev_s, n_rows = [], [], 0
-    for g in gates:
-        if g.get("gate") != "replay" or g.get("observed_s") is None:
-            continue
-        n_rows = max(n_rows, int(g.get("inputs", {}).get("n_rows", 0)))
-        if g.get("chosen") == "host":
-            host_s.append(g["observed_s"])
-        else:
-            dev_s.append(g["observed_s"])
-    if n_rows:
-        replay["n"] = n_rows
-        if host_s:
-            replay["t_host_s"] = sorted(host_s)[len(host_s) // 2]
-        if dev_s:
-            replay["t_device_compute_s"] = sorted(dev_s)[len(dev_s) // 2]
-    return {
-        "schema": "delta-tpu/device-merit-capture/v1",
-        "conditions": capture_conditions(),
-        "link": link,
-        "workloads": {"replay_fa": replay} if replay else {},
-        "gate_calibration": summarize_gates(gates),
-    }
 
 
 def summarize_gates(records: Optional[List[dict]] = None

@@ -302,12 +302,13 @@ def _handoff_fn(m: int, k_pads: tuple):
     return jax.jit(fn)
 
 
-def launch_checkpoint_handoff(parts: Sequence[PartKeys], n_shards: int = 1,
-                              forced: Optional[str] = None, device=None):
+def launch_checkpoint_handoff(parts: Sequence[PartKeys], engine=None,
+                              device=None):
     """Launch the checkpoint-only replay straight from device-resident
     part key lanes. Returns an `ops.replay.ReplayPending` (the device
     sorts while the host assembles the Arrow table) or None when the
-    single-chip route isn't chosen / the parts disqualify.
+    plain single-chip kernel isn't what the gate picks for `engine` /
+    the parts disqualify.
 
     Host work is O(unique paths): per-part dictionaries unify into one
     global code space and only the tiny uint32 remap tables cross the
@@ -321,7 +322,6 @@ def launch_checkpoint_handoff(parts: Sequence[PartKeys], n_shards: int = 1,
     from delta_tpu.ops.pallas_kernels import _x32
     from delta_tpu.ops.replay import ReplayPending, _pack_bits, pad_bucket
     from delta_tpu.parallel import gate
-    from delta_tpu.replay.state import BLOCKWISE_MIN_ROWS
 
     # the launch consumes (or abandons) every part's code lane
     # on every return path below — residency ends with this call
@@ -332,9 +332,7 @@ def launch_checkpoint_handoff(parts: Sequence[PartKeys], n_shards: int = 1,
             return None
         if any(p.n_bad > 0 or p.codes is None for p in live):
             return None
-        if n >= BLOCKWISE_MIN_ROWS:
-            return None
-        if gate.replay_route(n, n_shards=n_shards, forced=forced) != "single":
+        if gate.replay_kernel(n, engine) != "single":
             return None
 
         # global path-code unification over RAW dictionary bytes, with the

@@ -1,67 +1,55 @@
-"""Link-model profitability gate for the replay product path.
+"""The routing gate: the one place that decides where work runs.
 
-The replay driver has three routes — host-vectorized, single-chip
-kernel, and mesh-sharded (`parallel/sharded_replay.py`) — and the right
-one depends on the *link*, not the compute: the host<->device path's
-bandwidth per transfer size and its round trip. This module turns a
-`LinkModel` into the routing decision instead of hardcoded row counts:
+Five gates, one record a decision (`obs.record_gate_decision`):
 
-- tiny segments are RTT-dominated -> host replay beats any device
-  dispatch;
-- mid-size segments -> single-chip kernel, with H2D transfers chunked
-  to the fast-bucket size (`LinkModel.chunk_bytes`);
-- large segments on a >1-device mesh -> sharded replay, where per-shard
-  state residency (parallel/resident.py) amortizes the link cost across
-  `Snapshot.update()` calls.
+- `replay`: the host twin, the single-chip kernel or the mesh-sharded
+  one (`replay_route`), and which of their five implementations runs
+  (`replay_kernel`: each device route has a blockwise variant for a log
+  past `BLOCKWISE_MIN_ROWS` a chip);
+- `parse`, `decode`, `skip`, `sql`: host or device (`parse_route`,
+  `decode_route`, `skip_route`, `sql_route`). The four share one body
+  (`_two_way`); each states only its inputs and its two cost terms.
 
-On accelerator backends the model is the `_FALLBACK_*` placeholders
-below unless `DELTA_TPU_LINK_MODEL` names a measured capture; on CPU
-backends (tests, dev boxes) transfers are memcpys and the model
-collapses to "device always profitable" so behavior is deterministic.
-Env overrides:
+A decision is, in order: the gate's override (`ROUTES[gate].env`), the
+caller's `forced`, the engine's opt-in, then host seconds against device
+seconds under `link_model()`: the placeholders below on an accelerator,
+free transfers on a CPU backend (tests, dev boxes), where the replay
+always takes the device and the two-way gates follow the opt-in alone.
+An open route breaker sends an economic device choice to its host twin.
 
-  DELTA_TPU_REPLAY_ROUTE       force "host" | "single" | "sharded"
-  DELTA_TPU_SHARDED_MIN_ROWS   row floor for the sharded route
-  DELTA_TPU_LINK_MODEL         path to a link-model json (device_merit shape)
-  DELTA_TPU_LINK_H2D_BPS       flat H2D bandwidth override (bytes/s)
-  DELTA_TPU_LINK_RTT_S         round-trip override (seconds)
-  DELTA_TPU_H2D_CHUNK          transfer chunk size override (bytes)
-  DELTA_TPU_DEVICE_PARSE       force|1|on -> device JSON parse,
-                               0|off -> host (parse_route)
-  DELTA_TPU_DEVICE_SKIP        force|1|on -> device data skipping,
-                               0|off -> host numpy twin (skip_route)
-  DELTA_TPU_DEVICE_DECODE      force|1|on -> device checkpoint page
-                               decode, 0|off -> Arrow (decode_route)
-  DELTA_TPU_DEVICE_SQL         force|1|on -> device SQL operators,
-                               0|off -> host pandas (sql_route)
+  DELTA_TPU_REPLAY_ROUTE    "host" | "single" | "sharded"
+  DELTA_TPU_DEVICE_PARSE    force|1|on|device, 0|off|host (parse_route)
+  DELTA_TPU_DEVICE_SKIP     the same (skip_route)
+  DELTA_TPU_DEVICE_DECODE   the same (decode_route)
+  DELTA_TPU_DEVICE_SQL      the same (sql_route)
 """
 
 from __future__ import annotations
 
-import functools
-import json
 import os
-from pathlib import Path
-from typing import Dict, NamedTuple, Optional
+from typing import Callable, Dict, NamedTuple, Optional
 
 from delta_tpu.obs.device import record_gate_decision
 from delta_tpu.obs.registry import counter
 
-# PLACEHOLDERS, not measured on this device: no capture of the link the
-# engine runs on today exists yet (ROADMAP A2), so these shape the
-# routing until one is supplied through DELTA_TPU_LINK_MODEL.
+# PLACEHOLDERS, not measured on this device: PR 22's smoke read the
+# link at 4.26-5.80 GB/s, and ROADMAP A1 re-prices the gate from the
+# chip's own readings (PERF.md) once a cell can judge it.
 _FALLBACK_H2D = {8 << 20: 1_050_000_000.0, 64 << 20: 29_000_000.0}
 _FALLBACK_RTT_S = 0.078
-# replay_fa workload calibration fallbacks: host-vectorized replay rate
-# and device compute rate (rows/s) when the json carries no workloads.
+# host-vectorized replay rate and device compute rate (rows/s)
 _FALLBACK_HOST_ROWS_S = 17e6
 _FALLBACK_DEVICE_ROWS_S = 170e6
 
 # Sharding below this many rows never pays on a single host: the host
 # routing pass (stable shard argsort) costs more than the per-shard sort
-# saving. Overridable; the sharded tests force it down to exercise the
-# mesh on tiny logs, bench artifacts record where the real crossover is.
+# saving. An engine built with a mesh of its own keeps it below the
+# floor (`forced`); DELTA_TPU_REPLAY_ROUTE forces either side.
 DEFAULT_SHARDED_MIN_ROWS = 4_000_000
+
+# beyond this many file actions a chip, one-shot device replay would
+# need multi-GB HBM headroom for the sort; stream blocks instead
+BLOCKWISE_MIN_ROWS = 32_000_000
 
 # FA delta coding ships ~2 bits/row of flags plus byte-packed refs for
 # the non-new minority — ~4 rows/byte is the planning estimate.
@@ -113,9 +101,6 @@ class LinkModel(NamedTuple):
     def chunk_bytes(self) -> int:
         """Largest transfer size that still rides the fastest measured
         bandwidth bucket — the H2D chunking quantum."""
-        override = os.environ.get("DELTA_TPU_H2D_CHUNK")
-        if override:
-            return int(override)
         if not self.h2d_bps:
             return 0
         return int(max(self.h2d_bps, key=lambda sz: self.h2d_bps[sz]))
@@ -131,58 +116,19 @@ class LinkModel(NamedTuple):
 
 
 _CPU_MODEL = LinkModel({}, 0.0, _FALLBACK_HOST_ROWS_S, float("inf"))
+_ACCELERATOR_MODEL = LinkModel(_FALLBACK_H2D, _FALLBACK_RTT_S,
+                               _FALLBACK_HOST_ROWS_S,
+                               _FALLBACK_DEVICE_ROWS_S)
 
 
-@functools.lru_cache(maxsize=1)
 def link_model() -> LinkModel:
-    """The active link model: the `DELTA_TPU_LINK_MODEL` capture (or the
-    placeholders) on accelerator backends, the trivial (free-transfer)
-    model on CPU backends."""
+    """The active link model: the placeholders on accelerator backends,
+    the trivial (free-transfer) model on CPU backends."""
     import jax
 
-    override = os.environ.get("DELTA_TPU_LINK_MODEL")
-    if jax.default_backend() == "cpu" and not override:
+    if jax.default_backend() == "cpu":
         return _CPU_MODEL
-
-    h2d = dict(_FALLBACK_H2D)
-    rtt = _FALLBACK_RTT_S
-    host_rate = _FALLBACK_HOST_ROWS_S
-    dev_rate = _FALLBACK_DEVICE_ROWS_S
-    if override:
-        try:
-            merit = json.loads(Path(override).read_text())
-            link = merit.get("link", {})
-            raw = link.get("h2d_bytes_per_s") or {}
-            if raw:
-                h2d = {int(k): float(v) for k, v in raw.items()}
-            rtt = float(link.get("rtt_s", rtt))
-            fa = merit.get("workloads", {}).get("replay_fa", {})
-            n = float(fa.get("n", 0))
-            if n and fa.get("t_host_s"):
-                host_rate = n / float(fa["t_host_s"])
-            if n and fa.get("t_device_compute_s"):
-                dev_rate = n / float(fa["t_device_compute_s"])
-        except (OSError, ValueError):
-            pass  # fall back to the baked-in shape
-    bps_env = os.environ.get("DELTA_TPU_LINK_H2D_BPS")
-    if bps_env:
-        h2d = {self_sz: float(bps_env) for self_sz in (h2d or {8 << 20: 0})}
-    rtt_env = os.environ.get("DELTA_TPU_LINK_RTT_S")
-    if rtt_env:
-        rtt = float(rtt_env)
-    return LinkModel(h2d, rtt, host_rate, dev_rate)
-
-
-def reset_model_cache() -> None:
-    """Drop the cached model (tests flip env knobs)."""
-    link_model.cache_clear()
-
-
-def sharded_min_rows() -> int:
-    env = os.environ.get("DELTA_TPU_SHARDED_MIN_ROWS")
-    if env:
-        return int(env)
-    return DEFAULT_SHARDED_MIN_ROWS
+    return _ACCELERATOR_MODEL
 
 
 class RouteSpec(NamedTuple):
@@ -322,10 +268,11 @@ def replay_route(
     DELTA_TPU_REPLAY_ROUTE env var outranks everything (tests, bench
     lanes). Every decision emits a gate record — inputs, per-route
     predicted seconds, chosen route, reason — for calibration against
-    the observed dispatch cost (see obs/device.py)."""
+    the observed dispatch cost (see obs/device.py). Callers with an
+    engine in hand ask `replay_kernel`."""
     inputs = {"n_rows": n_rows, "n_shards": n_shards,
               "nbytes_est": nbytes_est}
-    env_route = os.environ.get("DELTA_TPU_REPLAY_ROUTE")
+    env_route = os.environ.get(ROUTES["replay"].env)
     if env_route in ("host", "single", "sharded"):
         if env_route == "sharded" and n_shards <= 1:
             return _decide("replay", "single", inputs, reason="env")
@@ -347,9 +294,68 @@ def replay_route(
     predicted = {"host": t_host, "single": t_device, "sharded": t_device}
     if t_host < t_device:
         return _decide("replay", "host", inputs, predicted)
-    if n_shards > 1 and n_rows >= sharded_min_rows():
+    if n_shards > 1 and n_rows >= DEFAULT_SHARDED_MIN_ROWS:
         return _decide("replay", "sharded", inputs, predicted)
     return _decide("replay", "single", inputs, predicted)
+
+
+def replay_kernel(n_rows: int, engine=None) -> str:
+    """Which replay implementation runs for `n_rows` file actions on
+    `engine`: "host" (the twin), "single", "single-blockwise", "sharded"
+    or "sharded-blockwise".
+
+    The one place that reads the engine's mesh for a routing decision:
+    a mesh the caller built (`engine._mesh_forced`) keeps the sharded
+    route whatever the size. Each call records one `replay` decision
+    (`replay_route`), whose `chosen` stays host | single | sharded; a
+    device route streams blocks once a chip's share of the rows reaches
+    `BLOCKWISE_MIN_ROWS`. The early launches (`replay/columnar.py`,
+    `ops/page_decode.py`) ask whether the answer is plain "single";
+    `replay/state.py::compute_masks_device` dispatches on it."""
+    mesh = getattr(engine, "mesh", None)
+    n_shards = mesh.devices.size if mesh is not None else 1
+    forced = ("sharded" if n_shards > 1
+              and getattr(engine, "_mesh_forced", False) else None)
+    route = replay_route(n_rows, n_shards=n_shards, forced=forced)
+    if route == "host":
+        return route
+    chips = n_shards if route == "sharded" else 1
+    if n_rows >= BLOCKWISE_MIN_ROWS * chips:
+        return route + "-blockwise"
+    return route
+
+
+_DEVICE_WORDS = ("force", "1", "on", "device")
+_HOST_WORDS = ("0", "off", "host")
+
+
+def _two_way(gate: str, inputs: Dict[str, object], nonempty: bool,
+             forced: Optional[str], host_s: float,
+             device_s: Callable[[LinkModel], float],
+             probe_failed: bool = False) -> str:
+    """The body the host-or-device gates share. In order: the gate's
+    env override (outranks everything: tests, bench lanes), a broken
+    link probe (sql only), the caller's `forced`, the engine's
+    construction-time opt-in (`inputs["engine_enabled"]`; the CPU
+    free-transfer model must NOT flip these to device-always, the host
+    side IS the calibrated fast path there) and a non-positive size,
+    then the two cost terms under the link model."""
+    override = (os.environ.get(ROUTES[gate].env) or "").lower()
+    if override in _DEVICE_WORDS:
+        return _decide(gate, "device", inputs, reason="env")
+    if override in _HOST_WORDS:
+        return _decide(gate, "host", inputs, reason="env")
+    if probe_failed:
+        return _decide(gate, "host", inputs, reason="probe-failed")
+    if forced in ("host", "device"):
+        return _decide(gate, forced, inputs, reason="forced")
+    if not inputs["engine_enabled"] or not nonempty:
+        return _decide(gate, "host", inputs, reason="engine-disabled")
+    predicted = {"host": host_s, "device": device_s(link_model())}
+    return _decide(
+        gate,
+        "device" if predicted["device"] < predicted["host"] else "host",
+        inputs, predicted)
 
 
 def parse_route(
@@ -359,30 +365,14 @@ def parse_route(
 ) -> str:
     """Pick the commit-JSON parse route: "host" (C++ scanner / generic
     Arrow) or "device" (ops/json_parse.py batched field extraction).
-
-    Unlike `replay_route`, the CPU free-transfer model does NOT flip
-    this to device-always: the host C++ scanner IS the calibrated
-    fast path on CPU backends, so the device route needs the engine's
-    construction-time opt-in (`use_device_parse`, true on accelerator
-    backends) before the link economics are even consulted.
-    DELTA_TPU_DEVICE_PARSE outranks everything (tests, bench lanes)."""
-    inputs = {"nbytes": nbytes, "engine_enabled": engine_enabled}
-    env = os.environ.get("DELTA_TPU_DEVICE_PARSE")
-    if env is not None:
-        if env.lower() in ("force", "1", "on", "device"):
-            return _decide("parse", "device", inputs, reason="env")
-        if env.lower() in ("0", "off", "host"):
-            return _decide("parse", "host", inputs, reason="env")
-    if forced in ("host", "device"):
-        return _decide("parse", forced, inputs, reason="forced")
-    if not engine_enabled or nbytes <= 0:
-        return _decide("parse", "host", inputs, reason="engine-disabled")
-    model = link_model()
-    t_host = nbytes / _HOST_SCAN_BPS
-    t_device = model.h2d_seconds(nbytes) + nbytes / _DEVICE_PARSE_BPS
-    predicted = {"host": t_host, "device": t_device}
-    return _decide("parse", "device" if t_device < t_host else "host",
-                   inputs, predicted)
+    The opt-in is `use_device_parse` (true on accelerator backends);
+    the window's bytes cross the link."""
+    return _two_way(
+        "parse", {"nbytes": nbytes, "engine_enabled": engine_enabled},
+        nbytes > 0, forced,
+        host_s=nbytes / _HOST_SCAN_BPS,
+        device_s=lambda link: (link.h2d_seconds(nbytes)
+                               + nbytes / _DEVICE_PARSE_BPS))
 
 
 def decode_route(
@@ -396,31 +386,15 @@ def decode_route(
 
     Decided ONCE per checkpoint read over the parts' total byte size —
     the dispatch funnel then accumulates every part's observed cost
-    onto the single decision. Like `parse_route`, the CPU free-transfer
-    model does NOT flip this to device-always: Arrow IS the calibrated
-    fast path on CPU backends, so the device route needs the engine's
-    construction-time opt-in (`use_device_decode`, true on accelerator
-    backends) before the link economics are consulted. Unsupported
-    shapes fall back whole-part mid-flight (`obs.gate_fell_back`).
-    DELTA_TPU_DEVICE_DECODE outranks everything (tests, bench lanes)."""
-    inputs = {"nbytes": nbytes, "engine_enabled": engine_enabled}
-    env = os.environ.get("DELTA_TPU_DEVICE_DECODE")
-    if env is not None:
-        if env.lower() in ("force", "1", "on", "device"):
-            return _decide("decode", "device", inputs, reason="env")
-        if env.lower() in ("0", "off", "host"):
-            return _decide("decode", "host", inputs, reason="env")
-    if forced in ("host", "device"):
-        return _decide("decode", forced, inputs, reason="forced")
-    if not engine_enabled or nbytes <= 0:
-        return _decide("decode", "host", inputs,
-                       reason="engine-disabled")
-    model = link_model()
-    t_host = nbytes / _HOST_ARROW_BPS
-    t_device = model.h2d_seconds(nbytes) + nbytes / _DEVICE_DECODE_BPS
-    predicted = {"host": t_host, "device": t_device}
-    return _decide("decode", "device" if t_device < t_host else "host",
-                   inputs, predicted)
+    onto the single decision. The opt-in is `use_device_decode`;
+    unsupported shapes fall back whole-part mid-flight
+    (`obs.gate_fell_back`)."""
+    return _two_way(
+        "decode", {"nbytes": nbytes, "engine_enabled": engine_enabled},
+        nbytes > 0, forced,
+        host_s=nbytes / _HOST_ARROW_BPS,
+        device_s=lambda link: (link.h2d_seconds(nbytes)
+                               + nbytes / _DEVICE_DECODE_BPS))
 
 
 def sql_route(
@@ -440,34 +414,19 @@ def sql_route(
     `nbytes` is the operand bytes that must cross the link for this
     operator — rows already HBM-resident via the operand cache
     (`sqlengine/operands.py`) are excluded by the caller, which is how
-    a warm cache shifts the crossover toward the device. Like
-    `parse_route`, the device route needs the engine's opt-in
-    (`use_device_sql`, true on TpuEngine) before the economics run;
-    `probe_failed` marks a broken link probe (the decision record says
-    so instead of a spine silently resolving to None).
-    DELTA_TPU_DEVICE_SQL outranks everything (tests, bench lanes)."""
-    inputs = {"op": op, "n_rows": n_rows, "nbytes": nbytes,
-              "engine_enabled": engine_enabled}
-    env = os.environ.get("DELTA_TPU_DEVICE_SQL")
-    if env is not None and env != "":
-        if env.lower() in ("force", "1", "on", "device"):
-            return _decide("sql", "device", inputs, reason="env")
-        if env.lower() in ("0", "off", "host"):
-            return _decide("sql", "host", inputs, reason="env")
-    if probe_failed:
-        return _decide("sql", "host", inputs, reason="probe-failed")
-    if forced in ("host", "device"):
-        return _decide("sql", forced, inputs, reason="forced")
-    if not engine_enabled or n_rows <= 0:
-        return _decide("sql", "host", inputs, reason="engine-disabled")
-    model = link_model()
+    a warm cache shifts the crossover toward the device. The opt-in is
+    `use_device_sql` (true on TpuEngine); `probe_failed` marks a broken
+    link probe (the decision record says so instead of a spine silently
+    resolving to None) and outranks `forced`."""
     rate_h = _HOST_SQL_ROWS_PS.get(op, _HOST_SQL_ROWS_PS["join"])
     rate_d = _DEVICE_SQL_ROWS_PS.get(op, _DEVICE_SQL_ROWS_PS["join"])
-    t_host = n_rows / rate_h
-    t_device = model.h2d_seconds(nbytes) + n_rows / rate_d
-    predicted = {"host": t_host, "device": t_device}
-    return _decide("sql", "device" if t_device < t_host else "host",
-                   inputs, predicted)
+    return _two_way(
+        "sql", {"op": op, "n_rows": n_rows, "nbytes": nbytes,
+                "engine_enabled": engine_enabled},
+        n_rows > 0, forced,
+        host_s=n_rows / rate_h,
+        device_s=lambda link: link.h2d_seconds(nbytes) + n_rows / rate_d,
+        probe_failed=probe_failed)
 
 
 def skip_route(
@@ -478,32 +437,14 @@ def skip_route(
 ) -> str:
     """Pick the data-skipping route for one scan plan: "host" (numpy
     twin over the encoded lanes) or "device" (ops/skipping.py batched
-    kernel over the resident index).
-
-    Like `parse_route`, the CPU free-transfer model does not flip this
-    to device-always — the numpy twin is fast and allocation-free on
-    CPU backends, so the device route needs the engine's
-    construction-time opt-in (`use_device_skip`) before the economics
-    run. The economics differ from `parse_route` in one way: the lane
-    matrix is already HBM-resident (shipped once per snapshot version),
-    so the device side pays one dispatch RTT, never a bulk H2D.
-    DELTA_TPU_DEVICE_SKIP outranks everything (tests, bench lanes)."""
-    inputs = {"n_files": n_files, "n_atoms": n_atoms,
-              "engine_enabled": engine_enabled}
-    env = os.environ.get("DELTA_TPU_DEVICE_SKIP")
-    if env is not None:
-        if env.lower() in ("force", "1", "on", "device"):
-            return _decide("skip", "device", inputs, reason="env")
-        if env.lower() in ("0", "off", "host"):
-            return _decide("skip", "host", inputs, reason="env")
-    if forced in ("host", "device"):
-        return _decide("skip", forced, inputs, reason="forced")
-    if not engine_enabled or n_files <= 0 or n_atoms <= 0:
-        return _decide("skip", "host", inputs, reason="engine-disabled")
-    model = link_model()
+    kernel over the resident index). The opt-in is `use_device_skip`.
+    The lane matrix is already HBM-resident (shipped once per snapshot
+    version), so the device side pays one dispatch RTT, never a bulk
+    H2D."""
     cells = float(n_files) * float(n_atoms)
-    t_host = cells / _HOST_SKIP_CELLS_PS
-    t_device = model.rtt_s + cells / _DEVICE_SKIP_CELLS_PS
-    predicted = {"host": t_host, "device": t_device}
-    return _decide("skip", "device" if t_device < t_host else "host",
-                   inputs, predicted)
+    return _two_way(
+        "skip", {"n_files": n_files, "n_atoms": n_atoms,
+                 "engine_enabled": engine_enabled},
+        n_files > 0 and n_atoms > 0, forced,
+        host_s=cells / _HOST_SKIP_CELLS_PS,
+        device_s=lambda link: link.rtt_s + cells / _DEVICE_SKIP_CELLS_PS)

@@ -1361,12 +1361,8 @@ def _columnarize_log_segment(
                 launch_checkpoint_handoff,
             )
 
-            mesh = getattr(engine, "mesh", None)
-            n_shards = mesh.devices.size if mesh is not None else 1
-            forced = ("sharded" if n_shards > 1 and getattr(
-                engine, "_mesh_forced", False) else None)
             native_pending = launch_checkpoint_handoff(
-                handoff["parts"], n_shards=n_shards, forced=forced)
+                handoff["parts"], engine)
 
     # --- compacted deltas + commits: parallel read, one JSON parse ---
     from delta_tpu.utils import filenames as fn
@@ -1410,27 +1406,19 @@ def _columnarize_log_segment(
             # the scan's key lanes exist — the device sorts while the host
             # assembles the Arrow table.
             launch = None
-            mesh = getattr(engine, "mesh", None)
             sole_fresh = not blocks and not span_parts
             if early_replay and sole_fresh and not small_only:
                 def launch(scan, row_versions, row_orders):
                     from delta_tpu.ops.replay import replay_select_launch
                     from delta_tpu.parallel import gate
-                    from delta_tpu.replay.state import BLOCKWISE_MIN_ROWS
                     from delta_tpu.resilience import device_faults
 
-                    # Same routing decision compute_masks_device will
-                    # make: an early launch may only claim the replay
-                    # when the single-chip kernel is the chosen route
-                    # (host/sharded routes dispatch there instead).
-                    n_shards = mesh.devices.size if mesh is not None else 1
-                    forced = ("sharded" if n_shards > 1 and getattr(
-                        engine, "_mesh_forced", False) else None)
-                    if gate.replay_route(scan.n_rows, n_shards=n_shards,
-                                         forced=forced) != "single":
+                    # An early launch may only claim the replay when the
+                    # plain single-chip kernel is what the gate picks
+                    # (the host, sharded and blockwise kernels dispatch
+                    # in compute_masks_device, which asks again).
+                    if gate.replay_kernel(scan.n_rows, engine) != "single":
                         return None
-                    if scan.n_rows >= BLOCKWISE_MIN_ROWS:
-                        return None  # >HBM: compute_masks_device streams blocks
                     if row_versions.max(initial=0) >= 2**31:
                         return None
                     try:

@@ -299,11 +299,6 @@ def _dv_codes_only(file_actions: pa.Table) -> np.ndarray:
     return (codes + 1).astype(np.uint32)
 
 
-# beyond this many file actions, one-shot device replay would need
-# multi-GB HBM headroom for the sort; stream blocks instead
-BLOCKWISE_MIN_ROWS = 32_000_000
-
-
 def _replay_host_twin(columnar: ColumnarActions,
                       exc: Exception) -> tuple[np.ndarray, np.ndarray]:
     """Fallback bookkeeping + host replay after an absorbed (already
@@ -363,30 +358,27 @@ def compute_masks_device(
             sp.set_attrs(factorized=fa_hint is None,
                          bytes=fa.column("path").nbytes)
 
-    mesh = getattr(engine, "mesh", None) if engine is not None else None
-    n_shards = mesh.devices.size if mesh is not None else 1
-    forced = ("sharded" if n_shards > 1
-              and getattr(engine, "_mesh_forced", False) else None)
-    route = gate.replay_route(n, n_shards=n_shards, forced=forced)
-    if route == "host":
+    kernel = gate.replay_kernel(n, engine)
+    if kernel == "host":
         # RTT-dominated tiny segment: dispatching to the device costs
-        # more than the host-vectorized replay (DEVICE_MERIT link model)
+        # more than the host-vectorized replay (gate.py's link model)
         with obs.gate_observation("replay", "host"):
             return compute_masks_host(columnar)
-    def _run_device() -> tuple[np.ndarray, np.ndarray]:
-        if route == "sharded":
-            if n >= BLOCKWISE_MIN_ROWS * n_shards:
-                # sharded AND >HBM: each shard streams its substream in
-                # bounded blocks with a persistent bitset — the
-                # `Snapshot.scala:481-511` multi-host configuration
-                from delta_tpu.parallel.sharded_blockwise import (
-                    replay_select_sharded_blockwise,
-                )
 
-                live, tomb, _ = replay_select_sharded_blockwise(
-                    [path_codes, dv_codes], version.astype(np.int32),
-                    order, is_add, mesh)
-                return live, tomb
+    def _run_device() -> tuple[np.ndarray, np.ndarray]:
+        if kernel == "sharded-blockwise":
+            # sharded AND >HBM: each shard streams its substream in
+            # bounded blocks with a persistent bitset — the
+            # `Snapshot.scala:481-511` multi-host configuration
+            from delta_tpu.parallel.sharded_blockwise import (
+                replay_select_sharded_blockwise,
+            )
+
+            live, tomb, _ = replay_select_sharded_blockwise(
+                [path_codes, dv_codes], version.astype(np.int32),
+                order, is_add, engine.mesh)
+            return live, tomb
+        if kernel == "sharded":
             from delta_tpu.parallel import resident as _resident
             from delta_tpu.parallel.sharded_replay import (
                 sharded_replay_select,
@@ -395,7 +387,8 @@ def compute_masks_device(
             sink = [] if _resident.enabled() else None
             live, tomb, _, _ = sharded_replay_select(
                 path_codes, dv_codes, version.astype(np.int32), order,
-                is_add, mesh=mesh, fa_hint=fa_hint, resident_sink=sink,
+                is_add, mesh=engine.mesh, fa_hint=fa_hint,
+                resident_sink=sink,
             )
             if sink:
                 # keep the per-shard state on device so Snapshot.update()
@@ -404,7 +397,7 @@ def compute_masks_device(
                 columnar.resident = _resident.establish_resident(
                     sink[0], fa, path_codes)
             return live, tomb
-        if n >= BLOCKWISE_MIN_ROWS:
+        if kernel == "single-blockwise":
             # >HBM scale path (SURVEY §5.7): stream fixed-size blocks
             # through the device with a persistent key bitset instead of
             # one giant sort

@@ -23,14 +23,15 @@ the metric-name pass):
   ``CAPTURE_ENV_KEYS`` stamp tuple — the PR 16 "forgot to stamp
   DELTA_TPU_DEVICE_DECODE" class of omission.
 
-The census resolves two indirections interprocedurally: names held in
-module-level string constants (``BASELINE_ENV = "DELTA_LINT_BASELINE"``
-then ``os.environ.get(BASELINE_ENV)``) and module-local env-helper
-functions (a function passing a parameter straight to
-``os.environ.get`` — ``_env_num("DELTA_TPU_SERVE_WORKERS", 4)`` is a
-read site). Dynamic names beyond that are out of scope by design; a
-dynamic knob would surface as a dead catalog entry, which is the
-point.
+The census resolves three indirections: names held in module-level
+string constants (``BASELINE_ENV = "DELTA_LINT_BASELINE"`` then
+``os.environ.get(BASELINE_ENV)``), module-local env-helper functions
+(a function passing a parameter straight to ``os.environ.get`` —
+``_env_num("DELTA_TPU_SERVE_WORKERS", 4)`` is a read site) and the
+route registry (``os.environ.get(ROUTES[gate].env)`` in the gate
+module reads every ``env`` its literal ``ROUTES`` declares). Dynamic
+names beyond that are out of scope by design; a dynamic knob would
+surface as a dead catalog entry, which is the point.
 
 The catalog path defaults to the packaged resource and can be
 overridden with ``DELTA_LINT_ENV_CATALOG`` (fixture tests); the obs
@@ -49,8 +50,11 @@ from delta_tpu.tools.analyzer.core import Finding, ModuleInfo, Rule, register
 from delta_tpu.tools.analyzer.passes._astutil import call_name
 from delta_tpu.tools.analyzer.passes.metrics_catalog import _catalog_key_line
 from delta_tpu.tools.analyzer.passes.route_contract import (
+    REGISTRY_ENV,
+    _env_name,
     _module_str_constants,
     _obs_module,
+    _parse_routes,
     _str_const,
 )
 
@@ -124,10 +128,13 @@ class _EnvScan:
                 if cn is None:
                     continue
                 arg = node.args[0]
-                name = _str_const(arg)
-                if name is None and isinstance(arg, ast.Name):
-                    name = consts.get(arg.id)
-                if cn in _ENV_GETTERS:
+                name = _env_name(arg, consts)
+                if cn in _ENV_GETTERS and name == REGISTRY_ENV:
+                    # a read through the route registry reads every
+                    # override the registry declares
+                    for spec in _parse_routes(mod.tree)[0].values():
+                        self._add(spec.env, mod.rel, node.lineno)
+                elif cn in _ENV_GETTERS:
                     self._add(name, mod.rel, node.lineno)
                 elif cn.rpartition(".")[2] in helpers:
                     # helper reads resolve only for literal/const names

@@ -20,7 +20,9 @@ gate:
    ``record_gate_decision`` (directly or through local helpers like
    ``_decide``) with that literal gate name — and every such function
    has a ``ROUTES`` entry (both directions);
-2. the route function reads its declared env override knob;
+2. the route function reads its declared env override knob, by name
+   or through the registry (``os.environ.get(ROUTES[gate].env)``),
+   itself or in a local helper it calls;
 3. the knob is stamped into the obs module's ``CAPTURE_ENV_KEYS``
    (consumed by ``capture_conditions()``);
 4. at least one ``device_dispatch(..., gate="<g>")`` funnel exists
@@ -185,18 +187,36 @@ def _module_str_constants(tree: ast.Module) -> Dict[str, str]:
     return out
 
 
+# what `_env_name` answers for `ROUTES[<gate>].env`: every registered
+# override, whichever gate the subscript names at run time
+REGISTRY_ENV = "ROUTES[].env"
+
+
 def _env_name(arg: ast.AST, consts: Dict[str, str]) -> Optional[str]:
     name = _str_const(arg)
     if name is None and isinstance(arg, ast.Name):
         name = consts.get(arg.id)
+    if name is None and isinstance(arg, ast.Attribute) \
+            and arg.attr == "env" and isinstance(arg.value, ast.Subscript) \
+            and isinstance(arg.value.value, ast.Name) \
+            and arg.value.value.id == "ROUTES":
+        name = REGISTRY_ENV
     return name
 
 
-def _env_reads(fn: ast.AST, consts: Dict[str, str]) -> Set[str]:
+def _env_reads(fn: ast.AST, consts: Dict[str, str],
+               local: Optional[Dict[str, ast.FunctionDef]] = None
+               ) -> Set[str]:
     """Env-var names this function reads via os.environ.get /
-    os.getenv / os.environ[...] (literal or module-constant names)."""
+    os.getenv / os.environ[...] (literal or module-constant names, or
+    `REGISTRY_ENV`), in its own body or in a `local` function it
+    calls."""
     out: Set[str] = set()
     for node in ast.walk(fn):
+        if local and isinstance(node, ast.Call):
+            callee = local.get((call_name(node) or "").rpartition(".")[2])
+            if callee is not None and callee is not fn:
+                out |= _env_reads(callee, consts)
         if isinstance(node, ast.Call) and node.args:
             cn = call_name(node)
             if cn in ("os.environ.get", "environ.get", "os.getenv",
@@ -363,8 +383,8 @@ class RouteContractRule(Rule):
             line = fn.lineno if fn is not None else routes_line
 
             # 2. env override read
-            if spec.env and fn is not None \
-                    and spec.env not in _env_reads(fn, consts):
+            if spec.env and fn is not None and not (
+                    {spec.env, REGISTRY_ENV} & _env_reads(fn, consts, local)):
                 out.append(Finding(
                     self.id, gate_mod.rel, line, 0,
                     f"route {g!r}: declared env override {spec.env!r} "

@@ -14,12 +14,7 @@ Usage::
     delta-gate gate_log.jsonl                 # calibration table
     delta-gate gate_log.jsonl --dispatches    # per-kernel dispatch rollup
     delta-gate gate_log.jsonl --json          # summary as JSON
-    delta-gate gate_log.jsonl --merit out.json  # link-model capture
     python -m delta_tpu.tools.gate_cli ...    # same, without the script
-
-``--merit`` distills the log into a link-model capture (observed link
-bandwidth, replay workload rates, capture conditions), the shape
-``DELTA_TPU_LINK_MODEL`` reads back.
 """
 
 from __future__ import annotations
@@ -29,7 +24,7 @@ import json
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
-from delta_tpu.obs.device import export_device_merit, summarize_gates
+from delta_tpu.obs.device import summarize_gates
 
 
 def load_gate_log(path: str) -> Tuple[List[dict], List[dict]]:
@@ -123,9 +118,6 @@ def main(argv: Optional[list] = None) -> int:
     parser.add_argument("--dispatches", action="store_true",
                         help="per-kernel dispatch rollup instead of the "
                              "calibration table")
-    parser.add_argument("--merit", metavar="OUT",
-                        help="also write a DEVICE_MERIT-shaped capture "
-                             "distilled from the log")
     args = parser.parse_args(argv)
 
     try:
@@ -143,13 +135,6 @@ def main(argv: Optional[list] = None) -> int:
         payload = summarize_gates(gates)
         print(json.dumps(payload, indent=2) if args.json
               else render_calibration(payload))
-
-    if args.merit:
-        capture = export_device_merit(gates, dispatches)
-        with open(args.merit, "w", encoding="utf-8") as f:
-            json.dump(capture, f, indent=2, sort_keys=True)
-            f.write("\n")
-        print(f"merit capture -> {args.merit}", file=sys.stderr)
     return 0
 
 

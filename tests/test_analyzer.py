@@ -2324,6 +2324,45 @@ def test_route_contract_missing_env_read(tmp_path, monkeypatch):
     assert "is never read in demo_route()" in found[0].message
 
 
+_SHARED_BODY_GATE_SRC = """
+import os
+from delta_tpu.obs.device import record_gate_decision
+
+ROUTES = {
+    "demo": RouteSpec(env="DELTA_TPU_DEMO",
+                      fallback_counter="demo.fallbacks",
+                      doc_anchor="demo-route"),
+}
+
+def _decide(gate, chosen):
+    record_gate_decision(gate, chosen, {}, None, "x")
+    return chosen
+
+def _two_way(gate, n):
+    if os.environ.get(%s):
+        return _decide(gate, "device")
+    return _decide(gate, "host")
+
+def demo_route(n):
+    return _two_way("demo", n)
+"""
+
+
+@pytest.mark.parametrize("read,clean", [
+    ("ROUTES[gate].env", True), ('"DELTA_TPU_DEMO"', True),
+    ('"DELTA_TPU_OTHER"', False)])
+def test_route_contract_env_read_in_a_shared_body(tmp_path, monkeypatch,
+                                                  read, clean):
+    """The override may be read through the registry, in the one body
+    the route functions share."""
+    report = _route_fixture(tmp_path, monkeypatch,
+                            gate_src=_SHARED_BODY_GATE_SRC % read)
+    found = _rules_fired(report, "route-contract")
+    assert (not found) == clean, [f.message for f in found]
+    if not clean:
+        assert "is never read in demo_route()" in found[0].message
+
+
 def test_route_contract_missing_capture_stamp(tmp_path, monkeypatch):
     report = _route_fixture(tmp_path, monkeypatch, capture_key=False)
     found = _rules_fired(report, "route-contract")
@@ -2737,6 +2776,32 @@ W = _env_num("DELTA_TPU_BAZ", 1)
 """
     report = analyze_sources({"pkg/a.py": src}, rules=_ENV_RULES)
     assert not report.findings, [f.message for f in report.findings]
+
+
+def test_env_knob_read_through_the_route_registry(tmp_path, monkeypatch):
+    """`os.environ.get(ROUTES[gate].env)` reads every override the
+    module's registry declares, and nothing it does not."""
+    knob = {"default": "", "modules": ["pkg/gate.py"], "doc": "x",
+            "help": "h"}
+    _env_catalog(tmp_path, monkeypatch, {
+        "DELTA_TPU_DEMO": knob, "DELTA_TPU_OTHER": knob,
+        "DELTA_TPU_GHOST": knob})
+    src = """
+import os
+
+ROUTES = {
+    "demo": RouteSpec(env="DELTA_TPU_DEMO", fallback_counter="",
+                      doc_anchor=""),
+    "other": RouteSpec(env="DELTA_TPU_OTHER", fallback_counter="",
+                       doc_anchor=""),
+}
+
+def _two_way(gate):
+    return os.environ.get(ROUTES[gate].env)
+"""
+    report = analyze_sources({"pkg/gate.py": src}, rules=_ENV_RULES)
+    assert ["'DELTA_TPU_GHOST'" in f.message for f in report.findings] \
+        == [True], [f.message for f in report.findings]
 
 
 def test_env_knob_capture_stamp_missing_flagged(tmp_path, monkeypatch):

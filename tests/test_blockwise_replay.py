@@ -70,7 +70,7 @@ def test_product_load_routes_blockwise_above_threshold(
     import pyarrow as pa
 
     import delta_tpu.api as dta
-    import delta_tpu.replay.state as state_mod
+    import delta_tpu.parallel.gate as gate_mod
     from delta_tpu.engine.tpu import TpuEngine
     from delta_tpu.table import Table
 
@@ -82,7 +82,7 @@ def test_product_load_routes_blockwise_above_threshold(
             {"id": pa.array([i], pa.int64())}), mode="append")
 
     normal = Table.for_path(tmp_table_path, TpuEngine()).latest_snapshot()
-    monkeypatch.setattr(state_mod, "BLOCKWISE_MIN_ROWS", 1)
+    monkeypatch.setattr(gate_mod, "BLOCKWISE_MIN_ROWS", 1)
     blockwise = Table.for_path(
         tmp_table_path, TpuEngine()).latest_snapshot()
     a = sorted(normal.state.add_files_table.column("path").to_pylist())

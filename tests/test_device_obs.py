@@ -370,42 +370,6 @@ def test_dump_gate_log_round_trips_through_cli(tmp_path, capsys):
     assert "replay.single_fa" in out and "h2d=512" in out
 
 
-def test_gate_cli_merit_export(tmp_path, capsys):
-    log = _seed_records(tmp_path)
-    merit_out = tmp_path / "merit.json"
-    assert gate_cli.main([str(log), "--merit", str(merit_out)]) == 0
-    capture = json.loads(merit_out.read_text())
-    assert capture["schema"] == "delta-tpu/device-merit-capture/v1"
-    assert capture["conditions"]["schema"] == obs.CONDITIONS_SCHEMA
-    assert "replay" in capture["gate_calibration"]
-    assert capture["workloads"]["replay_fa"]["n"] == 128
-
-
-def test_export_device_merit_buckets_link_bandwidth():
-    # two steady 4MB dispatches at ~4GB/s + one compile (excluded)
-    mb4 = 4 << 20
-    dispatches = [
-        {"type": "device_dispatch", "h2d_bytes": mb4, "wall_ns": 1_000_000,
-         "compile": False},
-        {"type": "device_dispatch", "h2d_bytes": mb4, "wall_ns": 2_000_000,
-         "compile": False},
-        {"type": "device_dispatch", "h2d_bytes": mb4, "wall_ns": 10,
-         "compile": True},
-        {"type": "device_dispatch", "h2d_bytes": 64 << 20,
-         "wall_ns": 20_000_000, "compile": False},
-    ]
-    gates = [{"type": "gate_decision", "gate": "replay", "chosen": "host",
-              "observed_s": 0.25, "inputs": {"n_rows": 1 << 20},
-              "predicted_s": {}}]
-    cap = obs.export_device_merit(gates, dispatches)
-    bps = cap["link"]["h2d_bytes_per_s"]
-    # upper-median of the two steady rates; the compile is excluded
-    assert bps[str(8 << 20)] == pytest.approx(mb4 / 1e-3)
-    assert bps[str(64 << 20)] == pytest.approx((64 << 20) / 20e-3)
-    assert cap["workloads"]["replay_fa"] == {
-        "n": 1 << 20, "t_host_s": 0.25}
-
-
 # ------------------------------ flight recorder / chrome wiring -------------
 
 def test_gate_events_reach_flight_recorder_and_chrome_export():
