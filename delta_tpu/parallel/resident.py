@@ -28,6 +28,14 @@ cannot express (DV rows, batches older than the resident tail, capacity
 overflow) returns None and the caller falls back to the host delta
 path, dropping residency; in-batch disorder is sorted away, not
 rejected — real commits columnarize removes after adds. Disable with DELTA_TPU_RESIDENT=0.
+
+What the chip found (PR 46, `ckpt-query-under-ingest-10m-v5e4`: 6.0M
+rows on four v5e chips, the lanes really donated): every refresh took
+route=resident and none fell back; an append is ~69 ms of a ~205 ms
+refresh, ~56 of them on the host (`resident.masks` ~52: both masks
+rebuilt over all `shards x m` slots) and ~13 waiting for the chips.
+The host route beside it on one machine, and the verdict that pair
+leaves open (ROADMAP C2): PERF.md §6.
 """
 
 from __future__ import annotations
@@ -213,34 +221,49 @@ class ResidentShardState:
                 _FALLBACKS.inc()
                 return None
 
+        # the phases below are spans under `on` from PHASE_SPAN_ROWS
+        # rows held, as the host route's are (replay/state.py)
+        small = self.n < obs.PHASE_SPAN_ROWS
         with obs.span("replay.resident_append", rows=d, base=self.n):
-            codes = self._code_paths(delta_fa.column("path").to_pylist())
-            is_add = np.asarray(delta_fa.column("is_add"), bool)
-            codes_c = codes[chrono]
-            is_add_c = is_add[chrono]
-            s = self.n_shards
-            shard_of = (codes_c % np.uint32(s)).astype(np.int64)
-            counts = np.bincount(shard_of, minlength=s)
-            new_n_real = self.n_real + counts
-            if int(new_n_real.max(initial=0)) > self.m:
-                _FALLBACKS.inc()  # shard full: re-establish on next load
-                return None
+            if self._index is None:
+                with obs.span("resident.index_build", _verbose=small,
+                              rows=len(self._base_codes)):
+                    self._ensure_index()
+            with obs.span("resident.code_paths", _verbose=small,
+                          rows=d) as ph:
+                known = self.n_uniq
+                codes = self._code_paths(
+                    delta_fa.column("path").to_pylist())
+                ph.set_attr("new_paths", self.n_uniq - known)
+            with obs.span("resident.place", _verbose=small) as ph:
+                is_add = np.asarray(delta_fa.column("is_add"), bool)
+                codes_c = codes[chrono]
+                is_add_c = is_add[chrono]
+                s = self.n_shards
+                shard_of = (codes_c % np.uint32(s)).astype(np.int64)
+                counts = np.bincount(shard_of, minlength=s)
+                new_n_real = self.n_real + counts
+                if int(new_n_real.max(initial=0)) > self.m:
+                    _FALLBACKS.inc()  # shard full: re-establish on next load
+                    return None
 
-            # slot of row i = shard fill level + rank among its shard's
-            # delta rows (stable shard sort keeps chronological order)
-            sort_idx = np.argsort(shard_of, kind="stable")
-            starts = np.zeros(s + 1, np.int64)
-            np.cumsum(counts, out=starts[1:])
-            rows = shard_of[sort_idx]
-            slots = (np.arange(d) - starts[rows]) + self.n_real[rows]
+                # slot of row i = shard fill level + rank among its
+                # shard's delta rows (stable shard sort keeps
+                # chronological order)
+                sort_idx = np.argsort(shard_of, kind="stable")
+                starts = np.zeros(s + 1, np.int64)
+                np.cumsum(counts, out=starts[1:])
+                rows = shard_of[sort_idx]
+                slots = (np.arange(d) - starts[rows]) + self.n_real[rows]
 
-            d_pad = max(128, 1 << int(d - 1).bit_length()) if d else 128
-            idx2d = np.full((s, d_pad), self.m, np.int32)  # m = drop
-            val2d = np.zeros((s, d_pad), np.uint32)
-            cols = np.arange(d) - starts[rows]
-            idx2d[rows, cols] = slots.astype(np.int32)
-            val2d[rows, cols] = (codes_c[sort_idx] //
-                                 np.uint32(s)).astype(np.uint32)
+                d_pad = max(128, 1 << int(d - 1).bit_length()) if d else 128
+                idx2d = np.full((s, d_pad), self.m, np.int32)  # m = drop
+                val2d = np.zeros((s, d_pad), np.uint32)
+                cols = np.arange(d) - starts[rows]
+                idx2d[rows, cols] = slots.astype(np.int32)
+                val2d[rows, cols] = (codes_c[sort_idx] //
+                                     np.uint32(s)).astype(np.uint32)
+                ph.set_attr("d_pad", d_pad)
 
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -257,6 +280,7 @@ class ResidentShardState:
                                      key=(s, d_pad),
                                      budget="resident-append",
                                      units=s * d_pad) as dd:
+                dd.set(shards=s, m=self.m, d_pad=d_pad)
                 dd.h2d("idx2d", idx2d)
                 dd.h2d("val2d", val2d)
                 dd.h2d("n_real_op", n_real_op)
@@ -281,17 +305,23 @@ class ResidentShardState:
             if d:
                 self._max_version = int(version[chrono[-1]])
 
-            winner_np = np.asarray(winner_sh)  # [S, M/32] packed D2H
-            winner = np.unpackbits(
-                winner_np.view(np.uint8).reshape(s, -1),
-                axis=1, bitorder="little")[:, :self.m].astype(bool)
-            live_slots = winner & self.add
-            tomb_slots = winner & ~self.add
-            valid = self.scatter >= 0
-            live = np.zeros(self.n, bool)
-            tomb = np.zeros(self.n, bool)
-            live[self.scatter[valid]] = live_slots[valid]
-            tomb[self.scatter[valid]] = tomb_slots[valid]
+            # the launch returned at once; this read is where the host
+            # waits for the chips
+            with obs.span("resident.wait", rows=self.n) as ph:
+                winner_np = np.asarray(winner_sh)  # [S, M/32] packed D2H
+                ph.set_attr("bytes", winner_np.nbytes)
+            with obs.span("resident.masks", _verbose=small,
+                          slots=s * self.m):
+                winner = np.unpackbits(
+                    winner_np.view(np.uint8).reshape(s, -1),
+                    axis=1, bitorder="little")[:, :self.m].astype(bool)
+                live_slots = winner & self.add
+                tomb_slots = winner & ~self.add
+                valid = self.scatter >= 0
+                live = np.zeros(self.n, bool)
+                tomb = np.zeros(self.n, bool)
+                live[self.scatter[valid]] = live_slots[valid]
+                tomb[self.scatter[valid]] = tomb_slots[valid]
             _APPENDS.inc()
             return live, tomb
 

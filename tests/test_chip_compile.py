@@ -280,3 +280,42 @@ def test_sharded_replay_fa_6m_rows_on_four_chips(topo):
     # one collective, the count's, under its scope
     assert text.count(" all-reduce(") == 1
     assert "replay.psum/psum" in text
+
+
+def test_resident_append_6m_rows_on_four_chips(topo, monkeypatch):
+    """The resident route's append (`parallel/resident.py`) at the shape
+    a refresh of `deltalog-10m-stream` gives it: four key lanes of
+    1,572,864 slots, a landed commit's 100 rows in 128 delta slots a
+    shard, the lanes donated as on a chip (the CPU backend does not
+    donate, so the builder is steered onto its TPU branch here): the
+    scatter and the sort in place, no second lane."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from delta_tpu.parallel import resident
+    from delta_tpu.parallel.mesh import REPLAY_AXIS
+
+    shards, d_pad = 4, 128
+    mesh = Mesh(np.array(topo.devices[:shards]), (REPLAY_AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(REPLAY_AXIS, None))
+    m = replay.pad_bucket(1_500_095)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct((shards, *dims), dtype, sharding=rows)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        fn = resident._append_fn_cached(mesh, d_pad)
+        compiled = fn.lower(shape((m,), jnp.uint32),
+                            shape((d_pad,), jnp.int32),
+                            shape((d_pad,), jnp.uint32),
+                            shape((1,), jnp.int32)).compile()
+    finally:
+        resident._append_fn_cached.cache_clear()
+    _assert_fits(compiled)
+    ma = compiled.memory_analysis()
+    # a chip's own: its lane of m keys comes back in the buffer it came
+    # in, beside the winner words
+    assert ma.alias_size_in_bytes == m * 4
+    assert ma.output_size_in_bytes <= m * 4 + m // 8 + 1024
+    assert " all-reduce(" not in compiled.as_text()     # no shard asks another
