@@ -27,7 +27,12 @@ from delta_tpu.engine.spi import (
     ParquetHandler,
 )
 from delta_tpu.resilience import endpoint_of, io_call
-from delta_tpu.storage.logstore import FileStatus, LogStore, logstore_for_path
+from delta_tpu.storage.logstore import (
+    FileStatus,
+    LocalLogStore,
+    LogStore,
+    logstore_for_path,
+)
 
 # process-wide storage I/O counters; per-file spans are verbose-only
 # (a 100k-commit load would emit 100k spans), the counters always run
@@ -38,8 +43,104 @@ _WRITE_CALLS = obs.counter("storage.write.calls")
 _WRITE_BYTES = obs.counter("storage.write.bytes")
 _PARQUET_PREFETCHED = obs.counter("storage.parquet.prefetched_files")
 
+_SMALL_GROUPS_SKIPPED = obs.counter("checkpoint.small_row_groups_skipped")
+
 # how many parquet byte-reads to keep in flight ahead of the decoder
 _PARQUET_PREFETCH_DEPTH = 2
+
+# rows a batch of a `present_only` read: a row group is left as soon as
+# the batches read hold every present row its footer counts
+_PRESENT_BATCH_ROWS = 65536
+
+
+def _local_os_path(store: LogStore, path: str) -> Optional[str]:
+    """`path` as the operating system names it where `store` is the
+    local one, else None."""
+    if not isinstance(store, LocalLogStore):
+        return None
+    return path[len("file://"):] if path.startswith("file://") else path
+
+
+def _leaves_under(f: pq.ParquetFile, cols: List[str]) -> dict:
+    """column of `cols` -> [(leaf's index in the file, it does not
+    repeat)] of the leaves under it. A column whose name holds a dot
+    finds none, and is then one the footer says nothing of."""
+    leaves: dict = {c: [] for c in cols}
+    for i in range(f.metadata.num_columns):
+        leaf = f.schema.column(i)
+        top = leaf.path.split(".", 1)[0]
+        if top in leaves:
+            leaves[top].append((i, leaf.max_repetition_level == 0))
+    return leaves
+
+
+def _present_counts(f: pq.ParquetFile, leaves: dict) -> List[dict]:
+    """Per row group of `f`, by the footer's statistics alone: for each
+    column of `leaves` (`_leaves_under`), how many rows of the group
+    hold it. 0 where every leaf under the column reads `null_count ==
+    num_values`; the largest count of non-null values among its leaves
+    that do not repeat (a struct that is there has at least that many
+    rows); None where the footer cannot say (a leaf without statistics
+    or without a `null_count`, values under repeated leaves only, no
+    leaf found), which reads the group to its end."""
+    groups = []
+    for g in range(f.metadata.num_row_groups):
+        rg = f.metadata.row_group(g)
+        counts = {}
+        for c, under in leaves.items():
+            some, flat = not under, 0
+            for i, is_flat in under:
+                chunk = rg.column(i)
+                st = chunk.statistics if chunk.is_stats_set else None
+                if st is None or not st.has_null_count:
+                    some, flat = True, None
+                    break
+                held = chunk.num_values - st.null_count
+                some = some or held > 0
+                if is_flat:
+                    flat = max(flat, held)
+            counts[c] = (flat or None) if some else 0
+        groups.append(counts)
+    return groups
+
+
+def _read_present(f: pq.ParquetFile, cols: List[str],
+                  fetched: Optional[int] = None) -> pa.Table:
+    """The `present_only` read of `cols` (all in the file): the row
+    groups that may hold a non-null value of one of them, each read in
+    batches until as many present rows of every column were seen as its
+    footer counts. The active span learns what the footer spared;
+    `fetched` is the file's size where it was fetched whole, else the
+    bytes read are the footer and the chosen groups' column chunks."""
+    md = f.metadata
+    leaves = _leaves_under(f, cols)
+    want = _present_counts(f, leaves)
+    chosen = [g for g, counts in enumerate(want)
+              if any(n != 0 for n in counts.values())]
+    _SMALL_GROUPS_SKIPPED.inc(md.num_row_groups - len(chosen))
+    batches = []
+    for g in chosen:
+        need = want[g]
+        bounded = None not in need.values()
+        seen = dict.fromkeys(cols, 0)
+        for batch in f.iter_batches(batch_size=_PRESENT_BATCH_ROWS,
+                                    row_groups=[g], columns=cols):
+            batches.append(batch)
+            if not bounded:
+                continue
+            for c in cols:
+                seen[c] += len(batch) - batch.column(c).null_count
+            if all(seen[c] >= need[c] for c in cols):
+                break
+    if fetched is None:
+        fetched = md.serialized_size + 8 + sum(
+            md.row_group(g).column(i).total_compressed_size
+            for g in chosen for under in leaves.values() for i, _ in under)
+    obs.set_attrs(row_groups=md.num_row_groups, row_groups_read=len(chosen),
+                  file_rows=md.num_rows, bytes_read=fetched)
+    schema = pa.schema([f.schema_arrow.field(c) for c in cols],
+                       metadata=f.schema_arrow.metadata)
+    return pa.Table.from_batches(batches, schema=schema)
 
 
 class HostJsonHandler(JsonHandler):
@@ -97,9 +198,11 @@ class HostParquetHandler(ParquetHandler):
     def __init__(self, store_resolver=logstore_for_path):
         self._store_for = store_resolver
 
-    def _decode(self, data: bytes, columns: Optional[List[str]]) -> pa.Table:
+    def _decode(self, source: pa.NativeFile, columns: Optional[List[str]],
+                present_only: bool = False,
+                fetched: Optional[int] = None) -> pa.Table:
         if columns is None:
-            return pq.read_table(pa.BufferReader(data))
+            return pq.read_table(source)
         # one footer parse serves both the schema check and the
         # read. Project onto the columns the file actually has — a
         # checkpoint from another engine may omit e.g. txn or
@@ -107,19 +210,36 @@ class HostParquetHandler(ParquetHandler):
         # read-twice fallbacks. An empty intersection stays an empty
         # projection (0 columns, correct row count) — never a
         # decode-everything full read.
-        f = pq.ParquetFile(pa.BufferReader(data))
+        f = pq.ParquetFile(source)
         present = set(f.schema_arrow.names)
-        return f.read(columns=[c for c in columns if c in present])
+        cols = [c for c in columns if c in present]
+        if present_only and cols:
+            return _read_present(f, cols, fetched)
+        return f.read(columns=cols)
 
     def read_parquet_files(
-        self, paths: Sequence[str], columns: Optional[List[str]] = None
+        self, paths: Sequence[str], columns: Optional[List[str]] = None,
+        present_only: bool = False,
     ) -> Iterator[pa.Table]:
         paths = list(paths)
         if len(paths) <= 1:
             for p in paths:
                 store = self._store_for(p)
+                local = (_local_os_path(store, p)
+                         if present_only and columns is not None else None)
+                if local is not None:
+                    # opened by path, Arrow reads the footer and the
+                    # column chunks it is asked for and nothing else;
+                    # only the open is a storage call: a torn footer
+                    # raises from the decode, as on bytes read whole
+                    with io_call(endpoint_of(p),
+                                 lambda: pa.OSFile(local)) as source:
+                        tbl = self._decode(source, columns, True)
+                    yield tbl
+                    continue
                 data = io_call(endpoint_of(p), lambda: store.read(p))
-                yield self._decode(data, columns)
+                yield self._decode(pa.BufferReader(data), columns,
+                                   present_only, len(data))
             return
         # Byte-prefetch: keep the next reads in flight on the shared I/O
         # pool so decoding file i overlaps reading file i+1 (checkpoint
@@ -141,7 +261,9 @@ class HostParquetHandler(ParquetHandler):
                         _PARQUET_PREFETCHED.inc()
                     pending.append(pool.submit(read, paths[i]))
                     i += 1
-                yield self._decode(pending.popleft().result(), columns)
+                data = pending.popleft().result()
+                yield self._decode(pa.BufferReader(data), columns,
+                                   present_only, len(data))
         finally:
             for fut in pending:
                 fut.cancel()
@@ -237,11 +359,7 @@ class HostFileSystemClient(FileSystemClient):
         return path
 
     def os_path(self, path: str):
-        from delta_tpu.storage.logstore import LocalLogStore
-
-        if not isinstance(self._store_for(path), LocalLogStore):
-            return None
-        return path[len("file://"):] if path.startswith("file://") else path
+        return _local_os_path(self._store_for(path), path)
 
     def mkdirs(self, path: str) -> None:
         self._store_for(path).mkdirs(path)

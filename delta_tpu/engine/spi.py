@@ -39,8 +39,24 @@ class ParquetHandler:
     """Read/write Parquet (checkpoints, data files)."""
 
     def read_parquet_files(
-        self, paths: Sequence[str], columns: Optional[List[str]] = None
+        self, paths: Sequence[str], columns: Optional[List[str]] = None,
+        present_only: bool = False,
     ) -> Iterator[pa.Table]:
+        """One table a file, in the order of `paths`; `columns` projects
+        onto those of them the file has.
+
+        `present_only` is a hint in the manner of the predicate of Delta
+        Kernel's `readParquetFiles(files, schema, predicate)`: the caller
+        will use only the rows in which at least one of `columns` is
+        non-null, so the handler may leave out any of the other rows
+        (and number the rows it hands back as they come). Handing back
+        more rows, or all of them, is always right, so a handler may
+        ignore it. Only the small-action read of a checkpoint part
+        passes it; a read of a data file must not, since it wants every
+        row. Its one limit: a handler that decides by the footer's
+        statistics, which count nulls leaf by leaf, cannot see a struct
+        that is there with every leaf null. PROTOCOL.md gives each small
+        action a required field, so no valid checkpoint holds one."""
         raise NotImplementedError
 
     def write_parquet_file(self, path: str, table: pa.Table) -> FileStatus:

@@ -1206,12 +1206,22 @@ def _columnarize_log_segment(
             engine.fs.read_file(fstat.path))), fstat.size)
 
     def _read_checkpoint_part(path: str):
+        """The part's table. The small-action read asks for the small
+        columns and, by `present_only`, for no more than the rows that
+        hold one: the host handler then reads the part's footer, the row
+        groups whose statistics admit a small action and, of each, the
+        batches up to the last one the statistics count (a part without
+        statistics is read whole, as before). The rows come numbered as
+        handed back; within a checkpoint a row's position decides
+        nothing. The hint's one limit (a small action with every leaf
+        null) is `ParquetHandler.read_parquet_files`'s to state."""
         if not small_only:
             yield from engine.parquet.read_parquet_files([path])
             return
         try:
             yield from engine.parquet.read_parquet_files(
-                [path], columns=list(SMALL_ACTION_COLUMNS))
+                [path], columns=list(SMALL_ACTION_COLUMNS),
+                present_only=True)
         except (pa.ArrowException, KeyError, ValueError):
             # part lacks some small column (e.g. a multipart tail part
             # written by another engine): fall back to a full read

@@ -182,7 +182,16 @@ class Snapshot:
         """Small actions WITHOUT the file replay (P&M fast path,
         `Snapshot.scala:440`): metadata-only consumers on a large table
         never pay for decoding the checkpoint's add/remove columns. The
-        full state, once materialized, serves as the small state too."""
+        full state, once materialized, serves as the small state too.
+
+        Behind a checkpoint the read asks each part for the small
+        columns with `present_only` (`ParquetHandler.read_parquet_files`):
+        a hint that only rows holding a small action are wanted, which
+        the host handler answers from the part's footer (the row groups
+        that can hold one, read until the last one counted) and any
+        other handler may ignore. Its one limit is stated there: a
+        small action whose every leaf is null is invisible to leaf
+        statistics, and no valid checkpoint holds one."""
         if self._state is not None:
             return self._state
         if self._small is None:

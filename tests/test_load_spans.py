@@ -247,6 +247,20 @@ def test_an_interleaved_part_goes_through_filter_and_the_counters_add_up(
     canonicalized = sum(s["attrs"]["rows"]
                         for s in _named(spans, "canonicalize.columns"))
     assert canonicalized == n == snap.state.file_actions_raw.num_rows
+    # no `.crc` here, so `latest_snapshot` took protocol and metaData by
+    # the small-action read, which goes by the part's footer: one row
+    # group, read (it holds both), and of the file's bytes the footer
+    # and the small columns' chunks; the full read after it is as ever
+    small, full = [s["attrs"] for s in _named(spans, "checkpoint.read_part")]
+    log = os.path.join(path, "_delta_log")
+    [part] = [f for f in os.listdir(log) if f.endswith(".parquet")]
+    size = os.path.getsize(os.path.join(log, part))
+    assert full == {"bytes": size, "rows": full["rows"]}
+    assert small == {"bytes": size, "rows": full["rows"],
+                     "file_rows": full["rows"], "row_groups": 1,
+                     "row_groups_read": 1,
+                     "bytes_read": small["bytes_read"]}
+    assert 0 < small["bytes_read"] < size
     assert (viewed.value - before[0], filtered.value - before[1]) == (0, n)
 
 
