@@ -10,7 +10,7 @@ TpuEngine must beat.
 from __future__ import annotations
 
 import json
-from typing import Iterator, List, Optional, Sequence
+from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 import pyarrow as pa
@@ -44,6 +44,7 @@ _WRITE_BYTES = obs.counter("storage.write.bytes")
 _PARQUET_PREFETCHED = obs.counter("storage.parquet.prefetched_files")
 
 _SMALL_GROUPS_SKIPPED = obs.counter("checkpoint.small_row_groups_skipped")
+_PARTS_DEALT = obs.counter("checkpoint.parts_decoded_dealt")
 
 # how many parquet byte-reads to keep in flight ahead of the decoder
 _PARQUET_PREFETCH_DEPTH = 2
@@ -51,6 +52,31 @@ _PARQUET_PREFETCH_DEPTH = 2
 # rows a batch of a `present_only` read: a row group is left as soon as
 # the batches read hold every present row its footer counts
 _PRESENT_BATCH_ROWS = 65536
+
+
+# The weight from which a piece of a file's full decode is a task of its
+# own (`_deal_plan`). A leaf weighs in a row group what the footer counts
+# of it there: its bytes before compression, and a byte a value at the
+# least (a million nulls in 2 KB still cost a million levels to walk).
+# A file none of whose row groups weighs this much is read whole by
+# `pq.read_table`, as is one that comes to a single task; any other is
+# dealt out over the scan pool: a task a row group and, within a group
+# of this weight, a task a column of this weight (a top-level column or a
+# top-level struct's child, with all beneath it), the lighter columns
+# sharing tasks of at most this weight.
+# Set by the sandbox's sweep (PR 48; 8 cores, pyarrow 25; a struct of
+# path, size and a stats string, snappy, 4-66 MiB in 1, 2, 4 and 8 row
+# groups; ms whole -> dealt): `read_table` does well where many small
+# groups keep its own readahead busy and badly where a group is large or
+# one leaf is most of it. At 4 MiB the rule dealt out files of eight
+# 4 MiB groups, 7.0 -> 9.2; at 8 MiB every shape gains or stays: one
+# group of 8 MiB 10.0 -> 5.8, of 16 MiB 17.6 -> 10.5, of 66 MiB 68 -> 41;
+# two groups of 8 MiB 10.0 -> 6.3; four of 16 MiB 20 -> 13-14; 4 MiB
+# files and files of eight 1-4 MiB groups are read whole (2.5-7 ms);
+# only eight groups of 8.4 MiB lose, 12.5-13.3 -> 14.5-14.9. The cold
+# loads' checkpoint (69 MB, three groups, `add.stats` 105 MB of a full
+# group's 136): 215 -> 112.
+_DEAL_MIN_BYTES = 8 << 20
 
 
 def _local_os_path(store: LogStore, path: str) -> Optional[str]:
@@ -143,6 +169,114 @@ def _read_present(f: pq.ParquetFile, cols: List[str],
     return pa.Table.from_batches(batches, schema=schema)
 
 
+def _deal_plan(md: pq.FileMetaData,
+               schema: pa.Schema) -> List[Tuple[int, Optional[List[str]]]]:
+    """The tasks of a file's full decode by its footer, `_DEAL_MIN_BYTES`
+    stating the rule: [(row group, the columns of the task by their
+    dotted prefix, None for every column)]; empty where the file is read
+    whole. A column here is a top-level one or, of a top-level struct,
+    a child with all beneath it. A file whose names hold a dot is read
+    whole: a prefix would not say which column it means."""
+    groups = [md.row_group(g) for g in range(md.num_row_groups)]
+    weights = [max(rg.total_byte_size, rg.num_rows * md.num_columns)
+               for rg in groups]
+    if max(weights, default=0) < _DEAL_MIN_BYTES:
+        return []
+    children = {fld.name: [c.name for c in fld.type] for fld in schema
+                if pa.types.is_struct(fld.type)}
+    if any("." in n for n in schema.names) or any(
+            "." in c for under in children.values() for c in under):
+        return []
+    pq_schema = md.schema
+    unit_of = []         # leaf -> the column it decodes with
+    for i in range(md.num_columns):
+        at = pq_schema.column(i).path.split(".")
+        unit_of.append(".".join(at[:2]) if at[0] in children else at[0])
+    if set(unit_of) != {u for fld in schema for u in (
+            [f"{fld.name}.{c}" for c in children[fld.name]]
+            if fld.name in children else [fld.name])}:
+        return []        # a column without a leaf: nothing to go by
+    tasks = []           # (weight, row group, columns)
+    for g, rg in enumerate(groups):
+        if weights[g] < _DEAL_MIN_BYTES:
+            tasks.append((weights[g], g, None))
+            continue
+        weight = dict.fromkeys(unit_of, 0)
+        for i, unit in enumerate(unit_of):
+            chunk = rg.column(i)
+            weight[unit] += max(chunk.total_uncompressed_size,
+                                chunk.num_values)
+        of_group, shared, held = [], [], 0
+        for unit, n in weight.items():
+            if n >= _DEAL_MIN_BYTES:
+                of_group.append((n, g, [unit]))
+                continue
+            if held + n > _DEAL_MIN_BYTES:
+                of_group.append((held, g, shared))
+                shared, held = [], 0
+            shared.append(unit)
+            held += n
+        if shared:
+            of_group.append((held, g, shared))
+        tasks += (of_group if len(of_group) > 1
+                  else [(weights[g], g, None)])
+    if len(tasks) < 2:
+        return []
+    # the heaviest first: it sets the pace, so it must not queue
+    tasks.sort(key=lambda task: task[0], reverse=True)
+    return [(g, cols) for _, g, cols in tasks]
+
+
+def _one_chunk(col: pa.ChunkedArray) -> pa.Array:
+    return col.chunk(0) if col.num_chunks == 1 else col.combine_chunks()
+
+
+def _read_dealt(f: pq.ParquetFile, tasks, reopen) -> pa.Table:
+    """The file's table from `tasks` (`_deal_plan`), each read on the
+    scan pool through a handle of its own with the footer `f` parsed,
+    and put together without a copy: a row group's struct from the
+    children its tasks read (validity from the first of them: every
+    read of a struct's child rebuilds the struct's own), the groups
+    by `concat_tables`. Equals `pq.read_table`'s but for the chunking:
+    a chunk a row group. The tasks are leaves: none waits for the pool."""
+    from delta_tpu.utils.threads import scan_pool
+
+    md, schema = f.metadata, f.schema_arrow
+
+    def read(task) -> pa.Table:
+        g, cols = task
+        with reopen() as source:
+            return pq.ParquetFile(source, metadata=md).read_row_group(
+                g, columns=cols, use_threads=False)
+
+    by_group: dict = {}
+    for (g, cols), tbl in zip(tasks, scan_pool().map(read, tasks)):
+        by_group.setdefault(g, []).append((cols, tbl))
+    pieces = []
+    for g in sorted(by_group):
+        reads = by_group[g]
+        if reads[0][0] is None:          # the group whole, one task
+            pieces.append(reads[0][1])
+            continue
+        read_by = {u: tbl for cols, tbl in reads for u in cols}
+        columns = []
+        for fld in schema:
+            if fld.name in read_by:      # not a struct
+                columns.append(read_by[fld.name].column(fld.name))
+                continue
+            under = [read_by[f"{fld.name}.{c.name}"] for c in fld.type]
+            if all(tbl is under[0] for tbl in under):
+                columns.append(under[0].column(fld.name))   # as read
+                continue
+            parts = [_one_chunk(tbl.column(fld.name)) for tbl in under]
+            nulls = parts[0].is_null() if parts[0].null_count else None
+            columns.append(pa.StructArray.from_arrays(
+                [part.field(c.name) for part, c in zip(parts, fld.type)],
+                fields=list(fld.type), mask=nulls))
+        pieces.append(pa.Table.from_arrays(columns, schema=schema))
+    return pa.concat_tables(pieces)
+
+
 class HostJsonHandler(JsonHandler):
     def __init__(self, store_resolver=logstore_for_path):
         self._store_for = store_resolver
@@ -198,11 +332,22 @@ class HostParquetHandler(ParquetHandler):
     def __init__(self, store_resolver=logstore_for_path):
         self._store_for = store_resolver
 
-    def _decode(self, source: pa.NativeFile, columns: Optional[List[str]],
-                present_only: bool = False,
+    def _decode(self, source: pa.NativeFile,
+                reopen: Callable[[], pa.NativeFile],
+                columns: Optional[List[str]], present_only: bool = False,
                 fetched: Optional[int] = None) -> pa.Table:
+        """`source`'s table. `reopen` gives a further handle on the same
+        bytes: a full read that is dealt out opens one a task."""
+        f = pq.ParquetFile(source)
         if columns is None:
-            return pq.read_table(source)
+            tasks = _deal_plan(f.metadata, f.schema_arrow)
+            obs.set_attrs(row_groups=f.metadata.num_row_groups,
+                          decode_tasks=len(tasks) or 1,
+                          decode="dealt" if tasks else "whole")
+            if not tasks:
+                return pq.read_table(source)
+            _PARTS_DEALT.inc()
+            return _read_dealt(f, tasks, reopen)
         # one footer parse serves both the schema check and the
         # read. Project onto the columns the file actually has — a
         # checkpoint from another engine may omit e.g. txn or
@@ -210,12 +355,19 @@ class HostParquetHandler(ParquetHandler):
         # read-twice fallbacks. An empty intersection stays an empty
         # projection (0 columns, correct row count) — never a
         # decode-everything full read.
-        f = pq.ParquetFile(source)
         present = set(f.schema_arrow.names)
         cols = [c for c in columns if c in present]
         if present_only and cols:
             return _read_present(f, cols, fetched)
         return f.read(columns=cols)
+
+    def _decode_bytes(self, data: bytes, columns: Optional[List[str]],
+                      present_only: bool) -> pa.Table:
+        """The table of a file fetched whole (zero copy: every handle
+        reads `data` where it lies)."""
+        return self._decode(pa.BufferReader(data),
+                            lambda: pa.BufferReader(data), columns,
+                            present_only, len(data))
 
     def read_parquet_files(
         self, paths: Sequence[str], columns: Optional[List[str]] = None,
@@ -226,25 +378,28 @@ class HostParquetHandler(ParquetHandler):
             for p in paths:
                 store = self._store_for(p)
                 local = (_local_os_path(store, p)
-                         if present_only and columns is not None else None)
+                         if columns is None or present_only else None)
                 if local is not None:
                     # opened by path, Arrow reads the footer and the
-                    # column chunks it is asked for and nothing else;
-                    # only the open is a storage call: a torn footer
-                    # raises from the decode, as on bytes read whole
+                    # column chunks it is asked for and nothing else (a
+                    # full read: no copy of the file into `bytes`
+                    # first); only the open is a storage call: a torn
+                    # footer raises from the decode, as on bytes read
+                    # whole
                     with io_call(endpoint_of(p),
                                  lambda: pa.OSFile(local)) as source:
-                        tbl = self._decode(source, columns, True)
+                        tbl = self._decode(source, lambda: pa.OSFile(local),
+                                           columns, present_only)
                     yield tbl
                     continue
                 data = io_call(endpoint_of(p), lambda: store.read(p))
-                yield self._decode(pa.BufferReader(data), columns,
-                                   present_only, len(data))
+                yield self._decode_bytes(data, columns, present_only)
             return
         # Byte-prefetch: keep the next reads in flight on the shared I/O
         # pool so decoding file i overlaps reading file i+1 (checkpoint
-        # parts, V2 sidecars). Reads are leaf pool tasks; decode stays on
-        # the consuming thread and consumption stays in input order.
+        # parts, V2 sidecars). Reads are leaf pool tasks; decode stays
+        # with the consuming thread (which deals a large part's out to
+        # the scan pool, `_decode`) and consumption stays in input order.
         from collections import deque
 
         from delta_tpu.utils.threads import shared_pool
@@ -262,8 +417,7 @@ class HostParquetHandler(ParquetHandler):
                     pending.append(pool.submit(read, paths[i]))
                     i += 1
                 data = pending.popleft().result()
-                yield self._decode(pa.BufferReader(data), columns,
-                                   present_only, len(data))
+                yield self._decode_bytes(data, columns, present_only)
         finally:
             for fut in pending:
                 fut.cancel()

@@ -167,6 +167,9 @@ def test_span_attributes_count_the_tables_work(loads, route, table_path):
     read = attrs("checkpoint.read_part")
     assert read["bytes"] == os.path.getsize(os.path.join(log, part))
     assert read["rows"] == attrs("checkpoint.canonicalize")["rows"]
+    # a part of a few KB is no work to deal out: `pq.read_table`, whole
+    assert (read["decode"], read["decode_tasks"], read["row_groups"]) == (
+        "whole", 1, 1)
     # the part's adds are one run behind the protocol and metaData rows:
     # they reach the canonical table as a view, never through `filter`
     [ckpt_file] = [a for a in _named(spans, "canonicalize.filter")
@@ -255,13 +258,58 @@ def test_an_interleaved_part_goes_through_filter_and_the_counters_add_up(
     log = os.path.join(path, "_delta_log")
     [part] = [f for f in os.listdir(log) if f.endswith(".parquet")]
     size = os.path.getsize(os.path.join(log, part))
-    assert full == {"bytes": size, "rows": full["rows"]}
+    assert full == {"bytes": size, "rows": full["rows"], "row_groups": 1,
+                    "decode_tasks": 1, "decode": "whole"}
     assert small == {"bytes": size, "rows": full["rows"],
                      "file_rows": full["rows"], "row_groups": 1,
                      "row_groups_read": 1,
                      "bytes_read": small["bytes_read"]}
     assert 0 < small["bytes_read"] < size
     assert (viewed.value - before[0], filtered.value - before[1]) == (0, n)
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_a_part_dealt_out_is_still_one_read_part_a_full_load(
+        table_path, route, monkeypatch):
+    """With the part's decode dealt out over the scan pool (forced: a
+    byte is enough), the full load holds one `checkpoint.read_part`
+    where it held it, on the driving thread, around the whole read: the
+    tasks open no span, and the answer is the same."""
+    from delta_tpu.engine import host
+
+    def load():
+        clear_parse_cache()
+        obs.set_trace_mode("on")
+        obs.reset_trace_buffer()
+        snap = Table.for_path(table_path,
+                              engine=TpuEngine()).latest_snapshot()
+        live = snap.state.add_files_table.sort_by("path")
+        spans = [s.to_dict() for s in obs.get_finished_spans()]
+        obs.set_trace_mode("off")
+        return (snap.num_files, live.column("path").to_pylist(),
+                live.column("size").to_pylist()), spans
+
+    for key, value in ROUTES[route].items():
+        monkeypatch.setenv(key, value)
+    dealt = obs.counter("checkpoint.parts_decoded_dealt")
+    before = dealt.value
+    whole, spans_whole = load()
+    assert dealt.value == before
+    monkeypatch.setattr(host, "_DEAL_MIN_BYTES", 1)
+    answer, spans = load()
+    assert answer == whole and answer[0] == COMMITS
+    assert dealt.value - before == 1
+    [read] = _named(spans, "checkpoint.read_part")
+    by_id = {s["span_id"]: s for s in spans}
+    assert by_id[read["parent_id"]]["name"] == "log.read_checkpoint"
+    assert read["thread_id"] == threading.get_ident()
+    assert read["attrs"]["decode"] == "dealt"
+    assert read["attrs"]["decode_tasks"] > read["attrs"]["row_groups"] == 1
+    assert read["attrs"]["rows"] == _named(
+        spans_whole, "checkpoint.read_part")[0]["attrs"]["rows"]
+    # the same spans, each as often, whichever way the part was decoded
+    names = sorted(s["name"] for s in spans)
+    assert names == sorted(s["name"] for s in spans_whole)
 
 
 def test_the_wait_joins_the_dispatch_record_and_the_gate(loads):
