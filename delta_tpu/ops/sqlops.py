@@ -13,10 +13,18 @@ the replay kernel's proven shape (`ops/replay.py`):
 - host: dictionary-encode string/float keys to dense uint32 codes
   (pandas factorize — same as `ops/join.py::equi_join_device`) and do
   O(output) gathers/expansions;
-- device: the O(n log n) sorts (`jax.lax.sort`, stable, multi-lane) and
-  O(n) segment reductions/scans (`jax.ops.segment_*`,
-  `jax.lax.associative_scan`) on bucket-padded static shapes so jit
-  caches a bounded number of programs across table sizes.
+- device: the O(n log n) sorts and O(n) segment reductions/scans
+  (`jax.ops.segment_*`, `jax.lax.associative_scan`) on bucket-padded
+  static shapes so jit caches a bounded number of programs across
+  table sizes.
+
+The join's and the ORDER BY's sort is `_radix_perm`: a radix sort from
+the least significant digit whose every pass is ONE single-operand
+`uint32` `jax.lax.sort`. A multi-operand sort of 64-bit lanes is what
+the v5e compiler takes minutes over (a stable three-key int64 sort
+125 s on the chip's host, PERF.md); one `uint32` operand it compiles in
+seconds, whatever the rows. The order is the total order (keys, then
+position), so the result is the stable multi-key sort's, bit for bit.
 
 Aggregation dtype policy: integer columns accumulate in int64 (exact),
 floats in float64 — x64 is enabled lazily on first use. The repo's other
@@ -70,11 +78,105 @@ def _ensure_x64() -> None:
 
 # ------------------------------------------------------------- sort ----
 
-@functools.partial(jax.jit, static_argnames=("num_keys",))
+_U64_TOP = np.uint64(1) << np.uint64(63)
+_WORD_BITS = 63     # of a packed sort word; its top bit is the pad's
+
+
+def _digit_bits(n: int) -> int:
+    """Bits of key a radix pass over `n` rows sorts by: what a uint32
+    word has left beside a row's position."""
+    width = 32 - max(1, (n - 1).bit_length())
+    if width < 1:
+        raise ValueError(f"{n} rows are more than one sort can hold")
+    return width
+
+
+def _radix_schedule(bits: Sequence[int], n: int):
+    """The passes that sort `n` rows by words of `bits[0]`, `bits[1]`,
+    ... significant bits, the first word the primary: `(word_of,
+    shift_of, passes)`, least significant digit first. The arrays are as
+    long as words of 64 bits would need, so their shape depends on the
+    number of words and `n` alone."""
+    width = _digit_bits(n)
+    most = -(-64 // width)
+    word_of = np.zeros(most * len(bits), np.int32)
+    shift_of = np.zeros(most * len(bits), np.int32)
+    at = 0
+    for w in range(len(bits) - 1, -1, -1):
+        for shift in range(0, int(bits[w]), width):
+            word_of[at], shift_of[at] = w, shift
+            at += 1
+    return word_of, shift_of, np.int32(at)
+
+
+def _radix_perm(words, word_of, shift_of, passes):
+    """int32 permutation that puts the rows of `words` (uint64 [W, n])
+    in ascending order of (word 0, word 1, ..., position). Each pass
+    sorts ONE uint32 lane, a digit of the key above the row's place in
+    the order so far, so a pass is stable and carries no payload."""
+    n = words.shape[1]
+    width = _digit_bits(n)
+    place_bits = 32 - width
+    place = jnp.arange(n, dtype=jnp.uint32)
+
+    def one_pass(i, perm):
+        word = jax.lax.dynamic_index_in_dim(words, word_of[i], 0,
+                                            keepdims=False)
+        digit = ((word >> shift_of[i].astype(jnp.uint64))
+                 & jnp.uint64((1 << width) - 1)).astype(jnp.uint32)
+        # every word is another (its low bits are the row's place), so
+        # the order is total: stability, which the v5e compiler takes
+        # three times as long over, has nothing to decide
+        took = jax.lax.sort((digit[perm] << place_bits) | place,
+                            is_stable=False)
+        return perm[(took & jnp.uint32((1 << place_bits) - 1))
+                    .astype(jnp.int32)]
+
+    return jax.lax.fori_loop(0, passes, one_pass,
+                             jnp.arange(n, dtype=jnp.int32))
+
+
+def _read(kernel: str, *arrays):
+    """The blocking read of a launch's results on the calling thread."""
+    with obs.span("sql.wait", kernel=kernel):
+        return tuple(np.asarray(a) for a in arrays)
+
+
+@jax.jit
 @obs.program("sqlops.sort")
-def _sort_kernel(operands, num_keys: int):
-    out = jax.lax.sort(operands, num_keys=num_keys, is_stable=True)
-    return out[-1]
+def _sort_kernel(words, word_of, shift_of, passes):
+    return _radix_perm(words, word_of, shift_of, passes)
+
+
+def _order_code(lane: np.ndarray) -> np.ndarray:
+    """uint64 codes in the lane's ascending order, as `jax.lax.sort`
+    compares it: integers by value, floats by value with -0.0 = 0.0
+    (lanes are NaN-free)."""
+    lane = np.asarray(lane)
+    if lane.dtype.kind == "f":
+        bits = (lane.astype(np.float64) + 0.0).view(np.uint64)
+        return np.where(bits >> np.uint64(63), ~bits, bits | _U64_TOP)
+    if lane.dtype.kind == "i":
+        return lane.astype(np.int64).view(np.uint64) ^ _U64_TOP
+    return lane.astype(np.uint64)   # unsigned and bool
+
+
+def _pack_sort_words(lanes: Sequence[np.ndarray]):
+    """The lanes as few uint64 words as hold them, `(words, bits a
+    word)`: a lane is its order code less the least of them, so it
+    takes the bits of its range, and lanes share a word while they fit
+    under its top bit."""
+    packed = [(np.zeros(len(lanes[0]), np.uint64), 0)]
+    for lane in lanes:
+        code = _order_code(lane)
+        code = code - code.min()
+        bits = int(code.max()).bit_length()
+        word, held = packed[-1]
+        if held + bits > _WORD_BITS:
+            packed.append((code, bits))
+        elif bits:
+            packed[-1] = ((word << np.uint64(bits)) | code, held + bits)
+    return [w for w, _held in packed], [held for _w, held in packed]
 
 
 def sort_permutation(lanes: Sequence[np.ndarray],
@@ -89,33 +191,20 @@ def sort_permutation(lanes: Sequence[np.ndarray],
     if n == 0:
         return np.empty(0, np.int64)
     npad = pad_bucket(n)
-    with obs.device_dispatch("sqlops.sort", key=(len(lanes), npad),
+    packed, bits = _pack_sort_words(lanes)
+    words = np.zeros((len(packed), npad), np.uint64)
+    words[:, :n] = packed
+    words[0, n:] = np.uint64(1) << np.uint64(bits[0])   # pads sort last
+    bits[0] += 1
+    word_of, shift_of, passes = _radix_schedule(bits, npad)
+    with obs.device_dispatch("sqlops.sort", key=(len(bits), npad),
                              budget="sql-sort-lanes", units=npad,
                              gate="sql") as dd:
-        padded = []
-        for lane in lanes:
-            lane = np.asarray(lane)
-            if lane.dtype == np.float32:
-                lane = lane.astype(np.float64)
-            elif lane.dtype == bool:  # 0/1 null-ordering lanes
-                lane = lane.astype(np.uint8)
-            if lane.dtype.kind == "f":
-                fill = np.inf
-            else:
-                fill = np.iinfo(lane.dtype).max
-            # "key" lanes mix dtypes (i64/f64 values, u8 null lanes), so
-            # the manifest prices them at runtime via the recorded bytes
-            # (entry is non-exhaustive); only iota is statically pinned
-            key = np.full(npad, fill, dtype=lane.dtype)
-            key[:n] = lane
-            dd.h2d("key", key)
-            padded.append(jax.device_put(key, device))
-        iota = np.arange(npad, dtype=np.int64)
-        dd.h2d("iota", iota)
-        perm = np.asarray(_sort_kernel(
-            tuple(padded) + (jax.device_put(iota, device),),
-            num_keys=len(padded)))
-    return perm[perm < n]
+        dd.set(n=n, n_pad=npad, words=len(bits), passes=int(passes))
+        dd.h2d("words", words, units=len(bits) * npad)
+        perm, = _read("sqlops.sort", _sort_kernel(
+            jax.device_put(words, device), word_of, shift_of, passes))
+    return perm[:n].astype(np.int64)
 
 
 # --------------------------------------------------- group-by reduce ----
@@ -255,6 +344,7 @@ class GroupAggregator:
         with obs.device_dispatch("sqlops.group_codes", key=(self.npad,),
                                  budget="sql-agg-lanes", units=self.npad,
                                  gate="sql") as dd:
+            dd.set(n=self.n, n_pad=self.npad, n_seg=self.n_seg)
             dd.h2d("codes_p", codes_p)
             dd.h2d("real", real)
             self.codes = jax.device_put(codes_p, device)
@@ -266,9 +356,9 @@ class GroupAggregator:
 
     def sizes(self) -> np.ndarray:
         """COUNT(*) per group."""
-        out = _group_sizes_kernel(self.codes, self._real,
-                                  n_seg=self.n_seg)
-        return np.asarray(out)[:self.n_groups]
+        out, = _read("sqlops.group_sizes", _group_sizes_kernel(
+            self.codes, self._real, n_seg=self.n_seg))
+        return out[:self.n_groups]
 
     def _pad(self, values: np.ndarray, valid: np.ndarray):
         v = np.asarray(values)
@@ -285,6 +375,7 @@ class GroupAggregator:
         with obs.device_dispatch("sqlops.agg_values", key=(self.npad,),
                                  budget="sql-agg-values", units=self.npad,
                                  gate="sql") as dd:
+            dd.set(n_pad=self.npad, n_seg=self.n_seg)
             dd.h2d("vp", vp)
             dd.h2d("mp", mp)
             return (jax.device_put(vp, self.device),
@@ -301,8 +392,8 @@ class GroupAggregator:
         else:
             agg, cnt = _segagg_kernel(self.codes, vp, mp, op=op,
                                       n_seg=self.n_seg)
-        return (np.asarray(agg)[:self.n_groups],
-                np.asarray(cnt)[:self.n_groups])
+        agg, cnt = _read("sqlops.segagg", agg, cnt)
+        return agg[:self.n_groups], cnt[:self.n_groups]
 
     def var(self, values, valid):
         """Two-pass sample variance (exact centering — a single-pass
@@ -316,8 +407,8 @@ class GroupAggregator:
         means = s / jnp.maximum(cnt, 1)
         ss = _centered_sumsq_kernel(self.codes, vp, mp, means,
                                     n_seg=self.n_seg)
-        cnt_np = np.asarray(cnt)[:self.n_groups]
-        ss_np = np.asarray(ss)[:self.n_groups]
+        cnt_np, ss_np = (a[:self.n_groups] for a in _read(
+            "sqlops.centered_sumsq", cnt, ss))
         with np.errstate(invalid="ignore", divide="ignore"):
             var = np.where(cnt_np >= 2, ss_np / np.maximum(cnt_np - 1, 1),
                            np.nan)
@@ -347,7 +438,8 @@ class GroupAggregator:
             out = _count_distinct_kernel(
                 jax.device_put(gp, self.device),
                 jax.device_put(vp, self.device), n_seg=self.n_seg)
-        return np.asarray(out)[:self.n_groups]
+        out, = _read("sqlops.count_distinct", out)
+        return out[:self.n_groups]
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
@@ -364,29 +456,56 @@ def _count_distinct_kernel(g, v, n_seg: int):
 
 # ----------------------------------------------------------- join ----
 
+def _join_order(code, pad, bits):
+    """Rows of a join's concatenated operand in the order of (pad,
+    code, position), as `(perm int32, first bool)`: `first` marks the
+    rows at which the code changes. `code` is uint64 under `2**bits`;
+    a pad takes the bit above, so pads sort last. Position follows
+    side, left before right, so this is the stable sort by (pad, code,
+    side) the expansion wants."""
+    n = code.shape[0]
+    word = jnp.where(pad, jnp.uint64(1) << bits.astype(jnp.uint64), code)
+    width = _digit_bits(n)
+    passes = (bits + 1 + (width - 1)) // width
+    steps = jnp.arange(-(-64 // width), dtype=jnp.int32)
+    perm = _radix_perm(word[None, :], jnp.zeros_like(steps), steps * width,
+                       passes)
+    ordered = word[perm]
+    first = jnp.concatenate([jnp.ones((1,), bool),
+                             ordered[1:] != ordered[:-1]])
+    return perm, first
+
+
 @jax.jit
 @obs.program("sqlops.join_codes")
-def _join_sort_kernel(codes, side, iota):
-    return jax.lax.sort((codes, side, iota), num_keys=2,
-                        is_stable=True)
+def _join_codes_kernel(codes, n_real, bits):
+    """Order the concatenated (left ++ right ++ pads) uint32 codes."""
+    pad = jnp.arange(codes.shape[0], dtype=jnp.int32) >= n_real
+    return _join_order(codes.astype(jnp.uint64), pad, bits)
 
 
 @jax.jit
 @obs.program("sqlops.join_lanes")
-def _join_lanes_kernel(l_vals, r_vals, n_l, n_r):
-    """Sort (pad_flag, value, side) over the concatenated padded int64
-    key lanes; side and iota are generated ON DEVICE (they never cross
-    the link), and pads are identified positionally so any fill value
-    in the padding is safe."""
+def _join_lanes_kernel(l_vals, r_vals, n_l, n_r, least, bits):
+    """Order the concatenated padded int64 key lanes by their distance
+    from `least`, the least real value of both. Side and position are
+    generated ON DEVICE (they never cross the link), and pads are
+    identified positionally so any fill value in the padding is safe."""
     nl_pad = l_vals.shape[0]
     vals = jnp.concatenate([l_vals, r_vals])
-    iota = jnp.arange(vals.shape[0], dtype=jnp.int64)
-    side = (iota >= nl_pad).astype(jnp.uint8)
-    local = jnp.where(side == 1, iota - nl_pad, iota)
-    limit = jnp.where(side == 1, n_r, n_l)
-    pad = (local >= limit).astype(jnp.uint8)
-    return jax.lax.sort((pad, vals, side, iota), num_keys=3,
-                        is_stable=True)
+    at = jnp.arange(vals.shape[0], dtype=jnp.int32)
+    right = at >= nl_pad
+    pad = jnp.where(right, at - nl_pad >= n_r, at >= n_l)
+    return _join_order((vals - least).astype(jnp.uint64), pad, bits)
+
+
+def _sorted_triples(perm: np.ndarray, first: np.ndarray, n_real: int,
+                    right_from: int):
+    """What `_expand_pairs` takes, from a join kernel's answer: the real
+    rows come first, a run's number stands for its key."""
+    s_pos = perm[:n_real].astype(np.int64)
+    return (np.cumsum(first[:n_real], dtype=np.int64),
+            (s_pos >= right_from).astype(np.int64), s_pos)
 
 
 def _expand_pairs(
@@ -467,77 +586,86 @@ def join_pairs(
     if n == 0:
         return empty, empty
     npad = pad_bucket(n)
-    codes = np.full(npad, _PAD_CODE, np.uint32)
+    codes = np.zeros(npad, np.uint32)
     codes[:nl] = l_codes
     codes[nl:n] = r_codes
-    side = np.zeros(npad, np.uint32)
-    side[nl:] = 1
-    iota = np.arange(npad, dtype=np.int64)
+    bits = int(codes.max()).bit_length()
     with obs.device_dispatch("sqlops.join_codes", key=(npad,),
                              budget="sql-join-lanes", units=npad,
                              gate="sql") as dd:
+        dd.set(n=n, n_pad=npad, bits=bits)
         dd.h2d("codes", codes)
-        dd.h2d("side", side)
-        dd.h2d("iota", iota)
-        s_code, s_side, s_pos = (
-            np.asarray(a) for a in _join_sort_kernel(
-                jax.device_put(codes, device),
-                jax.device_put(side, device),
-                jax.device_put(iota, device)))
-    real = s_code != _PAD_CODE
-    return _expand_pairs(s_code[real], s_side[real], s_pos[real],
-                         nl, how)
+        perm, first = _read("sqlops.join_codes", *_join_codes_kernel(
+            jax.device_put(codes, device), np.int32(n), np.int32(bits)))
+    return _expand_pairs(*_sorted_triples(perm, first, n, nl), nl, how)
 
 
 def join_pairs_lanes(
     l_vals: np.ndarray,
     r_vals: Optional[np.ndarray] = None,
-    r_resident: Optional[Tuple[object, int]] = None,
+    r_resident: Optional[Tuple[object, int, int, int]] = None,
     how: str = "inner",
     device=None,
-) -> tuple[np.ndarray, np.ndarray]:
+) -> Optional[tuple[np.ndarray, np.ndarray]]:
     """Single-key many-to-many equi-join directly on int64 value lanes
     — no host factorize, and the side/iota lanes are generated on
     device, so only the key values ever cross the link (8 B/row vs the
     16 B/row `join_pairs` ships for codes + side + iota).
 
-    `r_resident` is `(device_lane, n_rows)` from the operand cache
-    (`sqlengine/operands.py`): the build side then costs ZERO H2D
-    bytes. Exactly one of `r_vals` / `r_resident` must be given.
+    `r_resident` is `(device_lane, n_rows, least, most)` from the
+    operand cache (`sqlengine/operands.py`): the build side then costs
+    ZERO H2D bytes. Exactly one of `r_vals` / `r_resident` must be
+    given. None where the keys of both sides span 2**63 or more: the
+    sort's word has no bit left for the pads, and the caller joins on
+    joint codes instead.
     Output contract matches `join_pairs` (pair order is value-sorted
     rather than first-appearance-sorted; both are valid many-to-many
     expansions of the same multiset)."""
     _ensure_x64()
     nl = int(len(l_vals))
+    l_vals = np.asarray(l_vals, np.int64)
     if r_resident is not None:
-        r_dev, nr = r_resident
+        r_dev, nr, r_least, r_most = r_resident
         nr = int(nr)
         nr_pad = int(r_dev.shape[0])
     else:
+        r_vals = np.asarray(r_vals, np.int64)
         nr = int(len(r_vals))
         nr_pad = pad_bucket(max(nr, 1))
+        r_least, r_most = ((int(r_vals.min()), int(r_vals.max())) if nr
+                           else (None, None))
     empty = np.empty(0, np.int64)
     if nl + nr == 0:
         return empty, empty
+    ends = [v for v in (r_least, r_most) if v is not None]
+    if nl:
+        ends += [int(l_vals.min()), int(l_vals.max())]
+    least = min(ends)
+    bits = (max(ends) - least).bit_length()
+    if bits >= _WORD_BITS:
+        return None     # no room for the pad's bit: the caller's codes do
     nl_pad = pad_bucket(max(nl, 1))
     lp = np.zeros(nl_pad, np.int64)
-    lp[:nl] = np.asarray(l_vals, np.int64)
+    lp[:nl] = l_vals
     with obs.device_dispatch("sqlops.join_lanes",
                              key=(nl_pad, nr_pad),
                              budget="sql-join-values", units=nl_pad,
                              gate="sql") as dd:
+        dd.set(n_l=nl, n_r=nr, nl_pad=nl_pad, nr_pad=nr_pad, bits=bits,
+               resident=r_resident is not None)
         dd.h2d("lp", lp)
         l_dev = jax.device_put(lp, device)
         if r_resident is None:
             rp = np.zeros(nr_pad, np.int64)
-            rp[:nr] = np.asarray(r_vals, np.int64)
+            rp[:nr] = r_vals
             dd.h2d("rp", rp, units=nr_pad)
             r_dev = jax.device_put(rp, device)
-        s_pad, s_val, s_side, s_pos = (
-            np.asarray(a) for a in _join_lanes_kernel(
-                l_dev, r_dev, np.int64(nl), np.int64(nr)))
-    real = s_pad == 0
-    return _expand_pairs(s_val[real], s_side[real], s_pos[real],
+        perm, first = _read("sqlops.join_lanes", *_join_lanes_kernel(
+            l_dev, r_dev, np.int32(nl), np.int32(nr), np.int64(least),
+            np.int32(bits)))
+    # the kernel's real rows are the left's, then the right's; a right
+    # row's position is past the left's pad
+    return _expand_pairs(*_sorted_triples(perm, first, nl + nr, nl_pad),
                          nl_pad, how)
 
 
@@ -585,8 +713,8 @@ def window_ranks(pb: np.ndarray, kb: np.ndarray, device=None):
         dd.h2d("kbp", kbp)
         rn, rk, dr = _ranks_kernel(jax.device_put(pbp, device),
                                    jax.device_put(kbp, device))
-    return (np.asarray(rn)[:n], np.asarray(rk)[:n],
-            np.asarray(dr)[:n])
+    rn, rk, dr = _read("sqlops.window_ranks", rn, rk, dr)
+    return rn[:n], rk[:n], dr[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("op",))
@@ -657,7 +785,8 @@ def window_running(v: np.ndarray, valid: np.ndarray, pb: np.ndarray,
         out, cnt = _segscan_kernel(jax.device_put(vp, device),
                                    jax.device_put(mp, device),
                                    jax.device_put(pbp, device), op=op)
-    return np.asarray(out)[:n], np.asarray(cnt)[:n]
+    out, cnt = _read("sqlops.window_running", out, cnt)
+    return out[:n], cnt[:n]
 
 
 @jax.jit
@@ -701,4 +830,5 @@ def window_peer_last(vals: np.ndarray, counts: np.ndarray,
         v_out, c_out = _peer_last_kernel(jax.device_put(vp, device),
                                          jax.device_put(cp, device),
                                          jax.device_put(kbp, device))
-    return np.asarray(v_out)[:n], np.asarray(c_out)[:n]
+    v_out, c_out = _read("sqlops.window_peer_last", v_out, c_out)
+    return v_out[:n], c_out[:n]

@@ -322,6 +322,7 @@ class DeviceSpine:
                 if raw is None:
                     cache = None
         hot = cache is not None and cache.peek(raw) is not None
+        obs.set_attr("hot", hot)    # on the executor's `sql.join` span
         nbytes = 8 * n_l + (0 if hot else 8 * n_r)
         if not self._route("join", n_l + n_r, nbytes):
             return None
@@ -358,10 +359,12 @@ class DeviceSpine:
             l_vals = _int64_lane(left[lcol])
             if l_vals is None:
                 return None
-        l_idx, r_idx = sqlops.join_pairs_lanes(
-            l_vals, r_resident=(lane.dev, lane.n), how=how,
-            device=self.device)
-        return self._gather(left, right, how, l_idx, r_idx)
+        pairs = sqlops.join_pairs_lanes(
+            l_vals, r_resident=(lane.dev, lane.n, lane.least, lane.most),
+            how=how, device=self.device)
+        if pairs is None:
+            return None
+        return self._gather(left, right, how, *pairs)
 
     @staticmethod
     def _gather(left: pd.DataFrame, right: pd.DataFrame, how: str,

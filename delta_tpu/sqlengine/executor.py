@@ -31,7 +31,9 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 import pandas as pd
 import pyarrow as pa
+import pyarrow.compute as pc
 
+from delta_tpu import obs
 from delta_tpu.errors import AmbiguousColumnError, CatalogTableError, DeltaError, SqlParseError, SubqueryShapeError, UnresolvedColumnError, UnsupportedSqlError
 from delta_tpu.sqlengine.parser import (
     And, Between, BinOp, CaseWhen, Cast, Cmp, Col, Exists, Func, InList,
@@ -47,17 +49,26 @@ _NULL_SUPPLYING = {"left outer": ("right",), "right outer": ("left",),
 
 # ---------------------------------------------------------------- API --
 
+_SCAN_FILES = obs.counter("sql.scan_files")
+
+
 def execute_select(statement_or_ast, engine=None, catalog=None,
-                   ctes=None) -> pa.Table:
-    if isinstance(statement_or_ast, Select):
-        q = Query(selects=[statement_or_ast])
-    elif isinstance(statement_or_ast, Query):
-        q = statement_or_ast
-    else:
-        q = parse_query(statement_or_ast)
-    df, names = _run_query(q, engine, catalog, dict(ctes or {}))
-    out = pa.Table.from_pandas(df, preserve_index=False)
-    return out.rename_columns(names)
+                   ctes=None, name: Optional[str] = None) -> pa.Table:
+    """Run one SELECT. `name` is the caller's name for the query: it
+    goes on the `sql.query` span and nowhere else."""
+    with obs.span("sql.query") as sp:
+        if name:
+            sp.set_attr("name", name)
+        if isinstance(statement_or_ast, Select):
+            q = Query(selects=[statement_or_ast])
+        elif isinstance(statement_or_ast, Query):
+            q = statement_or_ast
+        else:
+            q = parse_query(statement_or_ast)
+        df, names = _run_query(q, engine, catalog, dict(ctes or {}))
+        out = pa.Table.from_pandas(df, preserve_index=False)
+        sp.set_attr("rows", out.num_rows)
+        return out.rename_columns(names)
 
 
 def _run_query(q: Query, engine, catalog, ctes) -> Tuple[pd.DataFrame,
@@ -280,62 +291,95 @@ def _merge_null_safe(left: pd.DataFrame, right: pd.DataFrame, how: str,
     kernel; the null-key bookkeeping stays identical."""
     from delta_tpu.obs.device import gate_observation
 
-    lnull = left[lk].isna().any(axis=1)
-    rnull = right[rk].isna().any(axis=1)
-    if not lnull.any() and not rnull.any():  # hot path: no copies
-        if spine is not None:
-            merged = spine.merge(left, right, how, lk, rk)
-            if merged is not None:
-                return merged
-            # the gate routed this join to host: run the pandas merge
-            # under the observation scope so its cost joins the
-            # decision record for calibration
-            with gate_observation("sql", "host"):
-                return left.merge(right, how=how, left_on=lk,
-                                  right_on=rk)
-        return left.merge(right, how=how, left_on=lk, right_on=rk)
-    # keep the original object when a side is already null-free (the
-    # spine's operand-cache lookup keys on frame identity), and pass
-    # the pre-exclusion right as provenance: for a single-key join the
-    # null-drop is exactly "rows minus that column's nulls", so the
-    # cached lane built from one query's rm aligns with every other
-    # query's rm
-    lm = left if not lnull.any() else left[~lnull]
-    rm = right if not rnull.any() else right[~rnull]
-    merged = spine.merge(lm, rm, how, lk, rk, right_origin=right) \
-        if spine is not None else None
-    if merged is None:
-        if spine is not None:
-            with gate_observation("sql", "host"):
-                merged = lm.merge(rm, how=how, left_on=lk, right_on=rk)
+    def match(lm, rm, origin):
+        """The join of the null-free sides: on the device where the
+        gate sends it, else the pandas merge, which runs under the
+        observation scope so its cost joins the decision record."""
+        if spine is None:
+            return lm.merge(rm, how=how, left_on=lk, right_on=rk)
+        merged = spine.merge(lm, rm, how, lk, rk, right_origin=origin)
+        if merged is not None:
+            sp.set_attr("route", "device")
+            return merged
+        with gate_observation("sql", "host"):
+            return lm.merge(rm, how=how, left_on=lk, right_on=rk)
+
+    with obs.span("sql.join", how=how, n_left=len(left),
+                  n_right=len(right), route="host", hot=False) as sp:
+        lnull = left[lk].isna().any(axis=1)
+        rnull = right[rk].isna().any(axis=1)
+        if not lnull.any() and not rnull.any():  # hot path: no copies
+            merged = match(left, right, None)
         else:
-            merged = lm.merge(rm, how=how, left_on=lk, right_on=rk)
-    extra = []
-    if how in ("left", "outer") and lnull.any():
-        extra.append(left[lnull])
-    if how in ("right", "outer") and rnull.any():
-        extra.append(right[rnull])
-    if extra:
-        merged = pd.concat([merged] + extra, ignore_index=True)
-    return merged
+            # keep the original object when a side is already null-free
+            # (the spine's operand-cache lookup keys on frame identity),
+            # and pass the pre-exclusion right as provenance: for a
+            # single-key join the null-drop is exactly "rows minus that
+            # column's nulls", so the cached lane built from one query's
+            # rm aligns with every other query's rm
+            lm = left if not lnull.any() else left[~lnull]
+            rm = right if not rnull.any() else right[~rnull]
+            merged = match(lm, rm, right)
+            extra = []
+            if how in ("left", "outer") and lnull.any():
+                extra.append(left[lnull])
+            if how in ("right", "outer") and rnull.any():
+                extra.append(right[rnull])
+            if extra:
+                merged = pd.concat([merged] + extra, ignore_index=True)
+        sp.set_attr("rows", len(merged))
+        return merged
+
+
+_EXACT_DIGITS = 15     # 10**15 < 2**53: the unscaled value is a double
+
+
+def _decimals_as_float64(table: pa.Table) -> pa.Table:
+    """The frame computes in float64, so a `decimal(p,s)` column is
+    turned into one here, a buffer at a time, and never passes through a
+    Python `Decimal` a value. At p <= 15 the unscaled value is exact in
+    a double and so is 10**s, so their quotient is the double nearest
+    the decimal: what `float(Decimal)` gives, a sum of cents exact to
+    the cent. (Arrow's own cast multiplies by an inexact 10**-s and is
+    an ulp off on one value in eight.) Past 15 digits that cast is what
+    there is, and the double is only near."""
+    for i, field in enumerate(table.schema):
+        kind = field.type
+        if not pa.types.is_decimal(kind):
+            continue
+        column = table.column(i)
+        if kind.precision <= _EXACT_DIGITS:
+            wide = pa.decimal128(kind.precision, kind.scale)
+            column = pa.chunked_array(
+                [_exact_float64(chunk.cast(wide), kind.scale)
+                 for chunk in column.chunks], pa.float64())
+        else:
+            column = pc.cast(column, pa.float64())
+        table = table.set_column(i, field.name, column)
+    return table
+
+
+def _exact_float64(chunk: pa.Array, scale: int) -> pa.Array:
+    """A `decimal128` chunk of at most 15 digits as float64: the low
+    word of each little-endian value is the unscaled integer."""
+    words = np.frombuffer(chunk.buffers()[1], np.int64,
+                          2 * len(chunk), 16 * chunk.offset)
+    values = words[::2].astype(np.float64) / float(10 ** scale)
+    if not chunk.null_count:
+        return pa.array(values)
+    return pa.array(values, mask=chunk.is_null().to_numpy(
+        zero_copy_only=False))
 
 
 def _normalize_frame(df: pd.DataFrame) -> pd.DataFrame:
-    """Post-to_pandas cleanup: date32 -> datetime64, Decimal -> float."""
+    """Post-to_pandas cleanup: date32 -> datetime64."""
     for c in df.columns:
         s = df[c]
         if s.dtype == object and len(s):
             first = s.dropna().head(1)
-            if len(first):
-                v = first.iloc[0]
-                if isinstance(v, datetime.date) and not isinstance(
-                        v, datetime.datetime):
-                    df[c] = pd.to_datetime(s)
-                else:
-                    import decimal
-
-                    if isinstance(v, decimal.Decimal):
-                        df[c] = s.astype(float)
+            if len(first) and isinstance(first.iloc[0], datetime.date) \
+                    and not isinstance(first.iloc[0], datetime.datetime):
+                df[c] = pd.to_datetime(s)
     return df
 
 
@@ -536,19 +580,25 @@ class _Exec:
             cols = [c for c in s["cols"] if c in needed[s["alias"]]] \
                 or s["cols"][:1]
             full_rows = filt is None
-            try:
-                arrow = s["snap"].scan(filter=filt,
-                                       columns=cols).to_arrow()
-            except pa.lib.ArrowNotImplementedError:
-                # type-mismatched pushdown (e.g. date32 column vs the
-                # query's string literal): drop the scan filter — the
-                # residual WHERE still applies the predicate with the
-                # executor's coercions
-                arrow = s["snap"].scan(filter=None,
-                                       columns=cols).to_arrow()
-                full_rows = True
-            df = arrow.to_pandas()
-            df = _normalize_frame(df)
+            with obs.span("sql.scan", table=s["alias"],
+                          columns=len(cols),
+                          pushed=len(pushed[s["alias"]])) as sp:
+                scan = s["snap"].scan(filter=filt, columns=cols)
+                try:
+                    arrow = scan.to_arrow()
+                except pa.lib.ArrowNotImplementedError:
+                    # type-mismatched pushdown (e.g. date32 column vs
+                    # the query's string literal): drop the scan filter
+                    # — the residual WHERE still applies the predicate
+                    # with the executor's coercions
+                    scan = s["snap"].scan(filter=None, columns=cols)
+                    arrow = scan.to_arrow()
+                    full_rows = True
+                files = scan.add_files_table().num_rows   # the plan's, kept
+                _SCAN_FILES.inc(files)
+                df = _decimals_as_float64(arrow).to_pandas()
+                df = _normalize_frame(df)
+                sp.set_attrs(files=files, rows=len(df))
             df.columns = [f"{s['alias']}.{c}" for c in df.columns]
             s["frame"] = df
             if full_rows and self.spine is not None:
@@ -912,10 +962,14 @@ class _Exec:
                 tmp[f"__s{i}"] = s.values
             scols = [f"__s{i}" for i in range(len(sort_series))]
             sascs = [asc for _s, asc in sort_series]
-            sorted_dev = (self.spine.sort_frame(tmp, scols, sascs)
-                          if self.spine is not None else None)
-            tmp = sorted_dev if sorted_dev is not None \
-                else _sql_sort(tmp, scols, sascs)
+            with obs.span("sql.sort", rows=len(tmp), keys=len(scols),
+                          route="host") as sp:
+                sorted_dev = (self.spine.sort_frame(tmp, scols, sascs)
+                              if self.spine is not None else None)
+                if sorted_dev is not None:
+                    sp.set_attr("route", "device")
+                tmp = sorted_dev if sorted_dev is not None \
+                    else _sql_sort(tmp, scols, sascs)
             result = tmp.drop(columns=[f"__s{i}"
                                        for i in range(len(sort_series))])
 
@@ -963,9 +1017,17 @@ class _Exec:
         def agg_over(names):
             """Aggregate `work` grouped by the given key columns
             (global single row when empty)."""
+            with obs.span("sql.groupby", rows=len(work), keys=len(names),
+                          route="host") as sp:
+                out = grouped(names, sp)
+                sp.set_attr("groups", len(out))
+                return out
+
+        def grouped(names, sp):
             if names and self.spine is not None:
                 dev = self.spine.groupby(work, names, agg_specs)
                 if dev is not None:
+                    sp.set_attr("route", "device")
                     return dev
             if names:
                 gb = work.groupby(names, dropna=False, sort=False)

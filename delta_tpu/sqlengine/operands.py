@@ -67,14 +67,16 @@ class ColumnLane:
     metadata consumers need (`ops/sqlops.py::join_pairs_lanes` takes
     `dev`/`n` directly; string probes remap through `dictionary`)."""
 
-    __slots__ = ("kind", "dev", "n", "dictionary")
+    __slots__ = ("kind", "dev", "n", "dictionary", "least", "most")
 
     def __init__(self, kind: str, dev, n: int,
-                 dictionary: Optional[pd.Index]):
+                 dictionary: Optional[pd.Index], least=None, most=None):
         self.kind = kind          # "int" | "codes"
         self.dev = dev            # int64 device lane, pad_bucket(n) long
         self.n = n                # real row count
         self.dictionary = dictionary  # codes kind only
+        self.least = least        # of the real values; None of no rows:
+        self.most = most          # the join's sort keys by their range
 
 
 def _encode_column(series: pd.Series):
@@ -199,7 +201,9 @@ class ResidentOperandCache:
         else:
             self._hbm.grow(arrays=tuple(self._arrays),
                            nbytes=self._nbytes)
-        return ColumnLane(kind, dev, n, dictionary)
+        return ColumnLane(kind, dev, n, dictionary,
+                          int(raw.min()) if n else None,
+                          int(raw.max()) if n else None)
 
     def resident_bytes(self) -> int:
         with self._lock:

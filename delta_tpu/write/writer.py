@@ -179,44 +179,51 @@ def write_data_files(
 
 
 def _partition_groups(data: pa.Table, partition_columns: List[str]):
-    """Split rows by partition-column values (vectorized grouping)."""
-    import pandas as pd
-
-    key_cols = []
+    """Split rows by partition-column values, groups in the order their
+    first row appears. The values are the Arrow column's own (an
+    `integer` is 2450816 and a null is null, whatever pandas would make
+    of a nullable column), and the rows are grouped by one stable sort
+    of their group codes, not one pass a partition."""
+    codes = None
+    columns = []    # per column: (its distinct values, a row's index in them)
     for c in partition_columns:
         if c not in data.column_names:
             raise SchemaMismatchError(
                 f"partition column {c} missing from data",
                 error_class="DELTA_MISSING_PARTITION_COLUMN")
-        key_cols.append(data.column(c).to_pandas())
-    if len(key_cols) == 1:
-        codes, uniques = pd.factorize(key_cols[0], use_na_sentinel=False)
-        unique_tuples = [(u,) for u in uniques]
-    else:
-        mi = pd.MultiIndex.from_arrays(key_cols)
-        codes, uniques = pd.factorize(mi, use_na_sentinel=False)
-        unique_tuples = list(uniques)
+        col = data.column(c)
+        if pa.types.is_dictionary(col.type):
+            col = col.cast(col.type.value_type)
+        encoded = pc.dictionary_encode(col.combine_chunks(),
+                                       null_encoding="encode")
+        if isinstance(encoded, pa.ChunkedArray):
+            encoded = encoded.unify_dictionaries().combine_chunks()
+        values = encoded.dictionary.to_pylist()
+        idx = encoded.indices.to_numpy(zero_copy_only=False).astype(
+            np.int64, copy=False)
+        columns.append((values, idx))
+        if codes is None:
+            codes = idx
+        else:
+            # dense after every column, so the product never overflows
+            _, codes = np.unique(codes * len(values) + idx,
+                                 return_inverse=True)
+    if data.num_rows == 0:
+        return []
+    order = np.argsort(codes, kind="stable")
+    ordered = codes[order]
+    starts = np.flatnonzero(np.concatenate(
+        [[True], ordered[1:] != ordered[:-1]]))
+    stops = np.append(starts[1:], len(order))
+    by_group = data.take(pa.array(order, pa.int64()))
+    firsts = order[starts]      # a stable sort keeps a group's first row first
     out = []
-    codes = np.asarray(codes)
-    for gid, key in enumerate(unique_tuples):
-        idx = np.nonzero(codes == gid)[0]
-        pv = {
-            c: serialize_partition_value(_null_to_none(v))
-            for c, v in zip(partition_columns, key)
-        }
-        out.append((pv, data.take(pa.array(idx, pa.int64()))))
+    for g in np.argsort(firsts, kind="stable"):
+        pv = {c: serialize_partition_value(values[idx[firsts[g]]])
+              for c, (values, idx) in zip(partition_columns, columns)}
+        out.append((pv, by_group.slice(int(starts[g]),
+                                       int(stops[g] - starts[g]))))
     return out
-
-
-def _null_to_none(v):
-    import pandas as pd
-
-    try:
-        if v is None or (isinstance(v, float) and np.isnan(v)) or v is pd.NaT:
-            return None
-    except (TypeError, ValueError):
-        pass
-    return v
 
 
 def _split_rows(data: pa.Table, target_rows: Optional[int]):

@@ -244,6 +244,28 @@ def test_sql_group_aggregate_4m_rows(on_chip):
     _assert_fits(compiled)
 
 
+@pytest.mark.parametrize("nl_pad, nr_pad", [
+    (8_192, 3_145_728),       # a filtered dimension probes store_sales
+    (3_145_728, 131_072),     # store_sales probes date_dim or time_dim
+    (3_145_728, 2_097_152),   # store_sales probes customer_demographics
+])
+def test_sql_join_lanes_at_tpcds_sf1_shapes(on_chip, nl_pad, nr_pad):
+    """The join's sort in its radix form (one single-operand uint32 sort
+    a pass) at the padded shapes `tpcds-q-mix` gives it: 2,880,404 rows
+    of `store_sales` in a bucket of 3,145,728 against a dimension."""
+    with jax.enable_x64(True):
+        compiled = sqlops._join_lanes_kernel.lower(
+            on_chip((nl_pad,), jnp.int64), on_chip((nr_pad,), jnp.int64),
+            on_chip((), jnp.int32), on_chip((), jnp.int32),
+            on_chip((), jnp.int64), on_chip((), jnp.int32)).compile()
+    _assert_fits(compiled)
+    # one sort in the program, and that of one operand: the form the
+    # compiler takes seconds over, not minutes
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line]
+    assert len(sorts) == 1 and "u32[" in sorts[0], sorts
+
+
 def test_sharded_replay_fa_6m_rows_on_four_chips(topo):
     """The mesh route's `shard_map` program at the shape a cold load of
     `deltalog-10m-ckpt10` gives it (6,000,380 rows: 1,500,095 a shard in

@@ -275,3 +275,159 @@ def test_window_peer_last_first_run_unflagged():
     v, c = window_peer_last(vals, cnts, kb)
     assert v.tolist() == [2.0, 2.0, 4.0, 4.0]
     assert c.tolist() == [2, 2, 4, 4]
+
+
+# ---------------------------------------- the radix form of the sorts --
+# What the join's and the ORDER BY's sort were until PR 49: one stable
+# multi-operand `lax.sort`. The radix form (`_radix_perm`: passes of one
+# single-operand uint32 sort) has to give the same rows in the same
+# order, bit for bit.
+
+def _stable_join_lanes(l_vals, r_vals, nl_pad, nr_pad):
+    """(s_val, s_side, s_pos) of the real rows, by the stable form."""
+    import jax
+    import jax.numpy as jnp
+
+    nl, nr = len(l_vals), len(r_vals)
+    lp = np.full(nl_pad, 7, np.int64)       # any fill: pads are positional
+    lp[:nl] = l_vals
+    rp = np.full(nr_pad, -7, np.int64)
+    rp[:nr] = r_vals
+    vals = jnp.concatenate([jnp.asarray(lp), jnp.asarray(rp)])
+    iota = jnp.arange(vals.shape[0], dtype=jnp.int64)
+    side = (iota >= nl_pad).astype(jnp.uint8)
+    local = jnp.where(side == 1, iota - nl_pad, iota)
+    pad = (local >= jnp.where(side == 1, nr, nl)).astype(jnp.uint8)
+    s_pad, s_val, s_side, s_pos = (np.asarray(a) for a in jax.lax.sort(
+        (pad, vals, side, iota), num_keys=3, is_stable=True))
+    real = s_pad == 0
+    return s_val[real], s_side[real].astype(np.int64), s_pos[real]
+
+
+JOIN_CASES = {
+    "duplicates": (lambda r: r.integers(0, 9, 700), lambda r: r.integers(0, 9, 300)),
+    "ties_on_both_sides": (lambda r: np.repeat([5, 5, 6], 50), lambda r: np.repeat([5, 6, 6], 40)),
+    "negative_and_wide": (lambda r: r.integers(-(1 << 40), 1 << 40, 500),
+                          lambda r: r.integers(-(1 << 40), 1 << 40, 500)),
+    "mostly_pads": (lambda r: r.integers(0, 3, 3), lambda r: r.integers(0, 3, 1025)),
+    "empty_left": (lambda r: np.empty(0, np.int64), lambda r: r.integers(0, 4, 64)),
+    "one_value": (lambda r: np.zeros(33, np.int64), lambda r: np.zeros(17, np.int64)),
+    "int64_ends": (lambda r: np.array([-(1 << 61), (1 << 61) - 1, 0, 0]),
+                   lambda r: np.array([(1 << 61) - 1, -(1 << 61), 0])),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("case", sorted(JOIN_CASES))
+def test_radix_join_order_is_the_stable_sorts(case, seed):
+    from delta_tpu.ops import sqlops
+    from delta_tpu.ops.replay import pad_bucket
+
+    rng = np.random.default_rng(seed)
+    l_vals, r_vals = (np.asarray(make(rng), np.int64)
+                      for make in JOIN_CASES[case])
+    sqlops._ensure_x64()
+    nl, nr = len(l_vals), len(r_vals)
+    nl_pad, nr_pad = pad_bucket(max(nl, 1)), pad_bucket(max(nr, 1))
+    lp = np.zeros(nl_pad, np.int64)
+    lp[:nl] = l_vals
+    rp = np.zeros(nr_pad, np.int64)
+    rp[:nr] = r_vals
+    both = np.concatenate([l_vals, r_vals])
+    least = int(both.min())
+    bits = (int(both.max()) - least).bit_length()
+    perm, first = (np.asarray(a) for a in sqlops._join_lanes_kernel(
+        lp, rp, np.int32(nl), np.int32(nr), np.int64(least), np.int32(bits)))
+    s_key, s_side, s_pos = sqlops._sorted_triples(perm, first, nl + nr, nl_pad)
+    want_val, want_side, want_pos = _stable_join_lanes(l_vals, r_vals,
+                                                       nl_pad, nr_pad)
+    assert np.array_equal(s_pos, want_pos)
+    assert np.array_equal(s_side, want_side)
+    # a run's number stands for its key: the same runs
+    assert np.array_equal(np.diff(s_key) != 0, np.diff(want_val) != 0)
+    for how in ("inner", "left", "right", "outer"):
+        got = sqlops.join_pairs_lanes(l_vals, r_vals=r_vals, how=how)
+        want = sqlops._expand_pairs(want_val, want_side, want_pos,
+                                    nl_pad, how)
+        assert np.array_equal(got[0], want[0])
+        assert np.array_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "right", "outer"])
+def test_radix_join_codes_is_the_stable_sorts(rng, how):
+    import jax
+
+    from delta_tpu.ops import sqlops
+    from delta_tpu.ops.replay import pad_bucket
+
+    lk = rng.integers(0, 40, 900).astype(np.uint32)
+    rk = rng.integers(20, 60, 500).astype(np.uint32)
+    nl, n = len(lk), len(lk) + len(rk)
+    npad = pad_bucket(n)
+    codes = np.full(npad, 0xFFFFFFFF, np.uint32)
+    codes[:nl], codes[nl:n] = lk, rk
+    side = np.zeros(npad, np.uint32)
+    side[nl:] = 1
+    s_code, s_side, s_pos = (np.asarray(a) for a in jax.lax.sort(
+        (codes, side, np.arange(npad, dtype=np.int64)), num_keys=2,
+        is_stable=True))
+    real = s_code != 0xFFFFFFFF
+    want = sqlops._expand_pairs(s_code[real], s_side[real], s_pos[real],
+                                nl, how)
+    got = join_pairs(lk, rk, how=how)
+    assert np.array_equal(got[0], want[0])
+    assert np.array_equal(got[1], want[1])
+
+
+def test_a_join_whose_keys_span_the_whole_of_int64_declines():
+    from delta_tpu.ops import sqlops
+
+    ends = np.array([np.iinfo(np.int64).min, np.iinfo(np.int64).max])
+    assert sqlops.join_pairs_lanes(ends, r_vals=ends) is None
+
+
+SORT_CASES = {
+    "duplicates_and_ties": lambda r, n: [r.integers(0, 4, n), r.integers(0, 3, n).astype(np.float64)],
+    "null_lanes": lambda r, n: [(r.random(n) < 0.3).astype(np.uint8), r.integers(-50, 50, n),
+                                r.random(n) < 0.5, -r.integers(0, 5, n).astype(np.float64)],
+    "signed_zeros": lambda r, n: [r.choice([-0.0, 0.0, -1.5, 2.5], n), r.integers(0, 2, n)],
+    "wide_lanes": lambda r, n: [r.integers(-(1 << 62), 1 << 62, n), r.standard_normal(n),
+                                r.integers(-(1 << 62), 1 << 62, n)],
+    "constant": lambda r, n: [np.zeros(n, np.int64), np.ones(n)],
+    "float32_and_extremes": lambda r, n: [r.choice([np.inf, -np.inf, 1e-300, -1e300, 0.5], n),
+                                          r.standard_normal(n).astype(np.float32)],
+}
+
+
+@pytest.mark.parametrize("n", [1, 255, 1000, 1025])
+@pytest.mark.parametrize("case", sorted(SORT_CASES))
+def test_radix_sort_permutation_is_the_stable_sorts(case, n):
+    import jax
+
+    from delta_tpu.ops import sqlops
+
+    lanes = [np.asarray(a) for a in SORT_CASES[case](
+        np.random.default_rng(n), n)]
+    sqlops._ensure_x64()
+    operands = tuple(a.astype(np.float64) if a.dtype == np.float32
+                     else a.astype(np.uint8) if a.dtype == bool else a
+                     for a in lanes)
+    want = np.asarray(jax.lax.sort(
+        operands + (np.arange(n, dtype=np.int64),),
+        num_keys=len(lanes), is_stable=True)[-1])
+    got = sort_permutation(lanes)
+    assert got.dtype == np.int64
+    assert np.array_equal(got, want)
+
+
+def test_sort_words_pack_the_lanes_that_fit(rng):
+    from delta_tpu.ops import sqlops
+
+    lanes = [rng.integers(0, 2, 100).astype(np.uint8),       # 1 bit
+             rng.integers(0, 1 << 20, 100),                  # <= 20 bits
+             np.zeros(100),                                  # 0 bits
+             rng.integers(-(1 << 62), 1 << 62, 100)]         # 63 bits
+    words, bits = sqlops._pack_sort_words(lanes)
+    assert len(words) == 2 and all(w.dtype == np.uint64 for w in words)
+    assert bits[0] <= 1 + 20 and bits[1] <= 63
+    assert int(words[0].max()) < 1 << bits[0]
