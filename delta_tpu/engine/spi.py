@@ -40,10 +40,16 @@ class ParquetHandler:
 
     def read_parquet_files(
         self, paths: Sequence[str], columns: Optional[List[str]] = None,
-        present_only: bool = False,
+        present_only: bool = False, sizes: Optional[Sequence[int]] = None,
     ) -> Iterator[pa.Table]:
         """One table a file, in the order of `paths`; `columns` projects
         onto those of them the file has.
+
+        `sizes` is a hint too: the files' bytes as the caller knows them
+        (the log's `size`, one a path), by which a handler that reads a
+        batch of files on several threads may share them out. A handler
+        may ignore it; whatever it does, the tables come in the order of
+        `paths` and a file that is missing raises as it does read alone.
 
         `present_only` is a hint in the manner of the predicate of Delta
         Kernel's `readParquetFiles(files, schema, predicate)`: the caller

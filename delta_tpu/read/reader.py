@@ -14,8 +14,8 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
-from delta_tpu.models.schema import PrimitiveType, to_arrow_type
-from delta_tpu.stats.partition import deserialize_partition_value
+from delta_tpu import obs
+from delta_tpu.models.schema import to_arrow_type
 
 
 def _absolute_path(table_path: str, file_path: str) -> str:
@@ -35,57 +35,84 @@ def _dv_row_mask(engine, table_path: str, dv_row: dict, num_rows: int) -> Option
     return ~deleted
 
 
-def _align_to_logical(tbl: pa.Table, schema, partition_columns, p2l,
-                      needed=None) -> pa.Table:
-    """Physical→logical renames + schema alignment for one file's rows:
-    dropped columns disappear, columns added after the file was written
-    read as null (restricted to `needed` when projecting), and files
-    written before a type-widening change cast up."""
-    if p2l:
-        tbl = tbl.rename_columns([p2l.get(c, c) for c in tbl.column_names])
+def _alignment(physical: pa.Schema, schema, partition_columns, p2l,
+               needed=None):
+    """How a file of Arrow schema `physical` is brought to the logical
+    schema (`_align`): None where it is there already, else (the
+    columns' logical names, the positions kept, the casts as (position,
+    name, type), the columns to add as nulls). Dropped columns
+    disappear, columns added after the file was written read as null
+    (restricted to `needed` when projecting), and files written before a
+    type-widening change cast up."""
+    names = [p2l.get(c, c) for c in physical.names]
     if schema is None:
-        return tbl
+        return (None if names == physical.names
+                else (names, list(range(len(names))), (), ()))
     known = {f.name: f for f in schema.fields if f.name not in partition_columns}
-    tbl = tbl.select([c for c in tbl.column_names if c in known])
-    for idx, c in enumerate(tbl.column_names):
-        target_t = to_arrow_type(known[c].dataType)
-        if tbl.schema.field(idx).type != target_t:
-            try:
-                tbl = tbl.set_column(
-                    idx, pa.field(c, target_t), tbl.column(c).cast(target_t))
-            except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
-                pass  # non-widening mismatch: surface as-is
-    for f in schema.fields:
-        if f.name in partition_columns or f.name in tbl.column_names:
-            continue
-        if needed is not None and f.name not in needed:
-            continue
-        tbl = tbl.append_column(
-            f.name, pa.nulls(tbl.num_rows, to_arrow_type(f.dataType)))
+    keep = [i for i, c in enumerate(names) if c in known]
+    casts = []
+    for at, i in enumerate(keep):
+        target_t = to_arrow_type(known[names[i]].dataType)
+        if physical.field(i).type != target_t:
+            casts.append((at, names[i], target_t))
+    have = {names[i] for i in keep}
+    nulls = [(f.name, to_arrow_type(f.dataType)) for f in schema.fields
+             if f.name in known and f.name not in have
+             and (needed is None or f.name in needed)]
+    if (names == physical.names and len(keep) == len(names)
+            and not casts and not nulls):
+        return None
+    return names, keep, casts, nulls
+
+
+def _align(tbl: pa.Table, alignment) -> pa.Table:
+    """One file's rows under its schema's `_alignment`."""
+    if alignment is None:
+        return tbl
+    names, keep, casts, nulls = alignment
+    tbl = tbl.rename_columns(names)
+    if len(keep) != len(names):
+        tbl = tbl.select(keep)
+    for at, name, target_t in casts:
+        try:
+            tbl = tbl.set_column(
+                at, pa.field(name, target_t), tbl.column(at).cast(target_t))
+        except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+            pass  # non-widening mismatch: surface as-is
+    for name, t in nulls:
+        tbl = tbl.append_column(name, pa.nulls(tbl.num_rows, t))
     return tbl
 
 
-def _append_partition_columns(tbl: pa.Table, pv_dict, partition_columns,
-                              schema, mapped: bool, needed=None) -> pa.Table:
+def _append_partition_columns(tbl: pa.Table, partition_values: pa.ChunkedArray,
+                              counts, metadata, needed=None) -> pa.Table:
     """Splice partition-column values (serialized strings in
     `partitionValues`, keyed by physical name under column mapping) back
-    into the row set as typed columns."""
-    for c in partition_columns:
-        if needed is not None and c not in needed:
-            continue
-        dtype = PrimitiveType("string")
-        pv_key = c
-        if schema is not None and c in schema:
-            f = schema[c]
-            if isinstance(f.dataType, PrimitiveType):
-                dtype = f.dataType
-            if mapped:
-                pv_key = f.physical_name
-        value = deserialize_partition_value(
-            pv_dict.get(pv_key, pv_dict.get(c)), dtype)
-        tbl = tbl.append_column(
-            c, pa.array([value] * tbl.num_rows, to_arrow_type(dtype)))
-    return tbl
+    into the row set as typed columns: `partition_values` holds a map a
+    file and `counts` the rows `tbl` has of each, in order. One value a
+    file is typed, then each repeated over its file's rows in one pass
+    (a run-end decode)."""
+    from delta_tpu.stats.partition import partition_values_to_columns
+
+    wanted = [c for c in metadata.partitionColumns
+              if needed is None or c in needed]
+    if not wanted:
+        return tbl
+    counts = np.asarray(counts, dtype=np.int64)
+    held = counts > 0        # a run has a row at the least
+    per_file = partition_values_to_columns(partition_values, metadata)
+    if not held.all():
+        per_file = per_file.filter(pa.array(held))
+    ends = pa.array(np.cumsum(counts[held]))
+    # built whole: a projection of partition columns alone reads tables
+    # of no column, whose concatenation forgets its rows
+    return pa.Table.from_arrays(
+        tbl.columns + [
+            pc.run_end_decode(pa.RunEndEncodedArray.from_arrays(
+                ends, per_file.column(c).combine_chunks()))
+            for c in wanted],
+        schema=pa.schema(list(tbl.schema) + [per_file.field(c) for c in wanted],
+                         metadata=tbl.schema.metadata))
 
 
 def read_add_file_logical(engine, table_path: str, snapshot, add,
@@ -114,17 +141,37 @@ def read_add_file_logical(engine, table_path: str, snapshot, add,
         raise FileNotFoundInLogError(
             f"data file referenced by the log is missing: {add.path} "
             "(removed by VACUUM, or the log is ahead of storage)") from e
-    tbl = _align_to_logical(tbl, schema, partition_columns, p2l)
+    tbl = _align(tbl, _alignment(tbl.schema, schema, partition_columns, p2l))
     if apply_dv and add.deletionVector is not None:
         mask = _dv_row_mask(engine, table_path, add.deletionVector.to_dict(),
                             tbl.num_rows)
         if mask is not None:
             tbl = tbl.filter(pa.array(mask))
-    return _append_partition_columns(
-        tbl, add.partitionValues or {}, partition_columns, schema, mapped)
+    partition_values = pa.array(
+        [list((add.partitionValues or {}).items())],
+        pa.map_(pa.string(), pa.string()))
+    return _append_partition_columns(tbl, partition_values, [tbl.num_rows],
+                                     meta)
+
+
+def _deletion_vectors(files: pa.Table) -> dict:
+    """row of the plan -> its deletion vector's descriptor, for the rows
+    that have one."""
+    column = files.column("deletion_vector")
+    if not pa.types.is_struct(column.type) or column.null_count == len(column):
+        return {}
+    has = pc.is_valid(pc.struct_field(column, "storageType"))
+    rows = np.flatnonzero(has.to_numpy(zero_copy_only=False))
+    return dict(zip(rows.tolist(), column.take(rows).to_pylist()))
 
 
 def read_scan(scan) -> pa.Table:
+    """The plan's files as one table, in the plan's order. The files are
+    handed to the engine's Parquet handler as one batch (which may read
+    them on several threads); what is the same for every file is done
+    once a scan: the alignment is decided once a physical schema, a
+    deletion vector is looked for only where the plan has one, and the
+    partition columns are built after the concatenation."""
     from delta_tpu.columnmapping import (
         logical_to_physical_names,
         mapping_mode,
@@ -150,33 +197,49 @@ def read_scan(scan) -> pa.Table:
     if requested is not None and scan.filter is not None:
         refs = [r[0] for r in scan.filter.references()]
         needed = requested + [c for c in dict.fromkeys(refs) if c not in requested]
+    # Always named where there is a schema, so that the handler reads the
+    # batch as a projection: of the columns asked for, those a file has
+    # (one that predates a column reads without it). With no projection
+    # every column of the schema is asked for, under its physical and
+    # its logical name: a file written before the table was mapped
+    # carries the logical ones, and read whole it was taken as it came.
     data_columns = None
     if needed is not None:
         data_columns = [
             l2p.get(c, c) for c in needed if c not in partition_columns
         ]
+    elif schema is not None:
+        names = [f.name for f in schema.fields
+                 if f.name not in partition_columns]
+        data_columns = list(dict.fromkeys(
+            [l2p.get(c, c) for c in names] + names))
 
+    paths = [_absolute_path(table_path, p)
+             for p in files.column("path").to_pylist()]
+    sizes = files.column("size").fill_null(0).to_numpy()
+    dvs = _deletion_vectors(files)
+    # (a physical schema met, its alignment): few, and `equals` is
+    # cheap where hashing a schema is not
+    alignments: List[tuple] = []
     batches: List[pa.Table] = []
-    paths = files.column("path").to_pylist()
-    pvs = files.column("partition_values").to_pylist()
-    dvs = files.column("deletion_vector").to_pylist()
-    for path, pv, dv in zip(paths, pvs, dvs):
-        abs_path = _absolute_path(table_path, path)
-        try:
-            tbl = next(
-                iter(engine.parquet.read_parquet_files([abs_path], columns=data_columns))
-            )
-        except (pa.ArrowInvalid, KeyError):
-            # file predates newly added columns — read everything it has
-            tbl = next(iter(engine.parquet.read_parquet_files([abs_path])))
-        tbl = _align_to_logical(tbl, schema, partition_columns, p2l, needed)
-        mask = _dv_row_mask(engine, table_path, dv, tbl.num_rows)
-        if mask is not None:
-            tbl = tbl.filter(pa.array(mask))
-        pv_dict = {k: v for k, v in pv} if isinstance(pv, list) else (pv or {})
-        tbl = _append_partition_columns(
-            tbl, pv_dict, partition_columns, schema, mapped, needed)
-        batches.append(tbl)
+    with obs.span("scan.read", files=len(paths), bytes=int(sizes.sum())):
+        tables = engine.parquet.read_parquet_files(
+            paths, columns=data_columns, sizes=sizes)
+        for row, tbl in enumerate(tables):
+            physical = tbl.schema
+            for met, alignment in alignments:
+                if physical.equals(met, check_metadata=False):
+                    break
+            else:
+                alignment = _alignment(
+                    physical, schema, partition_columns, p2l, needed)
+                alignments.append((physical, alignment))
+            tbl = _align(tbl, alignment)
+            if row in dvs:
+                mask = _dv_row_mask(engine, table_path, dvs[row], tbl.num_rows)
+                if mask is not None:
+                    tbl = tbl.filter(pa.array(mask))
+            batches.append(tbl)
 
     if not batches:
         cols = requested or (
@@ -188,7 +251,10 @@ def read_scan(scan) -> pa.Table:
             empty[c] = pa.array([], t)
         return pa.table(empty)
 
-    result = pa.concat_tables(batches, promote_options="permissive")
+    result = _append_partition_columns(
+        pa.concat_tables(batches, promote_options="permissive"),
+        files.column("partition_values"), [t.num_rows for t in batches],
+        meta, needed)
     if scan.filter is not None:
         from delta_tpu.expressions.eval import evaluate_predicate_host
 

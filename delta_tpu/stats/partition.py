@@ -100,15 +100,20 @@ def partition_values_to_columns(pv_column: pa.ChunkedArray, metadata) -> pa.Tabl
     # Flatten map → per-row dict lookup via numpy. Maps are small (few
     # partition columns), so flatten + searchsorted-style grouping:
     offsets = np.asarray(arr.offsets)
-    keys = np.asarray(arr.keys, dtype=object)
-    items = np.asarray(arr.items, dtype=object)
+    first, end = (int(offsets[0]), int(offsets[-1])) if n else (0, 0)
+    # a slice keeps its parent's entries: those of its own rows
+    keys = np.asarray(arr.keys[first:end], dtype=object)
+    items = np.asarray(arr.items[first:end], dtype=object)
     row_of_entry = np.repeat(np.arange(n), np.diff(offsets))
 
     cols = {}
     for name, (map_key, dtype) in types.items():
         values = np.full(n, None, dtype=object)
-        sel = keys == map_key
-        values[row_of_entry[sel]] = items[sel]
+        # a writer that keyed a mapped column by its logical name is
+        # read too; the physical name's entry wins
+        for key in dict.fromkeys((name, map_key)):
+            sel = keys == key
+            values[row_of_entry[sel]] = items[sel]
         py = [deserialize_partition_value(v, dtype) for v in values]
         try:
             cols[name] = pa.array(py, to_arrow_type(dtype))
