@@ -9,7 +9,9 @@ TpuEngine must beat.
 
 from __future__ import annotations
 
+import contextlib
 import json
+import time
 from collections import deque
 from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
@@ -56,6 +58,14 @@ _FILES_INLINE = obs.counter("scan.files_inline")
 
 # how many parquet byte-reads to keep in flight ahead of the decoder
 _PARQUET_PREFETCH_DEPTH = 2
+
+_UNTIMED = contextlib.nullcontext()
+
+
+def _untimed(key: str) -> contextlib.AbstractContextManager:
+    """`Span.timed` of a batch read under no span: nothing is timed."""
+    return _UNTIMED
+
 
 # rows a batch of a `present_only` read: a row group is left as soon as
 # the batches read hold every present row its footer counts
@@ -432,31 +442,52 @@ class HostParquetHandler(ParquetHandler):
             return io_call(endpoint_of(path), lambda: pa.OSFile(local))
         return pa.BufferReader(self._fetch(path))
 
+    def _open_parquet(self, path: str) -> Tuple[pa.NativeFile,
+                                                pq.ParquetFile]:
+        """`path` opened (`_open`) and its footer read; the handle is
+        the caller's to close."""
+        source = self._open(path)
+        try:
+            # no pre-buffering: the bytes are in memory or in the page
+            # cache, and a read handed to Arrow's I/O pool is one more
+            # thread to wait for (1,824 files of ~60 KB on the chip's
+            # host, PR 50: 2.43 against 2.72 s on one thread, 0.92
+            # against 0.97 on four, 0.87 against 0.81 on thirteen)
+            return source, pq.ParquetFile(source, pre_buffer=False)
+        except BaseException:
+            source.close()
+            raise
+
     def _project_files(self, paths: Sequence[str], columns: List[str],
-                       use_threads: bool) -> Iterator[pa.Table]:
+                       use_threads: bool,
+                       timed: Callable[
+                           [str], contextlib.AbstractContextManager]
+                       ) -> Iterator[pa.Table]:
         """Of `columns`, those each of `paths` has, decoded from it in
         the file's own order (none of them: no column and the file's
         rows), one file after another on the calling thread, each by a
         storage call of its own (`_open`). Which columns a file has is
         asked of its footer once a Parquet schema, not once a file.
         `use_threads` is Arrow's own fan-out over the columns: off where
-        the files are a task of a pool."""
+        the files are a task of a pool. `timed` is the `Span.timed` of
+        the span that answers for these files: it adds up `open_ms` (the
+        storage call and the footer) and `decode_ms` (the columns) over
+        them, since a span a file would be thousands a scan."""
         wanted = set(columns)
         seen = cols = None
+        # entered once a file each, made once: every call in this loop
+        # is a point where a task hands the interpreter's lock on
+        opening, decoding = timed("open_ms"), timed("decode_ms")
         for p in paths:
-            with self._open(p) as source:
-                # no pre-buffering: the bytes are in memory or in the
-                # page cache, and a read handed to Arrow's I/O pool is
-                # one more thread to wait for (1,824 files of ~60 KB on
-                # the chip's host, PR 50: 2.43 against 2.72 s on one
-                # thread, 0.92 against 0.97 on four, 0.87 against 0.81
-                # on thirteen)
-                f = pq.ParquetFile(source, pre_buffer=False)
+            with opening:
+                source, f = self._open_parquet(p)
+            with source:
                 schema = f.metadata.schema
                 if seen is None or not schema.equals(seen):
                     seen = schema
                     cols = [c for c in f.schema_arrow.names if c in wanted]
-                tbl = f.read(columns=cols, use_threads=use_threads)
+                with decoding:
+                    tbl = f.read(columns=cols, use_threads=use_threads)
             yield tbl
 
     def _read_projected(self, paths: List[str], columns: List[str],
@@ -466,27 +497,47 @@ class HostParquetHandler(ParquetHandler):
         (`_file_runs`), or here where the batch is not worth that. A
         task decodes its files on its one thread and deals nothing out
         further, so none waits for the pool it runs on. The active span
-        (`scan.read`) learns what was done."""
+        (`scan.read`) learns what was done: how the batch was dealt and,
+        dealt, how long this thread was blocked waiting for the pool
+        (`wait_ms`); each task says the rest on a `scan.read_run` of its
+        own (`obs.wrap` makes it that span's child, on the worker's
+        thread). An inline batch opens none and times its files on the
+        active span itself."""
         from delta_tpu.utils.threads import default_scan_threads, scan_pool
 
         workers = default_scan_threads()
         runs = _file_runs([0] * len(paths) if sizes is None else sizes,
                           workers)
+        sp = obs.current_span()
+        timed = _untimed if sp is None else sp.timed
         if len(runs) < 2:
             _FILES_INLINE.inc(len(paths))
             obs.set_attrs(tasks=0, threads=1, inline=True)
-            yield from self._project_files(paths, columns, True)
+            yield from self._project_files(paths, columns, True, timed)
             return
         _FILES_DEALT.inc(len(paths))
         obs.set_attrs(tasks=len(runs), threads=workers, inline=False)
         deadline = current_deadline()
 
         def read_run(run: Tuple[int, int]) -> List[pa.Table]:
-            with deadline_scope_at(deadline):
-                return list(self._project_files(paths[run[0]:run[1]],
-                                                columns, False))
+            with deadline_scope_at(deadline), obs.span(
+                    "scan.read_run", files=run[1] - run[0]) as task:
+                # the thread's own CPU: what the span lasts beyond it,
+                # the thread was not running (on a warm local store,
+                # waiting for the interpreter's lock)
+                cpu_ns = time.thread_time_ns() if task.recording else 0
+                tables = list(self._project_files(
+                    paths[run[0]:run[1]], columns, False, task.timed))
+                if task.recording:
+                    task.set_attrs(
+                        rows=sum(t.num_rows for t in tables),
+                        bytes=sum(t.get_total_buffer_size() for t in tables),
+                        cpu_ms=(time.thread_time_ns() - cpu_ns) / 1e6)
+                return tables
 
-        for tables in scan_pool().map(obs.wrap(read_run), runs):
+        with timed("wait_ms"):
+            dealt = scan_pool().map(obs.wrap(read_run), runs)
+        for tables in dealt:
             yield from tables
 
     def read_parquet_files(

@@ -188,6 +188,14 @@ class Span:
     def recording(self) -> bool:
         return True
 
+    def timed(self, key: str) -> "_Timed":
+        """A context manager that adds the milliseconds spent inside it
+        to attribute `key` of this span: a sum over many short intervals
+        (a file's open, its decode) that are too many to be spans of
+        their own. It may be kept and entered again and again, by the
+        span's own thread, one entry at a time."""
+        return _Timed(self.attrs, key)
+
     def to_dict(self) -> Dict[str, object]:
         return {
             "type": "span",
@@ -210,6 +218,25 @@ class Span:
         return (f"Span({self.name!r}, trace={self.trace_id[:8]}, "
                 f"span={self.span_id}, parent={self.parent_id}, "
                 f"status={self.status})")
+
+
+class _Timed:
+    """One entry of `Span.timed`: the span clock round a block, added
+    to the attribute whether the block returns or raises."""
+
+    __slots__ = ("_attrs", "_key", "_start_ns")
+
+    def __init__(self, attrs: Dict[str, object], key: str):
+        self._attrs = attrs
+        self._key = key
+
+    def __enter__(self) -> None:
+        self._start_ns = time.perf_counter_ns()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        spent = (time.perf_counter_ns() - self._start_ns) / 1e6
+        self._attrs[self._key] = self._attrs.get(self._key, 0.0) + spent
+        return False
 
 
 class _NoopSpan:
@@ -237,6 +264,9 @@ class _NoopSpan:
     @property
     def recording(self) -> bool:
         return False
+
+    def timed(self, key: str) -> "_NoopCtx":
+        return _NOOP_CTX    # no clock read, nothing allocated
 
 
 class _NoopCtx:

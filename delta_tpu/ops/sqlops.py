@@ -508,6 +508,18 @@ def _sorted_triples(perm: np.ndarray, first: np.ndarray, n_real: int,
             (s_pos >= right_from).astype(np.int64), s_pos)
 
 
+def _pairs_of(perm: np.ndarray, first: np.ndarray, n_real: int,
+              right_from: int, how: str) -> tuple[np.ndarray, np.ndarray]:
+    """A join kernel's answer as row pairs, the host's share of the join
+    after the read: span `join.expand`."""
+    with obs.span("join.expand", rows=n_real) as sp:
+        pairs = _expand_pairs(
+            *_sorted_triples(perm, first, n_real, right_from),
+            right_from, how)
+        sp.set_attr("pairs", len(pairs[0]))
+        return pairs
+
+
 def _expand_pairs(
     s_key: np.ndarray,
     s_side: np.ndarray,
@@ -597,7 +609,7 @@ def join_pairs(
         dd.h2d("codes", codes)
         perm, first = _read("sqlops.join_codes", *_join_codes_kernel(
             jax.device_put(codes, device), np.int32(n), np.int32(bits)))
-    return _expand_pairs(*_sorted_triples(perm, first, n, nl), nl, how)
+    return _pairs_of(perm, first, n, nl, how)
 
 
 def join_pairs_lanes(
@@ -665,8 +677,7 @@ def join_pairs_lanes(
             np.int32(bits)))
     # the kernel's real rows are the left's, then the right's; a right
     # row's position is past the left's pad
-    return _expand_pairs(*_sorted_triples(perm, first, nl + nr, nl_pad),
-                         nl_pad, how)
+    return _pairs_of(perm, first, nl + nr, nl_pad, how)
 
 
 # --------------------------------------------------------- windows ----

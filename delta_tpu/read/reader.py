@@ -251,18 +251,25 @@ def read_scan(scan) -> pa.Table:
             empty[c] = pa.array([], t)
         return pa.table(empty)
 
-    result = _append_partition_columns(
-        pa.concat_tables(batches, promote_options="permissive"),
-        files.column("partition_values"), [t.num_rows for t in batches],
-        meta, needed)
-    if scan.filter is not None:
-        from delta_tpu.expressions.eval import evaluate_predicate_host
+    # what a scan does to its batches once they are read: one table, the
+    # partition columns, the residual filter, the projection
+    with obs.span("scan.assemble", batches=len(batches),
+                  filtered=scan.filter is not None) as sp:
+        result = _append_partition_columns(
+            pa.concat_tables(batches, promote_options="permissive"),
+            files.column("partition_values"), [t.num_rows for t in batches],
+            meta, needed)
+        sp.set_attr("rows_in", result.num_rows)
+        if scan.filter is not None:
+            from delta_tpu.expressions.eval import evaluate_predicate_host
 
-        try:
-            keep = evaluate_predicate_host(scan.filter, result)
-            result = result.filter(pa.array(keep))
-        except KeyError:
-            pass  # filter references columns not projected
-    if requested is not None:
-        result = result.select([c for c in requested if c in result.column_names])
+            try:
+                keep = evaluate_predicate_host(scan.filter, result)
+                result = result.filter(pa.array(keep))
+            except KeyError:
+                pass  # filter references columns not projected
+        if requested is not None:
+            result = result.select(
+                [c for c in requested if c in result.column_names])
+        sp.set_attr("rows", result.num_rows)
     return result

@@ -332,9 +332,10 @@ class DeviceSpine:
                 out = self._merge_lanes(left, right, how, lk[0], lane)
                 if out is not None:
                     return out
-        codes, _ = _joint_codes([
-            np.concatenate([left[a].to_numpy(), right[b].to_numpy()])
-            for a, b in zip(lk, rk)])
+        with obs.span("join.encode", kind="codes", rows=n_l + n_r):
+            codes, _ = _joint_codes([
+                np.concatenate([left[a].to_numpy(), right[b].to_numpy()])
+                for a, b in zip(lk, rk)])
         l_idx, r_idx = sqlops.join_pairs(codes[:n_l], codes[n_l:],
                                          how=how, device=self.device)
         return self._gather(left, right, how, l_idx, r_idx)
@@ -345,20 +346,21 @@ class DeviceSpine:
         side encodes host-side to the lane's int64 domain; None when it
         can't (dtype mismatch) and the caller re-joins via the joint
         factorize path."""
-        if lane.kind == "codes":
-            lv = left[lcol].to_numpy()
-            if lv.dtype.kind not in "OUS":
-                return None
-            probe = lane.dictionary.get_indexer(lv)
-            # probe values absent from the build dictionary can never
-            # match: remap the -1 misses past every real code (the pad
-            # sentinel stays reserved for padding)
-            l_vals = np.where(probe < 0, len(lane.dictionary),
-                              probe).astype(np.int64)
-        else:
-            l_vals = _int64_lane(left[lcol])
-            if l_vals is None:
-                return None
+        with obs.span("join.encode", kind="lanes", rows=len(left)):
+            if lane.kind == "codes":
+                lv = left[lcol].to_numpy()
+                if lv.dtype.kind not in "OUS":
+                    return None
+                probe = lane.dictionary.get_indexer(lv)
+                # probe values absent from the build dictionary can
+                # never match: remap the -1 misses past every real code
+                # (the pad sentinel stays reserved for padding)
+                l_vals = np.where(probe < 0, len(lane.dictionary),
+                                  probe).astype(np.int64)
+            else:
+                l_vals = _int64_lane(left[lcol])
+                if l_vals is None:
+                    return None
         pairs = sqlops.join_pairs_lanes(
             l_vals, r_resident=(lane.dev, lane.n, lane.least, lane.most),
             how=how, device=self.device)
@@ -371,15 +373,17 @@ class DeviceSpine:
                 l_idx: np.ndarray, r_idx: np.ndarray) -> pd.DataFrame:
         """Reconstruct the pandas-merge-shaped output from matched row
         index pairs (-1 = null-extended side)."""
-        lpart = left.take(np.where(l_idx >= 0, l_idx, 0)) \
-            .reset_index(drop=True)
-        rpart = right.take(np.where(r_idx >= 0, r_idx, 0)) \
-            .reset_index(drop=True)
-        if how in ("right", "outer"):
-            lpart = lpart.where(pd.Series(l_idx >= 0))
-        if how in ("left", "outer"):
-            rpart = rpart.where(pd.Series(r_idx >= 0))
-        return pd.concat([lpart, rpart], axis=1)
+        with obs.span("join.gather", rows=len(l_idx),
+                      columns=left.shape[1] + right.shape[1]):
+            lpart = left.take(np.where(l_idx >= 0, l_idx, 0)) \
+                .reset_index(drop=True)
+            rpart = right.take(np.where(r_idx >= 0, r_idx, 0)) \
+                .reset_index(drop=True)
+            if how in ("right", "outer"):
+                lpart = lpart.where(pd.Series(l_idx >= 0))
+            if how in ("left", "outer"):
+                rpart = rpart.where(pd.Series(r_idx >= 0))
+            return pd.concat([lpart, rpart], axis=1)
 
     # --------------------------------------------------------- sorts --
 

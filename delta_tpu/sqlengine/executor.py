@@ -306,24 +306,29 @@ def _merge_null_safe(left: pd.DataFrame, right: pd.DataFrame, how: str,
 
     with obs.span("sql.join", how=how, n_left=len(left),
                   n_right=len(right), route="host", hot=False) as sp:
-        lnull = left[lk].isna().any(axis=1)
-        rnull = right[rk].isna().any(axis=1)
-        if not lnull.any() and not rnull.any():  # hot path: no copies
-            merged = match(left, right, None)
-        else:
+        # a phase of its own where the device may take the match (the
+        # gate is asked after it); without a spine the join is its
+        # merge, and this is a span under `verbose` alone
+        with obs.span("join.nulls", _verbose=spine is None):
+            lnull = left[lk].isna().any(axis=1)
+            rnull = right[rk].isna().any(axis=1)
+            l_any, r_any = lnull.any(), rnull.any()
             # keep the original object when a side is already null-free
             # (the spine's operand-cache lookup keys on frame identity),
             # and pass the pre-exclusion right as provenance: for a
             # single-key join the null-drop is exactly "rows minus that
             # column's nulls", so the cached lane built from one query's
             # rm aligns with every other query's rm
-            lm = left if not lnull.any() else left[~lnull]
-            rm = right if not rnull.any() else right[~rnull]
+            lm = left[~lnull] if l_any else left
+            rm = right[~rnull] if r_any else right
+        if not l_any and not r_any:  # hot path: no copies
+            merged = match(left, right, None)
+        else:
             merged = match(lm, rm, right)
             extra = []
-            if how in ("left", "outer") and lnull.any():
+            if how in ("left", "outer") and l_any:
                 extra.append(left[lnull])
-            if how in ("right", "outer") and rnull.any():
+            if how in ("right", "outer") and r_any:
                 extra.append(right[rnull])
             if extra:
                 merged = pd.concat([merged] + extra, ignore_index=True)
@@ -594,10 +599,20 @@ class _Exec:
                     scan = s["snap"].scan(filter=None, columns=cols)
                     arrow = scan.to_arrow()
                     full_rows = True
+                with obs.span("sql.frame", rows=arrow.num_rows,
+                              columns=arrow.num_columns) as fsp:
+                    if fsp.recording:
+                        # not `nbytes`: that walks every chunk's slices,
+                        # 40-70 ms for a fact table's 1,824 batches
+                        fsp.set_attrs(
+                            decimal_columns=sum(
+                                pa.types.is_decimal(f.type)
+                                for f in arrow.schema),
+                            bytes=arrow.get_total_buffer_size())
+                    df = _normalize_frame(
+                        _decimals_as_float64(arrow).to_pandas())
                 files = scan.add_files_table().num_rows   # the plan's, kept
                 _SCAN_FILES.inc(files)
-                df = _decimals_as_float64(arrow).to_pandas()
-                df = _normalize_frame(df)
                 sp.set_attrs(files=files, rows=len(df))
             df.columns = [f"{s['alias']}.{c}" for c in df.columns]
             s["frame"] = df

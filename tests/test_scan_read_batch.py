@@ -487,10 +487,18 @@ def test_a_scan_says_how_it_read_its_files(tmp_path, monkeypatch):
     reads = [s for s in spans if s.name == "test.storage_read"]
     assert len(reads) == FILES + 1
     by_id = {s.span_id: s for s in spans}
-    for s in reads:
-        assert by_id[s.parent_id].name == "scan.read"
+
+    def scan_read_over(s):
+        """The `scan.read` a storage span lies under: its parent, or in
+        a pool task the parent of the task's `scan.read_run`."""
+        above = by_id[s.parent_id]
+        if above.name == "scan.read_run":
+            above = by_id[above.parent_id]
+        assert above.name == "scan.read"
+        return above
+
     in_workers = {s.attrs["thread"] for s in reads
-                  if by_id[s.parent_id] is many}
+                  if scan_read_over(s) is many}
     assert threading.current_thread().name not in in_workers
     assert all(name.startswith("delta-tpu-scan") for name in in_workers)
 
