@@ -29,13 +29,22 @@ overflow) returns None and the caller falls back to the host delta
 path, dropping residency; in-batch disorder is sorted away, not
 rejected — real commits columnarize removes after adds. Disable with DELTA_TPU_RESIDENT=0.
 
+The masks of an append are the last append's, patched: the state keeps
+the winner words it read last and the two masks it returned, and an
+append looks only at the words that differ (a delta of 100 rows can
+change the winner of ~200 slots of millions), copies the two masks
+once and writes the rows of those slots. With nothing to diff against
+(the first append of a load: establishment unpacks nothing) both masks
+are rebuilt over every slot, as they were at every append until PR 52.
+
 What the chip found (PR 46, `ckpt-query-under-ingest-10m-v5e4`: 6.0M
 rows on four v5e chips, the lanes really donated): every refresh took
 route=resident and none fell back; an append is ~69 ms of a ~205 ms
 refresh, ~56 of them on the host (`resident.masks` ~52: both masks
 rebuilt over all `shards x m` slots) and ~13 waiting for the chips.
 The host route beside it on one machine, and the verdict that pair
-leaves open (ROADMAP C2): PERF.md §6.
+leaves open (ROADMAP C2): PERF.md §6. What the diff made of those 52 ms
+(PR 52): PERF.md §6.
 """
 
 from __future__ import annotations
@@ -56,6 +65,8 @@ _APPENDS = obs.counter("replay.resident_appends")
 _FALLBACKS = obs.counter("replay.resident_fallbacks")
 _ESTABLISHED = obs.counter("replay.resident_established")
 _RELEASED = obs.counter("replay.resident_released")
+_MASK_DIFFS = obs.counter("replay.resident_mask_diffs")
+_MASK_REBUILDS = obs.counter("replay.resident_mask_rebuilds")
 # device bytes pinned by resident key lanes are accounted in the
 # process-wide resident ledger (obs/hbm.py), which also derives the
 # `replay.resident_hbm_bytes` gauge this module used to maintain
@@ -144,6 +155,11 @@ class ResidentShardState:
         self._index = None
         self._overlay: dict = {}       # paths first seen after establish
         self._max_version: Optional[int] = None  # newest appended version
+        # (winner words, live, tomb) as the last append left them: what
+        # the next one diffs against. None until an append has run, so a
+        # load that is never refreshed unpacks nothing. The masks are the
+        # owning snapshot's own arrays: references, never written to.
+        self._last: Optional[tuple] = None
 
     # ------------------------------------------------------------ codes
 
@@ -311,19 +327,60 @@ class ResidentShardState:
                 winner_np = np.asarray(winner_sh)  # [S, M/32] packed D2H
                 ph.set_attr("bytes", winner_np.nbytes)
             with obs.span("resident.masks", _verbose=small,
-                          slots=s * self.m):
-                winner = np.unpackbits(
-                    winner_np.view(np.uint8).reshape(s, -1),
-                    axis=1, bitorder="little")[:, :self.m].astype(bool)
-                live_slots = winner & self.add
-                tomb_slots = winner & ~self.add
-                valid = self.scatter >= 0
-                live = np.zeros(self.n, bool)
-                tomb = np.zeros(self.n, bool)
-                live[self.scatter[valid]] = live_slots[valid]
-                tomb[self.scatter[valid]] = tomb_slots[valid]
+                          slots=s * self.m) as ph:
+                live, tomb = self._masks(winner_np, n_prev, ph)
+            self._last = (winner_np, live, tomb)
             _APPENDS.inc()
             return live, tomb
+
+    def _masks(self, words: np.ndarray, n_prev: int, ph):
+        """(live, tomb) over the `self.n` rows held, from the packed
+        winner words `[shards, m / 32]` of the append just run. A slot's
+        add bit and row never change once written, so its two mask bits
+        change only where its winner bit does: the last append's masks,
+        copied once, are patched at the slots under the words that
+        differ from the last ones. A slot this delta filled was 0 in the
+        last words (padding never wins), so it shows up there if it won
+        and stays false if not. Both masks are rebuilt over every slot
+        where the state holds nothing of this shape to diff against."""
+        last_words, last_live, last_tomb = self._last or (None,) * 3
+        if (last_words is None or last_words.shape != words.shape
+                or len(last_live) != n_prev):
+            winner = np.unpackbits(
+                words.view(np.uint8).reshape(self.n_shards, -1),
+                axis=1, bitorder="little")[:, :self.m].astype(bool)
+            live_slots = winner & self.add
+            tomb_slots = winner & ~self.add
+            valid = self.scatter >= 0
+            live = np.zeros(self.n, bool)
+            tomb = np.zeros(self.n, bool)
+            live[self.scatter[valid]] = live_slots[valid]
+            tomb[self.scatter[valid]] = tomb_slots[valid]
+            ph.set_attr("mode", "full")
+            _MASK_REBUILDS.inc()
+            return live, tomb
+
+        # fresh arrays every time: a reader that holds the snapshot
+        # before this one keeps the masks it was handed
+        behind = np.zeros(self.n - n_prev, bool)
+        live = np.concatenate([last_live, behind])
+        tomb = np.concatenate([last_tomb, behind])
+        changed = (words ^ last_words).ravel()
+        at = np.flatnonzero(changed)
+        bits = np.unpackbits(changed[at].view(np.uint8).reshape(-1, 4),
+                             axis=1, bitorder="little")
+        k, bit = np.nonzero(bits)       # the k-th changed word, and where
+        won = (words.ravel()[at[k]] >> bit.astype(np.uint32)) & 1 != 0
+        shard, word = np.divmod(at[k], words.shape[1])
+        slot = word * 32 + bit
+        row = self.scatter[shard, slot]
+        is_add = self.add[shard, slot]
+        live[row] = won & is_add
+        tomb[row] = won & ~is_add
+        ph.set_attrs(mode="diff", changed_words=len(at),
+                     changed_slots=len(row))
+        _MASK_DIFFS.inc()
+        return live, tomb
 
     def device_hint(self):
         """First device of the owning mesh, or None once released — the
