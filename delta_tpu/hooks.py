@@ -26,15 +26,21 @@ def _snapshot_for_hook(table, version: int):
     commit's own bytes were just handed to the snapshot cache
     (`Table.notify_commit`), so `update()` normally serves this from the
     incrementally-advanced state with zero log reads; `snapshot_at` is
-    the fallback when another writer got past `version` already."""
-    try:
-        snap = table.update()
-        if snap.version == version:
-            return snap
-    except Exception as e:
-        _log.debug("update() fast path failed for hook snapshot at "
-                   "version %d (%s); rebuilding via snapshot_at", version, e)
-    return table.snapshot_at(version)
+    the fallback when another writer got past `version` already. The
+    span `hook.snapshot` says which of the two served it, so a hook's
+    read of the state is told apart from what it then does with it."""
+    with obs.span("hook.snapshot", version=version) as sp:
+        try:
+            snap = table.update()
+            if snap.version == version:
+                sp.set_attr("served", "update")
+                return snap
+        except Exception as e:
+            _log.debug("update() fast path failed for hook snapshot at "
+                       "version %d (%s); rebuilding via snapshot_at",
+                       version, e)
+        sp.set_attr("served", "snapshot_at")
+        return table.snapshot_at(version)
 
 
 def register_post_commit_hook(hook: Hook) -> None:

@@ -322,43 +322,44 @@ def _single_action_table(
 ) -> pa.Table:
     """Assemble a SingleAction table: each input occupies its own row
     range; all other columns null there."""
-    blocks = [
-        ("protocol", PROTOCOL_STRUCT, protocol_rows),
-        ("metaData", METADATA_STRUCT, metadata_rows),
-        ("txn", TXN_STRUCT, txn_rows),
-        ("domainMetadata", DOMAIN_STRUCT, domain_rows),
-        ("add", ADD_STRUCT, add_rows),
-        ("remove", REMOVE_STRUCT, remove_rows),
-    ]
-    sizes = [len(b[2]) if b[2] is not None else 0 for b in blocks]
-    total = sum(sizes)
-    assert total == n, (total, n)
-    # chunked columns, not concat_arrays: the null spans and the payload
-    # arrays become chunks as-is, so a million-file checkpoint table is
-    # assembled without copying a single struct row
-    cols = {}
-    offset = 0
-    offsets = []
-    for (name, typ, arr), sz in zip(blocks, sizes):
-        offsets.append(offset)
-        offset += sz
-    for i, (name, typ, arr) in enumerate(blocks):
-        sz = sizes[i]
-        # honor the payload's actual type when present — the add struct
-        # may carry an extra stats_parsed field beyond the static schema
-        if arr is not None and sz:
-            typ = arr.type
-        before, after = offsets[i], n - offsets[i] - sz
-        chunks = []
-        if before:
-            chunks.append(pa.nulls(before, typ))
-        if arr is not None and sz:
-            chunks.append(arr)
-        if after:
-            chunks.append(pa.nulls(after, typ))
-        cols[name] = (pa.chunked_array(chunks, type=typ) if chunks
-                      else pa.chunked_array([], type=typ))
-    return pa.table(cols)
+    with obs.span("checkpoint.table", rows=n):
+        blocks = [
+            ("protocol", PROTOCOL_STRUCT, protocol_rows),
+            ("metaData", METADATA_STRUCT, metadata_rows),
+            ("txn", TXN_STRUCT, txn_rows),
+            ("domainMetadata", DOMAIN_STRUCT, domain_rows),
+            ("add", ADD_STRUCT, add_rows),
+            ("remove", REMOVE_STRUCT, remove_rows),
+        ]
+        sizes = [len(b[2]) if b[2] is not None else 0 for b in blocks]
+        total = sum(sizes)
+        assert total == n, (total, n)
+        # chunked columns, not concat_arrays: the null spans and the payload
+        # arrays become chunks as-is, so a million-file checkpoint table is
+        # assembled without copying a single struct row
+        cols = {}
+        offset = 0
+        offsets = []
+        for (name, typ, arr), sz in zip(blocks, sizes):
+            offsets.append(offset)
+            offset += sz
+        for i, (name, typ, arr) in enumerate(blocks):
+            sz = sizes[i]
+            # honor the payload's actual type when present — the add struct
+            # may carry an extra stats_parsed field beyond the static schema
+            if arr is not None and sz:
+                typ = arr.type
+            before, after = offsets[i], n - offsets[i] - sz
+            chunks = []
+            if before:
+                chunks.append(pa.nulls(before, typ))
+            if arr is not None and sz:
+                chunks.append(arr)
+            if after:
+                chunks.append(pa.nulls(after, typ))
+            cols[name] = (pa.chunked_array(chunks, type=typ) if chunks
+                          else pa.chunked_array([], type=typ))
+        return pa.table(cols)
 
 
 def _small_action_arrays(state, txn_min_last_updated: Optional[int] = None) -> tuple:
@@ -490,7 +491,7 @@ def _checkpoint_aggregates(engine, state, adds: pa.Table, plan) -> None:
         part_of = np.zeros(n, np.int32)
         for i, (a0, a1, _r0, _r1) in enumerate(plan):
             part_of[a0:a1] = i
-        mode = "host"
+        mode, device_error = "host", None
         if ckstats.device_stats_enabled(engine):
             resident = getattr(state, "resident", None)
             hint = resident.device_hint() if resident is not None else None
@@ -502,7 +503,8 @@ def _checkpoint_aggregates(engine, state, adds: pa.Table, plan) -> None:
             # block is telemetry riding the checkpoint write — a device
             # dispatch failure must degrade to the bit-identical host
             # twin, never abort the checkpoint)
-            except Exception:
+            except Exception as e:
+                device_error = type(e).__name__
                 block = ckstats.host_stats_block(
                     lanes, valids, part_of, n_parts, n_codes)
         else:
@@ -514,7 +516,17 @@ def _checkpoint_aggregates(engine, state, adds: pa.Table, plan) -> None:
             logical_bytes=int(block[2 * n_l].sum()),
             dv_cardinality=int(block[2 * n_l + 2].sum()),
             distinct_partition_values=int(block[4 * n_l].max(initial=0)),
+            # the whole block over the parts, a value a lane in the
+            # order of `lanes`: what a reader of the span can hold
+            # against the table (an empty lane reads the identities)
+            lanes="size,modification_time,dv_cardinality,partition_code",
+            lane_min=block[0:n_l].min(axis=1).tolist(),
+            lane_max=block[n_l:2 * n_l].max(axis=1).tolist(),
+            lane_sum=block[2 * n_l:3 * n_l].sum(axis=1).tolist(),
+            lane_nulls=block[3 * n_l:4 * n_l].sum(axis=1).tolist(),
         )
+        if device_error is not None:
+            sp.set_attr("device_error", device_error)
 
 
 def write_checkpoint(engine, snapshot, policy: Optional[str] = None,
@@ -527,45 +539,57 @@ def write_checkpoint(engine, snapshot, policy: Optional[str] = None,
     parts/sidecars are reused instead of re-serialized."""
     with obs.span("checkpoint.write", log_path=snapshot._table.log_path,
                   version=snapshot.version) as sp:
-        info = _write_checkpoint(engine, snapshot, policy, prev_info)
+        info, route, parts = _write_checkpoint(engine, snapshot, policy,
+                                               prev_info)
+        # `parts`: the files of file actions: the one classic file,
+        # multipart's chunks (its part 1, the small actions, beside
+        # them), V2's sidecars
         sp.set_attrs(actions=info.size, num_add_files=info.numOfAddFiles,
-                     size_bytes=info.sizeInBytes)
+                     size_bytes=info.sizeInBytes, route=route, parts=parts)
         return info
 
 
 def _write_checkpoint(engine, snapshot, policy: Optional[str],
                       prev_info: Optional[LastCheckpointInfo] = None,
-                      ) -> LastCheckpointInfo:
-    state = snapshot.state
-    meta_conf = state.metadata.configuration
-    if policy is None:
-        policy = get_table_config(meta_conf, CHECKPOINT_POLICY)
-    now_ms = int(time.time() * 1000)
-    retention = get_table_config(meta_conf, TOMBSTONE_RETENTION)
-    from delta_tpu.config import (
-        CHECKPOINT_WRITE_STATS_AS_JSON,
-        CHECKPOINT_WRITE_STATS_AS_STRUCT,
-        SET_TXN_RETENTION,
-    )
+                      ) -> tuple:
+    """-> (the hint written, the route taken, its files of file
+    actions)."""
+    with obs.span("checkpoint.assemble") as sp:
+        state = snapshot.state
+        meta_conf = state.metadata.configuration
+        if policy is None:
+            policy = get_table_config(meta_conf, CHECKPOINT_POLICY)
+        now_ms = int(time.time() * 1000)
+        retention = get_table_config(meta_conf, TOMBSTONE_RETENTION)
+        from delta_tpu.config import (
+            CHECKPOINT_WRITE_STATS_AS_JSON,
+            CHECKPOINT_WRITE_STATS_AS_STRUCT,
+            SET_TXN_RETENTION,
+        )
 
-    stats_as_json = get_table_config(meta_conf, CHECKPOINT_WRITE_STATS_AS_JSON)
-    stats_as_struct = get_table_config(meta_conf, CHECKPOINT_WRITE_STATS_AS_STRUCT)
-    txn_retention = get_table_config(meta_conf, SET_TXN_RETENTION)
-    txn_min = (now_ms - txn_retention) if txn_retention is not None else None
+        stats_as_json = get_table_config(
+            meta_conf, CHECKPOINT_WRITE_STATS_AS_JSON)
+        stats_as_struct = get_table_config(
+            meta_conf, CHECKPOINT_WRITE_STATS_AS_STRUCT)
+        txn_retention = get_table_config(meta_conf, SET_TXN_RETENTION)
+        txn_min = ((now_ms - txn_retention) if txn_retention is not None
+                   else None)
 
-    adds = state.add_files_table
-    tombs = _retained_tombstones(state, now_ms, retention)
-    stats_schema = (_stats_parsed_schema(
-        state.metadata.schema, meta_conf,
-        list(state.metadata.partitionColumns or []))
-        if stats_as_struct else None)
-    add_struct = _file_struct_from_canonical(
-        adds, is_add=True,
-        stats_as_json=stats_as_json, stats_as_struct=stats_as_struct,
-        stats_schema=stats_schema)
-    remove_struct = _file_struct_from_canonical(tombs, is_add=False)
-    protocol_rows, metadata_rows, txn_rows, domain_rows = _small_action_arrays(
-        state, txn_min_last_updated=txn_min)
+        adds = state.add_files_table
+        tombs = _retained_tombstones(state, now_ms, retention)
+        stats_schema = (_stats_parsed_schema(
+            state.metadata.schema, meta_conf,
+            list(state.metadata.partitionColumns or []))
+            if stats_as_struct else None)
+        add_struct = _file_struct_from_canonical(
+            adds, is_add=True,
+            stats_as_json=stats_as_json, stats_as_struct=stats_as_struct,
+            stats_schema=stats_schema)
+        remove_struct = _file_struct_from_canonical(tombs, is_add=False)
+        protocol_rows, metadata_rows, txn_rows, domain_rows = (
+            _small_action_arrays(state, txn_min_last_updated=txn_min))
+        sp.set_attrs(adds=len(add_struct), removes=len(remove_struct),
+                     stats_as_struct=bool(stats_as_struct))
 
     if settings.verify_checkpoint_row_count and len(add_struct) != state.num_files:
         raise ChecksumMismatchError(
@@ -634,7 +658,6 @@ def _write_checkpoint(engine, snapshot, policy: Optional[str],
             info = LastCheckpointInfo(
                 version=version,
                 size=n,
-                sizeInBytes=_file_size(engine, path),
                 numOfAddFiles=len(add_struct),
             )
     except ckpt_pipeline.CheckpointWriteError as e:
@@ -644,8 +667,11 @@ def _write_checkpoint(engine, snapshot, policy: Optional[str],
         _ABORTED_WRITES.inc()
         _cleanup_orphans(engine, e.touched_paths)
         raise
-    write_last_checkpoint(engine.json, log_path, info)
-    return info
+    with obs.span("checkpoint.hint", version=version):
+        if route == "classic":
+            info.sizeInBytes = _file_size(engine, path)
+        write_last_checkpoint(engine.json, log_path, info)
+    return info, route, len(plan)
 
 
 def _file_size(engine, path: str) -> Optional[int]:

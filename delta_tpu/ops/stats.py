@@ -17,9 +17,14 @@ aggregation ran (asserted by the write->read parity matrix in
 tests/test_checkpoint_write.py).
 
 H2D lanes are pinned by `resources/transfer_budget.json`
-(`ckpt-stats-block`, `ckpt-dv-pack`): lane matrix int64, validity as a
-packed bitplane, part ids int32; DV packing ships one int64 flat bit
-index per set bit.
+(`ckpt-stats-block`, `ckpt-dv-pack`): lane matrix int64 `[L, n_pad]`,
+validity as a packed bitplane in uint32 words `[L, n_pad / 32]` (bit k
+of word j is row 32 j + k), part ids int32 `[n_pad]`, and three scalars
+(the parts, the code multiplier, the bits of the largest pair key); DV packing
+ships one int64 flat bit index per set bit. The dispatch record of
+`stats.ckpt_block` carries its shape as `attrs` (`lanes`, `n_pad`,
+`p_pad`, which is what a roofline reader prices it from, and `win`,
+the rows a part's pass reads).
 
 Env:
   DELTA_TPU_DEVICE_CKPT_STATS=1|0  force the aggregation stage on/off
@@ -90,51 +95,111 @@ def accel_backend_default() -> bool:
 
 
 @functools.lru_cache(maxsize=16)
-def _agg_fn_cached(n_lanes: int, n_pad: int, p_pad: int):
+def _agg_fn_cached(n_lanes: int, n_pad: int, p_pad: int, win: int,
+                   one_code: bool):
     """jit'd segmented min/max/sum/null-count over an int64 lane matrix
     plus a distinct-count of the (part, code) pairs in the LAST lane.
-    Padded rows carry part id `p_pad` and are dropped by the segment
-    ops. One dense output block -> one D2H transfer."""
+    Padded rows carry part id `p_pad`, which no pass asks for. One
+    dense output block -> one D2H transfer.
+
+    `win` is the rows a part's pass reads. The writer's parts are runs
+    of rows in order (`checkpointer._chunk_plan`), so a part's rows lie
+    in one window of the largest part's bucket from its first row, and
+    a block costs about its rows whatever the parts; part ids in no
+    order take `win == n_pad`, every pass over every row. `one_code`
+    says every valid code is 0 (an unpartitioned table): a part then
+    has one pair where it has a row, and nothing is sorted.
+
+    Three forms are what the v5e compiler and the chip are quick over at
+    a 2.6M-row bucket (PERF.md has the seconds): the validity words are
+    uint32 and unpacked by the tree's own shift-and-mask (a
+    `jnp.unpackbits` shifts 8-bit lanes); the pairs are put in order by
+    `sqlops._radix_perm`, passes of one unstable uint32 sort each (a
+    one-key int64 `jnp.sort` alone compiles for 83 s); and a part's
+    reductions are one masked pass over the lanes, a part after another
+    (sixteen `segment_min/max/sum` scatters of 2.6M rows into 8 segments
+    run for 0.9 s vmapped, with 2.5 GB of temporaries, and for 2.5 s a
+    lane at a time: a scatter's cost is its rows', whatever the
+    segments)."""
     import jax
     import jax.numpy as jnp
 
+    from delta_tpu.ops.replay import _unpack_bits_device
+    from delta_tpu.ops.sqlops import _digit_bits, _radix_perm
+
+    width = _digit_bits(n_pad)
+    part_range = np.arange(p_pad + 1, dtype=np.int32)
+
+    def pairs_by_part(vals, valid, parts, code_mult, key_bits):
+        # distinct (part, partition-code) pairs via one ordered pass
+        # over the last lane: order the combined key and mark the fresh
+        # values; the order is the parts' too, so a part's pairs are the
+        # fresh ones between its bounds. Padded and invalid rows share
+        # the one key past every real pair and the part `p_pad`, so
+        # they count for nothing.
+        okrow = valid[-1] & (parts < p_pad)
+        pair_part = jnp.where(okrow, parts, jnp.int32(p_pad))
+        key = (pair_part.astype(jnp.int64) * code_mult
+               + jnp.where(okrow, vals[-1], jnp.int64(0))).astype(jnp.uint64)
+        steps = jnp.arange(-(-64 // width), dtype=jnp.int32)
+        perm = _radix_perm(key[None, :], jnp.zeros_like(steps),
+                           steps * width, (key_bits + width - 1) // width)
+        skey = key[perm]
+        fresh = jnp.concatenate([jnp.ones((1,), bool),
+                                 skey[1:] != skey[:-1]])
+        before = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(fresh.astype(jnp.int32))])
+        bounds = jnp.searchsorted(pair_part[perm], part_range)
+        return (before[bounds[1:]] - before[bounds[:-1]]).astype(jnp.int64)
+
     @obs.program("stats.ckpt_block")
-    def kernel(vals, valid_words, parts, code_mult):
-        valid = jnp.unpackbits(valid_words, axis=1, count=n_pad,
-                               bitorder="little").astype(bool)
-        seg = parts
-        vmin = jnp.where(valid, vals, jnp.int64(IDENT_MIN))
-        vmax = jnp.where(valid, vals, jnp.int64(IDENT_MAX))
-        vsum = jnp.where(valid, vals, jnp.int64(0))
-        nulls = (~valid).astype(jnp.int64)
-        mins = jax.vmap(
-            lambda v: jax.ops.segment_min(v, seg, num_segments=p_pad))(vmin)
-        maxs = jax.vmap(
-            lambda v: jax.ops.segment_max(v, seg, num_segments=p_pad))(vmax)
-        sums = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, seg, num_segments=p_pad))(vsum)
-        nullc = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, seg, num_segments=p_pad))(nulls)
-        # distinct (part, partition-code) pairs via one sorted pass over
-        # the last lane: sort the combined key, count fresh values per
-        # part segment (sentinel = padded/invalid rows, sorts last)
-        codes = vals[-1]
-        okrow = valid[-1] & (seg < p_pad)
-        sentinel = jnp.int64(IDENT_MIN)
-        key = jnp.where(okrow, seg.astype(jnp.int64) * code_mult + codes,
-                        sentinel)
-        skey = jnp.sort(key)
-        fresh = jnp.concatenate(
-            [skey[:1] != sentinel,
-             (skey[1:] != skey[:-1]) & (skey[1:] != sentinel)])
-        part_of = jnp.where(skey == sentinel, jnp.int64(p_pad),
-                            skey // code_mult).astype(jnp.int32)
-        distinct = jax.ops.segment_sum(fresh.astype(jnp.int64), part_of,
-                                       num_segments=p_pad)
-        return jnp.concatenate(
-            [mins, maxs, sums, nullc, distinct[None, :]], axis=0)
+    def kernel(vals, valid_words, parts, n_parts, code_mult, key_bits):
+        valid = (_unpack_bits_device(valid_words.reshape(-1))
+                 != 0).reshape(n_lanes, n_pad)
+        pairs = (None if one_code else
+                 pairs_by_part(vals, valid, parts, code_mult, key_bits))
+        # where the parts are in order, the first row of each
+        first = (jnp.searchsorted(parts, part_range[:-1]).astype(jnp.int32)
+                 if win < n_pad else None)
+
+        def with_part(p, block):
+            v, ok, ids = vals, valid, parts
+            if first is not None:
+                at = jnp.minimum(first[p], n_pad - win)
+                v = jax.lax.dynamic_slice_in_dim(v, at, win, axis=1)
+                ok = jax.lax.dynamic_slice_in_dim(ok, at, win, axis=1)
+                ids = jax.lax.dynamic_slice_in_dim(ids, at, win)
+            mine = (ids == p)[None, :]
+            nulls = jnp.sum((mine & ~ok).astype(jnp.int64), axis=1)
+            ok = mine & ok
+            column = jnp.concatenate([
+                jnp.min(jnp.where(ok, v, jnp.int64(IDENT_MIN)), axis=1),
+                jnp.max(jnp.where(ok, v, jnp.int64(IDENT_MAX)), axis=1),
+                jnp.sum(jnp.where(ok, v, jnp.int64(0)), axis=1),
+                nulls,
+                (jnp.any(ok[-1]).astype(jnp.int64)[None] if one_code
+                 else pairs[p][None])])
+            return jax.lax.dynamic_update_slice_in_dim(
+                block, column[:, None], p, axis=1)
+
+        # a pass a real part: the padding's columns are cut off unread
+        return jax.lax.fori_loop(
+            0, n_parts, with_part,
+            jnp.zeros((4 * n_lanes + 1, p_pad), jnp.int64))
 
     return jax.jit(kernel)
+
+
+def _pass_rows(part_ids: np.ndarray, n: int, n_parts: int, n_pad: int) -> int:
+    """The rows a part's pass has to read (`win` above): the bucket of
+    the largest part where the rows' part ids never step down, else
+    every row."""
+    ids = part_ids[:n]
+    if n_parts <= 1 or n == 0 or (ids[1:] < ids[:-1]).any():
+        return n_pad
+    from delta_tpu.ops.replay import pad_bucket
+
+    return min(pad_bucket(int(np.bincount(ids).max())), n_pad)
 
 
 def checkpoint_stats_block(
@@ -148,11 +213,13 @@ def checkpoint_stats_block(
     """Per-part aggregates of `lanes` on device, one dispatch, one dense
     D2H block of shape [4*L + 1, n_parts]: rows 0..L-1 min, L..2L-1 max,
     2L..3L-1 sum, 3L..4L-1 null count, last row = distinct partition
-    codes (the last lane holds the partition-value dictionary codes).
+    codes (the last lane holds the partition-value dictionary codes,
+    each under `n_codes`).
 
     `device` colocates the lane upload with e.g. the resident replay
-    state's device. All lanes int64, validity a packed bitplane, part
-    ids int32 — the transfer plane committed in transfer_budget.json.
+    state's device. All lanes int64, validity a bitplane packed into
+    uint32 words, part ids int32 — the transfer plane committed in
+    transfer_budget.json.
     """
     import jax
 
@@ -167,25 +234,36 @@ def checkpoint_stats_block(
     for i, (lane, valid) in enumerate(zip(lanes, valids)):
         lane_vals[i, :n] = np.asarray(lane, np.int64)
         vb[i, :n] = np.asarray(valid, bool)
-    valid_words = np.packbits(vb, axis=1, bitorder="little")
+    # bit k of word j is row 32 j + k (n_pad is a multiple of 1024)
+    valid_words = np.packbits(vb, axis=1, bitorder="little").view("<u4")
     part_ids = np.full(n_pad, p_pad, np.int32)
     part_ids[:n] = np.asarray(part_of_row, np.int32)
     # a code multiplier > any code keeps (part, code) pairs distinct
     code_mult = np.int64(max(int(n_codes), 1) + 1)
-    fn = _agg_fn_cached(n_l, n_pad, p_pad)
+    # the pairs' keys run to p_pad * code_mult, the padding's included
+    key_bits = np.int32((int(p_pad) * int(code_mult)).bit_length())
+    win = _pass_rows(part_ids, n, n_parts, n_pad)
+    one_code = int(n_codes) <= 1
+    fn = _agg_fn_cached(n_l, n_pad, p_pad, win, one_code)
     # lane matrices are [n_l, n_pad]: each lane prices at its own unit
     # count (the manifest unit is one padded file row per stat lane)
-    with obs.device_dispatch("stats.ckpt_block", key=(n_l, n_pad, p_pad),
+    with obs.device_dispatch("stats.ckpt_block",
+                             key=(n_l, n_pad, p_pad, win, one_code),
                              budget="ckpt-stats-block",
                              units=n_pad) as dd, _x64():
         dd.h2d("lane_vals", lane_vals, units=n_l * n_pad)
         dd.h2d("valid_words", valid_words, units=n_l * n_pad)
         dd.h2d("part_ids", part_ids)
+        dd.set(lanes=n_l, n_pad=n_pad, p_pad=p_pad, win=win)
         block = fn(jax.device_put(lane_vals, device),
                    jax.device_put(valid_words, device),
                    jax.device_put(part_ids, device),
-                   code_mult)
-        return dd.d2h("block", np.asarray(block))[:, :n_parts]
+                   np.int32(n_parts), code_mult, key_bits)
+        # the launch is asynchronous: this read is the wait for the chip
+        with obs.span("stats.wait", kernel="stats.ckpt_block",
+                      rows=n, parts=int(n_parts)):
+            block = np.asarray(block)
+        return dd.d2h("block", block)[:, :n_parts]
 
 
 def host_stats_block(
