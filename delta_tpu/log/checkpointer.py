@@ -54,6 +54,7 @@ from delta_tpu.config import (
 )
 from delta_tpu import obs
 from delta_tpu.errors import ChecksumMismatchError, InvalidArgumentError
+from delta_tpu.log import parquet_stitch
 from delta_tpu.log.last_checkpoint import LastCheckpointInfo, write_last_checkpoint
 from delta_tpu.models.actions import Sidecar
 from delta_tpu.replay.columnar import DV_STRUCT_TYPE
@@ -759,11 +760,12 @@ def _prev_part_index(prev_info: Optional[LastCheckpointInfo],
 
 
 def _encode_parquet(table: pa.Table) -> bytes:
-    import pyarrow.parquet as pq
-
-    sink = pa.BufferOutputStream()
-    pq.write_table(table, sink, compression="snappy")
-    return sink.getvalue().to_pybytes()
+    """`table` as `pq.write_table(table, sink, compression="snappy")`
+    writes it, byte for byte: a large table's pieces are encoded on the
+    scan pool and stitched under one footer (`log/parquet_stitch.py`),
+    a small one in that one call. Part fingerprints and incremental
+    reuse rest on the bytes being a function of the rows alone."""
+    return parquet_stitch.encode(table)
 
 
 def _file_part_build(engine, log_path: str, prev_entry: Optional[dict],

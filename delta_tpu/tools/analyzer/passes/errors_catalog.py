@@ -48,7 +48,7 @@ _ALLOWED_NON_DELTA = {
     "FileAlreadyExistsError", "PreconditionFailedError",
     "TableAlreadyExistsError", "TableNotInCatalogError",
     "ParseError", "CommitFailedException",
-    "DecodeUnsupported", "DynamoDbError",
+    "DecodeUnsupported", "DynamoDbError", "StandDown",
     # storage-protocol IOError subclasses: StorageRequestError carries
     # the HTTP status the resilience classifier keys on; ChaosError is
     # the chaos harness's injected (always-transient) fault, and the

@@ -145,6 +145,39 @@ def test_digest_parity_matrix(tmp_path, monkeypatch, policy, part_size,
     assert reloaded[0] == version and reloaded[1] == 5 * version
 
 
+@pytest.mark.parametrize("policy,part_size", [
+    ("classic", None),
+    ("multipart", 120),
+    ("v2", 120),
+])
+def test_digest_parity_on_a_dealt_encode(tmp_path, monkeypatch, policy,
+                                         part_size):
+    """The same reload, of files whose encode was dealt over the scan
+    pool and stitched (`log/parquet_stitch.py`): several row groups a
+    file, `add` cut into its leaves, every part over the line."""
+    import pyarrow._parquet as _parquet
+
+    from delta_tpu.log import parquet_stitch
+
+    monkeypatch.setattr(_parquet, "_DEFAULT_ROW_GROUP_SIZE", 50)
+    monkeypatch.setattr(parquet_stitch, "_DEAL_MIN_ROWS", 40)
+    dealt = obs.counter("checkpoint.encodes_dealt")
+    log = _build_local_log(tmp_path, 60)
+    settings.checkpoint_part_size = part_size
+    eng = HostEngine()
+    snap = Table.for_path(str(tmp_path), eng).latest_snapshot()
+    before = dealt.value
+    write_checkpoint(eng, snap, policy="v2" if policy == "v2" else None)
+    # 300 adds: the one file, or three parts of 120, 120 and 60 rows
+    assert dealt.value - before == (1 if policy == "classic" else 3)
+
+    live = _digest(tmp_path, eng)
+    _drop_commits(log, 60)
+    reloaded = _digest(tmp_path, eng)
+    assert reloaded == live
+    assert reloaded[0] == 60 and reloaded[1] == 300
+
+
 def test_host_device_checkpoints_byte_identical(tmp_path, monkeypatch):
     """Stat mode is telemetry only: flipping it may not change a single
     checkpoint byte (host and device aggregates are bit-identical and

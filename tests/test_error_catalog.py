@@ -74,6 +74,10 @@ _ALLOWED_NON_DELTA = {
     # internal fall-back signal of the page decoder: always caught,
     # the Arrow reader takes over (log/page_decode.py)
     "DecodeUnsupported",
+    # its twin on the write side: the checkpoint encode's stitcher
+    # meeting a footer it does not carry; always caught, the one
+    # `pq.write_table` call takes over (log/parquet_stitch.py)
+    "StandDown",
     # storage-protocol error carrying the DynamoDB __type; the arbiter
     # maps the arbitration-relevant case (ConditionalCheckFailed) to
     # FileAlreadyExistsError like the other store clients
