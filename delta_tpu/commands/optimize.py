@@ -12,6 +12,7 @@ entirely on device (`ops/zorder.py`); bin packing is a host heuristic
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
@@ -29,6 +30,11 @@ from delta_tpu.write.writer import write_data_files
 
 DEFAULT_MIN_FILE_SIZE = 256 * 1024 * 1024   # files below this are compacted
 DEFAULT_MAX_FILE_SIZE = 256 * 1024 * 1024   # bin capacity
+
+_BINS = obs.counter("optimize.bins")
+_ROWS_CLUSTERED = obs.counter("optimize.rows_clustered")
+_FILES_REMOVED = obs.counter("optimize.files_removed")
+_FILES_ADDED = obs.counter("optimize.files_added")
 
 
 @dataclass
@@ -207,24 +213,43 @@ def _run_optimize_inner(
                     "delta.dataSkippingNumIndexedCols)",
                     error_class="DELTA_ZORDERING_ON_COLUMN_WITHOUT_STATS")
 
-    candidates = txn.scan_files(filter=filter)
-    if full:
-        zcube_tags = zcube_tags or (
-            new_zcube_tags(cluster_cols, curve) if cluster_cols else None)
-        # OPTIMIZE FULL ignores ZCube stability: everything re-clusters
-    elif zcube_tags is not None:
-        # skip files already in a stable cube over the same columns
-        cube_sizes: Dict[str, int] = {}
-        from delta_tpu.clustering import ZCUBE_ID_TAG
+    with obs.span("optimize.plan") as sp:
+        candidates = txn.scan_files(filter=filter)
+        if full:
+            zcube_tags = zcube_tags or (
+                new_zcube_tags(cluster_cols, curve) if cluster_cols else None)
+            # OPTIMIZE FULL ignores ZCube stability: everything re-clusters
+        elif zcube_tags is not None:
+            # skip files already in a stable cube over the same columns
+            cube_sizes: Dict[str, int] = {}
+            from delta_tpu.clustering import ZCUBE_ID_TAG
 
+            for f in candidates:
+                cid = (f.tags or {}).get(ZCUBE_ID_TAG)
+                if cid:
+                    cube_sizes[cid] = cube_sizes.get(cid, 0) + f.size
+            candidates = [
+                f for f in candidates
+                if not file_in_stable_zcube(f, zorder_by, cube_sizes)
+            ]
+
+        # group per partition (bins never span partitions)
+        by_partition: Dict[tuple, List[AddFile]] = {}
         for f in candidates:
-            cid = (f.tags or {}).get(ZCUBE_ID_TAG)
-            if cid:
-                cube_sizes[cid] = cube_sizes.get(cid, 0) + f.size
-        candidates = [
-            f for f in candidates
-            if not file_in_stable_zcube(f, zorder_by, cube_sizes)
-        ]
+            key = tuple(sorted((f.partitionValues or {}).items()))
+            by_partition.setdefault(key, []).append(f)
+
+        plan: List[List[List[AddFile]]] = []    # a partition's bins
+        for _pkey, files in sorted(by_partition.items()):
+            if zorder_by is None:
+                small = [f for f in files if f.size < min_file_size]
+                plan.append([b for b in bin_pack_by_size(small, max_file_size)
+                             if len(b) > 1])
+            else:
+                # multi-dim clustering rewrites every candidate file
+                plan.append([files] if files else [])
+        sp.set_attrs(candidates=len(candidates),
+                     bins=sum(len(bins) for bins in plan))
     metrics = OptimizeMetrics()
 
     # Explicit Z-order stamps ZCube tags on its output too, so scan
@@ -236,31 +261,15 @@ def _run_optimize_inner(
     explicit_tags = (new_zcube_tags(zorder_by, curve)
                      if zorder_by and zcube_tags is None else None)
 
-    # group per partition (bins never span partitions)
-    by_partition: Dict[tuple, List[AddFile]] = {}
-    for f in candidates:
-        key = tuple(sorted((f.partitionValues or {}).items()))
-        by_partition.setdefault(key, []).append(f)
-
     now_ms = int(time.time() * 1000)
     new_adds: List[AddFile] = []
     removed: List[AddFile] = []
-    for pkey, files in sorted(by_partition.items()):
-        if zorder_by is None:
-            small = [f for f in files if f.size < min_file_size]
-            bins = [
-                b for b in bin_pack_by_size(small, max_file_size) if len(b) > 1
-            ]
-        else:
-            # multi-dim clustering rewrites every candidate file
-            bins = [files] if files else []
+    for bins in plan:
         for bin_files in bins:
             adds = _rewrite_bin(
                 table, snapshot, bin_files, zorder_by, curve, max_file_size
             )
             if zcube_tags is not None:
-                import dataclasses
-
                 adds = [
                     dataclasses.replace(
                         a, tags={**(a.tags or {}), **zcube_tags},
@@ -269,8 +278,6 @@ def _run_optimize_inner(
                     for a in adds
                 ]
             elif explicit_tags is not None:
-                import dataclasses
-
                 adds = [
                     dataclasses.replace(
                         a, tags={**(a.tags or {}), **explicit_tags})
@@ -285,31 +292,38 @@ def _run_optimize_inner(
     if not removed:
         return metrics  # nothing to do; no commit
 
-    for f in removed:
-        txn.remove_file(f.remove(deletion_timestamp=now_ms, data_change=False))
-    txn.add_files(new_adds)
-    txn.set_operation_parameters(
-        {
-            "predicate": repr(filter) if filter is not None else "[]",
-            "zOrderBy": list(zorder_by) if zorder_by and zcube_tags is None else [],
-            "clusterBy": list(zorder_by) if zcube_tags is not None else [],
-            "auto": False,
-        }
-    )
-    metrics.num_files_added = len(new_adds)
-    metrics.num_files_removed = len(removed)
-    metrics.bytes_added = sum(a.size for a in new_adds)
-    metrics.bytes_removed = sum(r.size for r in removed)
-    txn.set_operation_metrics(
-        {
-            "numAddedFiles": metrics.num_files_added,
-            "numRemovedFiles": metrics.num_files_removed,
-            "numAddedBytes": metrics.bytes_added,
-            "numRemovedBytes": metrics.bytes_removed,
-        }
-    )
-    result = txn.commit()
+    with obs.span("optimize.commit", adds=len(new_adds),
+                  removes=len(removed)):
+        for f in removed:
+            txn.remove_file(
+                f.remove(deletion_timestamp=now_ms, data_change=False))
+        txn.add_files(new_adds)
+        txn.set_operation_parameters(
+            {
+                "predicate": repr(filter) if filter is not None else "[]",
+                "zOrderBy": (list(zorder_by)
+                             if zorder_by and zcube_tags is None else []),
+                "clusterBy": list(zorder_by) if zcube_tags is not None else [],
+                "auto": False,
+            }
+        )
+        metrics.num_files_added = len(new_adds)
+        metrics.num_files_removed = len(removed)
+        metrics.bytes_added = sum(a.size for a in new_adds)
+        metrics.bytes_removed = sum(r.size for r in removed)
+        txn.set_operation_metrics(
+            {
+                "numAddedFiles": metrics.num_files_added,
+                "numRemovedFiles": metrics.num_files_removed,
+                "numAddedBytes": metrics.bytes_added,
+                "numRemovedBytes": metrics.bytes_removed,
+            }
+        )
+        result = txn.commit()
     metrics.version = result.version
+    _BINS.inc(metrics.num_bins)
+    _FILES_REMOVED.inc(metrics.num_files_removed)
+    _FILES_ADDED.inc(metrics.num_files_added)
     return metrics
 
 
@@ -321,48 +335,73 @@ def _rewrite_bin(
     names mapped), optionally reorder along the curve, and write back as
     (approximately) bin-size files. Rewritten files drop their DVs —
     OPTIMIZE purges soft-deleted rows like the reference's
-    `OptimizeExecutor`."""
+    `OptimizeExecutor`.
+
+    Under clustering the bin's files are read in ascending order of
+    `path`, each file's rows in file order: the curve's ranks break ties
+    by position, so the output's row order is a function of the table's
+    state and not of the order in which the replay happens to hand the
+    files over. A bin of plain compaction is read in the packing's order
+    (by size, equal sizes as handed over): its rows are only laid end
+    to end."""
     from delta_tpu.read.reader import read_add_file_logical
 
     engine = table.engine
     meta = snapshot.metadata
     schema = meta.schema
-    data = pa.concat_tables(
-        [read_add_file_logical(engine, table.path, snapshot, f)
-         for f in bin_files],
-        promote_options="permissive",
-    )
-
     if zorder_by:
+        bin_files = sorted(bin_files, key=lambda f: f.path)
+    with obs.span("optimize.read", files=len(bin_files),
+                  bytes=sum(f.size for f in bin_files)) as sp:
+        data = pa.concat_tables(
+            [read_add_file_logical(engine, table.path, snapshot, f)
+             for f in bin_files],
+            promote_options="permissive",
+        )
+        sp.set_attr("rows", data.num_rows)
+
+    if zorder_by and data.num_rows:
         import pyarrow.compute as pc
 
-        cols = []
-        for c in zorder_by:
-            arr = data.column(c).combine_chunks()
-            if arr.null_count:
-                fill = "" if pa.types.is_string(arr.type) else 0
-                arr = pc.fill_null(arr, fill)
-            a = np.asarray(arr)
-            if a.dtype == object:
-                a = a.astype(str)
-            cols.append(a)
-        from delta_tpu.ops.zorder import zorder_sort_indices
+        from delta_tpu.ops.zorder import curve_keys, curve_perm
 
-        perm = zorder_sort_indices(cols, curve=curve)
-        data = data.take(pa.array(perm, pa.int64()))
+        with obs.span("optimize.keys", columns=len(zorder_by),
+                      rows=data.num_rows) as sp:
+            cols = []
+            for c in zorder_by:
+                arr = data.column(c).combine_chunks()
+                if arr.null_count:
+                    fill = "" if pa.types.is_string(arr.type) else 0
+                    arr = pc.fill_null(arr, fill)
+                a = np.asarray(arr)
+                if a.dtype == object:
+                    a = a.astype(str)
+                cols.append(a)
+            stacked = curve_keys(cols)
+            sp.set_attr("n_pad", stacked.shape[1])
+        # the dispatch and its blocking read
+        with obs.span("optimize.curve", curve=curve, n_pad=stacked.shape[1]):
+            perm = curve_perm(stacked, data.num_rows, curve)
+        with obs.span("optimize.gather", rows=data.num_rows,
+                      columns=data.num_columns, bytes=data.nbytes):
+            data = data.take(pa.array(perm, pa.int64()))
+        _ROWS_CLUSTERED.inc(data.num_rows)
 
     total_bytes = sum(f.size for f in bin_files)
     n_out = max(1, -(-total_bytes // max_file_size))
     rows_per_file = max(1, -(-data.num_rows // n_out))
 
     part_cols = meta.partitionColumns
-    return write_data_files(
-        engine=engine,
-        table_path=table.path,
-        data=data,
-        schema=schema,
-        partition_columns=part_cols,
-        configuration=meta.configuration,
-        data_change=False,
-        target_rows_per_file=rows_per_file if n_out > 1 else None,
-    )
+    with obs.span("optimize.write", rows=data.num_rows) as sp:
+        adds = write_data_files(
+            engine=engine,
+            table_path=table.path,
+            data=data,
+            schema=schema,
+            partition_columns=part_cols,
+            configuration=meta.configuration,
+            data_change=False,
+            target_rows_per_file=rows_per_file if n_out > 1 else None,
+        )
+        sp.set_attrs(files=len(adds), bytes=sum(a.size for a in adds))
+    return adds

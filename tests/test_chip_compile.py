@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from delta_tpu.ops import json_parse, page_decode, pallas_kernels, skipping
-from delta_tpu.ops import replay, scans, sqlops
+from delta_tpu.ops import replay, scans, sqlops, zorder
 from delta_tpu.ops import stats as ckstats
 from delta_tpu.stats import device_index
 
@@ -51,7 +51,7 @@ def topo():
     skipping._skip_fn_cached.cache_clear()
     device_index._halves_fn.cache_clear()
     ckstats._agg_fn_cached.cache_clear()
-    jax.clear_caches()
+    jax.clear_caches()      # `zorder._curve_perm`'s trace among them
 
 
 @pytest.fixture
@@ -87,6 +87,34 @@ def test_interleave_bits_tiled_4m_rows(on_chip):
         compiled = (pallas_kernels.interleave_bits_tiled
                     .lower(cols, n_bits=32).compile())
     _assert_mosaic(compiled)
+
+
+def test_zorder_curve_perm_one_sold_date(on_chip):
+    """`optimize-zorder-under-ingest`'s launch: three key lanes of one
+    sold date of the 3 TB `store_sales`, 4,739,406 rows in a bucket of
+    5,242,880. Every sort of the program is one two-operand sort in one
+    loop, so the compiler builds ONE; as three stable `argsort`s with
+    their scatters and one stable four-operand sort it held seven
+    multi-operand sorts and took this compiler 155 s here (76 s on the
+    chip's host); on single-operand radix passes 8.5 s, but 3.75 s a
+    launch on the chip for 0.15 s (PERF.md, PR 55)."""
+    import time
+
+    began = time.perf_counter()
+    with jax.enable_x64(False):     # as `interleave_bits_auto` pins it
+        compiled = zorder._curve_perm.lower(
+            on_chip((3, 5_242_880), jnp.uint32), curve="zorder").compile()
+    took = time.perf_counter() - began
+    print(f"zorder.curve_perm (3, 5242880): compiled in {took:.1f} s, "
+          f"temporaries "
+          f"{compiled.memory_analysis().temp_size_in_bytes / 1e6:.0f} MB")
+    assert took < 90
+    _assert_mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line]
+    # one sort, of a key lane and the places: no scatter became another
+    assert len(sorts) == 1 and sorts[0].count("[5242880]") == 2, sorts
 
 
 def test_byte_class_tiled_64mib(on_chip):
