@@ -29,6 +29,7 @@ from delta_tpu.engine.spi import (
     MetricsReporter,
     ParquetHandler,
 )
+from delta_tpu.log import parquet_stitch
 from delta_tpu.resilience import (
     current_deadline,
     deadline_scope_at,
@@ -50,6 +51,8 @@ _LIST_CALLS = obs.counter("storage.list.calls")
 _WRITE_CALLS = obs.counter("storage.write.calls")
 _WRITE_BYTES = obs.counter("storage.write.bytes")
 _PARQUET_PREFETCHED = obs.counter("storage.parquet.prefetched_files")
+_ENCODES_DEALT = obs.counter("write.encodes_dealt")
+_ENCODES_SERIAL = obs.counter("write.encodes_serial")
 
 _SMALL_GROUPS_SKIPPED = obs.counter("checkpoint.small_row_groups_skipped")
 _PARTS_DEALT = obs.counter("checkpoint.parts_decoded_dealt")
@@ -594,9 +597,17 @@ class HostParquetHandler(ParquetHandler):
                 fut.cancel()
 
     def write_parquet_file(self, path: str, table: pa.Table) -> FileStatus:
-        sink = pa.BufferOutputStream()
-        pq.write_table(table, sink, compression="snappy")
-        buf = sink.getvalue().to_pybytes()
+        # The stitcher deals a large table's encode over `scan_pool()`
+        # and waits for it, so a file must not be written from a task of
+        # that pool: none is (its tasks are leaf reads). The span is
+        # verbose under the stitcher's line, as `storage.parquet_write`
+        # is: a sink's thousands of small files are one call each.
+        with obs.span("write.encode", _verbose=parquet_stitch.small(table),
+                      rows=table.num_rows,
+                      columns=table.num_columns) as sp:
+            buf, how = parquet_stitch.encode(table)
+            sp.set_attrs(bytes=len(buf), **how)
+        (_ENCODES_DEALT if how["dealt"] else _ENCODES_SERIAL).inc()
         store = self._store_for(path)
         with obs.span("storage.parquet_write", _verbose=True, path=path,
                       bytes=len(buf)):

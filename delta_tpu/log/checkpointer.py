@@ -65,6 +65,8 @@ _BYTES_WRITTEN = obs.counter("checkpoint.bytes_written")
 _PARTS_WRITTEN = obs.counter("checkpoint.parts_written")
 _PARTS_REUSED = obs.counter("checkpoint.parts_reused")
 _ABORTED_WRITES = obs.counter("checkpoint.aborted_writes")
+_ENCODES_DEALT = obs.counter("checkpoint.encodes_dealt")
+_ENCODES_SERIAL = obs.counter("checkpoint.encodes_serial")
 
 PV_MAP = pa.map_(pa.string(), pa.string())
 
@@ -764,8 +766,12 @@ def _encode_parquet(table: pa.Table) -> bytes:
     writes it, byte for byte: a large table's pieces are encoded on the
     scan pool and stitched under one footer (`log/parquet_stitch.py`),
     a small one in that one call. Part fingerprints and incremental
-    reuse rest on the bytes being a function of the rows alone."""
-    return parquet_stitch.encode(table)
+    reuse rest on the bytes being a function of the rows alone. The
+    active span (`checkpoint.serialize`) learns how it was made."""
+    data, how = parquet_stitch.encode(table)
+    obs.set_attrs(**how)
+    (_ENCODES_DEALT if how["dealt"] else _ENCODES_SERIAL).inc()
+    return data
 
 
 def _file_part_build(engine, log_path: str, prev_entry: Optional[dict],

@@ -19,11 +19,21 @@ its row groups taken from the pieces' footers with every file position
 shifted to where the chunk now lies.
 
 What is dealt is decided by what the input shows, and by nothing else:
-a table under `_DEAL_MIN_ROWS` is encoded in one call as before, and so
-is one whose footers hold a field this module was not written to carry
-(a page index, a bloom filter, an encryption field, whatever a later
-pyarrow adds): `pq.write_table`, the same bytes by definition, and the
-active span says why (`serial_reason`).
+a table under the line (`small`: rows by columns) is encoded in one call
+as before, and so is one whose footers hold a field this module was not
+written to carry (a page index, a bloom filter, an encryption field,
+whatever a later pyarrow adds): `pq.write_table`, the same bytes by
+definition, and what `encode` hands back beside the bytes says why
+(`serial_reason`).
+
+Two writers encode through here, and each says so on a span and a pair
+of counters of its own: the checkpoint writer
+(`log/checkpointer.py::_encode_parquet`: a classic file, a multipart
+part, a V2 sidecar, under `checkpoint.serialize`) and the writer of
+data files (`engine/host.py::HostParquetHandler.write_parquet_file`:
+every file of an append, a rewrite or an OPTIMIZE, under
+`write.encode`). The pieces run on `scan_pool()` and the caller waits
+for all of them, so neither may call from a task of that pool.
 
 The thrift here is the compact protocol's writer beside
 `log/page_decode.py::_Thrift`, the reader: a footer is read into a tree
@@ -60,19 +70,32 @@ from delta_tpu.log.page_decode import (
     _Thrift,
 )
 
-_ENCODES_DEALT = obs.counter("checkpoint.encodes_dealt")
-_ENCODES_SERIAL = obs.counter("checkpoint.encodes_serial")
-
 _MAGIC = b"PAR1"
 
-# A table is dealt from this many rows, and a struct column cut into its
-# leaves where it holds as many valid rows in a row group: under it a
-# second task costs what it saves. Measured on a checkpoint table of
-# this repository's shape (medians of 7, 8 cores, PERF.md §6, PR 54):
-# at 50,000 to 80,000 rows the dealt encode is level with the one call
-# (37 / 32 ms, 47 / 55 ms), at 100,000 it takes 25 ms of 68, at 400,000
-# 65 of 254.
+# A table of up to `_DEAL_COLUMNS` columns is dealt from this many rows,
+# and a struct column cut into its leaves where it holds as many valid
+# rows in a row group: under it a second task costs what it saves.
+# Measured on a checkpoint's table of this repository's shape (six
+# columns, one action a row, so one struct of fifteen leaves does the
+# work; medians of 7, 8 cores, PERF.md §6, PR 54): at 50,000 to 80,000
+# rows the dealt encode is level with the one call (37 / 32 ms, 47 / 55
+# ms), at 100,000 it takes 25 ms of 68, at 400,000 65 of 254.
 _DEAL_MIN_ROWS = 100_000
+# A column is a piece, so a wider table reaches the line with fewer rows
+# in proportion, down to the rows from which a piece is worth a task at
+# all (`small`). Measured on a data file's flat table, the benchmark's
+# `store_sales` (nine `integer`, a `long`, twelve `decimal(7,2)`), its
+# columns taken 6, 22 and 88 at a time (one call / dealt in ms, medians
+# of 9 on the chip's host, 13 cores, PERF.md §6, PR 56): 22 columns 7.2
+# / 10.9 at 6,000 rows, 14.8 / 10.8 at 12,500, 26.3 / 11.1 at 25,000,
+# 98.7 / 20.2 at 100,000; 88 columns 30.3 / 38.3, 56.8 / 40.2, 107.0 /
+# 40.5, 466 / 69; six decimals 2.7 / 3.4, 5.2 / 4.1, 10.1 / 5.6, 38.3 /
+# 14.0; six integers level to 50,000 rows and 14.9 / 9.3 at 100,000. A
+# task costs ~0.4 ms whatever it holds, so no width wins under ~10,000
+# rows; at the line (27,273 rows of 22 columns) the deal takes under
+# half the one call's time.
+_DEAL_COLUMNS = 6
+_DEAL_PIECE_SHARE = 8    # a piece holds a `_DEAL_MIN_ROWS` / 8 at least
 
 
 class StandDown(Exception):
@@ -304,8 +327,10 @@ def _leaves(typ: pa.DataType, name: str, at: Tuple[int, ...] = ()):
 
 def _plan(table: pa.Table, group_rows: int) -> List[List[_Piece]]:
     """The pieces of `table` by row group, in the file's order: a piece
-    a column, and of a struct column that holds `_DEAL_MIN_ROWS` valid
-    rows in the group (a checkpoint's `add`) a piece a leaf."""
+    a column (a data file's columns, chunked as the caller left them:
+    a slice of a chunked column is its chunks' slices), and of a struct
+    column that holds `_DEAL_MIN_ROWS` valid rows in the group (a
+    checkpoint's `add`) a piece a leaf."""
     groups = []
     for g, start in enumerate(range(0, table.num_rows, group_rows)):
         rows = table.slice(start, group_rows)
@@ -329,7 +354,8 @@ def _plan(table: pa.Table, group_rows: int) -> List[List[_Piece]]:
 
 def _write(table: pa.Table) -> pa.Buffer:
     """The one call this module makes of Arrow's writer, with the
-    arguments `log/checkpointer.py` has always passed."""
+    arguments both callers have always passed (snappy, every other
+    setting pyarrow's default)."""
     sink = pa.BufferOutputStream()
     pq.write_table(table, sink, compression="snappy")
     return sink.getvalue()
@@ -423,7 +449,7 @@ def _settled(futures: List[Future]) -> list:
     return results
 
 
-def _dealt(table: pa.Table, group_rows: int) -> bytes:
+def _dealt(table: pa.Table, group_rows: int) -> Tuple[bytes, dict]:
     from delta_tpu.utils.threads import default_scan_threads, scan_pool
 
     # the zero-row encode first: where this pyarrow's footer holds what
@@ -432,8 +458,6 @@ def _dealt(table: pa.Table, group_rows: int) -> bytes:
     _row_groups(template)
     groups = _plan(table, group_rows)
     tasks = [p for pieces in groups for p in pieces]
-    obs.set_attrs(row_groups=len(groups), tasks=len(tasks),
-                  threads=default_scan_threads())
     if len(tasks) < 2:
         raise StandDown("small")
     # the heaviest first: it sets the pace, so it must not queue
@@ -447,30 +471,36 @@ def _dealt(table: pa.Table, group_rows: int) -> bytes:
     with obs.span("serialize.stitch") as sp:
         data = _stitch(template, groups, encoded, table.num_rows)
         sp.set_attr("bytes", len(data))
-    return data
+    return data, {"dealt": True, "row_groups": len(groups),
+                  "tasks": len(tasks), "threads": default_scan_threads()}
 
 
-def encode(table: pa.Table) -> bytes:
+def small(table: pa.Table) -> bool:
+    """Whether `table` is under the line from which `encode` cuts it:
+    `_DEAL_MIN_ROWS` rows of up to `_DEAL_COLUMNS` columns, as many rows
+    x columns of a wider table, and never under the rows a piece needs."""
+    rows, columns = table.num_rows, max(table.num_columns, _DEAL_COLUMNS)
+    return (rows * columns < _DEAL_MIN_ROWS * _DEAL_COLUMNS
+            or rows * _DEAL_PIECE_SHARE < _DEAL_MIN_ROWS)
+
+
+def encode(table: pa.Table) -> Tuple[bytes, dict]:
     """`table` as the Parquet file `pq.write_table(table, sink,
-    compression="snappy")` writes. The active span (the caller's
-    `checkpoint.serialize`) learns how: `dealt`, `tasks`, `threads`,
-    `row_groups` where the table was cut, and `serial_reason` where it
-    was one call after all."""
+    compression="snappy")` writes, and how it was made, for the span
+    and the counters of whoever asked: `dealt`, `tasks`, `threads`,
+    and `row_groups` where the table was cut, `serial_reason` where it
+    was one call after all. Not to be called from a task of
+    `scan_pool()`: the caller waits here for that pool."""
     # asked at every call, as `pq.write_table` asks it
     group_rows = getattr(_parquet, "_DEFAULT_ROW_GROUP_SIZE", None)
-    reason = None
     if not group_rows:
         reason = "row_group_size"    # a pyarrow that does not say it
-    elif table.num_rows < _DEAL_MIN_ROWS:
+    elif small(table):
         reason = "small"
     else:
         try:
-            data = _dealt(table, group_rows)
-            obs.set_attrs(dealt=True)
-            _ENCODES_DEALT.inc()
-            return data
+            return _dealt(table, group_rows)
         except StandDown as e:
             reason = e.reason
-    obs.set_attrs(dealt=False, tasks=1, threads=1, serial_reason=reason)
-    _ENCODES_SERIAL.inc()
-    return _write(table).to_pybytes()
+    return _write(table).to_pybytes(), {
+        "dealt": False, "tasks": 1, "threads": 1, "serial_reason": reason}
