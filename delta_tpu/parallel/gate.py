@@ -194,9 +194,9 @@ def _breaker_admit(gate: str, chosen: str, reason: str):
 
     Open breaker -> degrade to the host twin ("breaker-open");
     half-open -> admit the decision as the probe ("breaker-probe") —
-    the executing site reports the outcome via :func:`route_ok` /
-    :func:`route_failed`, and a probe whose caller never reports is
-    reclaimed by the breaker after its reset window."""
+    `resilience/device_faults.py::guarded` reports the outcome via
+    :func:`route_ok` / :func:`route_failed`, and a probe whose caller
+    never reports is reclaimed by the breaker after its reset window."""
     from delta_tpu.errors import CircuitOpenError
     from delta_tpu.resilience.breaker import HALF_OPEN
     b = _route_breaker(gate)

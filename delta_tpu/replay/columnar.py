@@ -1238,7 +1238,6 @@ def _columnarize_log_segment(
         import pyarrow.parquet as pq
 
         from delta_tpu.log.page_decode import read_checkpoint_part_device
-        from delta_tpu.parallel import gate as gate_mod
         from delta_tpu.replay.pipeline import prefetch_file_bytes
         from delta_tpu.resilience import device_faults
 
@@ -1251,29 +1250,21 @@ def _columnarize_log_segment(
                     _consume_checkpoint_table(_read_json_part(fstat))
                 else:
                     data = next(byte_iter)
-                    host_reason = "unsupported-shape"
-                    try:
-                        out = device_faults.shed_retry(
-                            "decode",
-                            lambda data=data: read_checkpoint_part_device(
-                                data, want_keys=want_handoff))
-                    except Exception as e:
-                        # classify (feeds the route breaker); permanent
-                        # errors — a missing part file is one — re-raise
-                        # into the handler below
-                        if not device_faults.absorb_route_failure(
-                                "decode", e):
-                            raise
-                        out = None
-                        host_reason = f"device-error:{type(e).__name__}"
-                    if out is not None:
+                    # a permanent error (a missing part file is one)
+                    # leaves guarded() for the handler below
+                    out = device_faults.guarded(
+                        "decode",
+                        lambda data=data: read_checkpoint_part_device(
+                            data, want_keys=want_handoff),
+                        _OBS_DECODE_FALLBACKS)
+                    if out.value is not None:
                         _OBS_DECODE_PARTS.inc()
-                        gate_mod.route_ok("decode")
-                        _consume_checkpoint_table(out[0], out[1])
+                        _consume_checkpoint_table(*out.value)
                     else:
-                        _OBS_DECODE_FALLBACKS.inc()
-                        obs.gate_fell_back("decode", "host",
-                                           reason=host_reason)
+                        if out.fell_back is None:
+                            _OBS_DECODE_FALLBACKS.inc()
+                            obs.gate_fell_back("decode", "host",
+                                               reason="unsupported-shape")
                         with obs.gate_observation("decode", "host"):
                             tbl = _read_part(
                                 lambda data=data: pq.read_table(
@@ -1431,26 +1422,19 @@ def _columnarize_log_segment(
                         return None
                     if row_versions.max(initial=0) >= 2**31:
                         return None
-                    try:
-                        return device_faults.shed_retry(
-                            "replay", lambda: replay_select_launch(
-                                [scan.path_code,
-                                 np.zeros(scan.n_rows, np.uint32)],
-                                row_versions.astype(np.int32), row_orders,
-                                scan.is_add.astype(bool),
-                                fa_hint=(scan.path_new, scan.refs,
-                                         scan.n_uniq),
-                            ))
-                    except Exception as e:
-                        # The early launch is an overlap optimization:
-                        # a transient device failure here just forfeits
-                        # the head start — compute_masks_device makes
-                        # its own (absorbed) attempt later, so no
-                        # fallback counter and no host twin yet.
-                        if not device_faults.absorb_route_failure(
-                                "replay", e):
-                            raise
-                        return None
+                    # An overlap optimization: a transient failure
+                    # here just forfeits the head start (None), and
+                    # compute_masks_device makes the guarded attempt
+                    # later, so no counter, no record, no host twin yet.
+                    return device_faults.try_device(
+                        "replay", lambda: replay_select_launch(
+                            [scan.path_code,
+                             np.zeros(scan.n_rows, np.uint32)],
+                            row_versions.astype(np.int32), row_orders,
+                            scan.is_add.astype(bool),
+                            fa_hint=(scan.path_new, scan.refs,
+                                     scan.n_uniq),
+                        )).value
             # Pipelined load: when the tail is big enough to window,
             # overlap storage reads with parsing (and with the device
             # replay dispatch) instead of the phase-serial flow below.
@@ -1469,8 +1453,7 @@ def _columnarize_log_segment(
                                 engine, windows,
                                 allow_native=_native.available(
                                     allow_compile),
-                                lazy_stats=not os.environ.get(
-                                    "DELTA_TPU_EAGER_STATS"),
+                                lazy_stats=True,
                                 launch=launch,
                                 allow_device=getattr(
                                     engine, "use_device_parse", False)))
@@ -1489,41 +1472,28 @@ def _columnarize_log_segment(
                     from delta_tpu.replay import device_parse as _dp
                     from delta_tpu.resilience import device_faults
 
-                    fell_reason = None
+                    # the buffer (if read) is reused by the host branches
+                    # below, priced against the "device" prediction
                     read = _read_commits_buffer(engine, remaining)
-                    if read is not None:
+                    if read is None:
+                        obs.gate_fell_back("parse", "host",
+                                           reason="read-failed")
+                    else:
                         buf, starts, version_arr = read
-                        try:
-                            parsed_native = device_faults.shed_retry(
-                                "parse",
-                                lambda: _dp.parse_commits_device(
-                                    buf, starts, version_arr,
-                                    small_only=small_only,
-                                    lazy_stats=(not small_only
-                                                and not os.environ.get(
-                                                    "DELTA_TPU_EAGER_STATS"
-                                                ))))
-                        except Exception as e:
-                            # classify (feeds the route breaker);
-                            # transient -> host twin reuses the buffer
-                            if not device_faults.absorb_route_failure(
-                                    "parse", e):
-                                raise
-                            _OBS_PARSE_FALLBACKS.inc()
-                            fell_reason = (
-                                f"device-error:{type(e).__name__}")
+                        out = device_faults.guarded(
+                            "parse",
+                            lambda: _dp.parse_commits_device(
+                                buf, starts, version_arr,
+                                small_only=small_only,
+                                lazy_stats=not small_only),
+                            _OBS_PARSE_FALLBACKS)
+                        parsed_native = out.value
                         if parsed_native is not None:
-                            _gate.route_ok("parse")
                             bytes_parsed += int(starts[-1])
-                    if parsed_native is None:
-                        # buffer (if read) is reused by the host
-                        # branches; price them against the "device"
-                        # prediction for gate calibration
-                        obs.gate_fell_back(
-                            "parse", "host",
-                            reason=(fell_reason if fell_reason is not None
-                                    else "read-failed" if read is None
-                                    else "device-parse-unavailable"))
+                        elif out.fell_back is None:
+                            obs.gate_fell_back(
+                                "parse", "host",
+                                reason="device-parse-unavailable")
             if (fresh is None and parsed_native is None and read is None
                     and _native.available(allow_compile)):
                 # local files: one native read+scan round-trip (no per-file
@@ -1540,9 +1510,7 @@ def _columnarize_log_segment(
                         # stats decode defers only when a deferred column
                         # can later be assembled: the combined stats thunk
                         # spans blocks, so any non-small parse may defer
-                        lazy_stats=(not small_only
-                                    and not os.environ.get(
-                                        "DELTA_TPU_EAGER_STATS")))
+                        lazy_stats=not small_only)
                     if out is not None:
                         block, others, keys, pending, sthunk, total = out
                         parsed_native = (block, others, keys, pending, sthunk)

@@ -374,30 +374,22 @@ def _parse_window(w: _Window, allow_native: bool,
             from delta_tpu.replay.device_parse import parse_window_device
             from delta_tpu.resilience import device_faults
 
-            fell_reason = "device-parse-unavailable"
-            try:
-                out = device_faults.shed_retry(
-                    "parse",
-                    lambda: parse_window_device(w.buf, w.starts,
-                                                w.versions,
-                                                lazy_stats=lazy_stats))
-            except Exception as e:
-                # classify (feeds the route breaker); transient -> the
-                # host branches below reuse the window buffer
-                if not device_faults.absorb_route_failure("parse", e):
-                    raise
-                _PARSE_FALLBACKS.inc()
-                out = None
-                fell_reason = f"device-error:{type(e).__name__}"
-            if out is not None:
-                gate.route_ok("parse")
-                table, others, keys, uniq, dv_any, sthunk = out
+            out = device_faults.guarded(
+                "parse",
+                lambda: parse_window_device(w.buf, w.starts, w.versions,
+                                            lazy_stats=lazy_stats),
+                _PARSE_FALLBACKS)
+            if out.value is not None:
+                table, others, keys, uniq, dv_any, sthunk = out.value
                 sp.set_attrs(rows=table.num_rows, device=True)
                 return _Parsed(w.index, table, others, keys, uniq,
                                dv_any, sthunk, len(w.infos), w.nbytes)
-            # mid-flight fallback: calibration prices the device attempt
-            # PLUS the host parse below against the "device" prediction
-            obs.gate_fell_back("parse", "host", reason=fell_reason)
+            # mid-flight fallback: the host branches below reuse the
+            # window buffer, and calibration prices the device attempt
+            # PLUS the host parse against the "device" prediction
+            if out.fell_back is None:
+                obs.gate_fell_back("parse", "host",
+                                   reason="device-parse-unavailable")
         if allow_native:
             from delta_tpu.replay.native_parse import parse_window_native
 

@@ -299,14 +299,15 @@ def _dv_codes_only(file_actions: pa.Table) -> np.ndarray:
     return (codes + 1).astype(np.uint32)
 
 
-def _replay_host_twin(columnar: ColumnarActions,
-                      exc: Exception) -> tuple[np.ndarray, np.ndarray]:
-    """Fallback bookkeeping + host replay after an absorbed (already
-    classified transient) device failure: bump the cataloged fallback
-    counter and run the host twin under the calibration join."""
-    _ROUTE_FALLBACKS.inc()
-    obs.gate_fell_back("replay", "host",
-                       reason=f"device-error:{type(exc).__name__}")
+def _guarded_replay(columnar: ColumnarActions,
+                    run_device) -> tuple[np.ndarray, np.ndarray]:
+    """One device replay under the route contract; after an absorbed
+    failure, the host twin under the calibration join."""
+    from delta_tpu.resilience import device_faults
+
+    out = device_faults.guarded("replay", run_device, _ROUTE_FALLBACKS)
+    if out.fell_back is None:
+        return out.value
     with obs.gate_observation("replay", "host"):
         return compute_masks_host(columnar)
 
@@ -316,7 +317,6 @@ def compute_masks_device(
 ) -> tuple[np.ndarray, np.ndarray]:
     from delta_tpu.ops.replay import replay_select
     from delta_tpu.parallel import gate
-    from delta_tpu.resilience import device_faults
 
     fa = columnar.file_actions
     n = fa.num_rows
@@ -328,15 +328,7 @@ def compute_masks_device(
         # device replay was dispatched during columnarization (overlapped
         # with the Arrow assembly) — just collect the masks; a failed
         # overlapped dispatch degrades to the host twin like any other
-        try:
-            out = device_faults.shed_retry("replay", pending.finish)
-        except Exception as e:
-            # classify (feeds the route breaker); permanent -> re-raise
-            if not device_faults.absorb_route_failure("replay", e):
-                raise
-            return _replay_host_twin(columnar, e)
-        gate.route_ok("replay")
-        return out
+        return _guarded_replay(columnar, pending.finish)
     keys = columnar.replay_keys
     fa_hint = None
     with obs.span("replay.keys", rows=n) as sp:
@@ -413,15 +405,7 @@ def compute_masks_device(
             fa_hint=fa_hint,
         )
 
-    try:
-        out = device_faults.shed_retry("replay", _run_device)
-    except Exception as e:
-        # classify (feeds the route breaker); permanent -> re-raise
-        if not device_faults.absorb_route_failure("replay", e):
-            raise
-        return _replay_host_twin(columnar, e)
-    gate.route_ok("replay")
-    return out
+    return _guarded_replay(columnar, _run_device)
 
 
 def compute_masks_host(columnar: ColumnarActions) -> tuple[np.ndarray, np.ndarray]:

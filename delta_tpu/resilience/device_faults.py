@@ -1,41 +1,29 @@
 """Device-route failure absorption: shed-and-retry + classify-and-fall-back.
 
 Every gated device route (`replay`/`parse`/`decode`/`skip`/`sql`) has a
-host twin, so a failed device dispatch is never fatal — but the
-fallback must be *disciplined*: the exception is classified through
-`resilience/classify.py`, the verdict feeds the route's circuit breaker
-(`parallel/gate.py::route_failed`), the route's cataloged fallback
-counter is bumped, and only then does the host twin run. The
-retry-discipline lint pass enforces this shape at every
-`device_dispatch` call site.
+host twin, so a failed device dispatch is never fatal, but the fallback
+is *disciplined*, and :func:`guarded` is the one place that knows how: a
+route's executing site hands it the gate, the device thunk and the
+route's cataloged fallback counter, reads the :class:`Outcome`, and keeps
+only what is its own (its soft declines, its host twin under
+``obs.gate_observation``)::
 
-The canonical consumer-site pattern::
-
-    from delta_tpu.resilience import device_faults
-    from delta_tpu.parallel import gate as gate_mod
-
-    try:
-        out = device_faults.shed_retry("replay", run_device)
-        gate_mod.route_ok("replay")
-    except Exception as e:
-        if not device_faults.absorb_route_failure("replay", e):
-            raise                      # permanent: the error is an answer
-        _FALLBACKS.inc()
-        obs.gate_fell_back("replay", "host",
-                           reason=f"device-error:{type(e).__name__}")
-        with obs.gate_observation("replay", "host"):
-            out = run_host()
+    out = device_faults.guarded("replay", run_device, _FALLBACKS)
+    if out.fell_back is None:
+        return out.value
+    with obs.gate_observation("replay", "host"):
+        return run_host()
 
 :func:`shed_retry` implements HBM-pressure shed-and-retry: on an
 allocation failure (``RESOURCE_EXHAUSTED``) it asks the resident ledger
 (`obs/hbm.py`) to evict the cheapest-to-rebuild artifacts and retries
-the dispatch exactly once; a second failure — or nothing sheddable —
-propagates to the absorption path and the host twin takes over.
+the dispatch exactly once; a second failure, or nothing sheddable,
+propagates to the classification in :func:`try_device`.
 """
 
 from __future__ import annotations
 
-from typing import Callable, TypeVar
+from typing import Callable, NamedTuple, Optional, TypeVar
 
 from delta_tpu import obs
 
@@ -62,9 +50,10 @@ def shed_retry(gate: str, fn: Callable[[], T]) -> T:
 
     On an allocation failure, ask the resident ledger to shed the
     cheapest-to-rebuild artifacts and retry ``fn`` once; any other
-    exception — and a retry that fails again — propagates to the
-    caller's absorption handler. The retry is observable: it bumps
-    ``hbm.shed_retries`` and the ledger's shed counters."""
+    exception, and a retry that fails again, propagates. The retry is
+    observable: it bumps ``hbm.shed_retries`` and the ledger's shed
+    counters. What was shed may be what ``fn`` reads, so ``fn`` fetches
+    its resident inputs itself (the skip route's lanes re-upload)."""
     try:
         return fn()
     except Exception as exc:
@@ -74,18 +63,53 @@ def shed_retry(gate: str, fn: Callable[[], T]) -> T:
         n, _freed = hbm.shed()
         if not n:
             raise
-        _SHED_RETRIES.inc()
-        obs.add_event("device.shed_retry", gate=gate, evicted=n)
-        return fn()
+    # out of the handler: the failed attempt's traceback, and the device
+    # arrays its frames held, are let go before the second attempt
+    _SHED_RETRIES.inc()
+    obs.add_event("device.shed_retry", gate=gate, evicted=n)
+    return fn()
 
 
-def absorb_route_failure(gate: str, exc: BaseException) -> bool:
-    """Classify one device-route failure and feed the route breaker.
+class Outcome(NamedTuple):
+    """What one device attempt came to. ``fell_back`` is None unless a
+    transient failure was absorbed: then it is the ``gate_fell_back``
+    reason (``device-error:<Type>``) and ``value`` is None. Otherwise
+    ``value`` is the thunk's return: the answer, or None where the
+    route declined by itself (the site names that decline)."""
+    value: Optional[object]
+    fell_back: Optional[str] = None
 
-    Returns True for transient verdicts — the caller bumps its fallback
-    counter and runs the host twin; False for permanent ones — the
-    caller re-raises (real corruption or a genuine bug must surface,
-    not be silently recomputed on the host)."""
-    from delta_tpu.parallel.gate import route_failed
-    from delta_tpu.resilience.classify import TRANSIENT
-    return route_failed(gate, exc) == TRANSIENT
+
+def try_device(gate: str, device_fn: Callable[[], T]) -> Outcome:
+    """Shed-and-retry, then classify (which feeds the route breaker): a
+    permanent error leaves unchanged (real corruption or a genuine bug
+    must surface, not be recomputed on the host), a transient one is
+    the outcome's ``fell_back``. Counts nothing and reports no success:
+    the early replay launch, whose second half reports through
+    :func:`guarded`, calls this directly."""
+    try:
+        return Outcome(shed_retry(gate, device_fn))
+    except Exception as e:
+        from delta_tpu.parallel.gate import route_failed
+        from delta_tpu.resilience.classify import TRANSIENT
+        if route_failed(gate, e) != TRANSIENT:
+            raise
+        return Outcome(None, f"device-error:{type(e).__name__}")
+
+
+def guarded(gate: str, device_fn: Callable[[], T], fallbacks) -> Outcome:
+    """Run one gated route's device thunk under the route contract.
+
+    A transient failure bumps ``fallbacks`` (the route's cataloged
+    counter), then marks the gate record fallen back with the outcome's
+    reason; the caller runs its host twin. A non-None return reports
+    success to the route breaker (closing a half-open probe); None is
+    the route's soft decline and reports nothing."""
+    out = try_device(gate, device_fn)
+    if out.fell_back is not None:
+        fallbacks.inc()
+        obs.gate_fell_back(gate, "host", reason=out.fell_back)
+    elif out.value is not None:
+        from delta_tpu.parallel.gate import route_ok
+        route_ok(gate)
+    return out

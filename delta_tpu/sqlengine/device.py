@@ -32,7 +32,7 @@ import pandas as pd
 
 from delta_tpu import obs
 from delta_tpu.obs.device import gate_fell_back
-from delta_tpu.parallel.gate import route_ok, sql_route
+from delta_tpu.parallel.gate import sql_route
 
 _log = logging.getLogger(__name__)
 
@@ -46,28 +46,18 @@ sqlops = None  # set on first DeviceSpine construction (defers jax)
 
 
 def _absorbing(method):
-    """Disciplined device-failure contract around one public operator
-    entry point: shed-and-retry on allocation failure, classify the
-    exception through `resilience/classify.py` (feeding the sql route
-    breaker), bump the cataloged fallback counter, and return None so
-    the executor keeps its pandas path. Permanent verdicts re-raise —
-    a real bug must surface, not be recomputed on the host. Non-None
-    returns report success to the breaker (closing half-open probes)."""
+    """The route contract around one public operator entry point
+    (`device_faults.guarded`): a transient device failure is absorbed
+    and the operator returns None, so the executor keeps its pandas
+    path; a permanent one surfaces; a non-None return reports success
+    to the sql route breaker."""
 
     @functools.wraps(method)
     def wrapper(self, *args, **kwargs):
         from delta_tpu.resilience import device_faults
 
-        try:
-            out = device_faults.shed_retry(
-                "sql", lambda: method(self, *args, **kwargs))
-        except Exception as e:
-            if not device_faults.absorb_route_failure("sql", e):
-                raise
-            return self._fell_back(f"device-error:{type(e).__name__}")
-        if out is not None:
-            route_ok("sql")
-        return out
+        return device_faults.guarded(
+            "sql", lambda: method(self, *args, **kwargs), _FALLBACKS).value
 
     return wrapper
 

@@ -821,33 +821,27 @@ def skipping_mask(
                 engine_enabled=bool(getattr(engine, "use_device_skip", False)),
             )
             if route == "device":
-                from delta_tpu.parallel import gate as gate_mod
                 from delta_tpu.resilience import device_faults
-                try:
-                    lanes = device_faults.shed_retry(
-                        "skip", rs.device_lanes)
+
+                def device_mask():
+                    # fetched inside the thunk: a shed may evict the
+                    # resident lanes, and the retry then re-uploads them
+                    lanes = rs.device_lanes()
                     if lanes is None:
+                        return None
+                    return ops_skipping.skip_mask_block(*lanes, block, n)
+
+                out = device_faults.guarded("skip", device_mask,
+                                            _DEVICE_FALLBACKS)
+                if out.value is not None:
+                    keep &= out.value
+                    _DEVICE_PLANS.inc()
+                    if fallback:
+                        _DEVICE_FALLBACKS.inc(len(fallback))
+                else:
+                    if out.fell_back is None:
                         obs.gate_fell_back("skip", "host",
                                            reason="no-resident-lanes")
-                        route = "host"
-                    else:
-                        keep &= device_faults.shed_retry(
-                            "skip",
-                            lambda: ops_skipping.skip_mask_block(
-                                *lanes, block, n))
-                        gate_mod.route_ok("skip")
-                        _DEVICE_PLANS.inc()
-                        if fallback:
-                            _DEVICE_FALLBACKS.inc(len(fallback))
-                except Exception as e:
-                    # disciplined fallback: classify (feeds the route
-                    # breaker), bump the cataloged counter, host twin
-                    if not device_faults.absorb_route_failure("skip", e):
-                        raise
-                    _DEVICE_FALLBACKS.inc()
-                    obs.gate_fell_back(
-                        "skip", "host",
-                        reason=f"device-error:{type(e).__name__}")
                     route = "host"
             if route == "host":
                 with obs.gate_observation("skip", "host"):

@@ -23,10 +23,10 @@ Three shapes are flagged:
    body — a hard-coded attempt cap that belongs in ``RetryPolicy``
    (env-tunable), not in the call site;
 3. a ``try`` whose body dispatches to the device (a ``device_dispatch``
-   call, or a route thunk run through ``shed_retry``) with an exception
-   handler that neither classifies the error (``classify`` /
-   ``is_transient`` / ``route_failed`` / ``absorb_route_failure``) nor
-   bumps a fallback counter (``.inc(...)``) nor re-raises — a silent
+   call, or a route thunk run through ``guarded`` / ``try_device`` /
+   ``shed_retry``) with a handler that neither classifies (``classify`` /
+   ``is_transient`` / ``route_failed``) nor bumps a counter
+   (``.inc(...)``) nor re-raises, in any order or none: a silent
    device fallback that starves the route breaker and under-reports
    exactly the failures the chaos soak injects.
 
@@ -79,13 +79,12 @@ def _literal_range_loop(node: ast.For) -> bool:
 
 
 # exception-handler calls that count as "the error was classified":
-# the classifier itself, and the absorption helpers that route through
-# it (resilience/device_faults.py, parallel/gate.py)
-_CLASSIFIER_CALLS = {"classify", "is_transient", "route_failed",
-                     "absorb_route_failure"}
+# the classifier itself, and the breaker report that routes through it
+# (parallel/gate.py)
+_CLASSIFIER_CALLS = {"classify", "is_transient", "route_failed"}
 
 # calls that mark the try body as a device-route dispatch site
-_DISPATCH_CALLS = {"device_dispatch", "shed_retry"}
+_DISPATCH_CALLS = {"device_dispatch", "shed_retry", "guarded", "try_device"}
 
 
 def _walk_same_scope(stmts):
@@ -180,9 +179,9 @@ class RetryDisciplineRule(Rule):
                         handler.col_offset,
                         "device_dispatch exception handler neither "
                         "classifies the error (resilience.classify / "
-                        "absorb_route_failure), bumps a fallback "
+                        "gate.route_failed), bumps a fallback "
                         "counter, nor re-raises: silent device "
-                        "fallbacks starve the route breaker — follow "
-                        "the resilience/device_faults.py contract (or "
+                        "fallbacks starve the route breaker — call "
+                        "resilience/device_faults.py::guarded (or "
                         "audit + suppress)"))
         return out

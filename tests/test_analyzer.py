@@ -1107,11 +1107,19 @@ def read(route, thunk):
         return device_faults.shed_retry("decode", thunk)
     except Exception:
         return None
+
+def read_guarded(thunk, ctr):
+    from delta_tpu.resilience import device_faults
+    try:
+        return device_faults.guarded("decode", thunk, ctr).value
+    except Exception:
+        return None
 """
     report = analyze_sources({"delta_tpu/x.py": src},
                              rules=["retry-discipline"])
     found = _rules_fired(report, "retry-discipline")
-    assert found and "starve the route breaker" in found[0].message
+    assert len(found) == 2
+    assert all("starve the route breaker" in f.message for f in found)
 
 
 def test_retry_dispatch_handler_each_discipline_clean():
@@ -1145,9 +1153,16 @@ def d(thunk):
     try:
         return device_faults.shed_retry("skip", thunk)
     except Exception as e:
-        if not device_faults.absorb_route_failure("skip", e):
+        if route_failed("skip", e) != "transient":
             raise
         return None
+
+def e(thunk, ctr):
+    from delta_tpu.resilience import device_faults
+    try:
+        return device_faults.guarded("skip", thunk, ctr).value
+    except FileNotFoundError as e:
+        raise LogCorruptedError(str(e))
 """
     report = analyze_sources({"delta_tpu/x.py": src},
                              rules=["retry-discipline"])

@@ -302,6 +302,168 @@ def test_shed_noop_when_ledger_off():
     assert hbm.shed() == (0, 0)
 
 
+# ------------------------------------------------ the one guarded call
+
+
+def _half_open(monkeypatch, g):
+    """Gate `g`'s breaker tripped, cooled down, its one probe taken."""
+    monkeypatch.setenv("DELTA_TPU_ROUTE_BREAKER_THRESHOLD", "1")
+    monkeypatch.setenv("DELTA_TPU_ROUTE_BREAKER_RESET_S", "30")
+    resilience.reset()
+    gate.route_failed(g, DeviceChaosError("trip"))
+    b = route_breaker_for(g)
+    later = time.monotonic() + 31.0
+    b._clock = lambda: later
+    b.before_call()
+    assert b.state == "half_open"
+    return b
+
+
+def _scripted(steps):
+    """A device thunk that raises or returns `steps`, one a call."""
+    left = list(steps)
+
+    def thunk():
+        step = left.pop(0)
+        if isinstance(step, BaseException):
+            raise step
+        return step
+
+    thunk.left = left
+    return thunk
+
+
+def _oom():
+    return DeviceResourceExhaustedError("probe.kernel")
+
+
+# case: (the thunk's script, value, fell_back reason, fallback counter's
+# delta, shed retries, breaker after a half-open probe)
+_GUARDED_CASES = {
+    "answers": (lambda: ["answer"], "answer", None, 0, 0, "closed"),
+    "declines": (lambda: [None], None, None, 0, 0, "half_open"),
+    "transient": (lambda: [DeviceChaosError("injected")], None,
+                  "device-error:DeviceChaosError", 1, 0, "open"),
+    "permanent": (lambda: [FileNotFoundError("part gone")],
+                  FileNotFoundError, None, 0, 0, "closed"),
+    "oom-then-answers": (lambda: [_oom(), "answer"], "answer", None,
+                         0, 1, "closed"),
+    "oom-twice": (lambda: [_oom(), _oom()], None,
+                  "device-error:DeviceResourceExhaustedError", 1, 1,
+                  "open"),
+}
+
+
+@pytest.mark.parametrize("case", list(_GUARDED_CASES))
+@pytest.mark.parametrize("g", GATES)
+def test_guarded_is_the_route_contract(g, case, monkeypatch):
+    """`device_faults.guarded` for every gate and outcome: the value or
+    the exception, the cataloged counter's delta, the fallback on the
+    gate record, the shed-and-retry, and what the breaker was told."""
+    script, value, reason, counted, shed, state = _GUARDED_CASES[case]
+    b = _half_open(monkeypatch, g)
+    art = _Artifact("cheap")  # what an allocation failure can shed
+    fallbacks = obs.counter(gate.ROUTES[g].fallback_counter)
+    before = fallbacks.value
+    retries = obs.counter("hbm.shed_retries").value
+    obs.record_gate_decision(g, "device", {"op": "probe"}, {},
+                             reason="breaker-probe")
+    thunk = _scripted(script())
+    if isinstance(value, type):
+        with pytest.raises(value, match="part gone"):
+            device_faults.guarded(g, thunk, fallbacks)
+    else:
+        out = device_faults.guarded(g, thunk, fallbacks)
+        assert out.value == value and out.fell_back == reason
+    assert not thunk.left  # every scripted attempt was made, no more
+    assert fallbacks.value == before + counted
+    assert obs.counter("hbm.shed_retries").value == retries + shed
+    assert art.evicted == bool(shed)
+    rec = obs.get_gate_records()[-1]
+    assert rec["gate"] == g and rec["chosen"] == "device"
+    assert rec["fell_back_to"] == ("host" if reason else None)
+    assert rec.get("fallback_reason") == reason
+    assert b.state == state
+    if not art.evicted:
+        art.evict()
+
+
+def test_try_device_classifies_and_reports_nothing_else(monkeypatch):
+    """The inner step (the early replay launch's): a transient failure
+    feeds the breaker and is the outcome; no counter, no gate record,
+    and an answer closes no probe (`compute_masks_device` reports)."""
+    b = _half_open(monkeypatch, "replay")
+    fallbacks = obs.counter(gate.ROUTES["replay"].fallback_counter)
+    before = fallbacks.value
+    obs.record_gate_decision("replay", "single", {"op": "probe"}, {})
+    assert device_faults.try_device(
+        "replay", _scripted(["launched"])) == ("launched", None)
+    assert b.state == "half_open"
+    out = device_faults.try_device(
+        "replay", _scripted([DeviceChaosError("injected")]))
+    assert out == (None, "device-error:DeviceChaosError")
+    assert b.state == "open"
+    with pytest.raises(FileNotFoundError):
+        device_faults.try_device(
+            "replay", _scripted([FileNotFoundError("gone")]))
+    assert fallbacks.value == before
+    assert obs.get_gate_records()[-1]["fell_back_to"] is None
+
+
+def test_skip_retry_refetches_the_lanes_a_shed_evicted(monkeypatch):
+    """The skip route's one thunk fetches the resident lanes itself: an
+    allocation failure in the mask kernel sheds those very lanes (the
+    cheapest artifact held), and the retry uploads them anew instead of
+    reading arrays the ledger no longer counts."""
+    import json
+    import threading
+
+    from delta_tpu.expressions.tree import Comparison
+    from delta_tpu.ops import skipping as ops_skipping
+    from delta_tpu.stats.skipping import skipping_mask
+
+    class State:
+        stats_index = None
+
+    files = pa.table({
+        "path": [f"f{i}.parquet" for i in range(8)],
+        "stats": [json.dumps({"numRecords": 10, "minValues": {"a": i},
+                              "maxValues": {"a": i + 2},
+                              "nullCount": {"a": 0}}) for i in range(8)],
+    })
+    st = State()
+    st.add_files_table = files
+    st._stats_index_lock = threading.Lock()
+    conjs = [Comparison("<", col("a"), lit(5))]
+    want = skipping_mask(files, conjs, None)  # the Arrow ladder
+    assert want.sum() == 5
+    monkeypatch.setenv("DELTA_TPU_DEVICE_SKIP", "force")
+    assert (skipping_mask(files, conjs, None, state=st) == want).all()
+    assert st.stats_index._dev is not None  # resident from here on
+
+    real, seen = ops_skipping.skip_mask_block, []
+
+    def oom_once(*args):
+        seen.append(args[0])
+        if len(seen) == 1:
+            raise _oom()
+        return real(*args)
+
+    monkeypatch.setattr(ops_skipping, "skip_mask_block", oom_once)
+    counters = {n: obs.counter(n) for n in (
+        "scan.stats_index_lane_splits", "hbm.shed_retries",
+        "scan.device_plans", "scan.device_fallbacks")}
+    before = {n: c.value for n, c in counters.items()}
+    got = skipping_mask(files, conjs, None, state=st)
+    assert (got == want).all()
+    assert len(seen) == 2 and seen[0] is not seen[1]
+    assert {n: c.value - before[n] for n, c in counters.items()} == {
+        "scan.stats_index_lane_splits": 1, "hbm.shed_retries": 1,
+        "scan.device_plans": 1, "scan.device_fallbacks": 0}
+    assert obs.get_gate_records()[-1]["fell_back_to"] is None
+    assert hbm.audit()["ok"] and not hbm.leak_records()
+
+
 # --------------------------------------------- route breakers / gate
 
 
