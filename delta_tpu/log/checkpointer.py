@@ -263,6 +263,18 @@ def _parse_stats_structs(
     return parsed.to_struct_array().combine_chunks()
 
 
+# What a checkpoint reads of a live row, of the canonical schema's
+# seventeen columns: `_file_struct_from_canonical` unpacks an add row
+# from exactly these, in this order (`dataChange` is written as a
+# column of `false`), `_checkpoint_aggregates` reads four of them, and
+# `_write_checkpoint` asks the state for no others.
+ADD_COLUMNS = (
+    "path", "partition_values", "size", "modification_time", "stats",
+    "deletion_vector", "base_row_id", "default_row_commit_version",
+    "clustering_provider",
+)
+
+
 def _file_struct_from_canonical(
     tbl: pa.Table,
     is_add: bool,
@@ -277,22 +289,28 @@ def _file_struct_from_canonical(
     false_col = pa.array(np.zeros(n, dtype=bool))
 
     def col(name):
-        return tbl.column(name).combine_chunks()
+        # `combine_chunks` copies even a column of one chunk, which is
+        # how `live_columns` hands a large state's over
+        column = tbl.column(name)
+        return (column.chunk(0) if column.num_chunks == 1
+                else column.combine_chunks())
 
     if is_add:
-        stats = col("stats")
+        (path, partition_values, size, modification_time, stats,
+         deletion_vector, base_row_id, default_row_commit_version,
+         clustering_provider) = map(col, ADD_COLUMNS)
         fields = list(ADD_STRUCT)
         children = [
-            col("path"),
-            col("partition_values"),
-            col("size"),
-            col("modification_time"),
+            path,
+            partition_values,
+            size,
+            modification_time,
             false_col,  # dataChange normalized to false in checkpoints
             stats if stats_as_json else pa.nulls(n, pa.string()),
-            col("deletion_vector"),
-            col("base_row_id"),
-            col("default_row_commit_version"),
-            col("clustering_provider"),
+            deletion_vector,
+            base_row_id,
+            default_row_commit_version,
+            clustering_provider,
         ]
         if stats_as_struct:
             parsed = _parse_stats_structs(stats, stats_schema)
@@ -578,7 +596,10 @@ def _write_checkpoint(engine, snapshot, policy: Optional[str],
         txn_min = ((now_ms - txn_retention) if txn_retention is not None
                    else None)
 
-        adds = state.add_files_table
+        # the live rows of the columns read, and no live table: a
+        # large state's are filtered on the scan pool, which this
+        # thread waits for here as it does in `checkpoint.serialize`
+        adds = state.live_columns(list(ADD_COLUMNS))
         tombs = _retained_tombstones(state, now_ms, retention)
         stats_schema = (_stats_parsed_schema(
             state.metadata.schema, meta_conf,

@@ -433,24 +433,12 @@ def _stitch(template: list, groups: List[List[_Piece]],
     return b"".join(parts)
 
 
-def _settled(futures: List[Future]) -> list:
-    """Every task's result once ALL have ended; the first error after
-    that (`write/ckpt_pipeline.py::_run_serial`'s rule: whoever cleans
-    up after the error must not race a task still running)."""
-    results, first = [], None
-    for f in futures:
-        try:
-            results.append(f.result())
-        except BaseException as e:
-            results.append(None)
-            first = first or e
-    if first is not None:
-        raise first
-    return results
-
-
 def _dealt(table: pa.Table, group_rows: int) -> Tuple[bytes, dict]:
-    from delta_tpu.utils.threads import default_scan_threads, scan_pool
+    from delta_tpu.utils.threads import (
+        default_scan_threads,
+        scan_pool,
+        settled,
+    )
 
     # the zero-row encode first: where this pyarrow's footer holds what
     # is not carried, no piece is encoded in vain
@@ -466,7 +454,7 @@ def _dealt(table: pa.Table, group_rows: int) -> Tuple[bytes, dict]:
     futures: List[Optional[Future]] = [None] * len(tasks)
     for i in order:
         futures[i] = pool.submit(run, tasks[i])
-    flat = iter(_settled(futures))           # type: ignore[arg-type]
+    flat = iter(settled(futures))           # type: ignore[arg-type]
     encoded = [[next(flat) for _ in pieces] for pieces in groups]
     with obs.span("serialize.stitch") as sp:
         data = _stitch(template, groups, encoded, table.num_rows)

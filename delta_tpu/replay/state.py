@@ -16,6 +16,7 @@ and baseline.
 
 from __future__ import annotations
 
+import itertools
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, Optional
@@ -43,6 +44,8 @@ from delta_tpu.replay.path_codes import first_appearance_codes
 # fallback counter for the replay route (route-contract lint)
 _ROUTE_FALLBACKS = obs.counter("replay.resident_fallbacks")
 _LIVE_TABLE_BUILDS = obs.counter("state.live_table_builds")
+_LIVE_COLUMNS_DEALT = obs.counter("state.live_columns_dealt")
+_LIVE_COLUMNS_SERIAL = obs.counter("state.live_columns_serial")
 
 
 @dataclass
@@ -161,8 +164,24 @@ class SnapshotState:
 
     def live_columns(self, names) -> pa.Table:
         """Columns `names` of `add_files_table`, filtered out of the
-        rows held without building the rest."""
-        return _filter_rows(self.file_actions.select(names), self.live_mask)
+        rows held without building the rest: same rows, same order,
+        same types. Under the line `_deal_small` draws it is the one
+        filter on the calling thread; over it the columns are dealt
+        over `scan_pool()` (`_dealt_live_columns`) and each comes back
+        as one contiguous array. Not to be called from a task of that
+        pool: the caller waits here for it."""
+        table = self.file_actions.select(names)
+        with obs.span("state.filter_live", rows=table.num_rows,
+                      columns=table.num_columns, **{"as": "columns"}) as sp:
+            if _deal_small(table.num_rows, table.num_columns):
+                live = _filter_rows(table, self.live_mask)
+                how = {"tasks": 1, "threads": 1, "serial_reason": "small"}
+                _LIVE_COLUMNS_SERIAL.inc()
+            else:
+                live, how = _dealt_live_columns(table, self.live_mask)
+                _LIVE_COLUMNS_DEALT.inc()
+            sp.set_attrs(live_rows=live.num_rows, **how)
+        return live
 
     def live_subset(self, keep: np.ndarray) -> pa.Table:
         """`add_files_table.filter(keep)` read straight out of the rows
@@ -778,6 +797,168 @@ def _filter_rows(table: pa.Table, mask: np.ndarray) -> pa.Table:
     sliced = pa.Table.from_batches(table.to_batches(max_chunksize=rows),
                                    schema=table.schema)
     return sliced.filter(pa.array(mask))
+
+
+# `live_columns` deals its filter from this many rows held x columns
+# asked for: `log/parquet_stitch.py::small`'s own line, 100,000 rows of
+# six columns, so 66,667 rows of a checkpoint's nine. Measured on the
+# chip's host (13 cores; a state in the benchmark's shapes, the nine
+# columns, four of them null throughout; one call / dealt in ms,
+# medians of 25, the state in one chunk and in 41; PERF.md §6, PR 58):
+# 1.8 / 1.5 and 6.1 / 6.9 at 25,000 rows, 3.2 / 1.7 and 8.3 / 6.3 at
+# 50,000, 6.1 / 2.4 and 12.0 / 7.3 at 100,000, 30 / 11 and 36 / 13 at
+# 400,000, 176 / 36 and 174 / 38 at 2.44M. Under the line a deal saves
+# a millisecond or two at best and loses as much on a state in many
+# chunks.
+_DEAL_MIN_CELLS = 600_000
+# A task filters about this much of one column, a wide column cut into
+# ranges of rows: far under `_FILTER_SLICE_BYTES`, so the rule
+# `_filter_rows` keeps for a column past 1 GiB (no filter sizes a
+# string buffer it may double from more than ~256 MiB) holds by
+# construction.
+_DEAL_PIECE_BYTES = 16 << 20
+# ... or this many rows, where that is less: a list's or a struct's
+# filter costs by the row, whatever the bytes (an empty map a row,
+# 10 MB over 2.4M rows, takes as long as 60 MB of strings)
+_DEAL_PIECE_ROWS = 1 << 19
+
+
+def _deal_small(rows: int, columns: int) -> bool:
+    return rows * columns < _DEAL_MIN_CELLS
+
+
+def _filter_piece(name: str, col: pa.ChunkedArray, keep: pa.Array,
+                  lo: int, hi: int) -> list:
+    """The kept rows of `col[lo:hi]`, chunk by chunk as they lie."""
+    with obs.span("filter_live.piece", column=name, lo=lo,
+                  rows=hi - lo) as sp:
+        piece = col.slice(lo, hi - lo)
+        if sp.recording:
+            sp.set_attr("bytes", piece.nbytes)
+        return piece.filter(keep.slice(lo, hi - lo)).chunks
+
+
+def _concat_pieces(name: str, chunks: list) -> pa.Array:
+    with obs.span("filter_live.concat", column=name, chunks=len(chunks)):
+        return pa.concat_arrays(chunks)
+
+
+def _string_offsets(chunk: pa.Array) -> np.ndarray:
+    return np.frombuffer(chunk.buffers()[1], np.int32, len(chunk) + 1,
+                         chunk.offset * 4)
+
+
+def _copy_strings(name: str, chunks: list, offsets: np.ndarray,
+                  data: np.ndarray, row: int, at: int) -> None:
+    """`chunks`, neighbours, written behind one another from row `row`
+    and byte `at` of the array being made: their values' bytes as they
+    lie, their offsets moved to where those land."""
+    with obs.span("filter_live.concat", column=name, chunks=len(chunks),
+                  row=row) as sp:
+        start = at
+        for chunk in chunks:
+            ends = _string_offsets(chunk)
+            first, n = int(ends[0]), int(ends[-1]) - int(ends[0])
+            np.add(ends[1:], at - first,
+                   out=offsets[row + 1:row + len(chunk) + 1])
+            if n:
+                data[at:at + n] = np.frombuffer(chunk.buffers()[2], np.uint8,
+                                                n, first)
+            row, at = row + len(chunk), at + n
+        sp.set_attr("bytes", at - start)
+
+
+def _concat_tasks(pool, name: str, chunks: list) -> tuple:
+    """The tasks that make a column's kept chunks one array, and what
+    gives the array once they have ended. One `pa.concat_arrays` as a
+    rule. That is one thread's copy of the whole column, and on a
+    table of millions the stats strings' is the longest step of the
+    hand-out by far: a `string` or `binary` column with no null among
+    the rows kept, of two pieces' bytes or more, is laid out by hand,
+    every run of chunks of about a piece copied into its place by a
+    task of its own (numpy's copies let go of the interpreter's lock;
+    PERF.md §6, PR 58: 244 MB of stats in 15 copies of 3.7 ms)."""
+    typ = chunks[0].type
+    if ((pa.types.is_string(typ) or pa.types.is_binary(typ))
+            and not any(c.null_count for c in chunks)):
+        sizes = [int(e[-1]) - int(e[0]) for e in map(_string_offsets, chunks)]
+        total, rows = sum(sizes), sum(map(len, chunks))
+        if 2 * _DEAL_PIECE_BYTES <= total < 1 << 31:
+            offsets = pa.allocate_buffer(4 * (rows + 1))
+            data = pa.allocate_buffer(total)
+            out_offsets = np.frombuffer(offsets, np.int32)
+            out_offsets[0] = 0
+            out_data = np.frombuffer(data, np.uint8)
+            # where each chunk lands; a run: the chunks that start in
+            # the same piece's worth of bytes
+            at = np.cumsum([0] + sizes[:-1])
+            row = np.cumsum([0] + [len(c) for c in chunks[:-1]])
+            copy = obs.wrap(_copy_strings)
+            tasks = [pool.submit(copy, name, chunks[run[0]:run[-1] + 1],
+                                 out_offsets, out_data, int(row[run[0]]),
+                                 int(at[run[0]]))
+                     for run in (list(g) for _, g in itertools.groupby(
+                         range(len(chunks)),
+                         key=lambda k: at[k] // _DEAL_PIECE_BYTES))]
+            return tasks, lambda: pa.Array.from_buffers(
+                typ, rows, [None, offsets, data])
+    task = pool.submit(obs.wrap(_concat_pieces), name, chunks)
+    return [task], task.result
+
+
+def _dealt_live_columns(table: pa.Table, mask: np.ndarray):
+    """`table.filter(mask)` with every column one contiguous array, and
+    how it was made, for the span. A column that is null on every row
+    held is not filtered: it is as many nulls as rows are kept. The
+    others are filtered on `scan_pool()`, a task a column and, of a wide
+    column, a range of rows, the heaviest first (it sets the pace, so it
+    must not queue); then each column's kept chunks are made one array
+    there (`_concat_tasks`). The same Arrow kernels over the same rows
+    as the one call's, so the same values; a `string` column whose kept
+    values pass 2 GiB raises Arrow's "offset overflow" here, as the
+    checkpoint writer's `combine_chunks` of it did."""
+    from delta_tpu.utils.threads import (
+        default_scan_threads,
+        scan_pool,
+        settled,
+    )
+
+    rows, live = table.num_rows, int(np.count_nonzero(mask))
+    keep, names, pool = pa.array(mask), table.column_names, scan_pool()
+    filt = obs.wrap(_filter_piece)
+    cuts, weight = {}, {}   # column -> its ranges' bounds; a range's bytes
+    for i, col in enumerate(table.columns):
+        if col.null_count < rows:
+            nbytes = col.nbytes
+            n = max(-(-nbytes // _DEAL_PIECE_BYTES),
+                    -(-rows // _DEAL_PIECE_ROWS))
+            cuts[i] = [rows * k // n for k in range(n + 1)]
+            weight[i] = nbytes // n
+    pieces = sorted(((i, lo, hi) for i, at in cuts.items()
+                     for lo, hi in zip(at, at[1:])),
+                    key=lambda p: -weight[p[0]])
+    filters = {(i, lo): pool.submit(filt, names[i], table.column(i), keep,
+                                    lo, hi) for i, lo, hi in pieces}
+    # while the pool filters: the columns that need no filter
+    columns = {i: pa.nulls(live, field.type)
+               for i, field in enumerate(table.schema) if i not in cuts}
+    null_columns = len(columns)
+    settled(filters.values())
+    joins = {}
+    for i, at in cuts.items():
+        chunks = [c for lo in at[:-1] for c in filters[i, lo].result()]
+        if len(chunks) > 1:
+            joins[i] = _concat_tasks(pool, names[i], chunks)
+        else:               # one chunk, or no row kept
+            columns[i] = (chunks[0] if chunks
+                          else pa.nulls(0, table.schema.field(i).type))
+    settled([task for tasks, _ in joins.values() for task in tasks])
+    columns.update((i, array()) for i, (_, array) in joins.items())
+    return pa.Table.from_arrays([columns[i] for i in range(len(names))],
+                                schema=table.schema), {
+        "null_columns": null_columns,
+        "tasks": len(filters) + sum(len(t) for t, _ in joins.values()),
+        "threads": default_scan_threads()}
 
 
 # A gather copies only the rows it keeps, a filter walks every row held:

@@ -108,6 +108,22 @@ def scan_pool() -> DeltaThreadPool:
     return _SCAN
 
 
+def settled(futures: Iterable[Future]) -> list:
+    """Every task's result once ALL have ended; the first error after
+    that (`write/ckpt_pipeline.py::_run_serial`'s rule: whoever cleans
+    up after the error must not race a task still running)."""
+    results, first = [], None
+    for f in futures:
+        try:
+            results.append(f.result())
+        except BaseException as e:
+            results.append(None)
+            first = first or e
+    if first is not None:
+        raise first
+    return results
+
+
 def parallel_map(fn: Callable[[T], R], items: Sequence[T],
                  min_parallel: int = 8) -> List[R]:
     """Ordered parallel map over an I/O-bound function; falls back to a
